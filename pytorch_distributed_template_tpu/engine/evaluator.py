@@ -18,6 +18,7 @@ from ..data.loader import prefetch_to_device
 from ..models.base import inject_mesh
 from ..observability.trace import span
 from ..parallel import batch_sharding, dist, mesh_from_config
+from ..utils.util import write_json
 from .losses import resolve_loss
 from .optim import build_optimizer
 from .state import create_sharded_train_state
@@ -282,4 +283,11 @@ def evaluate(config, mesh=None, save_outputs=None, seed=None) -> dict:
     result = finalize_metrics(jax.tree.map(float, accum)) if accum else {}
     if dist.is_main_process():
         logger.info({"n_samples": n_samples, **result})
+        # machine-readable twin of the trainer's summary.json
+        write_json(
+            {"n_samples": n_samples, **result,
+             "checkpoint": str(config.resume),
+             "device": dist.device_summary()},
+            config.save_dir / "summary.json",
+        )
     return result

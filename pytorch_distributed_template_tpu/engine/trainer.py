@@ -264,6 +264,7 @@ class BaseTrainer:
                     else None
                 ),
                 "run_dir": str(self.config.save_dir),
+                "device": dist.device_summary(),
             }
             (self.config.save_dir / "summary.json").write_text(
                 json.dumps(summary, indent=2)
@@ -709,6 +710,30 @@ class Trainer(BaseTrainer):
                             or config["trainer"].get("heartbeat_file")),
         )
 
+    def _step_program_facts(self, batch) -> dict:
+        """Where the first batch and the params really live, and what
+        the compiled step holds — on the first flight record, so a
+        multi-chip run can be checked from its telemetry (code that has
+        only ever seen one chip may put everything on the first)."""
+        def n_devices(tree):
+            return len({s.device for leaf in jax.tree.leaves(tree)
+                        for s in leaf.addressable_shards})
+
+        facts = {"batch_devices": n_devices(batch),
+                 "param_devices": n_devices(self.state.params)}
+        compiled = (self._warmup.result("train_step")
+                    if self._warmup is not None else None)
+        if compiled is not None:
+            try:
+                text = compiled.as_text()
+            except Exception as e:  # noqa: BLE001 — say so, keep training
+                facts["hlo"] = f"unavailable: {e}"
+            else:
+                facts["all_reduce"] = (text.count("all-reduce(")
+                                       + text.count("all-reduce-start("))
+                facts["tpu_custom_call"] = text.count("tpu_custom_call")
+        return facts
+
     def _metric_keys(self):
         return ["loss_sum", "count"] + [
             f"{m.__name__}_sum" for m in self.metric_ftns
@@ -908,6 +933,7 @@ class Trainer(BaseTrainer):
                             self._train_anatomy = analyze_compiled(
                                 compiled)
                 jax.block_until_ready(m)
+                rec["step_program"] = self._step_program_facts(batch)
                 self.throughput.reset()  # exclude compilation from rates
                 self.epoch_meter.reset()
 

@@ -31,7 +31,7 @@ from jax.sharding import PartitionSpec as P
 from ..config.registry import MODELS
 from ..ops.attention import (
     grouped_query_attention, multihead_attention, ring_attention,
-    ulysses_attention, zigzag_perm,
+    sharded_flash_attention, ulysses_attention, zigzag_perm,
 )
 
 
@@ -174,10 +174,9 @@ class LlamaAttention(nn.Module):
                     window=self.window,
                 )
             elif self.attn_impl == "flash":
-                from ..ops.flash import flash_attention
-
-                ctx = flash_attention(q, k, v, causal=True,
-                                      window=self.window)
+                ctx = sharded_flash_attention(q, k, v, self.mesh,
+                                              causal=True,
+                                              window=self.window)
             else:
                 ctx = multihead_attention(q, k, v, causal=True,
                                           window=self.window)

@@ -29,7 +29,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..config.registry import MODELS
 from ..ops.attention import (
-    multihead_attention, ring_attention, ulysses_attention, zigzag_perm,
+    multihead_attention, ring_attention, sharded_flash_attention,
+    ulysses_attention, zigzag_perm,
 )
 
 
@@ -123,8 +124,8 @@ class SelfAttention(nn.Module):
                 ),
             )
         elif self.attn_impl == "flash":
-            from ..ops.flash import flash_attention
-            ctx = flash_attention(q, k, v, causal=self.causal)
+            ctx = sharded_flash_attention(q, k, v, self.mesh,
+                                          causal=self.causal)
         else:
             ctx = multihead_attention(q, k, v, causal=self.causal)
         ctx = ctx.reshape(b, t, self.d_model)

@@ -63,10 +63,7 @@ def executable_flops(compiled) -> Optional[float]:
     XLA's cost analysis (post-fusion). Returns None when the backend
     doesn't report."""
     try:
-        cost = compiled.cost_analysis()
-        if isinstance(cost, (list, tuple)):  # older jax returns [dict]
-            cost = cost[0] if cost else {}
-        flops = cost.get("flops")
+        flops = compiled.cost_analysis().get("flops")
         return float(flops) if flops else None
     except Exception:
         return None

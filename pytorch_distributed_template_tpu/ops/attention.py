@@ -47,10 +47,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.compat import shard_map
 
 NEG_INF = -1e30
 
@@ -534,6 +532,32 @@ def _sp_partition(mesh: Mesh, q, seq_axis: str, data_axes, head_axis):
     if hp is not None and q.shape[2] % mesh.shape[hp] != 0:
         hp = None
     return dp, hp, P(dp if dp else None, seq_axis, hp, None)
+
+
+def sharded_flash_attention(q, k, v, mesh: Optional[Mesh],
+                            causal: bool = True, window: int = 0,
+                            data_axes=("data", "fsdp"),
+                            head_axis: str = "tensor"):
+    """The Pallas flash kernel under a multi-device mesh.
+
+    The TPU compiler refuses to partition a Mosaic kernel on its own
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map"), so with more than one device the call
+    runs inside ``shard_map``: batch over the data axes, heads over
+    ``tensor``. Attention is independent per (batch row, head), so the
+    body needs no collective. One device (or no mesh) calls the kernel
+    directly.
+    """
+    from .flash import flash_attention
+
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal=causal, window=window)
+    _, _, spec = _sp_partition(mesh, q, None, data_axes, head_axis)
+    fn = functools.partial(flash_attention, causal=causal, window=window)
+    return shard_map(
+        fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False,
+    )(q, k, v)
 
 
 def _ulysses_local(q, k, v, *, axis_name: str, axis_size: int,

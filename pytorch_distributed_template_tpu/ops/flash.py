@@ -585,10 +585,9 @@ _flash_3d_lse.defvjp(_flash_3d_lse_fwd, _flash_3d_lse_bwd)
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+    # a backend that fails to initialize raises here: answering False
+    # would quietly turn a broken chip into interpret mode
+    return jax.devices()[0].platform == "tpu"
 
 
 # ---------------------------------------------------------------------------

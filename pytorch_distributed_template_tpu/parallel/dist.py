@@ -96,6 +96,16 @@ def global_device_count() -> int:
     return jax.device_count()
 
 
+def device_summary() -> dict:
+    """What the run actually ran on, as jax reports it — written into
+    every ``summary.json`` so a result can never be read as a device
+    number it is not."""
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind,
+            "count": len(devices)}
+
+
 def synchronize(name: str = "sync") -> None:
     """Barrier across hosts. Reference: ``synchronize`` (utils/dist.py:7-15).
 

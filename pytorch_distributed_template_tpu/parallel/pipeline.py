@@ -39,10 +39,8 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from ..utils.compat import shard_map
 
 
 def pipeline_apply(stage_fn: Callable, stage_params, microbatches,

@@ -5,13 +5,23 @@ process historically paid the full trace+compile on step 1 of every run
 (engine/steps.instrument_step labels it ``<name>/compile``) and the
 serving engine recompiled its ladder on every restart. jax ships a
 content-addressed on-disk executable cache behind
-``jax_compilation_cache_dir``; this module is the one place that turns
-it on from a config section so every entrypoint (train.py, test.py,
-serve.py, generate.py, bench.py) behaves identically:
+``jax_compilation_cache_dir``; this module is the one place that
+decides where it lives, so every entrypoint (train.py, test.py,
+serve.py, generate.py, bench.py) behaves identically.
+
+The rule, in this order:
+
+(a) ``JAX_COMPILATION_CACHE_DIR`` set in the environment: that
+    directory is used and nothing in the repo sets another.
+(b) else a directory given explicitly — the ``cache_dir`` argument, or
+    the config section below — is used.
+(c) else the cache is ON at ``DEFAULT_CACHE_DIR``: one fixed path
+    inside the checkout. The path is part of the cache key's
+    neighbourhood (a directory that moves never hits), so it is never
+    built from a temporary name, a pid or the time.
 
     "compile_cache": {
-        "dir": "~/.cache/pdt-xla-cache",   // enables the cache
-        "enabled": true,                    // default true when dir set
+        "dir": "/some/fixed/path",          // rule (b)
         "min_compile_time_secs": 0.0,       // cache everything (jax
                                             // defaults to 1.0 — small
                                             // executables skipped)
@@ -28,21 +38,20 @@ the bench ``warm_start`` rung. Note jax's ``backend_compile_duration``
 monitoring event fires on hits AND misses (it wraps
 ``compile_or_get_cached``), so the cache events are the only honest
 "was that a real compile?" signal.
-
-The env var ``JAX_COMPILATION_CACHE_DIR`` (jax's own spelling) still
-works and is never clobbered by a config without a ``compile_cache``
-section.
 """
 from __future__ import annotations
 
 import logging
 import os
+from pathlib import Path
 from typing import Optional
 
 logger = logging.getLogger(__name__)
 
 
 DEFAULT_MAX_SIZE_BYTES = 4 << 30    # 4 GiB LRU bound (jax: unbounded)
+# rule (c): <checkout>/.cache/xla (listed in .gitignore)
+DEFAULT_CACHE_DIR = str(Path(__file__).resolve().parents[2] / ".cache" / "xla")
 
 
 def configure_compile_cache(config=None, cache_dir: Optional[str] = None,
@@ -50,15 +59,14 @@ def configure_compile_cache(config=None, cache_dir: Optional[str] = None,
                             min_entry_size_bytes: Optional[int] = None,
                             max_size_bytes: Optional[int] = None,
                             ) -> Optional[str]:
-    """Enable the persistent compilation cache from a config section
-    and/or explicit overrides; returns the active cache dir (None when
-    the cache stays off).
+    """Enable the persistent compilation cache by the module's rule;
+    returns the active cache dir (None only when the directory cannot
+    be used).
 
     ``config`` is a ConfigParser or plain dict; its ``compile_cache``
-    section is read as documented above. Explicit kwargs win over the
-    section (bench.py passes ``--compile-cache-dir`` directly). With
-    neither, any value jax already holds (e.g. from
-    ``JAX_COMPILATION_CACHE_DIR``) is left untouched and returned.
+    section is read as documented above. An explicit ``cache_dir``
+    wins over the section (bench.py passes ``--compile-cache-dir``
+    directly); the environment variable wins over both.
 
     Never raises: a bad cache dir degrades to an uncached run with a
     warning — compile caching is an optimization, not a dependency.
@@ -69,8 +77,6 @@ def configure_compile_cache(config=None, cache_dir: Optional[str] = None,
             section = dict(config.get("compile_cache", None) or {})
         except Exception:
             section = {}
-    if cache_dir is None and section.get("enabled", True):
-        cache_dir = section.get("dir")
     if min_compile_time_secs is None:
         min_compile_time_secs = section.get("min_compile_time_secs", 0.0)
     if min_entry_size_bytes is None:
@@ -79,20 +85,16 @@ def configure_compile_cache(config=None, cache_dir: Optional[str] = None,
         max_size_bytes = section.get("max_size_bytes",
                                      DEFAULT_MAX_SIZE_BYTES)
 
-    # counters must exist even when the cache is configured via env var
-    # only — the listener is idempotent and cheap
+    # counters must exist however the cache was configured — the
+    # listener is idempotent and cheap
     from ..observability.telemetry import _install_compile_listener
 
     _install_compile_listener()
 
-    try:
-        import jax
-    except Exception:  # pragma: no cover — jax is a hard dep everywhere
-        return None
+    import jax
 
-    if cache_dir is None:
-        # nothing to set; report what jax already has (env var path)
-        return jax.config.jax_compilation_cache_dir
+    cache_dir = (os.environ.get("JAX_COMPILATION_CACHE_DIR")
+                 or cache_dir or section.get("dir") or DEFAULT_CACHE_DIR)
 
     try:
         cache_dir = os.path.abspath(os.path.expanduser(str(cache_dir)))
@@ -110,16 +112,13 @@ def configure_compile_cache(config=None, cache_dir: Optional[str] = None,
         # own default is -1 = grow forever)
         jax.config.update("jax_compilation_cache_max_size",
                           int(max_size_bytes))
-        try:
-            # jax memoizes the is-cache-used decision at the FIRST
-            # compile of the process; enabling the dir after any
-            # compile has happened (tests, notebooks, late config)
-            # silently does nothing until that memo is cleared
-            from jax._src import compilation_cache
+        # jax memoizes the is-cache-used decision at the FIRST compile
+        # of the process; enabling the dir after any compile has
+        # happened (tests, notebooks, late config) silently does
+        # nothing until that memo is cleared
+        from jax.experimental.compilation_cache import compilation_cache
 
-            compilation_cache.reset_cache()
-        except Exception:
-            pass
+        compilation_cache.reset_cache()
         logger.info("persistent compilation cache: %s", cache_dir)
         return cache_dir
     except Exception as e:  # noqa: BLE001 — never fail an entrypoint

@@ -96,12 +96,6 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-if os.environ.get("JAX_PLATFORMS"):
-    # Same platform-override dance as train.py/generate.py.
-    import jax
-
-    jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-
 from pytorch_distributed_template_tpu.config import ConfigParser  # noqa: E402
 import pytorch_distributed_template_tpu.data  # noqa: F401,E402
 import pytorch_distributed_template_tpu.engine  # noqa: F401,E402

@@ -4,9 +4,8 @@ imports cleanly.
 A jax API move (e.g. ``shard_map`` leaving ``jax.experimental``) used to
 surface as 24 separate test-collection errors, each pointing at a test
 file instead of the import that actually broke. This test walks the
-package and imports every module, so version-compat breakage shows up
-as ONE failure naming the offending module — and the fix belongs in
-``utils/compat.py``, the shared shim.
+package and imports every module, so breakage against the installed jax
+shows up as ONE failure naming the offending module.
 """
 import importlib
 import pkgutil
@@ -30,7 +29,7 @@ def test_package_has_expected_surface():
         "pytorch_distributed_template_tpu.parallel.pipeline",
         "pytorch_distributed_template_tpu.observability.telemetry",
         "pytorch_distributed_template_tpu.observability.trace",
-        "pytorch_distributed_template_tpu.utils.compat",
+        "pytorch_distributed_template_tpu.utils.compile_cache",
     ):
         assert expected in MODULES
 

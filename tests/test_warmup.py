@@ -180,7 +180,25 @@ def test_abstract_batch_matches_loader_and_transform():
 # ---------------------------------------------------------------------------
 
 
-def test_persistent_cache_roundtrip(tmp_path):
+@pytest.fixture
+def cache_config(monkeypatch):
+    """Restore jax's cache settings after a test that moved them, and
+    start from the rule's case (c): no env var."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes",
+             "jax_compilation_cache_max_size")
+    old = {n: getattr(jax.config, n) for n in names}
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    yield
+    for n, v in old.items():
+        jax.config.update(n, v)
+    compilation_cache.reset_cache()   # detach from the test's dir
+
+
+def test_persistent_cache_roundtrip(tmp_path, cache_config):
     """With ``compile_cache`` pointed at a temp dir, compiling an
     identical function a second time (fresh jit wrapper, so the
     in-memory jit cache cannot serve it) emits a cache HIT and no new
@@ -192,53 +210,94 @@ def test_persistent_cache_roundtrip(tmp_path):
         configure_compile_cache,
     )
 
-    old_dir = jax.config.jax_compilation_cache_dir
-    old_min_t = jax.config.jax_persistent_cache_min_compile_time_secs
-    old_min_b = jax.config.jax_persistent_cache_min_entry_size_bytes
-    try:
-        active = configure_compile_cache(
-            {"compile_cache": {"dir": str(tmp_path / "xla-cache")}})
-        assert active == str(tmp_path / "xla-cache")
-        assert compile_cache_stats()["enabled"]
+    active = configure_compile_cache(
+        {"compile_cache": {"dir": str(tmp_path / "xla-cache")}})
+    assert active == str(tmp_path / "xla-cache")
+    assert compile_cache_stats()["enabled"]
 
-        def make():
-            def g(x):
-                return jnp.tanh(x) @ x.T + 0.317
-            return jax.jit(g)
+    def make():
+        def g(x):
+            return jnp.tanh(x) @ x.T + 0.317
+        return jax.jit(g)
 
-        x = jnp.ones((16, 16))
-        before = compile_cache_stats()
-        make()(x).block_until_ready()
-        mid = compile_cache_stats()
-        assert mid["misses"] > before["misses"]   # cold: real compiles
-        drain_compile_events()
+    x = jnp.ones((16, 16))
+    before = compile_cache_stats()
+    make()(x).block_until_ready()
+    mid = compile_cache_stats()
+    assert mid["misses"] > before["misses"]   # cold: real compiles
+    drain_compile_events()
 
-        make()(x).block_until_ready()             # identical fn, new jit
-        after = compile_cache_stats()
-        assert after["misses"] == mid["misses"]   # NO new compile
-        assert after["hits"] > mid["hits"]        # served from disk
-        events = [e["event"] for e in drain_compile_events()]
-        assert any(e.endswith("cache_hits") for e in events)
-        assert not any(e.endswith("cache_misses") for e in events)
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", old_min_t)
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes", old_min_b)
-        from jax._src import compilation_cache
-
-        compilation_cache.reset_cache()   # detach from the tmp dir
+    make()(x).block_until_ready()             # identical fn, new jit
+    after = compile_cache_stats()
+    assert after["misses"] == mid["misses"]   # NO new compile
+    assert after["hits"] > mid["hits"]        # served from disk
+    events = [e["event"] for e in drain_compile_events()]
+    assert any(e.endswith("cache_hits") for e in events)
+    assert not any(e.endswith("cache_misses") for e in events)
 
 
-def test_configure_compile_cache_noop_without_section():
-    """No ``compile_cache`` section -> jax's current value is reported,
-    nothing changes, nothing raises."""
+def test_configure_compile_cache_noop_without_section(cache_config):
+    """Rule (c): no env var, no ``compile_cache`` section, no argument
+    -> the cache is ON at the one fixed path inside the checkout."""
+    from pathlib import Path
+
+    from pytorch_distributed_template_tpu.utils.compile_cache import (
+        DEFAULT_CACHE_DIR, configure_compile_cache,
+    )
+
+    repo = Path(__file__).resolve().parent.parent
+    assert DEFAULT_CACHE_DIR == str(repo / ".cache" / "xla")
+    assert configure_compile_cache({}) == DEFAULT_CACHE_DIR
+    assert configure_compile_cache(None) == DEFAULT_CACHE_DIR
+    assert jax.config.jax_compilation_cache_dir == DEFAULT_CACHE_DIR
+
+
+def test_compile_cache_env_var_wins(tmp_path, cache_config, monkeypatch):
+    """Rule (a): with ``JAX_COMPILATION_CACHE_DIR`` set, neither a
+    config section nor an explicit argument sets another directory."""
     from pytorch_distributed_template_tpu.utils.compile_cache import (
         configure_compile_cache,
     )
 
-    old = jax.config.jax_compilation_cache_dir
-    assert configure_compile_cache({}) == old
-    assert configure_compile_cache(None) == old
-    assert jax.config.jax_compilation_cache_dir == old
+    env_dir = str(tmp_path / "from-env")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    section = {"compile_cache": {"dir": str(tmp_path / "from-config")}}
+    assert configure_compile_cache(section) == env_dir
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert configure_compile_cache(
+        section, cache_dir=str(tmp_path / "from-arg")) == env_dir
+    assert jax.config.jax_compilation_cache_dir == env_dir
+    assert not (tmp_path / "from-config").exists()
+    assert not (tmp_path / "from-arg").exists()
+
+
+def test_compile_cache_default_independent_of_cwd(tmp_path, cache_config,
+                                                  monkeypatch):
+    """Rule (c) names the same directory from any working directory (a
+    cache that moves with the cwd never hits)."""
+    from pytorch_distributed_template_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    seen = []
+    for name in ("a", "b"):
+        (tmp_path / name).mkdir()
+        monkeypatch.chdir(tmp_path / name)
+        seen.append(configure_compile_cache({}))
+    assert seen[0] == seen[1]
+    assert not seen[0].startswith(str(tmp_path))
+
+
+def test_no_shipped_config_sets_a_cache_dir():
+    """The rule lives in utils/compile_cache.py alone: no file under
+    configs/ names a cache directory of its own."""
+    import json
+    from pathlib import Path
+
+    configs = sorted(
+        (Path(__file__).resolve().parent.parent / "configs").glob("*.json"))
+    assert configs
+    offenders = [p.name for p in configs
+                 if "dir" in (json.loads(p.read_text())
+                              .get("compile_cache") or {})]
+    assert offenders == []
