@@ -217,20 +217,21 @@ class Smoke:
     def check_device(self, device, count):
         if self.device is None:
             self.device = device
-        on_tpu = device["platform"] == "tpu"
-        if not (self.args.rehearse and not on_tpu):
-            check(on_tpu, f"ran on {device['platform']!r}, not on a TPU")
-            check(device["count"] == count,
-                  f"{device['count']} devices visible, this run needs {count}")
+        if self.args.rehearse and device["platform"] != "tpu":
+            return
+        check(device["platform"] == "tpu",
+              f"ran on {device['platform']!r}, not on a TPU")
+        check(device["count"] == count,
+              f"{device['count']} devices visible, this run needs {count}")
 
-    def derive_config(self, name, global_batch=None, steps=STEPS):
+    def derive_config(self, name, global_batch=None):
         cfg = json.loads(Path(self.args.config).read_text())
         tl, vl = cfg["train_loader"]["args"], cfg["valid_loader"]["args"]
         if global_batch:
             tl["batch_size"] = vl["batch_size"] = global_batch
-        tl["n"] = tl["batch_size"] * steps
+        tl["n"] = tl["batch_size"] * STEPS
         vl["n"] = vl["batch_size"] * 4
-        cfg["trainer"].update(epochs=1, len_epoch=steps, save_period=1,
+        cfg["trainer"].update(epochs=1, len_epoch=STEPS, save_period=1,
                               save_dir=str(self.runs / name))
         path = self.out / f"{name}.json"
         path.write_text(json.dumps(cfg, indent=2))
@@ -455,8 +456,7 @@ class Smoke:
                 traceback.print_exc()
             self.emit({"phase": "failed", "error": f"{type(e).__name__}: {e}"})
         finally:
-            if not self.args.keep_runs:
-                shutil.rmtree(self.runs, ignore_errors=True)
+            shutil.rmtree(self.runs, ignore_errors=True)   # checkpoints
         print(json.dumps({"ok": ok, "device": self.device}), flush=True)
         return 0 if ok else 1
 
@@ -472,8 +472,6 @@ def main() -> int:
                    help="phase logs, derived configs, phases.jsonl")
     p.add_argument("--rehearse", action="store_true",
                    help="CPU dry run of the control flow; never ends in ok")
-    p.add_argument("--keep-runs", action="store_true",
-                   help="keep the run dirs (checkpoints) under .cache/")
     p.add_argument("--child", nargs="+", help=argparse.SUPPRESS)
     args = p.parse_args()
     if args.child:

@@ -729,8 +729,8 @@ class Trainer(BaseTrainer):
             except Exception as e:  # noqa: BLE001 — say so, keep training
                 facts["hlo"] = f"unavailable: {e}"
             else:
-                facts["all_reduce"] = (text.count("all-reduce(")
-                                       + text.count("all-reduce-start("))
+                facts["all_reduce"] = (text.count(" all-reduce(")
+                                       + text.count(" all-reduce-start("))
                 facts["tpu_custom_call"] = text.count("tpu_custom_call")
         return facts
 

@@ -535,9 +535,7 @@ def _sp_partition(mesh: Mesh, q, seq_axis: str, data_axes, head_axis):
 
 
 def sharded_flash_attention(q, k, v, mesh: Optional[Mesh],
-                            causal: bool = True, window: int = 0,
-                            data_axes=("data", "fsdp"),
-                            head_axis: str = "tensor"):
+                            causal: bool = True, window: int = 0):
     """The Pallas flash kernel under a multi-device mesh.
 
     The TPU compiler refuses to partition a Mosaic kernel on its own
@@ -552,7 +550,7 @@ def sharded_flash_attention(q, k, v, mesh: Optional[Mesh],
 
     if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal=causal, window=window)
-    _, _, spec = _sp_partition(mesh, q, None, data_axes, head_axis)
+    _, _, spec = _sp_partition(mesh, q, None, ("data", "fsdp"), "tensor")
     fn = functools.partial(flash_attention, causal=causal, window=window)
     return shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

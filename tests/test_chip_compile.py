@@ -34,14 +34,15 @@ def one_chip():
     compilation_cache.reset_cache()
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
-    except Exception as e:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
         jax.config.update("jax_enable_compilation_cache", cache_was)
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", cache_was)
-    compilation_cache.reset_cache()
+        compilation_cache.reset_cache()
 
 
 def _qkv(shape, sharding):
