@@ -268,6 +268,7 @@ class Smoke:
         seq = cfg["train_loader"]["args"]["seq_len"]
         count = summary["device"]["count"]
         ex_s = summary["examples_per_sec"]
+        median_wall = statistics.median(r["wall_ms"] for r in steps[1:])
         facts = {
             "run_dir": str(run),
             "device": summary["device"],
@@ -279,12 +280,18 @@ class Smoke:
             "tokens_per_sec": round(ex_s * seq, 1),
             "tokens_per_sec_per_chip": round(ex_s * seq / count, 1),
             "first_step_s": round(steps[0]["wall_ms"] / 1e3, 2),
-            # host-side view of the steady steps: a loop iteration's wall
-            # time (dispatch + backpressure) and its wait for input
-            "median_step_wall_ms": statistics.median(
-                r["wall_ms"] for r in steps[1:]),
+            # host clock per loop iteration. The epoch meter above spans
+            # only ~19 steps, and iterations 2-3 still pay one-time host
+            # work (seconds on the chip), so the steady rate is read from
+            # the median iteration instead: the loop runs at most a step
+            # ahead of the device (the health monitor fetches one step
+            # deferred), so in steady state an iteration lasts one step
+            "step_wall_ms": [round(r["wall_ms"]) for r in steps],
+            "median_step_wall_ms": median_wall,
             "median_data_wait_ms": statistics.median(
                 r["data_wait_ms"] for r in steps[1:]),
+            "steady_steps_per_sec": round(1e3 / median_wall, 3),
+            "steady_tokens_per_sec": round(batch * seq * 1e3 / median_wall, 1),
             "compile_s": round(sum(
                 e.get("dur_ms", 0) for e in events
                 if e["event"].endswith("backend_compile_duration")) / 1e3, 2),
@@ -307,8 +314,10 @@ class Smoke:
                              + m["d_model"] * m["vocab_size"])
             flops_per_token = (6 * matmul_params
                                + 6 * m["n_layer"] * seq * m["d_model"])
-            facts["mfu"] = round(flops_per_token * facts["tokens_per_sec"]
-                                 / (self.peak_flops * count), 4)
+            per_flop_s = flops_per_token / (self.peak_flops * count)
+            facts["mfu"] = round(per_flop_s * facts["tokens_per_sec"], 4)
+            facts["steady_mfu"] = round(
+                per_flop_s * facts["steady_tokens_per_sec"], 4)
             facts["mfu_peak_from"] = self.device["kind"]
         return run, facts
 
@@ -430,6 +439,10 @@ class Smoke:
             "tokens_per_sec_per_chip": [dp["tokens_per_sec_per_chip"],
                                         one["tokens_per_sec_per_chip"]],
             "scaling": round(dp["tokens_per_sec"] / one["tokens_per_sec"], 3),
+            "steady_tokens_per_sec": [dp["steady_tokens_per_sec"],
+                                      one["steady_tokens_per_sec"]],
+            "steady_scaling": round(dp["steady_tokens_per_sec"]
+                                    / one["steady_tokens_per_sec"], 3),
             "step_program": prog,
         })
 
