@@ -284,7 +284,11 @@ def hlo_collectives(hlo: str):
     bytes of their result shapes — the same evidence the MULTICHIP
     dryruns use (``ok=true`` alone cannot distinguish a real TP program
     from silent replication). Returns ``(counts, bytes)`` dicts keyed
-    by op name."""
+    by op name. Of a tuple-shaped collective only the first element's
+    bytes are counted (a floor, as the callers use it)."""
+    # the TPU compiler prints long tuple shapes with ``/*index=5*/``
+    # markers, whose ``=`` would end the ``[^=]*?`` scan below
+    hlo = re.sub(r"/\*.*?\*/", "", hlo)
     pat = re.compile(
         r"=\s*\(?\s*(\w+)\[([0-9,]*)\][^=]*?\s"
         r"(all-reduce|all-gather|reduce-scatter|all-to-all|"

@@ -386,3 +386,30 @@ def test_lora_trains_under_tp_and_fsdp_meshes(axes):
     )
     assert frozen == 0.0, "frozen base moved under the sharded step"
     assert lora > 0.0, "adapters did not train"
+
+
+def test_hlo_collectives_reads_tpu_tuple_shapes():
+    """The TPU compiler prints tuple-shaped collectives with
+    ``/*index=5*/`` markers; their ``=`` must not hide the instruction
+    (the v5e text of the 4-chip GPT-2 step lost 3 of its 4 all-reduces
+    that way). CPU-style and async lines still count."""
+    from pytorch_distributed_template_tpu.parallel.tp import hlo_collectives
+
+    text = "\n".join([
+        "  %all-reduce.153 = (f32[]{:T(128)}, f32[768]{0:T(1024)S(1)}, "
+        "f32[768]{0:T(1024)S(1)}, f32[768]{0:T(1024)S(1)}, "
+        "f32[768]{0:T(1024)S(1)}, /*index=5*/f32[768]{0:T(1024)S(1)}) "
+        "all-reduce(%a, %b, %c, %d, %e, %f), channel_id=1, "
+        "replica_groups=[1,4]<=[4], to_apply=%add",
+        "  %all-reduce.150 = bf16[50257,768]{1,0:T(8,128)(2,1)} "
+        "all-reduce(%fusion.2), channel_id=152, replica_groups=[1,4]<=[4], "
+        "use_global_device_ids=true, to_apply=%region",
+        "  %ars = (f32[128]{0}, f32[128]{0}) all-reduce-start(%x), "
+        "channel_id=3",
+        "  %ag = f32[8,64]{1,0} all-gather(%y), dimensions={0}",
+        "  %fusion.2 = bf16[50257,768]{1,0} fusion(%p), kind=kLoop",
+    ])
+    counts, nbytes = hlo_collectives(text)
+    assert counts == {"all-reduce": 3, "all-gather": 1}
+    assert nbytes["all-reduce"] == 4 + 50257 * 768 * 2 + 128 * 4
+    assert nbytes["all-gather"] == 8 * 64 * 4
