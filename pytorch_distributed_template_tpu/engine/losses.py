@@ -127,6 +127,7 @@ def fused_lm_cross_entropy(chunk: int = 256):
     if chunk <= 0:
         raise ValueError(f"chunk must be positive, got {chunk}")
 
+    @jax.named_scope("head_loss")   # the trace's head-and-loss share
     def loss(output, target):
         h, w = output                       # [B, T, D], [D, V]
         tm1 = h.shape[1] - 1

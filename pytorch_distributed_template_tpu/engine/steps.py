@@ -200,7 +200,9 @@ def make_train_step(model, tx, criterion: Callable,
             )
             count = mask.sum()
             metrics = {"loss_sum": loss_sum, "count": count}
-            metrics.update(micro_metrics(output, batch[target_key], mask))
+            with jax.named_scope("metrics"):
+                metrics.update(
+                    micro_metrics(output, batch[target_key], mask))
         else:
             # [B, ...] -> [k, B/k, ...]; B is static so this is shape-checked
             # at trace time.
@@ -215,6 +217,7 @@ def make_train_step(model, tx, criterion: Callable,
 
             micro = jax.tree.map(split, batch)
 
+            @jax.named_scope("grad_accum")
             def body(carry, mb):
                 stats, gsum, msum = carry
                 rng = jax.random.fold_in(dropout_rng, mb["_idx"])
@@ -223,7 +226,8 @@ def make_train_step(model, tx, criterion: Callable,
                     state.params, stats, mb, rng
                 )
                 m = {"loss_sum": loss_sum, "count": mask.sum()}
-                m.update(micro_metrics(output, mb[target_key], mask))
+                with jax.named_scope("metrics"):
+                    m.update(micro_metrics(output, mb[target_key], mask))
                 gsum = jax.tree.map(jnp.add, gsum, grads)
                 msum = jax.tree.map(jnp.add, msum, m)
                 return (new_stats, gsum, msum), None
@@ -252,108 +256,117 @@ def make_train_step(model, tx, criterion: Callable,
                 lambda g: g + poison.astype(g.dtype), grads
             )
 
-        # Normalize the summed gradients by the global valid count (matches
-        # grad-of-mean on the full batch exactly).
-        denom = jnp.maximum(count.astype(jnp.float32), 1.0)
-        grads = jax.tree.map(
-            lambda g: (g / denom).astype(g.dtype), grads
-        )
-
-        if trainable_patterns:
-            # Mirror the optimizer's ``trainable`` freeze (optim.py
-            # _trainable_only) on the gradients themselves: frozen leaves
-            # still produce real grads (only LoRADense's base kernels are
-            # stop_gradient-pruned in-graph — embeddings, norms, biases
-            # are not), and counting those soon-to-be-discarded grads in
-            # the global norm below would over-clip the surviving updates
-            # and misreport grad_norm. The mask is static (Python bools at
-            # trace time), so the zeroed branches fold away.
-            import re as _re
-
-            from ..parallel.sharding import path_str
-
-            pats = [_re.compile(p) for p in trainable_patterns]
-
-            def _freeze(path, g):
-                if any(p.search(path_str(path)) for p in pats):
-                    return g
-                return jnp.zeros_like(g)
-
-            grads = jax.tree_util.tree_map_with_path(_freeze, grads)
-
-        # hold the PRE-CLIP gradients for the health summary, AFTER the
-        # normalize/freeze transforms: clipping can smear one NaN over
-        # every group (NaN global norm -> NaN scale), destroying the
-        # per-module attribution the dump exists for, while capturing
-        # after the freeze keeps the counted tree identical to the one
-        # gnorm below is computed on — the lax.cond fast path in
-        # pack_health_summary is only sound when they match (a NaN in a
-        # frozen — training-inert — leaf is deliberately out of scope
-        # for both)
-        health_grads = grads if health else None
-
-        if log_grad_norm or grad_clip_norm > 0 or health:
-            # pre-clip global norm of the mean gradient
-            gnorm = optax.global_norm(grads)
-        if log_grad_norm:
-            # count-weighted so finalize_metrics' divide-by-count yields
-            # the epoch's mean per-step grad norm
-            metrics["grad_norm_sum"] = gnorm * jnp.maximum(count, 1.0)
-        if grad_clip_norm > 0:
-            scale = jnp.minimum(1.0, grad_clip_norm / (gnorm + 1e-6))
-            grads = jax.tree.map(lambda g: g * scale, grads)
-
-        ok = jnp.array(True)
-        if skip_nonfinite:
-            ok = jnp.isfinite(loss_sum)
-            for g in jax.tree.leaves(grads):
-                ok = ok & jnp.all(jnp.isfinite(g))
-            # zero the grads on a bad step so the (discarded) optimizer
-            # update below is NaN-free even under jax_debug_nans
+        # everything between the summed gradients and the new state, under
+        # one name in the compiled step's op_name metadata (the device
+        # trace's optimizer share reads it); names only, same program
+        with jax.named_scope("optimizer"):
+            # Normalize the summed gradients by the global valid count
+            # (matches grad-of-mean on the full batch exactly).
+            denom = jnp.maximum(count.astype(jnp.float32), 1.0)
             grads = jax.tree.map(
-                lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads
+                lambda g: (g / denom).astype(g.dtype), grads
             )
 
-        updates, new_opt_state = tx.update(grads, state.opt_state, state.params)
-        if state.lr_scale is not None:
-            # host-driven LR multiplier (ReduceLROnPlateau): every registered
-            # optimizer ends in scale_by_learning_rate, so scaling the final
-            # update equals scaling the learning rate
-            s = state.lr_scale.astype(jnp.float32)
-            updates = jax.tree.map(lambda u: (u * s).astype(u.dtype), updates)
-        if health:
-            # post-LR-scale update magnitude: an optimizer blow-up shows
-            # here even when the gradients themselves were finite
-            health_update_norm = optax.global_norm(updates)
-        new_params = optax.apply_updates(state.params, updates)
-        if skip_nonfinite:
-            # branchless select: a suppressed step leaves params/opt_state/
-            # batch_stats bit-identical (no host round-trip, stays one XLA
-            # program), and its contaminated sufficient statistics are
-            # zeroed so epoch aggregates exclude the bad batch entirely
-            sel = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
-            new_params = jax.tree.map(sel, new_params, state.params)
-            new_opt_state = jax.tree.map(sel, new_opt_state, state.opt_state)
-            new_stats = jax.tree.map(sel, new_stats, state.batch_stats)
-            metrics = {
-                kk: jnp.where(ok, v, jnp.zeros_like(v))
-                for kk, v in metrics.items()
-            }
-            metrics["skipped_sum"] = (
-                (1.0 - ok.astype(jnp.float32)) * jnp.maximum(count, 1.0)
-            )
-        new_ema = state.ema_params
-        if ema_decay > 0 and new_ema is not None:
-            d = jnp.float32(ema_decay)
-            new_ema = jax.tree.map(
-                lambda e, p: (e * d + p.astype(e.dtype) * (1 - d)),
-                new_ema, new_params,
-            )
+            if trainable_patterns:
+                # Mirror the optimizer's ``trainable`` freeze (optim.py
+                # _trainable_only) on the gradients themselves: frozen leaves
+                # still produce real grads (only LoRADense's base kernels are
+                # stop_gradient-pruned in-graph — embeddings, norms, biases
+                # are not), and counting those soon-to-be-discarded grads in
+                # the global norm below would over-clip the surviving updates
+                # and misreport grad_norm. The mask is static (Python bools at
+                # trace time), so the zeroed branches fold away.
+                import re as _re
+
+                from ..parallel.sharding import path_str
+
+                pats = [_re.compile(p) for p in trainable_patterns]
+
+                def _freeze(path, g):
+                    if any(p.search(path_str(path)) for p in pats):
+                        return g
+                    return jnp.zeros_like(g)
+
+                grads = jax.tree_util.tree_map_with_path(_freeze, grads)
+
+            # hold the PRE-CLIP gradients for the health summary, AFTER the
+            # normalize/freeze transforms: clipping can smear one NaN over
+            # every group (NaN global norm -> NaN scale), destroying the
+            # per-module attribution the dump exists for, while capturing
+            # after the freeze keeps the counted tree identical to the one
+            # gnorm below is computed on — the lax.cond fast path in
+            # pack_health_summary is only sound when they match (a NaN in a
+            # frozen — training-inert — leaf is deliberately out of scope
+            # for both)
+            health_grads = grads if health else None
+
+            if log_grad_norm or grad_clip_norm > 0 or health:
+                # pre-clip global norm of the mean gradient
+                gnorm = optax.global_norm(grads)
+            if log_grad_norm:
+                # count-weighted so finalize_metrics' divide-by-count yields
+                # the epoch's mean per-step grad norm
+                metrics["grad_norm_sum"] = gnorm * jnp.maximum(count, 1.0)
+            if grad_clip_norm > 0:
+                scale = jnp.minimum(1.0, grad_clip_norm / (gnorm + 1e-6))
+                grads = jax.tree.map(lambda g: g * scale, grads)
+
+            ok = jnp.array(True)
             if skip_nonfinite:
-                new_ema = jax.tree.map(
-                    lambda n, o: jnp.where(ok, n, o),
-                    new_ema, state.ema_params,
+                ok = jnp.isfinite(loss_sum)
+                for g in jax.tree.leaves(grads):
+                    ok = ok & jnp.all(jnp.isfinite(g))
+                # zero the grads on a bad step so the (discarded) optimizer
+                # update below is NaN-free even under jax_debug_nans
+                grads = jax.tree.map(
+                    lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads
                 )
+
+            updates, new_opt_state = tx.update(
+                grads, state.opt_state, state.params)
+            if state.lr_scale is not None:
+                # host-driven LR multiplier (ReduceLROnPlateau): every
+                # registered optimizer ends in scale_by_learning_rate, so
+                # scaling the final update equals scaling the learning rate
+                s = state.lr_scale.astype(jnp.float32)
+                updates = jax.tree.map(
+                    lambda u: (u * s).astype(u.dtype), updates)
+            if health:
+                # post-LR-scale update magnitude: an optimizer blow-up shows
+                # here even when the gradients themselves were finite
+                health_update_norm = optax.global_norm(updates)
+            new_params = optax.apply_updates(state.params, updates)
+            if skip_nonfinite:
+                # branchless select: a suppressed step leaves params/opt_state/
+                # batch_stats bit-identical (no host round-trip, stays one XLA
+                # program), and its contaminated sufficient statistics are
+                # zeroed so epoch aggregates exclude the bad batch entirely
+                sel = lambda n, o: jnp.where(ok, n, o)  # noqa: E731
+                new_params = jax.tree.map(sel, new_params, state.params)
+                new_opt_state = jax.tree.map(
+                    sel, new_opt_state, state.opt_state)
+                new_stats = jax.tree.map(sel, new_stats, state.batch_stats)
+                with jax.named_scope("metrics"):
+                    metrics = {
+                        kk: jnp.where(ok, v, jnp.zeros_like(v))
+                        for kk, v in metrics.items()
+                    }
+                    metrics["skipped_sum"] = (
+                        (1.0 - ok.astype(jnp.float32))
+                        * jnp.maximum(count, 1.0)
+                    )
+            new_ema = state.ema_params
+            if ema_decay > 0 and new_ema is not None:
+                d = jnp.float32(ema_decay)
+                new_ema = jax.tree.map(
+                    lambda e, p: (e * d + p.astype(e.dtype) * (1 - d)),
+                    new_ema, new_params,
+                )
+                if skip_nonfinite:
+                    new_ema = jax.tree.map(
+                        lambda n, o: jnp.where(ok, n, o),
+                        new_ema, state.ema_params,
+                    )
         new_state = state.replace(
             step=state.step + 1,
             params=new_params,
@@ -370,13 +383,15 @@ def make_train_step(model, tx, criterion: Callable,
             # the detector
             from ..observability.health import pack_health_summary
 
-            metrics = {**metrics, "health": pack_health_summary(
-                loss=loss_sum.astype(jnp.float32) / denom,
-                grad_norm=gnorm,
-                update_norm=health_update_norm,
-                grads=health_grads,
-                new_params=new_params,
-            )}
+            with jax.named_scope("health_summary"):
+                summary = pack_health_summary(
+                    loss=loss_sum.astype(jnp.float32) / denom,
+                    grad_norm=gnorm,
+                    update_norm=health_update_norm,
+                    grads=health_grads,
+                    new_params=new_params,
+                )
+            metrics = {**metrics, "health": summary}
         return new_state, metrics
 
     return train_step
@@ -469,10 +484,14 @@ def instrument_step(jitted_fn, name: str, warmup=None):
     wrapper records the first call as ``<name>/compile+execute`` and
     every later one as ``<name>/dispatch`` (dispatch spans measure jit
     dispatch + donation backpressure, not device runtime — device time
-    belongs to ``jax.profiler``). A shape change mid-run recompiles
-    inside a ``dispatch`` span; the recompilation still surfaces, as a
-    ``compile_events`` entry on the next flight-recorder record
-    (observability/telemetry).
+    belongs to ``jax.profiler``). These are the only spans around the
+    call: the trainer's loop opens none of its own and times the call
+    into the flight record's ``dispatch_ms``. Like every ``span()`` they
+    are ``TraceAnnotation``s too, so a profiler capture shows them on
+    the dispatching thread's line beside the device's. A shape change
+    mid-run recompiles inside a ``dispatch`` span; the recompilation
+    still surfaces, as a ``compile_events`` entry on the next
+    flight-recorder record (observability/telemetry).
 
     ``warmup``: an optional ``engine.warmup.StepWarmup``. At the first
     call the wrapper collects the background-compiled executable for

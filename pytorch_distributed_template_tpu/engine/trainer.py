@@ -853,6 +853,7 @@ class Trainer(BaseTrainer):
         # (the old per-log-step pipeline bubble). Holds at most one
         # entry (a handful of scalar metric buffers).
         pending_log = deque()
+        log_flush_ms = None  # the last flush, until a record takes it
         t_iter = time.perf_counter()
         while True:
             # data-wait = time blocked on the prefetch pipeline; near
@@ -873,34 +874,53 @@ class Trainer(BaseTrainer):
             # kill@step:N means exactly N completed steps
             faults.on_step(step)
             self.trace.before_step(step)
-            with span("train/step", step=step):
-                self.state, m = self._train_step(self.state, batch)
+            # its span is instrument_step's (train_step/dispatch, or
+            # train_step/compile+execute on a lazy first call)
+            t_call = time.perf_counter()
+            self.state, m = self._train_step(self.state, batch)
+            dispatch_ms = (time.perf_counter() - t_call) * 1e3
             # the dispatched step completes on-device even if the host
             # dies after this point: the cursor counts it done
             self._cursor = (epoch, batch_idx + 1)
             self.trace.after_step(step, sync=m)
             self.watchdog.beat()
+            health_fetch_ms = None
             if self._health_keys:
                 # strip the health scalars out of the epoch accumulator
                 # (they are per-step signals, not sufficient statistics)
                 # and hand them to the monitor, which fetches them one
                 # step deferred — no sync on the step just dispatched
                 hm = {k: m.pop(k) for k in self._health_keys if k in m}
-                self.health.enqueue(
-                    step, hm,
-                    meta={"epoch": epoch, "batch_idx": batch_idx},
-                )
+                t_call = time.perf_counter()
+                # enqueues this step's summary and fetches the one
+                # queued before it
+                with span("train/health_fetch", step=step):
+                    self.health.enqueue(
+                        step, hm,
+                        meta={"epoch": epoch, "batch_idx": batch_idx},
+                    )
+                health_fetch_ms = (time.perf_counter() - t_call) * 1e3
             self.throughput.update(self.train_loader.batch_size)
             self.epoch_meter.update(self.train_loader.batch_size)
             # per-step flight record; wall_ms is the full loop iteration
             # (dispatch + donation backpressure + data wait), so summed
-            # wall time over a window is the honest steps/s denominator
+            # wall time over a window is the honest steps/s denominator.
+            # dispatch_ms, health_fetch_ms and log_flush_ms say where it
+            # went, each on the record of the iteration whose wall_ms
+            # holds it: a log flush runs after this record's clock has
+            # been read, so it is the next record's
             rec = {
                 "wall_ms": round((time.perf_counter() - t_iter) * 1e3, 3),
                 "data_wait_ms": round(data_wait_ms, 3),
+                "dispatch_ms": round(dispatch_ms, 3),
                 "examples": self.train_loader.batch_size,
             }
             t_iter = time.perf_counter()
+            if health_fetch_ms is not None:
+                rec["health_fetch_ms"] = round(health_fetch_ms, 3)
+            if log_flush_ms is not None:
+                rec["log_flush_ms"] = round(log_flush_ms, 3)
+                log_flush_ms = None
             if self._tokens_per_example:
                 rec["tokens"] = (self._tokens_per_example
                                  * self.train_loader.batch_size)
@@ -963,7 +983,9 @@ class Trainer(BaseTrainer):
                 # (the lazy first-step case) rides under its own step
                 # id, not whichever record happens to flush next
                 if pending_log:
+                    t_call = time.perf_counter()
                     self._flush_log_entry(pending_log.popleft())
+                    log_flush_ms = (time.perf_counter() - t_call) * 1e3
                 events = drain_compile_events()
                 if events:
                     rec["compile_events"] = events
@@ -1051,12 +1073,18 @@ class Trainer(BaseTrainer):
         """
         step, epoch, batch_idx, m, rec = entry
         with span("train/log", step=step):
-            m = jax.device_get(m)
+            # the wait, if there is one, apart from the arithmetic and
+            # the writer calls after it
+            with span("train/log_fetch", step=step):
+                m = jax.device_get(m)
             self.writer.set_step(step)
             loss_val = (float(m["loss_sum"])
                         / max(float(m["count"]), 1.0))
             self.train_metrics.update("loss", loss_val)
-            lr_val = float(self.lr_fn(step)) * self._lr_scale_host
+            # the schedule is jnp arithmetic: its small programs queue
+            # on the device behind the step dispatched just before
+            with span("train/log_lr", step=step):
+                lr_val = float(self.lr_fn(step)) * self._lr_scale_host
             self.writer.add_scalar("lr", lr_val)
             rec["loss"] = round(loss_val, 6)
             rec["lr"] = lr_val

@@ -8,7 +8,14 @@ timeline question). Spans nest, survive exceptions, cost two
 ring as Chrome trace-event ``"X"`` (complete) events — ``dump()``
 writes a file that chrome://tracing and Perfetto load directly.
 
-Two consumers beyond the viewer:
+Every span is also a ``jax.profiler.TraceAnnotation`` of the same name
+and attrs: while a profiler capture runs (``TraceCapture``, SIGUSR2),
+the span lands on the calling thread's line of the ``.xplane.pb``, on
+the device events' clock, so an idle gap of the device can be laid
+against what the host was inside. Outside a capture the annotation is
+a flag test. The ring keeps its own ``perf_counter`` origin.
+
+Consumers beyond the viewers:
 
 - the watchdog (utils/watchdog.py) snapshots ``active_spans()`` when a
   step stalls, so the dump says WHICH call never returned ("stuck 214 s
@@ -54,13 +61,18 @@ class SpanRecorder:
 
     @contextmanager
     def span(self, name: str, **attrs):
+        # imported here: the module stays importable where jax is not
+        # (the fleet router's process reaches it through the package)
+        from jax.profiler import TraceAnnotation
+
         tid = threading.get_ident()
         t0 = time.perf_counter()
         frame = {"name": name, "t0": t0, "args": attrs}
         with self._lock:
             self._open.setdefault(tid, []).append(frame)
         try:
-            yield frame
+            with TraceAnnotation(name, **attrs):
+                yield frame
         except BaseException:
             frame["args"] = {**frame["args"], "error": True}
             raise

@@ -226,6 +226,7 @@ def _flash_fwd_3d(q, k, v, *, causal: bool, block_q: int, block_k: int,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[..., 0]
 
@@ -497,6 +498,7 @@ def _bwd_pallas_3d(causal, block_q, block_k, t_valid, interpret,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, g, lse, delta, k, v)
 
     if banded:
@@ -525,6 +527,7 @@ def _bwd_pallas_3d(causal, block_q, block_k, t_valid, interpret,
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype)],
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, g, lse, delta, k, v)[0]
     return dq, dk, dv
 
@@ -861,6 +864,7 @@ def paged_attention(q, k_pool, v_pool, tables, row_starts, pad_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, t_pad, hq, d), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(tables.astype(jnp.int32), row_starts.astype(jnp.int32),
       pad_lens.astype(jnp.int32), *args)
     return out[:, t_pad - t:]

@@ -74,6 +74,26 @@ def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
     assert text.count("tpu_custom_call") >= 3
 
 
+def test_flash_kernels_carry_their_names_for_v5e(one_chip):
+    """`name=` on the pallas_calls reaches the HLO: each kernel's
+    custom call is under its own name in `op_name` (what a trace's
+    reduction joins on) and the instruction is named after it."""
+    import re
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, causal=True, interpret=False)
+        return jnp.sum(out.astype(jnp.float32))
+
+    step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+    text = step.lower(*_qkv(SHAPES[1], one_chip)).compile().as_text()
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        (line,) = [ln for ln in calls
+                   if re.search(rf'op_name="[^"]*{kernel}[^"]*pallas_call',
+                                ln)]
+        assert kernel in line.split(" = ")[0]
+
+
 @pytest.mark.xfail(strict=True, raises=ValueError,
                    reason="paged decode kernel refused: 'the last two "
                           "dimensions of your block shape [must be] divisible "

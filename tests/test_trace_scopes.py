@@ -1,0 +1,205 @@
+"""The program's spans in the profiler's trace, the flight record's
+host-time fields, and the names the compiled step carries (ISSUE 25).
+
+All on the CPU: what is checked is that the names are where a reduction
+of a chip trace looks for them, never a time.
+"""
+import json
+import re
+import threading
+from pathlib import Path
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+from pytorch_distributed_template_tpu.observability.trace import (
+    SpanRecorder,
+)
+
+REPO = Path(__file__).parent.parent
+
+
+def _lines(profile_dir):
+    """The host plane's lines, one per thread (threads share names, so
+    a list and not a dict), of the newest capture under `profile_dir`:
+    [[(event name, start, end, stats)]]."""
+    from jax.profiler import ProfileData
+
+    path = sorted(Path(profile_dir).glob("plugins/profile/*/*.xplane.pb"),
+                  key=lambda p: p.stat().st_mtime)[-1]
+    return [[(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for e in line.events]
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:CPU") for line in plane.lines]
+
+
+def _line_of(lines, name):
+    found = [i for i, events in enumerate(lines)
+             if any(e[0] == name for e in events)]
+    assert len(found) == 1, (name, found)
+    return found[0]
+
+
+def test_spans_land_on_the_calling_threads_line(tmp_path):
+    rec = SpanRecorder()
+
+    def other_thread():
+        with rec.span("data/host_gather"):
+            np.ones(8).sum()
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with rec.span("train/log", step=3):
+            with rec.span("train/log_fetch", step=3):
+                jnp.ones(4).block_until_ready()
+        t = threading.Thread(target=other_thread)
+        t.start()
+        t.join()
+    finally:
+        jax.profiler.stop_trace()
+
+    lines = _lines(tmp_path)
+    mine = _line_of(lines, "train/log")
+    assert _line_of(lines, "train/log_fetch") == mine
+    assert _line_of(lines, "data/host_gather") != mine
+    by_name = {e[0]: e for e in lines[mine]}
+    outer, inner = by_name["train/log"], by_name["train/log_fetch"]
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]    # nested
+    assert int(inner[3]["step"]) == 3
+    # the ring is as it was: both spans, innermost finished first
+    assert [e["name"] for e in rec.snapshot()] == [
+        "train/log_fetch", "train/log", "data/host_gather"]
+    assert rec.active_spans() == []
+
+
+def test_span_outside_a_capture_and_through_an_exception():
+    rec = SpanRecorder()
+    with pytest.raises(KeyError):
+        with rec.span("checkpoint/save", epoch=1):
+            raise KeyError("boom")
+    (event,) = rec.snapshot()
+    assert event["name"] == "checkpoint/save"
+    assert event["args"] == {"epoch": 1, "error": True}
+    assert rec.active_spans() == []
+
+
+@pytest.fixture(scope="module")
+def traced_epoch(tmp_path_factory):
+    """One tiny Trainer epoch with a profiler window in its middle."""
+    import pytorch_distributed_template_tpu.data  # noqa: F401
+    import pytorch_distributed_template_tpu.engine  # noqa: F401
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.config import (
+        ConfigParser, LOADERS, LOSSES, METRICS, MODELS,
+    )
+    from pytorch_distributed_template_tpu.engine import Trainer
+    from pytorch_distributed_template_tpu.parallel import mesh_from_config
+
+    cfg = json.loads((REPO / "configs" / "mnist_debug.json").read_text())
+    cfg["trainer"]["save_dir"] = str(tmp_path_factory.mktemp("runs"))
+    cfg["trainer"]["epochs"] = 1
+    cfg["trainer"]["tensorboard"] = False
+    # log_step is sqrt(batch size): a flush every fourth of 32 batches
+    cfg["train_loader"]["args"]["batch_size"] = 16
+    cfg["trainer"]["profiler"] = {
+        "enabled": True, "trace_start_step": 3, "trace_steps": 6,
+    }
+    config = ConfigParser(cfg, run_id="spans")
+    trainer = Trainer(
+        config.init_obj("arch", MODELS), LOSSES.get(config["loss"]),
+        [METRICS.get(m) for m in config["metrics"]], config=config,
+        train_loader=config.init_obj("train_loader", LOADERS),
+        mesh=mesh_from_config(config),
+    )
+    trainer.train()
+    records = [r for r in trainer.recorder.last() if "wall_ms" in r]
+    return trainer, records, _lines(Path(config.log_dir) / "profile")
+
+
+def test_flight_records_say_where_host_time_went(traced_epoch):
+    trainer, records, _ = traced_epoch
+    assert len(records) >= 6
+    flushed = [r for r in records if "log_flush_ms" in r]
+    assert flushed and len(flushed) < len(records)
+    for r in records:
+        assert r["dispatch_ms"] >= 0 and r["health_fetch_ms"] >= 0
+        parts = (r["data_wait_ms"] + r["dispatch_ms"] + r["health_fetch_ms"]
+                 + r.get("log_flush_ms", 0.0))
+        # each is two clock readings inside the iteration; the record's
+        # values are rounded to 1 us
+        assert parts <= r["wall_ms"] + 0.005, r
+    # a flush is on the record of the iteration after the log step that
+    # ran it, the one whose wall_ms holds it
+    steps = {r["step"] for r in flushed}
+    logged = {r["step"] for r in records if "loss" in r}
+    assert steps and all(s - 1 in logged for s in steps)
+
+
+def test_loop_spans_are_on_the_dispatching_threads_line(traced_epoch):
+    _, _, lines = traced_epoch
+    dispatching = _line_of(lines, "train_step/dispatch")
+    names = {e[0] for e in lines[dispatching]}
+    assert {"data/next_batch", "train/health_fetch", "train/log",
+            "train/log_fetch", "train/log_lr"} <= names
+    assert "train/step" not in names        # dropped: one span a call
+    fetch = [e for e in lines[dispatching] if e[0] == "train/log_fetch"]
+    log = [e for e in lines[dispatching] if e[0] == "train/log"]
+    # the fetch says which step's value it reads, and lies inside train/log
+    assert all("step" in e[3] for e in fetch)
+    assert all(any(o[1] <= e[1] and e[2] <= o[2] for o in log)
+               for e in fetch)
+
+
+def _tiny_step_text(grad_accum_steps: int) -> str:
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.config import MODELS
+    from pytorch_distributed_template_tpu.engine.losses import resolve_loss
+    from pytorch_distributed_template_tpu.engine.state import (
+        create_train_state,
+    )
+    from pytorch_distributed_template_tpu.engine.steps import make_train_step
+
+    model = MODELS.get("Mistral")(
+        vocab_size=256, n_layer=1, n_head=2, n_kv_head=1, d_model=32,
+        d_ff=64, max_len=128, window=32, bfloat16=True, attn_impl="flash",
+        remat=True, fused_head=True)
+    tx = optax.adamw(1e-3)
+    tokens = np.zeros((4, 64), np.int32)
+    state = create_train_state(model, tx, tokens)
+    step = make_train_step(
+        model, tx, resolve_loss({"type": "fused_lm_cross_entropy",
+                                 "args": {"chunk": 32}}),
+        (), input_key="tokens", target_key="tokens", grad_clip_norm=1.0,
+        grad_accum_steps=grad_accum_steps, skip_nonfinite=True, health=True)
+    batch = {"tokens": jnp.asarray(tokens),
+             "mask": jnp.ones((4,), jnp.float32)}
+    return jax.jit(step).lower(state, batch).compile().as_text()
+
+
+@pytest.mark.parametrize("grad_accum_steps", [1, 4])
+def test_compiled_step_carries_the_scopes(grad_accum_steps):
+    names = set(re.findall(r'op_name="([^"]*)"',
+                           _tiny_step_text(grad_accum_steps)))
+    assert names
+
+    def some(pattern):
+        return any(re.search(pattern, n) for n in names)
+
+    assert some(r"/optimizer/")
+    assert some(r"jvp\(head_loss\)") and some(r"transpose\(jvp\(head_loss\)\)")
+    assert some(r"rematted_computation")             # the remat marker
+    assert some(r"/health_summary/") and some(r"/metrics/")
+    assert some(r"/grad_accum/") == (grad_accum_steps > 1)
+    # interpret mode lowers a kernel to a loop under the kernel's name
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert some(rf"/self_attn/{kernel}/"), kernel
+    # forward, recomputation and backward can be told apart: the
+    # recomputed forward kernel is under the marker, the first is not
+    assert some(r"rematted_computation/.*flash_fwd")
+    assert any("self_attn/flash_fwd" in n and "transpose(" not in n
+               and "rematted_computation" not in n for n in names)
+    # nothing of the optimizer is inside forward or backward
+    assert not some(r"jvp\(.*optimizer")
