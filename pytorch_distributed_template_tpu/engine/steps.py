@@ -27,6 +27,9 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..models.remat_policy import step_holds
+from ..parallel.sharding import per_device_bytes
+
 
 def _masked_sum(per_example, mask):
     return jnp.sum(per_example * mask)
@@ -42,6 +45,31 @@ def _accepts_example_mask(model) -> bool:
         ).parameters
     except (TypeError, ValueError):  # exotic callables
         return False
+
+
+def _accumulator_dtype(dtype):
+    return jnp.promote_types(dtype, jnp.float32)
+
+
+def _held_through_backward(state, model, grad_accum_steps: int) -> int:
+    """Bytes on one device that the step holds from its first backward to
+    its last, for the blocks' checkpoint policy (models/remat_policy.py):
+    the state as it is traced (parameters, whatever the optimizer keeps,
+    shadow weights where ``ema_decay`` set them up, statistics) and, with
+    accumulation, the running sum of the micro-batches' gradients and one
+    micro-batch's own gradient, which is added to it only when its backward
+    is over. Under the sharding the trainer gives the state
+    (engine/state.py)."""
+    mesh = getattr(model, "mesh", None)
+    rules = getattr(model, "partition_rules", lambda: [])()
+    held = per_device_bytes(state, mesh, rules)
+    if grad_accum_steps > 1:
+        held += per_device_bytes(state.params, mesh, rules)
+        held += per_device_bytes(jax.tree.map(
+            lambda p: jax.ShapeDtypeStruct(p.shape,
+                                           _accumulator_dtype(p.dtype)),
+            state.params), mesh, rules)
+    return held
 
 
 def make_train_step(model, tx, criterion: Callable,
@@ -193,11 +221,13 @@ def make_train_step(model, tx, criterion: Callable,
             # broadcast to [B] so the grad-accum microbatch split applies
             batch["_mix_lam"] = jnp.full((x.shape[0],), lam, jnp.float32)
         k = grad_accum_steps
+        holds = step_holds(_held_through_backward(state, model, k))
 
         if k <= 1:
-            (loss_sum, (output, new_stats, mask)), grads = grad_fn(
-                state.params, state.batch_stats, batch, dropout_rng
-            )
+            with holds:
+                (loss_sum, (output, new_stats, mask)), grads = grad_fn(
+                    state.params, state.batch_stats, batch, dropout_rng
+                )
             count = mask.sum()
             metrics = {"loss_sum": loss_sum, "count": count}
             with jax.named_scope("metrics"):
@@ -234,17 +264,17 @@ def make_train_step(model, tx, criterion: Callable,
 
             micro["_idx"] = jnp.arange(k)
             zeros_g = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.promote_types(p.dtype,
-                                                               jnp.float32)),
+                lambda p: jnp.zeros(p.shape, _accumulator_dtype(p.dtype)),
                 state.params,
             )
             zeros_m = {"loss_sum": jnp.zeros((), jnp.float32),
                        "count": jnp.zeros((), jnp.float32)}
             for fn in metric_fns:
                 zeros_m[f"{fn.__name__}_sum"] = jnp.zeros((), jnp.float32)
-            (new_stats, grads, metrics), _ = jax.lax.scan(
-                body, (state.batch_stats, zeros_g, zeros_m), micro
-            )
+            with holds:
+                (new_stats, grads, metrics), _ = jax.lax.scan(
+                    body, (state.batch_stats, zeros_g, zeros_m), micro
+                )
             loss_sum, count = metrics["loss_sum"], metrics["count"]
 
         if inject_nan_grad_step is not None:

@@ -16,7 +16,18 @@ TPU-native throughout:
   query heads only at attention time;
 - attention dispatches through the same ladder as the GPT-2 family:
   ``xla`` | ``flash`` | ``ring`` | ``ring_flash`` | ``ulysses`` |
-  ``ulysses_flash`` (ops/attention.py, ops/flash.py).
+  ``ulysses_flash`` (ops/attention.py, ops/flash.py);
+- ``remat=True`` wraps each block in ``jax.checkpoint`` and means
+  "recompute in the backward what does not fit": a training call keeps, in
+  every block, the longest prefix of attention output and log-sum-exp,
+  q/k/v projections' outputs, ``o_proj`` output, MLP ``gate`` output,
+  MLP ``up`` output, the flash kernel's operands that fits the device's
+  memory beside what the training step says it holds (state, gradient
+  accumulator; engine/steps.py), reckoned from shapes, mesh and the
+  device's fixed capacity (models/remat_policy.py; the choice is one
+  ``remat/policy`` INFO line in ``info.log`` and one span). Where the
+  capacity is unknown (the CPU), or the gradient is taken outside such a
+  step, nothing is kept and the whole block is recomputed.
 """
 from __future__ import annotations
 
@@ -26,6 +37,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..config.registry import MODELS
@@ -33,6 +45,7 @@ from ..ops.attention import (
     grouped_query_attention, multihead_attention, ring_attention,
     sharded_flash_attention, ulysses_attention, zigzag_perm,
 )
+from .remat_policy import block_policy
 
 
 def _dense_init(stddev=0.02):
@@ -125,11 +138,15 @@ class LlamaAttention(nn.Module):
         groups = self.n_head // self.n_kv_head
         dense = _dense_or_quant(self.dtype, self.quant, self.lora_rank,
                                 self.lora_alpha)
-        q = dense(self.n_head * hd, "q_proj")(x).reshape(b, t, self.n_head, hd)
-        k = dense(self.n_kv_head * hd, "k_proj")(x).reshape(
-            b, t, self.n_kv_head, hd)
-        v = dense(self.n_kv_head * hd, "v_proj")(x).reshape(
-            b, t, self.n_kv_head, hd)
+        # matmul outputs carry names a block's checkpoint policy may keep
+        # (models/remat_policy.py); outside jax.checkpoint a name is nothing
+        def proj(name, heads):
+            y = checkpoint_name(dense(heads * hd, name)(x), "qkv_proj")
+            return y.reshape(b, t, heads, hd)
+
+        q = proj("q_proj", self.n_head)
+        k = proj("k_proj", self.n_kv_head)
+        v = proj("v_proj", self.n_kv_head)
 
         if decode:
             ctx = self._cached_attention(q, k, v, decode_index, groups,
@@ -181,7 +198,8 @@ class LlamaAttention(nn.Module):
                 ctx = multihead_attention(q, k, v, causal=True,
                                           window=self.window)
         ctx = ctx.reshape(b, t, self.n_head * hd)
-        return dense(self.d_model, "o_proj")(ctx)
+        return checkpoint_name(dense(self.d_model, "o_proj")(ctx),
+                               "attn_proj")
 
     def _paged_attention(self, q, k, v, cached_k, cached_v,
                          block_tables, row_starts, pad_lens,
@@ -530,9 +548,9 @@ class SwiGLU(nn.Module):
     def __call__(self, x):
         dense = _dense_or_quant(self.dtype, self.quant, self.lora_rank,
                                 self.lora_alpha)
-        gate = nn.silu(dense(self.d_ff, "gate_proj")(x))
-        up = dense(self.d_ff, "up_proj")(x)
-        return dense(self.d_model, "down_proj")(gate * up)
+        gate = checkpoint_name(dense(self.d_ff, "gate_proj")(x), "mlp_gate")
+        up = checkpoint_name(dense(self.d_ff, "up_proj")(x), "mlp_up")
+        return dense(self.d_model, "down_proj")(nn.silu(gate) * up)
 
 
 class LlamaBlock(nn.Module):
@@ -614,7 +632,7 @@ class LlamaLM(nn.Module):
     dtype: Any = jnp.float32
     attn_impl: str = "xla"
     mesh: Optional[Any] = None
-    remat: bool = False
+    remat: bool = False             # recompute what does not fit (docstring)
     seq_layout: str = "natural"
     rope_base: float = 10000.0
     rms_eps: float = 1e-6
@@ -718,16 +736,24 @@ class LlamaLM(nn.Module):
         else:
             positions = jnp.arange(t, dtype=jnp.int32)
 
+        n_run = (min(int(exit_layer), self.n_layer) if exit_layer
+                 else self.n_layer)
         block_cls = LlamaBlock
         if self.remat:
+            # features a token of the matmul outputs a block names; a
+            # sparse block names no MLP output
+            hd = self.d_model // self.n_head
+            widths = {"qkv_proj": (self.n_head + 2 * n_kv) * hd,
+                      "attn_proj": self.d_model}
+            if self.moe_experts <= 0 or self.moe_every > 1:
+                widths.update(mlp_gate=d_ff, mlp_up=d_ff)
+            policy = block_policy(self, train and not decode, widths,
+                                  n_blocks=n_run, batch=b, seq_len=t,
+                                  block_key="layers_")
             # static_argnums count self as 0: train=3 / decode=5 are Python
             # bools; positions (2) and example_mask (4) are traced
             block_cls = nn.remat(
-                LlamaBlock, static_argnums=(3, 5, 7),
-                policy=jax.checkpoint_policies.nothing_saveable,
-            )
-        n_run = (min(int(exit_layer), self.n_layer) if exit_layer
-                 else self.n_layer)
+                LlamaBlock, static_argnums=(3, 5, 7), policy=policy)
         for i in range(n_run):
             x = block_cls(
                 d_model=self.d_model, n_head=self.n_head, n_kv_head=n_kv,

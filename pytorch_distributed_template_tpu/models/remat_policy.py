@@ -1,0 +1,242 @@
+"""What a rematerialized block keeps for its backward: as much as fits.
+
+``remat=True`` wraps every block of the GPT-2 and Llama families in
+``jax.checkpoint``. With ``nothing_saveable`` the backward runs the whole
+block again, the flash forward kernel included. This module chooses, once
+per traced training step, a ``save_only_these_names`` policy over a prefix
+of one fixed preference order, by arithmetic on shapes, the mesh, the
+device's capacity and what the training step says it holds
+(``step_holds``, set by ``engine/steps.py`` around its backward: the
+state under its optimizer, shadow weights and gradient accumulator,
+whatever they are). Nothing here reads what is allocated, compiles a
+candidate or runs a trial, so every build of one job on one kind of chip
+chooses the same names, and says so in one log line and one
+``remat/policy`` span.
+
+The names (``jax.ad_checkpoint.checkpoint_name``), in order of preference,
+which is the order of recomputation spared for a byte kept:
+
+1. ``attn_out``, ``attn_lse``: the attention output and, from the flash
+   kernel, its log-sum-exp rows. Only the kernel can make them
+   (ops/flash.py names them where the custom-vjp forward rules build their
+   residuals), so keeping both spares the backward a second forward call:
+   7.8 ms for 68 MB in a Mistral-7B layer at 8192 tokens.
+2. ``qkv_proj``, ``attn_proj``, ``mlp_gate``, ``mlp_up``: the block's
+   matmul outputs. Each contracts over ``d_model``, so each spares the
+   same recomputation a byte (1.6 ms for 67 MB there), and the tie is
+   broken by what keeping costs the forward: the first three together
+   1.1 ms of a forward of 80; ``mlp_up``, which the forward never wrote
+   (it fused the activation into that matmul and wrote their product),
+   4.7 ms, because the activation moves into the down projection's
+   operand fusion (my chip runs, PR 27). The down projection's output has
+   no name: it feeds only the residual sum, the backward needs it for
+   nothing, and jax drops it from the recomputation whatever the policy.
+3. ``attn_qkv``: the flash kernel's operands as it takes them (rotated,
+   key and value heads repeated, folded to ``[heads, tokens, head size]``),
+   its other three residuals. Kept, the backward no longer rotates,
+   repeats and folds again (2.2 ms for 201 MB there) and needs
+   ``qkv_proj`` no more, which jax then drops from what is kept.
+
+One policy serves every block of a model. An empty prefix is
+``nothing_saveable``; so is a device whose capacity is unknown (the CPU),
+and a gradient taken outside a step that says what it holds.
+"""
+from __future__ import annotations
+
+import contextlib
+import contextvars
+import logging
+from typing import Mapping, Optional, Sequence, Tuple
+
+import jax
+import numpy as np
+
+from ..observability.trace import span
+from ..ops.flash import named_residual_bytes
+from ..parallel.mesh import axis_size
+from ..parallel.sharding import DATA_AXES, per_device_bytes
+
+logger = logging.getLogger(__name__)
+
+# groups are kept whole: the attention output without its log-sum-exp
+# would still cost the kernel call
+PREFERENCE: Tuple[Tuple[str, ...], ...] = (
+    ("attn_out", "attn_lse"),
+    ("qkv_proj",),
+    ("attn_proj",),
+    ("mlp_gate",),
+    ("mlp_up",),
+    ("attn_qkv",),
+)
+# left free under the device's limit: the allocator's fragmentation, the
+# compiler's own copies, and room for the step's peak to be read at least
+# 1 GiB under ``bytes_limit``
+HEADROOM_BYTES = 1 << 30
+
+_logged: set = set()
+_held: contextvars.ContextVar = contextvars.ContextVar(
+    "remat_policy_step_holds", default=None)
+
+
+@contextlib.contextmanager
+def step_holds(nbytes: int):
+    """The training step's word to the policy, around the trace of its
+    backward: ``nbytes`` on one device are alive through all of it, whatever
+    the blocks keep (``engine/steps.py`` reckons them: the state and, with
+    accumulation, the gradients' running sum). A model traced outside it
+    keeps nothing."""
+    token = _held.set(int(nbytes))
+    try:
+        yield
+    finally:
+        _held.reset(token)
+
+
+def device_capacity_bytes(mesh=None) -> Optional[int]:
+    """A device's memory as its runtime limits it: a constant of the chip
+    for the life of the process. ``None`` where the backend reports none
+    (the CPU) or the device is not this process's to ask. The only read of
+    the device in this module; tests patch it."""
+    device = (mesh.local_devices if mesh is not None
+              else jax.local_devices())[0]
+    if device not in jax.local_devices():
+        return None     # described, not attached: a compile rehearsal
+    stats = device.memory_stats()
+    return int(stats["bytes_limit"]) if stats else None
+
+
+def choose_names(block_bytes: Mapping[str, int], n_blocks: int,
+                 budget: int) -> Tuple[str, ...]:
+    """The names to keep: the longest prefix of ``PREFERENCE`` whose bytes,
+    kept in each of ``n_blocks`` blocks, stay inside ``budget``.
+    ``block_bytes`` maps a name to its bytes on one device in one block; a
+    name it lacks (no gate in a GELU MLP, no log-sum-exp from the XLA
+    attention) is passed over. A prefix, not a knapsack: a smaller budget
+    never keeps what a larger one leaves out."""
+    kept, total = [], 0
+    for group in PREFERENCE:
+        names = [n for n in group if n in block_bytes]
+        total += n_blocks * sum(block_bytes[n] for n in names)
+        if total > budget:
+            break
+        kept += names
+    return tuple(kept)
+
+
+def policy_of(names: Sequence[str]):
+    if not names:
+        return jax.checkpoint_policies.nothing_saveable
+    return jax.checkpoint_policies.save_only_these_names(*names)
+
+
+def token_shards(mesh, batch: int, seq_len: int,
+                 seq_sharded: bool = False) -> int:
+    """The devices a block's ``[batch, seq_len, ...]`` values are spread
+    over: the batch over the data axes, the sequence over ``seq`` where
+    the attention is sequence-parallel. An axis that does not divide its
+    dimension shards nothing, as in ``ops.attention._sp_partition``. The
+    ``tensor`` axis is left out: a column-parallel output is reckoned
+    whole, which errs to keeping less."""
+    if mesh is None:
+        return 1
+    rows = int(np.prod([axis_size(mesh, a) for a in DATA_AXES]))
+    shards = rows if batch % rows == 0 else 1
+    if seq_sharded and seq_len % axis_size(mesh, "seq") == 0:
+        shards *= axis_size(mesh, "seq")
+    return shards
+
+
+def budget_bytes(capacity: int, held_bytes: int, outside_param_bytes: int,
+                 block_input_bytes: int, head_bytes: int,
+                 block_bytes: Mapping[str, int], n_blocks: int) -> int:
+    """Bytes one device can give to kept intermediates.
+
+    What is kept is all alive when the backward starts, and that is the
+    moment reckoned here. The step's other high point, the optimizer pass
+    with every gradient alive, holds nothing kept and is the same under
+    any policy; between the two a block's kept bytes go as its gradient
+    comes, so neither is passed. Alive when the backward starts, whatever
+    the policy:
+
+    - ``held_bytes``, the step's own account (``step_holds``);
+    - the gradient as the step holds it then: that of the parameters
+      outside the blocks (head, embeddings, final norm), which the head's
+      backward makes first;
+    - every block's input, which ``jax.checkpoint`` keeps under any policy;
+    - ``head_bytes``, what stands in front of the head and behind it: the
+      final hidden state and its cotangent where the loss is fused and
+      works through them in chunks, the logits in full, their cotangent
+      and the softmax between them where it is not;
+    - one block's backward: the block's recomputed intermediates and as
+      much again for their cotangents, reckoned as twice all its named
+      bytes (the flash kernels' scratch is on-chip; their folded operands
+      are of the size of ``qkv_proj``);
+    - ``HEADROOM_BYTES``.
+    """
+    margin = (n_blocks * block_input_bytes + head_bytes
+              + 2 * sum(block_bytes.values()) + HEADROOM_BYTES)
+    return capacity - held_bytes - outside_param_bytes - margin
+
+
+def block_policy(model, training: bool, widths: Mapping[str, int],
+                 n_blocks: int, batch: int, seq_len: int, block_key: str):
+    """The checkpoint policy for the blocks of ``model`` (a bound
+    ``TransformerLM`` or ``LlamaLM`` inside its call, whose blocks'
+    parameters are under keys that start with ``block_key``).
+
+    Everything comes from the shapes traced there (``batch``, ``seq_len``,
+    and ``widths``: the features a token of each matmul output a block
+    names), from the model's fields, mesh, partition rules and parameters,
+    from ``step_holds`` and from the device's capacity. A call that is not
+    ``training`` (evaluation, decode, init) takes no gradient: it chooses
+    nothing and logs nothing. A training call logs its choice, once per
+    distinct choice in a process."""
+    mesh, held = model.mesh, _held.get()
+    capacity = None
+    if training and held is not None and not model.is_initializing():
+        capacity = device_capacity_bytes(mesh)
+    if capacity is None:
+        return policy_of(())
+    tok = batch * seq_len * np.dtype(model.dtype).itemsize
+    named = {name: tok * width for name, width in widths.items()}
+    hidden = tok * model.d_model
+    # the attention's own names, where one call a block makes them in the
+    # policy's sight; a ring's steps and the all-to-all's share of heads
+    # are not reckoned, and keep nothing
+    if model.attn_impl == "xla":
+        named["attn_out"] = hidden
+    elif model.attn_impl == "flash":
+        named.update(named_residual_bytes(
+            batch, seq_len, model.n_head, model.d_model // model.n_head,
+            model.dtype))
+    head = (2 * hidden if model.fused_head
+            else 3 * batch * seq_len * model.vocab_size * 4)
+    shards = token_shards(
+        mesh, batch, seq_len,
+        seq_sharded=model.attn_impl.split("_")[0] in ("ring", "ulysses"))
+    table = {name: b // shards for name, b in named.items()}
+    params = model.variables["params"]
+    outside = per_device_bytes(
+        {k: v for k, v in params.items() if not k.startswith(block_key)},
+        mesh, model.partition_rules())
+    budget = budget_bytes(capacity, held, outside, hidden // shards,
+                          head // shards, table, n_blocks)
+    names = choose_names(table, n_blocks, budget)
+    kept_block = sum(table[n] for n in names)
+    record = dict(
+        names=",".join(names), kept_bytes_per_block=kept_block,
+        kept_bytes=kept_block * n_blocks, budget_bytes=budget,
+        capacity_bytes=capacity, blocks=n_blocks, held_bytes=held,
+    )
+    key = tuple(record.items())
+    if key not in _logged:
+        _logged.add(key)
+        with span("remat/policy", **record):
+            pass
+        logger.info(
+            "remat/policy: keeping [%s] in each of %d blocks: %.1f MB a "
+            "block, %.3f GB in all on a device, of a budget of %.3f GB "
+            "(capacity %.3f GB, the step holds %.3f GB)", record["names"],
+            n_blocks, kept_block / 1e6, kept_block * n_blocks / 1e9,
+            budget / 1e9, capacity / 1e9, held / 1e9)
+    return policy_of(names)

@@ -11,8 +11,17 @@ reduction stress config. Designed for TPU:
 - **sequence parallelism** for long context: ``attn_impl='ring'`` routes
   attention through ``ops.ring_attention`` (shard_map + ppermute over the
   ``seq`` mesh axis) so the T×T score matrix never materializes;
-- ``remat='block'`` wraps each block in ``jax.checkpoint`` (rematerialize
-  activations in backward — HBM for FLOPs, the TPU long-seq default);
+- ``remat=True`` wraps each block in ``jax.checkpoint`` and means
+  "recompute in the backward what does not fit": a training call keeps, in
+  every block, the longest prefix of attention output and log-sum-exp,
+  ``qkv`` output, attention projection output, MLP ``up`` output, the
+  flash kernel's operands that fits the device's memory beside what the
+  training step says it holds (state, gradient accumulator;
+  engine/steps.py), reckoned from shapes, mesh and the device's fixed
+  capacity (models/remat_policy.py; the choice is one ``remat/policy``
+  INFO line in ``info.log`` and one span). Where the capacity is unknown
+  (the CPU), or the gradient is taken outside such a step, nothing is
+  kept and the whole block is recomputed;
 - bf16 compute / fp32 params + fp32 softmax and layernorm accumulation;
 - weight-tied LM head (embedding transpose), GPT-2 initialization scheme
   (normal(0.02), residual projections scaled by 1/sqrt(2L)).
@@ -25,6 +34,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..config.registry import MODELS
@@ -32,6 +42,7 @@ from ..ops.attention import (
     multihead_attention, ring_attention, sharded_flash_attention,
     ulysses_attention, zigzag_perm,
 )
+from .remat_policy import block_policy
 
 
 def _dense_init(stddev):
@@ -64,7 +75,10 @@ class MlpBlock(nn.Module):
     def __call__(self, x, train: bool):
         dense = _dense_or_quant_biased(self.dtype, self.quant,
                                        self.lora_rank, self.lora_alpha)
-        y = dense(self.d_ff, _dense_init(0.02), "up")(x)
+        # a name the block's checkpoint policy may keep
+        # (models/remat_policy.py); outside jax.checkpoint it is nothing
+        y = checkpoint_name(dense(self.d_ff, _dense_init(0.02), "up")(x),
+                            "mlp_up")
         y = nn.gelu(y)
         y = dense(self.d_model,
                   _dense_init(0.02 / (2 * self.n_layer) ** 0.5), "down")(y)
@@ -97,7 +111,8 @@ class SelfAttention(nn.Module):
         head_dim = self.d_model // self.n_head
         dense = _dense_or_quant_biased(self.dtype, self.quant,
                                        self.lora_rank, self.lora_alpha)
-        qkv = dense(3 * self.d_model, _dense_init(0.02), "qkv")(x)
+        qkv = checkpoint_name(
+            dense(3 * self.d_model, _dense_init(0.02), "qkv")(x), "qkv_proj")
         qkv = qkv.reshape(b, t, 3, self.n_head, head_dim)
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if decode:
@@ -129,9 +144,9 @@ class SelfAttention(nn.Module):
         else:
             ctx = multihead_attention(q, k, v, causal=self.causal)
         ctx = ctx.reshape(b, t, self.d_model)
-        out = dense(self.d_model,
-                    _dense_init(0.02 / (2 * self.n_layer) ** 0.5),
-                    "out")(ctx)
+        out = checkpoint_name(
+            dense(self.d_model, _dense_init(0.02 / (2 * self.n_layer) ** 0.5),
+                  "out")(ctx), "attn_proj")
         return nn.Dropout(self.dropout, deterministic=not train)(out)
 
     def _cached_attention(self, q, k, v, cur, prefill: bool = False):
@@ -282,7 +297,7 @@ class TransformerLM(nn.Module):
     dtype: Any = jnp.float32
     attn_impl: str = "xla"
     mesh: Optional[Any] = None
-    remat: bool = False
+    remat: bool = False             # recompute what does not fit (docstring)
     seq_layout: str = "natural"     # 'zigzag': balanced causal ring (ops/attention.py)
     fused_head: bool = False        # return (hidden, head_w) for chunked loss
     tie_embeddings: bool = True
@@ -380,13 +395,20 @@ class TransformerLM(nn.Module):
 
         block_cls = Block
         if self.remat:
+            # features a token of the matmul outputs a block names; a
+            # sparse block names no MLP output
+            widths = {"qkv_proj": 3 * self.d_model,
+                      "attn_proj": self.d_model}
+            if self.moe_experts <= 0 or self.moe_every > 1:
+                widths["mlp_up"] = d_ff
+            policy = block_policy(self, train and not decode, widths,
+                                  n_blocks=self.n_layer, batch=b, seq_len=t,
+                                  block_key="h_")
             # static_argnums count `self` as 0: train=2 and decode=4 are
             # Python bools and must stay static; example_mask (3) is a
             # traced [B] array and must NOT be listed
             block_cls = nn.remat(
-                Block, static_argnums=(2, 4, 6),
-                policy=jax.checkpoint_policies.nothing_saveable,
-            )
+                Block, static_argnums=(2, 4, 6), policy=policy)
         for i in range(self.n_layer):
             x = block_cls(
                 d_model=self.d_model, n_head=self.n_head, d_ff=d_ff,

@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax, shard_map
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -78,7 +79,9 @@ def multihead_attention(q, k, v, causal: bool = True,
         scores = jnp.where(mask, scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", probs, v.astype(jnp.float32))
-    return out.astype(dtype)
+    # the flash kernel's name for the same value (ops/flash.py): a block's
+    # checkpoint policy that keeps it spares the backward this attention
+    return checkpoint_name(out.astype(dtype), "attn_out")
 
 
 def grouped_query_attention(q, k, v, mask=None):

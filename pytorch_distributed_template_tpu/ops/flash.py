@@ -30,6 +30,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -532,6 +533,38 @@ def _bwd_pallas_3d(causal, block_q, block_k, t_valid, interpret,
     return dq, dk, dv
 
 
+# the minor dimension of a TPU tile: in the kernels' ``[heads, tokens,
+# head size]`` layout a shorter head size is padded to it in HBM
+LANES = 128
+
+
+def named_residual_bytes(b: int, t: int, h: int, d: int, dtype) -> dict:
+    """Bytes of what ``_named_forward`` names, for ``flash_attention`` on
+    ``[b, t, h, d]`` operands of ``dtype``: the output and the three
+    operands as the kernel lays them out (tokens padded to the blocks, the
+    head size to the lanes) and the float32 log-sum-exp rows."""
+    t_pad = _padded_len(t, *pick_block_sizes(t, d))
+    d_pad = -(-d // LANES) * LANES
+    tensor = b * h * t_pad * d_pad * jnp.dtype(dtype).itemsize
+    return {"attn_out": tensor, "attn_lse": b * h * t_pad * 4,
+            "attn_qkv": 3 * tensor}
+
+
+def _named_forward(q, k, v, **kw):
+    """The forward kernel for the custom-vjp forward rules, its operands
+    and results under the names a block's checkpoint policy may keep
+    (models/remat_policy.py): ``attn_out`` and ``attn_lse``, which only
+    the kernel can make, so that keeping both spares the backward a
+    second forward call; and ``attn_qkv``, the operands as the kernel
+    takes them (rotated, heads repeated, folded), which the forward holds
+    anyway. Returns ``(out, lse)`` and the residuals."""
+    q, k, v = (checkpoint_name(x, "attn_qkv") for x in (q, k, v))
+    out, lse = _flash_fwd_3d(q, k, v, **kw)
+    out = checkpoint_name(out, "attn_out")
+    lse = checkpoint_name(lse, "attn_lse")
+    return (out, lse), (q, k, v, out, lse)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def _flash_3d(q, k, v, causal, block_q, block_k, t_valid, interpret,
               window=0):
@@ -543,10 +576,10 @@ def _flash_3d(q, k, v, causal, block_q, block_k, t_valid, interpret,
 
 def _flash_3d_fwd(q, k, v, causal, block_q, block_k, t_valid, interpret,
                   window=0):
-    out, lse = _flash_fwd_3d(q, k, v, causal=causal, block_q=block_q,
-                             block_k=block_k, t_valid=t_valid,
-                             interpret=interpret, window=window)
-    return out, (q, k, v, out, lse)
+    (out, _), residuals = _named_forward(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        t_valid=t_valid, interpret=interpret, window=window)
+    return out, residuals
 
 
 def _flash_3d_bwd(causal, block_q, block_k, t_valid, interpret, window,
@@ -571,10 +604,9 @@ def _flash_3d_lse(q, k, v, causal, block_q, block_k, t_valid, interpret,
 
 def _flash_3d_lse_fwd(q, k, v, causal, block_q, block_k, t_valid, interpret,
                       window=0):
-    out, lse = _flash_fwd_3d(q, k, v, causal=causal, block_q=block_q,
-                             block_k=block_k, t_valid=t_valid,
-                             interpret=interpret, window=window)
-    return (out, lse), (q, k, v, out, lse)
+    return _named_forward(
+        q, k, v, causal=causal, block_q=block_q, block_k=block_k,
+        t_valid=t_valid, interpret=interpret, window=window)
 
 
 def _flash_3d_lse_bwd(causal, block_q, block_k, t_valid, interpret, window,
@@ -897,6 +929,15 @@ def pick_block_sizes(t: int, d: int) -> tuple:
     return 512, 1024
 
 
+def _padded_len(t: int, block_q: int, block_k: int) -> int:
+    """``t``, or the next multiple of both blocks where the blocks, clipped
+    to ``t`` as the kernels clip them, do not divide it."""
+    if t % min(block_q, t) == 0 and t % min(block_k, t) == 0:
+        return t
+    lcm = block_q * block_k // math.gcd(block_q, block_k)
+    return -(-t // lcm) * lcm
+
+
 def flash_attention(q, k, v, causal: bool = True,
                     block_q: int = 0,
                     block_k: int = 0,
@@ -923,11 +964,7 @@ def flash_attention(q, k, v, causal: bool = True,
         auto_q, auto_k = pick_block_sizes(t, d)
         block_q = block_q or auto_q
         block_k = block_k or auto_k
-    bq, bk = min(block_q, t), min(block_k, t)
-    t_pad = t
-    if t % bq or t % bk:
-        lcm = block_q * block_k // math.gcd(block_q, block_k)
-        t_pad = -(-t // lcm) * lcm
+    t_pad = _padded_len(t, block_q, block_k)
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
     q, k, v = fold(q), fold(k), fold(v)
     if t_pad != t:
@@ -971,11 +1008,7 @@ def flash_attention_lse(q, k, v, causal: bool = False,
     if k.shape[1] != t:
         raise ValueError(f"flash_attention_lse needs Tq == Tk; "
                          f"{t} vs {k.shape[1]}")
-    bq, bk = min(block_q, t), min(block_k, t)
-    t_pad = t
-    if t % bq or t % bk:
-        lcm = block_q * block_k // math.gcd(block_q, block_k)
-        t_pad = -(-t // lcm) * lcm
+    t_pad = _padded_len(t, block_q, block_k)
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
     qf, kf, vf = fold(q), fold(k), fold(v)
     if t_pad != t:

@@ -10,6 +10,7 @@ all-gathers (FSDP), and activation collectives (TP) automatically.
 """
 from __future__ import annotations
 
+import math
 import re
 from typing import Iterable, Sequence, Tuple
 
@@ -102,6 +103,21 @@ def apply_rules(params, mesh: Mesh,
         return NamedSharding(mesh, P())
 
     return jax.tree_util.tree_map_with_path(place, params)
+
+
+def per_device_bytes(tree, mesh, rules=()) -> int:
+    """Bytes of ``tree``'s leaves on one device, at their dtypes and under
+    the sharding ``apply_rules`` gives them (whole where there is no
+    mesh). Leaves need a shape and a dtype only, so a traced or abstract
+    tree will do."""
+    def nbytes(leaf, sharding=None):
+        shape = sharding.shard_shape(leaf.shape) if sharding else leaf.shape
+        return math.prod(shape) * leaf.dtype.itemsize
+
+    if mesh is None:
+        return sum(map(nbytes, jax.tree.leaves(tree)))
+    return sum(jax.tree.leaves(
+        jax.tree.map(nbytes, tree, apply_rules(tree, mesh, rules))))
 
 
 def _prune_spec(spec: P, mesh: Mesh) -> P:

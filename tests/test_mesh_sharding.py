@@ -110,6 +110,27 @@ def test_fsdp_default_shards_largest_axis():
     assert shardings["scalar"].spec == P()
 
 
+@pytest.mark.parametrize("axes,want", [
+    (None, 8 * 16 * 4 + 16 * 2 + 8),
+    ({"data": 8}, 8 * 16 * 4 + 16 * 2 + 8),             # replicated
+    ({"data": 2, "tensor": 4}, 8 * 4 * 4 + 16 * 2 + 8),  # the rule's share
+    ({"data": 2, "fsdp": 4}, 8 * 4 * 4 + 4 * 2 + 8),     # ZeRO-3's share
+], ids=["no-mesh", "dp8", "dp2-tp4", "dp2-fsdp4"])
+def test_per_device_bytes_follow_the_rules(axes, want):
+    """What one device holds of a tree under ``apply_rules``, at each
+    leaf's dtype; shapes and dtypes are enough (a traced state)."""
+    from pytorch_distributed_template_tpu.parallel.sharding import (
+        per_device_bytes,
+    )
+
+    tree = {"qkv": {"kernel": jax.ShapeDtypeStruct((8, 16), jnp.float32),
+                    "bias": jax.ShapeDtypeStruct((16,), jnp.bfloat16)},
+            "rng": jax.eval_shape(lambda: jax.random.key(0))}
+    mesh = build_mesh(axes) if axes else None
+    rules = [(r"qkv/kernel", P(None, "tensor"))]
+    assert per_device_bytes(tree, mesh, rules) == want
+
+
 def test_psum_grad_equivalence_on_mesh():
     """A jitted sharded loss-grad equals the unsharded one (the DDP allreduce
     contract, reference trainer/trainer.py:57, expressed by XLA)."""

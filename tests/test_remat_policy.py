@@ -1,0 +1,405 @@
+"""The blocks' checkpoint policy (models/remat_policy.py): what fits is kept.
+
+CPU only: the choosing arithmetic over hand-made byte tables, the two
+families with a capacity supplied (the CPU reports none, which means
+today's ``nothing_saveable``), the jaxpr that shows what the backward no
+longer recomputes, a data-parallel mesh against one device, what the
+training step says it holds (accumulation, shadow weights, the optimizer's
+own state), and the ``remat/policy`` record. Nothing here is a time or a
+rate.
+"""
+import contextlib
+import logging
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import pytorch_distributed_template_tpu.models  # noqa: F401
+from pytorch_distributed_template_tpu.config.registry import MODELS
+from pytorch_distributed_template_tpu.engine.state import create_train_state
+from pytorch_distributed_template_tpu.engine.steps import make_train_step
+from pytorch_distributed_template_tpu.models import remat_policy as rp
+from pytorch_distributed_template_tpu.observability.trace import get_recorder
+from pytorch_distributed_template_tpu.ops.flash import named_residual_bytes
+from pytorch_distributed_template_tpu.parallel.mesh import build_mesh
+from pytorch_distributed_template_tpu.parallel.sharding import batch_sharding
+
+GIB = 1 << 30
+# one Mistral-7B layer at 1 x 8192 tokens in bfloat16, and one GPT-2-large
+# layer at 8 x 1024: the tables the benchmark's cells reckon
+MISTRAL = {"attn_out": 67108864, "attn_lse": 1048576, "qkv_proj": 100663296,
+           "attn_proj": 67108864, "mlp_gate": 234881024,
+           "mlp_up": 234881024, "attn_qkv": 201326592}
+GPT2_LARGE = {"attn_out": 41943040, "attn_lse": 655360,
+              "qkv_proj": 62914560, "attn_proj": 20971520,
+              "mlp_up": 83886080, "attn_qkv": 125829120}
+ATTN = ("attn_out", "attn_lse")
+MATMULS = ("qkv_proj", "attn_proj", "mlp_gate", "mlp_up")
+
+
+@pytest.mark.parametrize("table,blocks,budget,want", [
+    (MISTRAL, 2, 0, ()),
+    (MISTRAL, 2, -GIB, ()),
+    (MISTRAL, 2, 2 * 68157440 - 1, ()),
+    (MISTRAL, 2, 2 * 68157440, ATTN),
+    (MISTRAL, 2, 2 * GIB, ATTN + MATMULS + ("attn_qkv",)),
+    (MISTRAL, 2, GIB + GIB // 2, ATTN + MATMULS),
+    (MISTRAL, 2, GIB, ATTN + MATMULS[:3]),
+    (GPT2_LARGE, 36, 2 * GIB, ATTN),
+    (GPT2_LARGE, 36, 5 * GIB, ATTN + ("qkv_proj", "attn_proj")),
+    (GPT2_LARGE, 36, 12 * GIB, ATTN + ("qkv_proj", "attn_proj", "mlp_up",
+                                       "attn_qkv")),
+    # the XLA attention has no log-sum-exp and no kernel operands, a GELU
+    # MLP no gate
+    ({"attn_out": 10, "qkv_proj": 10, "mlp_up": 10}, 3, 90,
+     ("attn_out", "qkv_proj", "mlp_up")),
+], ids=["empty", "negative", "one-byte-short", "attention-only", "all",
+        "all-but-operands", "all-but-up", "gpt2-attention", "gpt2-part",
+        "gpt2-all", "absent-names"])
+def test_choose_names_is_a_prefix_that_fits(table, blocks, budget, want):
+    got = rp.choose_names(table, blocks, budget)
+    assert got == want
+    assert blocks * sum(table[n] for n in got) <= max(budget, 0)
+    # the same answer on every call, whatever the table's order
+    assert rp.choose_names(dict(reversed(table.items())), blocks,
+                           budget) == got
+
+
+def test_preference_stops_at_the_first_group_that_does_not_fit():
+    # the up projection would fit where the larger gate does not: a
+    # prefix, not a knapsack, so that a smaller budget never keeps what a
+    # larger one leaves out
+    table = {"attn_out": 1, "qkv_proj": 1, "attn_proj": 1, "mlp_gate": 100,
+             "mlp_up": 1}
+    assert rp.choose_names(table, 1, 50) == ("attn_out", "qkv_proj",
+                                             "attn_proj")
+
+
+def test_attention_output_is_kept_with_its_log_sum_exp_or_not_at_all():
+    table = {"attn_out": 10, "attn_lse": 2, "qkv_proj": 1}
+    assert rp.choose_names(table, 1, 11) == ()
+    assert rp.choose_names(table, 1, 12) == ATTN
+
+
+def test_empty_choice_is_todays_policy():
+    assert rp.policy_of(()) is jax.checkpoint_policies.nothing_saveable
+    assert rp.policy_of(ATTN) is not jax.checkpoint_policies.nothing_saveable
+
+
+def test_budget_is_capacity_less_what_is_held_and_margin():
+    # GPT-2-large's own figures: 774 M float32 parameters and AdamW's two
+    # moments held by the step, wte and wpe (51.5 M) outside the blocks,
+    # 36 inputs of 8 x 1024 x 1280
+    args = dict(held_bytes=3 * 3096120320, outside_param_bytes=262568960,
+                block_input_bytes=20971520, head_bytes=2 * 20971520,
+                block_bytes=GPT2_LARGE, n_blocks=36)
+    budget = rp.budget_bytes(16_900_000_000, **args)
+    assert budget == (16_900_000_000 - 3 * 3096120320 - 262568960
+                      - 36 * 20971520 - 2 * 20971520
+                      - 2 * sum(GPT2_LARGE.values()) - rp.HEADROOM_BYTES)
+    assert rp.budget_bytes(17_900_000_000, **args) == budget + 10 ** 9
+    assert rp.choose_names(GPT2_LARGE, 36, budget) == ATTN + (
+        "qkv_proj", "attn_proj")
+    # shadow weights beside them leave room for the attention's output
+    # alone; accumulation's gradient sum and micro-batch gradient for none
+    for copies, want in ((1, ATTN), (2, ())):
+        held = args["held_bytes"] + copies * 3096120320
+        assert rp.choose_names(GPT2_LARGE, 36, rp.budget_bytes(
+            16_900_000_000, **{**args, "held_bytes": held})) == want
+
+
+@pytest.mark.parametrize("shape,table", [
+    ((1, 8192, 32, 128), MISTRAL), ((8, 1024, 20, 64), GPT2_LARGE),
+], ids=["mistral", "gpt2-large"])
+def test_flash_reckons_its_own_residuals(shape, table):
+    """ops/flash.py says what its named residuals weigh: the head size
+    padded to the lanes, the tokens to the blocks, float32 log-sum-exp."""
+    got = named_residual_bytes(*shape, jnp.bfloat16)
+    assert got == {n: table[n] for n in ("attn_out", "attn_lse", "attn_qkv")}
+    b, t, h, d = shape
+    longer = named_residual_bytes(b, t + 1, h, d, jnp.bfloat16)
+    assert longer["attn_lse"] > got["attn_lse"]
+    assert longer["attn_lse"] % 512 == 0        # whole blocks of tokens
+
+
+@pytest.mark.parametrize("axes,batch,seq_sharded,want", [
+    (None, 4, False, 1),
+    ({"data": 4}, 4, False, 4),
+    ({"data": 2, "fsdp": 2}, 8, False, 4),
+    ({"data": 4}, 1, False, 1),            # a batch no axis divides
+    ({"data": 2, "seq": 2}, 2, True, 4),
+    ({"data": 2, "seq": 2}, 2, False, 2),  # seq axis, attention not SP
+    ({"data": 2, "tensor": 2}, 2, False, 2),    # reckoned whole over tensor
+], ids=["no-mesh", "dp4", "dp2-fsdp2", "indivisible", "dp2-sp2",
+        "dp2-seq-unused", "dp2-tp2"])
+def test_tokens_are_reckoned_per_device(axes, batch, seq_sharded, want):
+    mesh = build_mesh(axes, devices=jax.devices()[:int(np.prod(
+        list(axes.values())))]) if axes else None
+    assert rp.token_shards(mesh, batch, 8192, seq_sharded) == want
+
+
+def _tokens(b=2, t=32):
+    return jnp.asarray(
+        np.random.default_rng(0).integers(0, 256, (b, t)), jnp.int32)
+
+
+def _loss_fn(model, tokens, held=0):
+    """A loss whose gradient is taken as a training step takes it: inside
+    ``step_holds`` (``held=None``: outside it)."""
+    def loss(params):
+        with (rp.step_holds(held) if held is not None
+              else contextlib.nullcontext()):
+            out = model.apply({"params": params}, tokens, train=True,
+                              rngs={"dropout": jax.random.key(2)})
+        return jnp.mean(out.astype(jnp.float32) ** 2)
+    return loss
+
+
+@pytest.fixture
+def capacity(monkeypatch):
+    """Supply the capacity the CPU does not report (item 4 of ISSUE 27:
+    patch the one function that reads it)."""
+    def supply(n):
+        monkeypatch.setattr(rp, "device_capacity_bytes", lambda mesh=None: n)
+    rp._logged.clear()
+    get_recorder().clear()
+    return supply
+
+
+FAMILIES = [("TinyLlama", 6), ("TinyLM", 3)]    # matmuls a block spared
+
+
+@pytest.mark.parametrize("family,matmuls", FAMILIES)
+@pytest.mark.parametrize("attn_impl", ["xla", "flash"])
+def test_kept_values_change_no_arithmetic(capacity, family, matmuls,
+                                          attn_impl):
+    """With everything kept, loss and gradients equal those of
+    ``remat=False`` and of ``nothing_saveable``, and the backward's jaxpr
+    recomputes ``matmuls`` fewer dots a block and one attention fewer."""
+    tokens = _tokens()
+    plain = MODELS.get(family)(remat=False, attn_impl=attn_impl)
+    remat = MODELS.get(family)(remat=True, attn_impl=attn_impl)
+    params = plain.init(jax.random.key(1), tokens)["params"]
+
+    def run(model):
+        fn = jax.value_and_grad(_loss_fn(model, tokens))
+        text = str(jax.make_jaxpr(fn)(params))
+        return jax.jit(fn)(params), text.count("dot_general"), text.count(
+            "pallas_call")
+
+    capacity(None)                      # the CPU: nothing kept
+    (l_none, g_none), dots_none, kernels_none = run(remat)
+    capacity(64 * GIB)
+    (l_kept, g_kept), dots_kept, kernels_kept = run(remat)
+    (l_plain, g_plain), _, _ = run(plain)
+    for other_l, other_g in ((l_none, g_none), (l_plain, g_plain)):
+        np.testing.assert_allclose(l_kept, other_l, rtol=1e-6)
+        for a, b in zip(jax.tree.leaves(g_kept), jax.tree.leaves(other_g)):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+    n_layer = 2
+    if attn_impl == "flash":
+        # the forward kernel once a layer, no longer twice
+        assert kernels_none - kernels_kept == n_layer
+        # (the kernel's own two dots are printed with each of its calls)
+        assert dots_none - dots_kept == n_layer * (matmuls + 2)
+    else:
+        # the XLA attention keeps its output: its second einsum goes too
+        assert kernels_none == kernels_kept == 0
+        assert dots_none - dots_kept == n_layer * (matmuls + 1)
+
+
+@pytest.mark.parametrize("family", ["TinyLlama", "TinyLM"])
+def test_unknown_capacity_is_todays_policy(capacity, family, caplog):
+    """``memory_stats()`` None (the CPU, unpatched): ``nothing_saveable``,
+    no record, and the whole block recomputed."""
+    assert rp.device_capacity_bytes() is None
+    tokens = _tokens()
+    model = MODELS.get(family)(remat=True)
+    params = model.init(jax.random.key(1), tokens)["params"]
+    with caplog.at_level(logging.INFO, logger=rp.logger.name):
+        text = str(jax.make_jaxpr(jax.grad(_loss_fn(model, tokens)))(params))
+    assert "policy=None" in text or "nothing_saveable" in text
+    assert not [e for e in get_recorder().snapshot()
+                if e["name"] == "remat/policy"]
+    assert not caplog.records
+
+
+def _record():
+    events = [e for e in get_recorder().snapshot()
+              if e["name"] == "remat/policy"]
+    return [e["args"] for e in events]
+
+
+@pytest.mark.parametrize("family", ["TinyLlama", "TinyLM"])
+def test_policy_record_and_determinism(capacity, family, caplog):
+    """One INFO line and one ``remat/policy`` span a choice: names, bytes
+    kept a block and in all, budget, capacity, blocks. A second build in
+    the process chooses the same and adds nothing."""
+    capacity(64 * GIB)
+    tokens = _tokens()
+    with caplog.at_level(logging.INFO, logger=rp.logger.name):
+        for _ in range(2):
+            model = MODELS.get(family)(remat=True, attn_impl="flash")
+            params = model.init(jax.random.key(1), tokens)["params"]
+            jax.make_jaxpr(jax.grad(_loss_fn(model, tokens)))(params)
+    (rec,) = _record()
+    assert rec["names"].split(",")[:2] == ["attn_out", "attn_lse"]
+    assert rec["blocks"] == 2
+    assert rec["kept_bytes"] == 2 * rec["kept_bytes_per_block"] > 0
+    assert rec["capacity_bytes"] == 64 * GIB
+    assert rec["kept_bytes"] <= rec["budget_bytes"] < rec["capacity_bytes"]
+    assert rec["held_bytes"] == 0           # as _loss_fn said
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert "remat/policy" in line and rec["names"] in line
+    # evaluation and init take no gradient and choose nothing
+    rp._logged.clear()
+    get_recorder().clear()
+    model.apply({"params": params}, tokens, train=False)
+    assert not _record()
+
+
+@pytest.mark.parametrize("family", ["TinyLlama", "TinyLM"])
+def test_tight_capacity_keeps_a_prefix(capacity, family):
+    """A capacity that leaves room for the attention output alone keeps it
+    alone, and the gradients still agree."""
+    tokens = _tokens()
+    model = MODELS.get(family)(remat=True)
+    plain = MODELS.get(family)(remat=False)
+    params = plain.init(jax.random.key(1), tokens)["params"]
+    capacity(64 * GIB)
+    jax.make_jaxpr(jax.grad(_loss_fn(model, tokens)))(params)
+    (full,) = _record()
+    attn_bytes = 2 * 2 * 32 * 64 * 4        # blocks x [2, 32, 64] float32
+    capacity(64 * GIB - full["budget_bytes"] + attn_bytes)
+    got = jax.jit(jax.grad(_loss_fn(model, tokens)))(params)
+    assert [r["names"] for r in _record()][-1] == "attn_out"
+    want = jax.jit(jax.grad(_loss_fn(plain, tokens)))(params)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("family", ["TinyLlama", "TinyLM"])
+def test_data_parallel_mesh_picks_what_one_device_picks(capacity, family):
+    """Global batch 4 over ``data: 4`` reckons one row a device: the names
+    and the bytes a device keeps are those of one device at batch 1 (the
+    four-chip cell against its one-chip twin)."""
+    tokens1, tokens4 = _tokens(b=1), _tokens(b=4)
+    one = MODELS.get(family)(remat=True, attn_impl="flash")
+    params = one.init(jax.random.key(1), tokens1)["params"]
+    # room for the attention output, its log-sum-exp and the projections
+    # of one row, not of four
+    capacity(64 * GIB)
+    jax.make_jaxpr(jax.grad(_loss_fn(one, tokens1)))(params)
+    (full,) = _record()
+    tight = 64 * GIB - full["budget_bytes"] + full["kept_bytes"] // 2
+    rp._logged.clear()
+    get_recorder().clear()
+    capacity(tight)
+    jax.make_jaxpr(jax.grad(_loss_fn(one, tokens1)))(params)
+    (rec1,) = _record()
+    assert 0 < rec1["kept_bytes"] < full["kept_bytes"]
+
+    mesh = build_mesh({"data": 4}, devices=jax.devices()[:4])
+    four = MODELS.get(family)(remat=True, attn_impl="flash", mesh=mesh)
+    rp._logged.clear()
+    get_recorder().clear()
+    step = jax.jit(jax.grad(_loss_fn(
+        four, jax.device_put(tokens4, batch_sharding(mesh)))))
+    grads = step(params)
+    (rec4,) = _record()
+    assert rec4 == rec1
+    # and the sharded step's gradients are those of the plain model
+    plain = MODELS.get(family)(remat=False)
+    want = jax.jit(jax.grad(_loss_fn(plain, tokens4)))(params)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("attn_impl", ["ring", "ring_flash", "ulysses"])
+def test_sequence_parallel_model_keeps_what_its_mesh_leaves(capacity,
+                                                            attn_impl):
+    """Attention over ``seq``: a ring's steps (each a kernel call of its
+    own under ``ring_flash``) and the all-to-all's share of heads are not
+    reckoned, so no name of the attention's is kept, neither an output
+    without its log-sum-exp; the projections around it are kept at the
+    bytes of a device's share of the tokens, and the gradients agree."""
+    mesh = build_mesh({"data": 2, "seq": 2}, devices=jax.devices()[:4])
+    tokens = _tokens(b=2, t=64)
+    sp = MODELS.get("TinyLlama")(remat=True, attn_impl=attn_impl, mesh=mesh)
+    plain = MODELS.get("TinyLlama")(remat=False, attn_impl=attn_impl,
+                                    mesh=mesh)
+    params = plain.init(jax.random.key(1), tokens)["params"]
+    capacity(64 * GIB)
+    got = jax.jit(jax.grad(_loss_fn(sp, tokens)))(params)
+    (rec,) = _record()
+    assert rec["names"] == "qkv_proj,attn_proj,mlp_gate,mlp_up"
+    d, kv, ff = 64, 2 * 32, 176             # TinyLlama: 4 heads, 2 KV heads
+    per_token = 4 * ((d + kv) + d + 2 * ff)          # float32
+    assert rec["kept_bytes_per_block"] == per_token * 2 * 64 // 4
+    want = jax.jit(jax.grad(_loss_fn(plain, tokens)))(params)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("family", ["TinyLlama", "TinyLM"])
+def test_gradient_outside_a_training_step_keeps_nothing(capacity, family):
+    """Only a step that says what it holds (``step_holds``) has its blocks
+    keep anything: the policy cannot know what else a caller's gradient
+    lives beside."""
+    capacity(64 * GIB)
+    tokens = _tokens()
+    model = MODELS.get(family)(remat=True)
+    params = model.init(jax.random.key(1), tokens)["params"]
+    text = str(jax.make_jaxpr(
+        jax.grad(_loss_fn(model, tokens, held=None)))(params))
+    assert "policy=None" in text or "nothing_saveable" in text
+    assert not _record()
+
+
+def _square_loss(output, target):
+    return jnp.mean(output.astype(jnp.float32) ** 2, axis=(1, 2))
+
+
+@pytest.mark.parametrize("family", ["TinyLlama", "TinyLM"])
+@pytest.mark.parametrize("setting,tx,extra", [
+    ("accum4", optax.adamw(1e-3), 2), ("ema", optax.adamw(1e-3), 1),
+    ("sgd", optax.sgd(1e-3), -2),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_the_step_says_what_it_holds(capacity, family, setting, tx, extra):
+    """``make_train_step`` reckons what it holds through the backward from
+    the state it is given: shadow weights (``ema_decay``) take one more
+    copy of the parameters out of the budget, accumulation
+    (``grad_accum_steps`` 4) two, the gradients' float32 sum and a
+    micro-batch's own gradient, which joins it when its backward is over;
+    plain SGD's missing moments give two back. AdamW without either holds
+    three."""
+    capacity(64 * GIB)
+    model = MODELS.get(family)(remat=True)
+
+    def record(tx, batch, **kw):
+        rp._logged.clear()
+        get_recorder().clear()
+        tokens = np.zeros((batch, 32), np.int32)
+        state = create_train_state(model, tx, tokens[:1], seed=0,
+                                   with_ema="ema_decay" in kw)
+        step = make_train_step(model, tx, _square_loss, [],
+                               input_key="tokens", target_key="tokens", **kw)
+        jax.make_jaxpr(step)(state, {"tokens": tokens,
+                                     "mask": np.ones(batch, bool)})
+        (rec,) = _record()
+        return rec, sum(x.size * x.dtype.itemsize
+                        for x in jax.tree.leaves(state.params))
+
+    kw = {"accum4": {"grad_accum_steps": 4},
+          "ema": {"ema_decay": 0.9}}.get(setting, {})
+    got, param_bytes = record(tx, 8, **kw)
+    # against AdamW alone at the batch the blocks see (a micro-batch)
+    base, _ = record(optax.adamw(1e-3), 8 // kw.get("grad_accum_steps", 1))
+    assert 0 <= base["held_bytes"] - 3 * param_bytes < 64   # and counters
+    for field, sign in (("held_bytes", 1), ("budget_bytes", -1)):
+        assert sign * (got[field] - base[field]) == pytest.approx(
+            extra * param_bytes, abs=64), field
+    assert got["kept_bytes"] == base["kept_bytes"] > 0
