@@ -49,7 +49,9 @@ from ..observability.anatomy import render_anatomy as _render_anatomy
 from ..observability.profiler import (
     ThroughputMeter, TraceCapture, executable_flops, mfu,
 )
-from ..parallel import batch_sharding, dist, mesh_from_config
+from ..parallel import (
+    batch_sharding, dist, mesh_from_config, train_step_compile_options,
+)
 from ..resilience import faults
 from ..utils import preemption
 from ..utils.debug import configure_debug
@@ -559,11 +561,15 @@ class Trainer(BaseTrainer):
             ["skipped_sum"] if self.skip_nonfinite else []
         ) + (["grad_norm_sum"] if self.log_grad_norm else []
              ) + self._health_keys
+        # the options ride on the jitted function, so the warm-up's
+        # ahead-of-time compile, the lazy first call and the profiler's
+        # lower().compile() all build the same executable
         train_step_jit = jax.jit(
             train_step,
             donate_argnums=0,
             out_shardings=(self.state_sharding,
                            {k: metric_sharding for k in train_keys}),
+            compiler_options=train_step_compile_options(self.mesh) or None,
         )
         eval_step = make_eval_step(
             model, criterion, self.metric_ftns,

@@ -15,6 +15,7 @@ from .sharding import (
     named_sharding,
     make_state_sharding,
     apply_rules,
+    train_step_compile_options,
 )
 from .tp import (
     TP_AXIS,

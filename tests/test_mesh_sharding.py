@@ -9,6 +9,7 @@ from pytorch_distributed_template_tpu.parallel import (
     batch_sharding,
     build_mesh,
     apply_rules,
+    train_step_compile_options,
 )
 from pytorch_distributed_template_tpu.parallel.mesh import (
     axis_size,
@@ -129,6 +130,92 @@ def test_per_device_bytes_follow_the_rules(axes, want):
     mesh = build_mesh(axes) if axes else None
     rules = [(r"qkv/kernel", P(None, "tensor"))]
     assert per_device_bytes(tree, mesh, rules) == want
+
+
+def _axes_id(axes):
+    return "x".join(f"{k}{v}" for k, v in axes.items())
+
+
+@pytest.mark.parametrize("axes", [
+    {"data": 8}, {"fsdp": 8}, {"data": 4, "tensor": 2},
+    {"data": 2, "fsdp": 2, "seq": 2}, {"tensor": 8}, {"data": 1, "tensor": 8},
+], ids=_axes_id)
+def test_no_compile_option_off_the_tpu(axes):
+    """The CPU backend gets the program it always got, whatever the mesh."""
+    assert train_step_compile_options(build_mesh(axes)) == {}
+
+
+@pytest.mark.parametrize("axes", [
+    {"tensor": 8}, {"data": 1, "tensor": 8}, {"seq": 4, "tensor": 2},
+    {"data": 1, "fsdp": 1, "pipe": 8},
+], ids=_axes_id)
+def test_no_compile_option_with_one_device_along_the_batch_axes(axes):
+    """No gradient crosses between chips, so nothing is asked of the TPU
+    compiler either: the one-chip program and its cache key stay."""
+    assert train_step_compile_options(build_mesh(axes), backend="tpu") == {}
+
+
+@pytest.mark.parametrize("axes", [
+    {"fsdp": 8}, {"data": 4, "tensor": 2}, {"data": 2, "fsdp": 4},
+    {"data": 2, "seq": 4},
+], ids=_axes_id)
+def test_same_compile_options_for_every_mesh_that_splits_the_batch(axes):
+    """One rule, not one per strategy: whatever splits the batch over
+    more than one TPU device gets what plain data parallel gets, and every
+    value is one the compiler's option parser takes (bool or int)."""
+    want = train_step_compile_options(build_mesh({"data": 8}), backend="tpu")
+    assert want and all(type(v) in (bool, int) for v in want.values())
+    assert train_step_compile_options(build_mesh(axes),
+                                      backend="tpu") == want
+
+
+@pytest.mark.parametrize("axes", [
+    {"data": 8}, {"fsdp": 8}, {"data": 4, "tensor": 2},
+], ids=_axes_id)
+def test_step_compiled_as_the_trainer_compiles_it_matches_one_device(axes):
+    """The training step jitted the way engine/trainer.py jits it (the
+    mesh's compile options on the jitted function; none on this backend)
+    gives the single-device loss, gradient norm and gradients."""
+    import optax
+
+    from pytorch_distributed_template_tpu.config.registry import (
+        LOSSES, MODELS,
+    )
+    import pytorch_distributed_template_tpu.engine  # noqa: F401
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.data.datasets import synthetic_lm
+    from pytorch_distributed_template_tpu.engine.state import (
+        create_train_state,
+    )
+    from pytorch_distributed_template_tpu.engine.steps import make_train_step
+
+    model = MODELS.get("TinyLM")(vocab_size=64, d_model=64, max_len=32)
+    tx = optax.sgd(1.0)     # the step a parameter takes IS its gradient
+    tokens = synthetic_lm(n=16, seq_len=32, vocab_size=64, seed=0)["tokens"]
+    step = make_train_step(model, tx, LOSSES.get("lm_cross_entropy"), [],
+                           input_key="tokens", target_key="tokens",
+                           grad_clip_norm=1.0, log_grad_norm=True)
+
+    def run(mesh):
+        state = create_train_state(model, tx, model.batch_template(1),
+                                   seed=0)
+        batch = {"tokens": jnp.asarray(tokens), "mask": jnp.ones(16, bool)}
+        options = None
+        if mesh is not None:
+            state = jax.device_put(
+                state, apply_rules(state, mesh, model.partition_rules()))
+            batch = jax.device_put(batch, batch_sharding(mesh))
+            options = train_step_compile_options(mesh) or None
+        new, m = jax.jit(step, compiler_options=options)(state, batch)
+        return (float(m["loss_sum"]), float(m["grad_norm_sum"]),
+                jax.tree.map(np.asarray, new.params))
+
+    loss, gnorm, params = run(build_mesh(axes))
+    ref_loss, ref_gnorm, ref_params = run(None)
+    np.testing.assert_allclose(loss, ref_loss, rtol=1e-5)
+    np.testing.assert_allclose(gnorm, ref_gnorm, rtol=1e-4)
+    jax.tree.map(lambda a, b: np.testing.assert_allclose(
+        a, b, rtol=1e-4, atol=2e-6), params, ref_params)
 
 
 def test_psum_grad_equivalence_on_mesh():

@@ -127,6 +127,66 @@ def test_warmup_unknown_name_and_no_warmup():
     assert float(out) == pytest.approx(6.0)
 
 
+def test_options_on_the_jitted_step_reach_both_compile_paths():
+    """`compiler_options` given to `jax.jit` ride on the function: the
+    warm-up's `lower().compile()` and the lazy first call both build with
+    them, so neither can end up with another executable. An option the
+    compiler does not know is refused on both."""
+    def f(state, batch):
+        return state + jnp.sum(batch["x"]), {}
+
+    jitted = jax.jit(f, compiler_options={"xla_no_such_option": True})
+    w = StepWarmup()
+    w.add("train_step", jitted, jnp.float32(0),
+          {"x": jax.ShapeDtypeStruct((4,), jnp.float32)})
+    assert w.start().result("train_step") is None     # refused ahead of time
+    with pytest.raises(Exception, match="xla_no_such_option"):
+        jitted(jnp.float32(0), {"x": jnp.ones((4,), jnp.float32)})
+
+
+def test_trainer_compiles_its_step_with_the_mesh_compile_options(
+        tmp_path, monkeypatch):
+    """engine/trainer.py asks parallel.train_step_compile_options for the
+    mesh's options once and puts them on the jitted train step, so every
+    path that compiles it gets them: with an option no compiler knows, the
+    ahead-of-time warm-up degrades and the lazy first step is refused."""
+    import json
+    from pathlib import Path
+
+    from pytorch_distributed_template_tpu.config import (
+        ConfigParser, LOADERS, LOSSES, METRICS, MODELS,
+    )
+    import pytorch_distributed_template_tpu.data  # noqa: F401
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.engine import Trainer
+    from pytorch_distributed_template_tpu.engine import trainer as trainer_mod
+    from pytorch_distributed_template_tpu.parallel import mesh_from_config
+
+    asked = []
+
+    def options(mesh):
+        asked.append(mesh)
+        return {"xla_no_such_option": True}
+
+    monkeypatch.setattr(trainer_mod, "train_step_compile_options", options)
+    cfg = json.loads(
+        (Path(__file__).parent.parent / "configs" / "mnist_debug.json")
+        .read_text())
+    cfg["trainer"]["save_dir"] = str(tmp_path)
+    cfg["trainer"]["epochs"] = 1
+    config = ConfigParser(cfg, run_id="opts")
+    mesh = mesh_from_config(config)
+    trainer = Trainer(
+        config.init_obj("arch", MODELS), LOSSES.get(config["loss"]),
+        [METRICS.get(m) for m in config["metrics"]], config=config,
+        train_loader=config.init_obj("train_loader", LOADERS), mesh=mesh,
+    )
+    assert asked == [mesh]
+    assert trainer._warmup.result("train_step") is None
+    with pytest.raises(Exception, match="xla_no_such_option"):
+        trainer.train()
+
+
 # ---------------------------------------------------------------------------
 # abstract batches from loader specs
 # ---------------------------------------------------------------------------
