@@ -9,11 +9,19 @@ duplicate-padded final batches the sampler produces (SURVEY.md §7 hard-part
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import logging
+
 import jax
 import jax.numpy as jnp
 import optax
 
 from ..config.registry import LOSSES
+from ..models.remat_policy import HEADROOM_BYTES, token_shards
+from ..observability.trace import span
+
+logger = logging.getLogger(__name__)
 
 
 @LOSSES.register("nll_loss")
@@ -110,17 +118,99 @@ def chunk_shifted_sequence(h, labels, chunk: int, pad_label: int = 0):
     return h_c, l_c, valid
 
 
+# Rows (positions x sequences on ONE device) that a slice of the fused head
+# and loss is made of, where the sequence is long enough. The backward adds
+# every slice's share into the head's whole weight gradient, [D, V] in the
+# compute dtype: read once and written once a turn, 2 x 2 D V bytes, beside
+# a matmul of 2 x rows x D x V operations. The two take the same time at
+# rows = 2 x peak / bandwidth: 481 on a v5e (197 TFLOP/s, 819 GB/s), and
+# of that order on the v4, v5p and v6e by their published figures. At four
+# times that, rewriting the accumulator is under a quarter of the matmul
+# (and the forward's re-read of the weight an eighth). A constant of the
+# arithmetic, not a knob: nothing reads a table or a device.
+SLICE_ROWS = 2048
+
+_step_mesh: contextvars.ContextVar = contextvars.ContextVar(
+    "head_loss_step_mesh", default=None)
+_logged: set = set()
+
+
+@contextlib.contextmanager
+def step_mesh(mesh):
+    """The step's word to the fused loss, around its trace: the batch it
+    traces is spread over ``mesh`` (``engine/steps.py`` sets it for the
+    train and the eval step). Under ``jit`` the loss sees the global
+    ``[B, T, D]`` and cannot know; called outside a step it reckons the
+    whole batch on one device."""
+    token = _step_mesh.set(mesh)
+    try:
+        yield
+    finally:
+        _step_mesh.reset(token)
+
+
+def slice_positions(sequences: int, chunk: int, seq_len: int,
+                    vocab: int) -> int:
+    """Positions of every sequence in one slice of the fused head and loss,
+    for ``sequences`` of ``seq_len`` positions on one device.
+
+    ``chunk`` is the floor. A slice takes as many positions as bring the
+    device's sequences to ``SLICE_ROWS`` rows, rounded up to a
+    multiple of ``chunk``, and never more than ``seq_len`` positions
+    padded to one; then halved, not below ``chunk``, while the slice's
+    float32 logits on a device exceed half of the checkpoint policy's
+    ``HEADROOM_BYTES`` (models/remat_policy.py leaves that room free and
+    reckons nothing of the loss's slices, so they have to fit in it)."""
+    def up(n):
+        return -(-n // chunk) * chunk
+
+    positions = min(up(-(-SLICE_ROWS // sequences)), up(seq_len))
+    while (positions > chunk and
+           sequences * positions * vocab * 4 > HEADROOM_BYTES // 2):
+        positions = up(positions // 2)
+    return positions
+
+
+def _say_slice(sequences, positions, turns, vocab, chunk):
+    """The choice, once a process and distinct choice: a log line and a
+    zero-length span, as ``remat/policy`` has."""
+    rows = sequences * positions
+    record = dict(rows_per_device=rows, positions=positions, turns=turns,
+                  slice_bytes=rows * vocab * 4, floor_positions=chunk)
+    key = tuple(record.items())
+    if key in _logged:
+        return
+    _logged.add(key)
+    with span("head_loss/slice", **record):
+        pass
+    logger.info(
+        "head_loss/slice: %d rows on a device a turn (%d positions x %d "
+        "sequences, the floor is %d positions), %d turns, %.1f MB of "
+        "float32 logits a slice", rows, positions, sequences, chunk, turns,
+        record["slice_bytes"] / 1e6)
+
+
 @LOSSES.register("fused_lm_cross_entropy")
 def fused_lm_cross_entropy(chunk: int = 256):
-    """FACTORY loss: next-token CE fused with the LM head, sequence-chunked.
+    """FACTORY loss: next-token CE fused with the LM head, in slices.
 
     Pairs with a model built with ``fused_head: true`` (models/transformer
     TransformerLM): ``output`` is ``(hidden [B,T,D], head_w [D,V])`` and
     the [B, T, V] logits tensor NEVER materializes — a ``lax.scan`` over
-    ``chunk``-token slices computes each slice's logits, its CE, and (via
+    slices of the sequence computes each slice's logits, its CE, and (via
     ``jax.checkpoint`` on the body) recomputes them in backward, so peak
-    HBM holds one [B, chunk, V] slice instead of the full T. At GPT-2
-    vocab (50257) and long T this is the dominant activation saved.
+    HBM holds one slice's logits instead of the full T. At GPT-2 vocab
+    (50257) and long T this is the dominant activation saved.
+
+    ``chunk`` is a FLOOR, in positions of one sequence. The slice itself
+    is reckoned in rows on one device (``slice_positions``): at least
+    ``chunk`` positions, and as many more, in multiples of ``chunk``, as
+    bring the sequences a device holds to ``SLICE_ROWS`` rows, under a
+    cap on the slice's bytes. A step that runs one or two long sequences
+    a device would otherwise rewrite the head's whole weight gradient
+    once per ``chunk`` rows, which costs more than their matmul. The
+    choice is one ``head_loss/slice`` log line and span a process.
+
     Numerically identical to ``lm_cross_entropy`` on the same params
     (same shift, per-sequence mean) up to float reassociation.
     """
@@ -132,14 +222,26 @@ def fused_lm_cross_entropy(chunk: int = 256):
         h, w = output                       # [B, T, D], [D, V]
         tm1 = h.shape[1] - 1
         b = h.shape[0]
+        on_device = b // token_shards(_step_mesh.get(), b, h.shape[1])
+        positions = slice_positions(on_device, chunk, tm1, w.shape[1])
         h_c, l_c, v_c = chunk_shifted_sequence(
-            h[:, :-1], target[:, 1:], chunk
+            h[:, :-1], target[:, 1:], positions
         )
+        _say_slice(on_device, positions, h_c.shape[0], w.shape[1], chunk)
+        if b == 1:
+            # a batch of one is folded away around the softmax (the sum
+            # below broadcasts it back): jnp.take_along_axis reads a
+            # dimension of size one as a window, not as a batch, and XLA
+            # then transposes the label's gather into a scatter over the
+            # slice's whole logits, where it otherwise fuses a one-hot
+            # select into the matmuls' operands (10 ms a step at
+            # 2048 x 32000)
+            h_c, l_c = h_c[:, 0], l_c[:, 0]
 
         @jax.checkpoint
         def body(carry, inp):
             hc, lc, vc = inp
-            logits = (hc @ w).astype(jnp.float32)       # [B, chunk, V]
+            logits = (hc @ w).astype(jnp.float32)       # [B, positions, V]
             tok = optax.softmax_cross_entropy_with_integer_labels(
                 logits, lc
             )

@@ -29,6 +29,7 @@ import optax
 
 from ..models.remat_policy import step_holds
 from ..parallel.sharding import per_device_bytes
+from .losses import step_mesh
 
 
 def _masked_sum(per_example, mask):
@@ -45,6 +46,20 @@ def _accepts_example_mask(model) -> bool:
         ).parameters
     except (TypeError, ValueError):  # exotic callables
         return False
+
+
+def _on_mesh_of(model, step):
+    """``step``, traced with the model's mesh in the fused loss's sight
+    (engine/losses.step_mesh): its criterion and metrics see the global
+    batch and reckon their slices in rows on one device."""
+    mesh = getattr(model, "mesh", None)
+
+    @functools.wraps(step)
+    def told(*args, **kwargs):
+        with step_mesh(mesh):
+            return step(*args, **kwargs)
+
+    return told
 
 
 def _accumulator_dtype(dtype):
@@ -424,7 +439,7 @@ def make_train_step(model, tx, criterion: Callable,
             metrics = {**metrics, "health": summary}
         return new_state, metrics
 
-    return train_step
+    return _on_mesh_of(model, train_step)
 
 
 def make_eval_step(model, criterion: Callable,
@@ -476,7 +491,7 @@ def make_eval_step(model, criterion: Callable,
             )
         return metrics
 
-    return eval_step
+    return _on_mesh_of(model, eval_step)
 
 
 def finalize_metrics(sums: Dict[str, float]) -> Dict[str, float]:

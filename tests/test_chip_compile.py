@@ -212,9 +212,16 @@ def test_what_the_policy_keeps_fits_the_capacity_for_v5e(
 
 
 def _compile_train_step(model, mesh, batch, seq, monkeypatch, without=None):
-    """The scheduled text of a whole training step on `mesh`, jitted the
-    way engine/trainer.py jits it: the state under the model's partition
-    rules, the batch over the batch axes, and the compile options that
+    """The scheduled text of `_compiled_train_step`'s program."""
+    options, compiled = _compiled_train_step(model, mesh, batch, seq,
+                                             monkeypatch, without)
+    return options, compiled.as_text()
+
+
+def _compiled_train_step(model, mesh, batch, seq, monkeypatch, without=None):
+    """A whole training step on `mesh`, compiled the way engine/trainer.py
+    jits it: the state under the model's partition rules, the batch over
+    the batch axes, and the compile options that
     `train_step_compile_options` gives for the mesh on the function
     (less the one named `without`)."""
     import optax
@@ -242,12 +249,12 @@ def _compile_train_step(model, mesh, batch, seq, monkeypatch, without=None):
         skip_nonfinite=True, health=True)
     options = {k: v for k, v in train_step_compile_options(mesh).items()
                if k != without}
-    text = jax.jit(
+    compiled = jax.jit(
         step, donate_argnums=0,
         out_shardings=(shardings, NamedSharding(mesh, P())),
         compiler_options=options or None,
-    ).lower(state, feed).compile().as_text()
-    return options, text
+    ).lower(state, feed).compile()
+    return options, compiled
 
 
 def _entry_instructions(text):
@@ -343,6 +350,157 @@ def test_one_chip_step_gets_no_option_and_no_collective_for_v5e(
     assert options == {}
     for word in ("all-reduce", "async-collective", "async_collective_fusion"):
         assert word not in text
+
+
+def _while_loops(text):
+    """(op_name, operand shapes, body) of the entry computation's loops."""
+    entry = re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text, re.S | re.M).group(1)
+    for line in entry.splitlines():
+        m = re.match(r"\s*%\S+ = (\(.*\)) while\(", line)
+        if m:
+            op = re.search(r'op_name="([^"]*)"', line)     # not every loop
+            yield (op.group(1) if op else "", m.group(1),
+                   re.search(r"body=%([\w\.\-]+)", line).group(1))
+
+
+def _computation(text, name):
+    return re.search(rf"^%{re.escape(name)} \(.*?^\}}", text,
+                     re.S | re.M).group(0)
+
+
+V5E_BYTES_LIMIT = 16_909_336_064    # `bytes_limit` as the chip reports it
+
+
+def _said(name):
+    from pytorch_distributed_template_tpu.observability.trace import (
+        get_recorder,
+    )
+    return [e["args"] for e in get_recorder().snapshot()
+            if e["name"] == name]
+
+
+@pytest.fixture
+def fresh_records(monkeypatch):
+    """The v5e's capacity supplied to the checkpoint policy, and nothing
+    said yet by it or by the fused loss."""
+    from pytorch_distributed_template_tpu.engine import losses
+    from pytorch_distributed_template_tpu.models import remat_policy
+    from pytorch_distributed_template_tpu.observability.trace import (
+        get_recorder,
+    )
+
+    monkeypatch.setattr(remat_policy, "device_capacity_bytes",
+                        lambda mesh=None: V5E_BYTES_LIMIT)
+    remat_policy._logged.clear()
+    losses._logged.clear()
+    get_recorder().clear()
+
+
+def test_mistral_head_and_loss_take_four_turns_inside_the_chip_for_v5e(
+        topo, monkeypatch, fresh_records):
+    """`mistral7b_l2.seq8k`'s step (1 x 8192 on one chip, the floor 256
+    positions): the fused loss's two loops run over 4 slices of 2048 rows,
+    not 32 of 256; the blocks' checkpoint policy chooses what it chose (all
+    seven names: its arithmetic leaves the slices to its headroom), and
+    the compiled step stays under the chip's `bytes_limit`."""
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.parallel import build_mesh
+
+    mesh = build_mesh({"data": 1}, devices=topo.devices[:1])
+    _, compiled = _compiled_train_step(
+        MODELS.get("Mistral")(**MISTRAL), mesh, 1, 8192, monkeypatch)
+    (said,) = _said("head_loss/slice")
+    assert said == dict(rows_per_device=2048, positions=2048, turns=4,
+                        slice_bytes=2048 * 32000 * 4, floor_positions=256)
+    text = compiled.as_text()
+    loops = [(op, shapes) for op, shapes, _ in _while_loops(text)
+             if "head_loss" in op]
+    assert len(loops) == 2      # the forward's and the backward's
+    for _, shapes in loops:     # the batch of one is folded away
+        assert "bf16[4,2048,4096]" in shapes
+        assert "bf16[32," not in shapes
+    # the label's gather goes back as a one-hot select inside the matmuls'
+    # operands, not as a scatter over a slice's float32 logits (a batch
+    # of one is folded away around the softmax for that)
+    assert not re.search(r"= f32\[[\d,]+\]\S* scatter\(", text)
+    assert "take_along_axis)/scatter-add" in text
+    (policy,) = _said("remat/policy")
+    assert policy["names"] == ("attn_out,attn_lse,qkv_proj,attn_proj,"
+                               "mlp_gate,mlp_up,attn_qkv")
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < V5E_BYTES_LIMIT - (1 << 30)
+
+
+def test_head_crossing_rides_the_four_turn_loss_loop_for_v5e(
+        four_chips, monkeypatch, fresh_records):
+    """`mistral7b_l2.seq8k_dp4`'s step (4 x 8192 over four chips): the
+    step traces the global batch and the slice is still reckoned a chip
+    (2048 rows, 4 turns); the head's weight gradient does not cross in a
+    bare all-reduce: it is started before the loss's forward loop, carried
+    by the loop's logits matmul and finished after it, as it was carried
+    by the loop of 32 turns."""
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+
+    _, text = _compile_train_step(
+        MODELS.get("Mistral")(**MISTRAL), four_chips, 4, 8192, monkeypatch)
+    (said,) = _said("head_loss/slice")
+    assert (said["rows_per_device"], said["positions"], said["turns"]) == (
+        2048, 2048, 4)
+    bare, carried = _crossings(text)
+    assert not [s for s in bare if "[4096,32000]" in s], bare
+    assert len(bare) <= 2 and len(carried) >= 14
+    (forward,) = [(shapes, body) for op, shapes, body in _while_loops(text)
+                  if "jvp(head_loss)" in op and "transpose" not in op]
+    shapes, body = forward
+    assert "bf16[4,1,2048,4096]" in shapes
+    carriers = [ln for ln in _computation(text, body).splitlines()
+                if "calls=%async_collective_fusion" in ln]
+    assert any(re.search(r"= \(bf16\[2048,32000\]", ln) for ln in carriers)
+
+
+def test_gpt2_large_step_is_left_as_it_was_for_v5e(
+        one_chip, monkeypatch, fresh_records):
+    """`gpt2_large.seq1k`'s shape (8 x 1024, the floor 256 positions) has
+    2048 rows a slice already: with the rule and with every slice held to
+    the floor, which is what the loss did before it reckoned rows, the
+    compiled step is the same text."""
+    import optax
+
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    from pytorch_distributed_template_tpu.engine import losses
+    from pytorch_distributed_template_tpu.engine.steps import make_train_step
+    from pytorch_distributed_template_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    model = MODELS.get("GPT2")(
+        size="gpt2-large", n_layer=2, bfloat16=True, attn_impl="flash",
+        remat=True, fused_head=True, dropout=0.0)
+    tx = optax.adamw(1e-4)
+    state, _, feed = _abstract_step_inputs(
+        model, tx, 8, 1024, one_chip, one_chip)
+
+    def text():
+        step = make_train_step(
+            model, tx, losses.fused_lm_cross_entropy(chunk=256), [],
+            input_key="tokens", target_key="tokens", grad_clip_norm=1.0)
+        return jax.jit(step, donate_argnums=0).lower(
+            state, feed).compile().as_text()
+
+    texts = []
+    for held_to_the_floor in (False, True):
+        if held_to_the_floor:
+            monkeypatch.setattr(
+                losses, "slice_positions",
+                lambda sequences, chunk, seq_len, vocab: chunk)
+        texts.append(text())    # one line: the text holds its caller's
+    (said,) = _said("head_loss/slice")
+    assert said == dict(rows_per_device=2048, positions=256, turns=4,
+                        slice_bytes=2048 * 50257 * 4, floor_positions=256)
+    assert texts[0] == texts[1]
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError,
