@@ -177,8 +177,6 @@ class Smoke:
         The child gets its own process group so a timeout stops
         everything it started."""
         env = dict(os.environ)
-        # an MFU is printed only against observability/profiler's table
-        env.pop("PDT_TPU_PEAK_FLOPS", None)
         env.update(env_extra or {})
         timeout = min(cap_s, self.deadline - time.monotonic())
         check(timeout > 5, f"{name}: no time left in the {TOTAL_BUDGET_S}s budget")

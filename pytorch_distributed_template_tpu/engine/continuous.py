@@ -38,8 +38,8 @@ tolerance exact like the static scheduler's mixed-length batching).
 Restricted to pad-capable models (RoPE positions + non-rolling cache);
 ``serve.py`` falls back to the static scheduler otherwise. The
 reference has no serving path at all (/root/reference/test.py is batch
-eval) — this subsystem is beyond-reference capability, measured by the
-``serve_mixed`` bench rung.
+eval) — this subsystem is beyond-reference capability; no benchmark
+cell measures it yet (PERF.md section 7).
 """
 from __future__ import annotations
 
@@ -51,7 +51,6 @@ import time
 
 import numpy as np
 
-from ..observability.anatomy import AnatomyStore
 from ..observability.trace import span
 from ..utils.promtext import percentile
 from .serving import GenerationService
@@ -477,20 +476,8 @@ class ContinuousBatchingService(GenerationService):
         self._recorder = recorder
         # fleet timeline store (ISSUE 14): each absorbed chunk feeds
         # one observation — counters become interval rates, queue/slot
-        # occupancy sample as gauges (observability/timeseries.py);
-        # the quick_timeseries bench rung gates the per-chunk cost
+        # occupancy sample as gauges (observability/timeseries.py)
         self._tsdb = tsdb
-        # step anatomy (ISSUE 16): kernel-class cost attribution for
-        # the decode-chunk executable. Registration queues ONE
-        # background AOT analysis per signature; the per-chunk cost is
-        # a set lookup + an EWMA update (gated < 2% by the
-        # quick_anatomy bench rung). PDT_ANATOMY=0 disables it.
-        self._anatomy = AnatomyStore()
-        self._anatomy_pending: list = []   # (t_dispatch, steps) FIFO
-        self._anatomy_tried = False        # registered once (shapes are
-        #                                    era-invariant — one sig)
-        self._anatomy_steps = 0            # steps of the analyzed chunk
-        self._anatomy_seen_version = 0     # last version put in a record
         # pool_exhaust fault window: until this monotonic instant the
         # prefix pool reports dry (paged admissions defer, scatter
         # lookups miss) — 0 = no window active
@@ -1550,14 +1537,8 @@ class ContinuousBatchingService(GenerationService):
         lru-cached like any other)."""
         tok, emitted, done, budgets, pad_lens, keys, stops, temps, \
             ks, ps = self._arrays
-        t_dispatch = time.monotonic()
         if self._paged:
             chunk = _paged_chunk_fn(self.model, steps, self.MAX_STOPS)
-            self._register_anatomy(
-                chunk, steps,
-                (self.params, self._cache, self._tables, self._starts,
-                 tok, emitted, done, budgets, pad_lens, keys, stops,
-                 temps, ks, ps))
             with span("serve/chunk_dispatch", steps=steps, paged=True):
                 cache, starts, toks, tok, emitted, done = chunk(
                     self.params, self._cache, self._tables,
@@ -1569,10 +1550,6 @@ class ContinuousBatchingService(GenerationService):
             self.stats["paged_chunks"] += 1
         else:
             chunk = _chunk_fn(self.model, steps, self.MAX_STOPS)
-            self._register_anatomy(
-                chunk, steps,
-                (self.params, self._cache, tok, emitted, done,
-                 budgets, pad_lens, keys, stops, temps, ks, ps))
             with span("serve/chunk_dispatch", steps=steps):
                 cache, toks, tok, emitted, done = chunk(
                     self.params, self._cache, tok, emitted, done,
@@ -1582,25 +1559,7 @@ class ContinuousBatchingService(GenerationService):
                         stops, temps, ks, ps)
         self._p += steps
         self.stats["chunks"] += 1
-        if self._anatomy.enabled:
-            self._anatomy_pending.append((t_dispatch, steps))
         return toks, emitted, done
-
-    def _register_anatomy(self, chunk, steps: int, args) -> None:
-        """Queue the ONE background anatomy analysis of the decode
-        chunk executable. The arg shapes are era-invariant (slots and
-        stop width are fixed), so a single registration covers the
-        engine's lifetime — later calls are one boolean check."""
-        if self._anatomy_tried or not self._anatomy.enabled:
-            return
-        self._anatomy_tried = True
-        if self._anatomy.register("decode_chunk", chunk, args):
-            self._anatomy_steps = steps
-
-    def anatomy_snapshot(self):
-        """The ``decode_step_anatomy`` /metrics section (None until
-        the background analysis lands or when PDT_ANATOMY=0)."""
-        return self._anatomy.snapshot("decode_chunk")
 
     def _absorb(self, toks, emitted, done):
         """Force a dispatched chunk's outputs and hand tokens to their
@@ -1610,16 +1569,6 @@ class ContinuousBatchingService(GenerationService):
             emitted = np.asarray(emitted)
             done = np.asarray(done)
         t_absorb = time.monotonic()
-        if self._anatomy_pending:
-            # chunk wall = dispatch -> force of this chunk's outputs
-            # (absorbs run in dispatch order). Only chunks matching the
-            # analyzed executable's step count feed the EWMA — tail
-            # chunks at era end run fewer in-graph steps and would
-            # skew the modeled-vs-measured gap
-            t0, steps = self._anatomy_pending.pop(0)
-            if steps == self._anatomy_steps or not self._anatomy_steps:
-                self._anatomy.observe(
-                    "decode_chunk", (t_absorb - t0) * 1e3)
         tok0_np: dict = {}          # one D2H read per admission group
         for s in range(self._slots):
             m = self._meta[s]
@@ -1713,15 +1662,6 @@ class ContinuousBatchingService(GenerationService):
                     self.stats.get("tokens_generated", 0),
                 "admissions_total": self.stats.get("admissions", 0),
             }
-            if self._anatomy.version != self._anatomy_seen_version:
-                # step anatomy rides a flight record exactly when the
-                # analysis (re)lands — the offline analyzer reads the
-                # LAST record carrying the field, so one emission per
-                # version is enough and keeps the JSONL lean
-                snap = self._anatomy.snapshot("decode_chunk")
-                if snap:
-                    rec["decode_step_anatomy"] = snap
-                    self._anatomy_seen_version = self._anatomy.version
             if self.tp > 1:
                 # TP serving telemetry (ISSUE 10): constant per-step
                 # accounting (precomputed at setup — tp_stats caches),

@@ -28,8 +28,8 @@ Token-exactness is inherited, not re-proven: a request's tokens depend
 only on its own prompt, seed, and sampling config (the continuous
 engine's contract), and every group runs identical weights — so which
 group serves a request cannot change its output, and (dp=2, tp=2) is
-token-identical to (dp=1, tp=1) by construction (gated anyway in the
-``serve_disagg`` bench rung).
+token-identical to (dp=1, tp=1) by construction (asserted anyway in
+tests/test_disagg.py).
 
 At ``tp == 1`` a group has no mesh: its params are COMMITTED to the
 group's device, and jax places every dispatch there (uncommitted

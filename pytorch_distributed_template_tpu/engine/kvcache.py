@@ -991,7 +991,7 @@ class PrefixCache:
             # later reads it stays a zero-copy pointer update), so on a
             # decode-role replica warm_admit_copy_bytes_total equals
             # exactly the page-transfer bytes (accounted like PR 10's
-            # collectives: observable, gated in the serve_disagg rung).
+            # collectives: observable, asserted in tests/test_disagg.py).
             "pages_exported": 0,
             "pages_imported": 0,
             "page_ship_out_bytes": 0,
@@ -1631,7 +1631,7 @@ class PrefixCache:
             # the transfer IS the decode replica's only genuine warm-
             # admit copy: the paged admit that reads these pages stays
             # a pointer update, so this counter's value on a decode
-            # replica is exactly the bytes shipped in (rung-gated)
+            # replica is exactly the bytes shipped in (tests/test_disagg.py)
             self.stats["warm_admit_copy_bytes"] += nbytes
         return {"imported_blocks": n,
                 "cached_tokens": (have_n + n) * self.block,

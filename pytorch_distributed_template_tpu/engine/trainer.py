@@ -44,10 +44,8 @@ from ..observability.telemetry import drain_compile_events
 from ..observability.trace import get_recorder as get_span_recorder
 from ..observability.trace import span
 from ..ops.augment import build_augment
-from ..observability.anatomy import analyze_compiled, anatomy_enabled
-from ..observability.anatomy import render_anatomy as _render_anatomy
 from ..observability.profiler import (
-    ThroughputMeter, TraceCapture, executable_flops, mfu,
+    ThroughputMeter, TraceCapture, compiled_flops, mfu,
 )
 from ..parallel import (
     batch_sharding, dist, mesh_from_config, train_step_compile_options,
@@ -640,11 +638,6 @@ class Trainer(BaseTrainer):
         )
         self._peak_flops = prof_cfg.get("peak_flops_per_device")
         self._flops_per_step = None  # measured lazily on the first batch
-        # step anatomy (ISSUE 16): kernel-class roofline analysis of the
-        # compiled train step, sharing the first-step AOT compile with
-        # the FLOPs probe; rendered against the live steps/s each log
-        # window (train_step_anatomy flight-record field)
-        self._train_anatomy = None
         # latch: the first-step meter reset (+ the profiler's one-time
         # AOT cost analysis) runs at most once per process
         self._first_step_timed = False
@@ -942,22 +935,12 @@ class Trainer(BaseTrainer):
                 # first — compiling — step has a nonzero ordinal.)
                 self._first_step_timed = True
                 if self.profile_enabled:
-                    # ONE AOT lower+compile of the step feeds both the
-                    # FLOPs probe and the kernel-class anatomy; the
-                    # latch stays set even when the backend reports no
-                    # FLOPs
-                    compiled = None
-                    try:
-                        compiled = self._train_step.lower(
-                            self.state, batch).compile()
-                    except Exception:  # noqa: BLE001 — profiling must
-                        pass           # never break the step loop
-                    if compiled is not None:
-                        self._flops_per_step = executable_flops(
-                            compiled)
-                        if anatomy_enabled():
-                            self._train_anatomy = analyze_compiled(
-                                compiled)
+                    # ONE AOT lower+compile of the step, for the FLOPs
+                    # probe (None when the backend reports none; the
+                    # latch stays set, and profiling never breaks the
+                    # step loop)
+                    self._flops_per_step = compiled_flops(
+                        self._train_step, self.state, batch)
                 jax.block_until_ready(m)
                 rec["step_program"] = self._step_program_facts(batch)
                 self.throughput.reset()  # exclude compilation from rates
@@ -1118,14 +1101,6 @@ class Trainer(BaseTrainer):
                 if util is not None:
                     self.writer.add_scalar("mfu", util)
                     rec["mfu"] = round(util, 4)
-                if (self._train_anatomy is not None
-                        and rate["steps_per_sec"] > 0):
-                    # kernel-class anatomy against this window's
-                    # measured step wall; the offline analyzer reads
-                    # the LAST record carrying the field
-                    rec["train_step_anatomy"] = _render_anatomy(
-                        self._train_anatomy,
-                        wall_ms=1e3 / rate["steps_per_sec"])
             self.logger.debug(
                 "Train Epoch: %d %s Loss: %.6f",
                 epoch, self._progress(batch_idx + 1), loss_val,

@@ -12,11 +12,6 @@ operator otherwise greps four JSONL files for:
 - **sparklines** over the poller-fed time-series store
   (observability/timeseries.py): queue depth, tokens/s, goodput/s,
   brownout level — the trend ``/metrics`` cannot show;
-- the **step anatomy panel** (ISSUE 16): per-replica modeled
-  kernel-class decomposition read from the poller's last
-  ``/metrics?format=json`` body — where a decode step's time goes
-  (attention vs dense matmul vs MoE dispatch vs collectives), with
-  roofline bound and dispatch-gap fraction;
 - the **p99 attribution table** from the run's stitched spans (the
   same machinery as ``scripts/trace_stitch.py``, bounded so a huge
   span archive cannot wedge a dashboard request).
@@ -256,46 +251,6 @@ def render_dashboard(manager, admission, stats, slo=None,
             parts.append(
                 f'<div class="sparkrow">{html.escape(name)} '
                 f"= {round(last, 3)}{sparkline(vals)}</div>")
-
-    # -- step anatomy (ISSUE 16) -------------------------------------------
-    # replicas running with anatomy enabled surface a rendered
-    # decode_step_anatomy on /metrics?format=json; the poller already
-    # stores that body per replica, so the panel is a read of polled
-    # state — never a replica touch. Degrades to a muted note when no
-    # replica reports one (PDT_ANATOMY=0, analysis not landed, or an
-    # old replica build).
-    parts.append("<h2>Step anatomy (modeled kernel classes)</h2>")
-    anat_rows = []
-    for r in snap["replicas"]:
-        rep = manager.replicas.get(r["id"])
-        an = ((rep.polled or {}).get("decode_step_anatomy")
-              if rep is not None else None)
-        if isinstance(an, dict) and an.get("classes"):
-            anat_rows.append((r["id"], an))
-    if not anat_rows:
-        parts.append('<p class="muted">no replica reports a decode '
-                     "step anatomy (disabled, or the background "
-                     "analysis has not landed yet)</p>")
-    for rid, an in anat_rows[:2]:
-        head = (f"replica {rid}: modeled "
-                f"{an.get('est_step_time_ms')} ms")
-        if an.get("wall_ms") is not None:
-            head += f" / measured {an.get('wall_ms')} ms"
-        if an.get("dispatch_gap_frac") is not None:
-            head += (" · dispatch gap "
-                     f"{round(100 * an['dispatch_gap_frac'], 1)}%")
-        if an.get("observed_steps"):
-            head += f" · {an['observed_steps']} steps"
-        parts.append(f'<p class="muted">{html.escape(head)}</p>')
-        rows = [(cls, c.get("frac_time"), c.get("time_ms", "-"),
-                 round(float(c.get("flops") or 0) / 1e9, 2),
-                 round(float(c.get("bytes") or 0) / 1e6, 1),
-                 c.get("bound") or "-")
-                for cls, c in sorted(
-                    an["classes"].items(),
-                    key=lambda kv: -(kv[1].get("frac_time") or 0))]
-        parts += _table(rows, ("kernel class", "time frac",
-                               "time ms", "GFLOPs", "MB", "bound"))
 
     # -- p99 attribution ---------------------------------------------------
     parts.append("<h2>p99 attribution (stitched spans)</h2>")

@@ -569,8 +569,8 @@ def summarize(replayed: dict, trace: Optional[List[dict]] = None,
     # terminal-outcome accounting (ISSUE 9): a request is STRANDED
     # when it never reached ANY classified outcome — no HTTP status,
     # no deliberate cancel (client-side timeouts and connect failures
-    # land here), or its worker thread never even reported. The chaos
-    # rung gates stranded == 0: every fault must resolve to a
+    # land here), or its worker thread never even reported. A chaos run
+    # wants stranded == 0: every fault must resolve to a
     # classified terminal state, never a silent hang.
     stranded = sum(1 for r in results
                    if r["status"] is None and not r["cancelled"]

@@ -9,8 +9,8 @@ Virtual time costs nothing: a diurnal day compresses to however fast
 the event loop runs, so policies and SLO budgets are validated at
 request scales this container can't run live. The policy interface is
 :mod:`.autoscaler`'s — the SAME :class:`AutoscalePolicy` instance
-class drives both worlds, which is the validation contract the bench
-rung gates (sim vs live within 15% on TTFT/TPOT p99).
+class drives both worlds (a sim-vs-live comparison on a real fleet has
+no test yet: ROADMAP D11).
 
 Determinism contract (pinned by tests/test_autoscale.py): same trace
 + same model + same seed ⇒ byte-identical event log and request rows.

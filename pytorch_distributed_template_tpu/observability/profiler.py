@@ -12,8 +12,8 @@ TPU-native tier promised in SURVEY.md §5 "Tracing / profiling":
 - ``compiled_flops``: cost analysis of the *compiled* XLA executable — the
   exact FLOPs the hardware will run (post-fusion), not an analytic estimate.
 - ``mfu``: model FLOPs utilization against the chip's peak, with a device
-  table for TPU generations (override via config or
-  ``PDT_TPU_PEAK_FLOPS``).
+  table for TPU generations (config key
+  ``trainer.profiler.peak_flops_per_device`` for a device it lacks).
 - ``TraceCapture``: a step-windowed ``jax.profiler`` trace (view in
   TensorBoard's profile plugin) — start/stop driven by the trainer's step
   counter so the capture covers steady-state steps, not compilation.
@@ -46,9 +46,6 @@ PEAK_FLOPS_TABLE = (
 
 def peak_flops_per_device(device=None) -> Optional[float]:
     """Peak FLOPs/s for one device, or None when unknown (e.g. CPU)."""
-    env = os.environ.get("PDT_TPU_PEAK_FLOPS")
-    if env:
-        return float(env)
     if device is None:
         device = jax.devices()[0]
     kind = getattr(device, "device_kind", "").lower()

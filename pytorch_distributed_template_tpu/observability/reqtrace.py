@@ -813,30 +813,13 @@ def _flow_id(rid: str) -> int:
     return h or 1
 
 
-#: virtual thread id for the modeled kernel-class track — far above any
-#: real tid so the anatomy row never interleaves with measured spans
-_ANATOMY_TID = 999_983
-
-
 def to_perfetto(spans: List[dict],
-                offsets: Optional[Dict[tuple, float]] = None,
-                anatomy: Optional[dict] = None,
-                anatomy_rids=None) -> dict:
+                offsets: Optional[Dict[tuple, float]] = None) -> dict:
     """One merged Chrome-trace-event JSON over every process's spans,
     with per-process ``process_name`` metadata and ``s``/``f`` flow
     events linking the router's proxy span to the replica's handler
     span per request — load it in Perfetto and follow a request across
-    process rows.
-
-    ``anatomy`` (ISSUE 16) is a rendered ``decode_step_anatomy``
-    section (observability/anatomy.render_anatomy — classes with
-    ``frac_time``/``bound`` + ``dispatch_gap_frac``): each selected
-    request's decode window (first_token -> complete) gains a "step
-    anatomy (modeled)" track splitting it into kernel-class slices
-    proportional to their modeled time share, with the dispatch gap as
-    its own trailing slice. ``anatomy_rids`` restricts the expansion
-    (trace_stitch passes the p99 request); None expands every stitched
-    decode window."""
+    process rows."""
     if offsets is None:
         offsets = estimate_offsets(spans)
     spans = apply_offsets(spans, offsets)
@@ -888,56 +871,6 @@ def to_perfetto(spans: List[dict],
             "ts": round((float(http["t"]) - t_origin) * 1e6, 1),
             "args": {"rid": rid},
         })
-    if anatomy and (anatomy.get("classes") or {}):
-        classes = [(cls, c) for cls, c in sorted(
-            anatomy["classes"].items(),
-            key=lambda kv: -(kv[1].get("frac_time") or 0.0))
-            if (c.get("frac_time") or 0.0) > 0.0]
-        gap = float(anatomy.get("dispatch_gap_frac") or 0.0)
-        named_pids: set = set()
-        want = set(anatomy_rids) if anatomy_rids is not None else None
-        for rid, recs in _by_rid(spans).items():
-            if want is not None and rid not in want:
-                continue
-            ft = _named(recs, "first_token")
-            done = _named(recs, "complete")
-            if ft is None or done is None:
-                continue
-            t0, t1 = float(ft["t"]), float(done["t"])
-            if t1 <= t0:
-                continue
-            pid = pid_for(ft)
-            if pid not in named_pids:
-                named_pids.add(pid)
-                events.append({
-                    "ph": "M", "name": "thread_name", "pid": pid,
-                    "tid": _ANATOMY_TID,
-                    "args": {"name": "step anatomy (modeled)"},
-                })
-            dur_us = (t1 - t0) * 1e6
-            dev_us = dur_us * (1.0 - gap)
-            cursor = (t0 - t_origin) * 1e6
-            for cls, c in classes:
-                d = dev_us * float(c["frac_time"])
-                events.append({
-                    "name": f"kernel/{cls}", "ph": "X",
-                    "cat": "anatomy", "ts": round(cursor, 1),
-                    "dur": max(round(d, 1), 1),
-                    "pid": pid, "tid": _ANATOMY_TID,
-                    "args": {"rid": rid,
-                             "frac_time": c.get("frac_time"),
-                             "bound": c.get("bound")},
-                })
-                cursor += d
-            if gap > 0:
-                events.append({
-                    "name": "dispatch_gap", "ph": "X",
-                    "cat": "anatomy", "ts": round(cursor, 1),
-                    "dur": max(round(dur_us - dev_us, 1), 1),
-                    "pid": pid, "tid": _ANATOMY_TID,
-                    "args": {"rid": rid,
-                             "dispatch_gap_frac": round(gap, 4)},
-                })
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 

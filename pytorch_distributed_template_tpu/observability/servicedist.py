@@ -282,7 +282,7 @@ class GoodputMeter:
     deadline-carrying requests — the budget was feasible AND met) and
     per-tenant raw/good shares. Rates are over the meter's lifetime
     since its first observation; ``goodput ≤ served ≤ raw`` holds by
-    construction and the serve_fleet rung gates it.
+    construction.
     """
 
     #: outcomes whose tokens count as SERVED (the router's _generate

@@ -1,13 +1,13 @@
 """Flight recorder: one structured record per training step.
 
 MegaScale-style per-step telemetry as a first-class subsystem: the
-trainer (and bench.py) feed one record per step into a bounded
+trainer feeds one record per step into a bounded
 in-memory ring buffer, and process 0 appends each record as a JSON
 line to ``<run_dir>/telemetry.jsonl``. A wedged or crashed run leaves
 its last ``capacity`` steps on disk and in the watchdog's stall dump
 (utils/watchdog.py) instead of evaporating; a healthy run leaves a
-machine-parseable timeline that tooling (bench.py, sweeps, dashboards)
-reads back without scraping logs.
+machine-parseable timeline that tooling (benchmarks/run.py, sweeps,
+dashboards) reads back without scraping logs.
 
 Record schema (all optional except ``v``/``step``/``t``):
 
@@ -133,7 +133,7 @@ _compile_events: "collections.deque" = collections.deque(maxlen=256)
 _compile_listener_installed = False
 # persistent-compilation-cache counters (utils/compile_cache wires the
 # cache itself; these count process lifetime hits/misses/requests —
-# /metrics and the bench warm_start rung read them). A "miss" IS a real
+# /metrics reads them). A "miss" IS a real
 # XLA compile; a "hit" is an executable deserialized from the cache dir.
 _cache_counters = {"hits": 0, "misses": 0, "requests": 0}
 
@@ -204,8 +204,7 @@ def compile_cache_stats() -> dict:
     ``hits`` counts executables loaded from the cache dir instead of
     compiled. All zero when the cache was never enabled (the listener
     only sees events jax emits, and jax emits none without a cache
-    dir). Consumers: serve.py ``GET /metrics`` and bench.py's
-    ``warm_start`` rung."""
+    dir). Consumer: serve.py ``GET /metrics``."""
     try:
         import jax
 
@@ -237,7 +236,7 @@ class FlightRecorder:
         N-th record (0 disables the memory fields entirely).
     :param filename: JSONL file name inside ``run_dir``.
 
-    Thread-safe: the serving/bench paths record from worker threads.
+    Thread-safe: the serving path records from worker threads.
     """
 
     def __init__(self, run_dir=None, capacity: int = 512,
@@ -334,7 +333,7 @@ class FlightRecorder:
 
     def aggregates(self) -> dict:
         """Throughput over the buffered window, computed from the
-        records themselves (the numbers bench.py reports): steps/s from
+        records themselves: steps/s from
         summed ``wall_ms``, tokens/s and examples/s from the summed
         ``tokens``/``examples`` fields over the same wall time."""
         records = self.last()
@@ -391,7 +390,7 @@ class FlightRecorder:
 
 def read_jsonl(path) -> list:
     """Load a telemetry JSONL file back into a list of records —
-    the round-trip consumers (tests, dashboards, bench) use."""
+    the round-trip consumers (tests, dashboards) use."""
     records = []
     with open(path) as f:
         for line in f:

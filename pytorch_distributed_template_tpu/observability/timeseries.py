@@ -29,8 +29,7 @@ gauges)`` calls into fixed-interval *points*:
 
 Feeders: the fleet poller calls ``observe`` once per health sweep
 (fleet aggregates + admission depths), and the continuous engine once
-per absorbed chunk (tokens/admissions/queue/pool). The recorder-side
-cost is gated < 2% by the ``quick_timeseries`` bench rung.
+per absorbed chunk (tokens/admissions/queue/pool).
 
 A process-wide default store (:func:`set_default_store`) lets the
 watchdog's ``stall_dump.json`` and the health layer's

@@ -1,7 +1,7 @@
 """In-process training supervisor: spawn, classify, back off, resume.
 
-Replaces the bash retry loop (``scripts/run_resilient.sh``) with a
-process manager that actually understands what happened to its child:
+A process manager that understands what happened to its child, where a
+bash retry loop would not:
 
 - **exit classification** — ``clean`` (rc 0), ``preemption`` (the
   trainer's SIGTERM-drain exit code :data:`EXIT_PREEMPTED`, or the

@@ -7,7 +7,7 @@ serving engine recompiled its ladder on every restart. jax ships a
 content-addressed on-disk executable cache behind
 ``jax_compilation_cache_dir``; this module is the one place that
 decides where it lives, so every entrypoint (train.py, test.py,
-serve.py, generate.py, bench.py) behaves identically.
+serve.py, generate.py) behaves identically.
 
 The rule, in this order:
 
@@ -33,8 +33,9 @@ The rule, in this order:
 
 Counters: a hit/miss listener (observability/telemetry) counts every
 cache event process-wide — surfaced per-step in the flight recorder's
-``compile_events`` and cumulatively via serve.py ``GET /metrics`` and
-the bench ``warm_start`` rung. Note jax's ``backend_compile_duration``
+``compile_events`` and cumulatively via serve.py ``GET /metrics``
+(``benchmarks/run.py`` reads its ``cache_misses`` from jax's own
+events). Note jax's ``backend_compile_duration``
 monitoring event fires on hits AND misses (it wraps
 ``compile_or_get_cached``), so the cache events are the only honest
 "was that a real compile?" signal.
@@ -65,8 +66,8 @@ def configure_compile_cache(config=None, cache_dir: Optional[str] = None,
 
     ``config`` is a ConfigParser or plain dict; its ``compile_cache``
     section is read as documented above. An explicit ``cache_dir``
-    wins over the section (bench.py passes ``--compile-cache-dir``
-    directly); the environment variable wins over both.
+    wins over the section (the ``--compile-cache-dir`` flag of the
+    scripts); the environment variable wins over both.
 
     Never raises: a bad cache dir degrades to an uncached run with a
     warning — compile caching is an optimization, not a dependency.

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Random-init params-only serving artifact, in seconds.
 
-The fleet bench rung, the ``fleet-smoke`` CI job, and the serving
-smoke tests need something ``serve.py -r`` can load WITHOUT a training
+The serving and fleet tests and the drive recipes need something ``serve.py -r`` can load WITHOUT a training
 run: routing, admission control, SSE plumbing, and recovery mechanics
 are model-quality-independent, so a randomly initialized TinyLlama is
 exactly as good a traffic target as a trained one — and ~100x faster
@@ -59,11 +58,11 @@ def make_artifact(out_dir, arch: str = "TinyLlama",
     out_dir = Path(out_dir)
     out_dir.mkdir(parents=True, exist_ok=True)
     if long:
-        # --long (ISSUE 15): the long-context bench/CI traffic target —
+        # --long (ISSUE 15): the long-context traffic target —
         # a bigger position budget, a sliding window (paged ring
         # layout), an int8-KV pool, and chunked streaming prefill, so
-        # the longctx-smoke job exercises every ISSUE 15 layer from one
-        # artifact. Explicit flags still win.
+        # one artifact exercises every ISSUE 15 layer. Explicit flags
+        # still win.
         max_len = int(max_len) if int(max_len) != 256 else 4096
         window = int(window) or 512
         kv_quant = kv_quant or "int8"
@@ -125,7 +124,7 @@ def make_artifact(out_dir, arch: str = "TinyLlama",
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
         description="random-init params-only serving artifact "
-                    "(fleet bench / CI / smoke traffic target)")
+                    "(fleet / smoke traffic target)")
     p.add_argument("-o", "--out", required=True,
                    help="artifact directory (config.json + model/)")
     p.add_argument("--arch", default="TinyLlama")
@@ -146,8 +145,7 @@ def main(argv=None) -> int:
     p.add_argument("--long", action="store_true",
                    help="long-context variant (ISSUE 15): 4k max_len, "
                         "sliding window (paged ring), int8-KV pool, "
-                        "chunked streaming prefill — the longctx-"
-                        "smoke / serve_longctx traffic target")
+                        "chunked streaming prefill")
     p.add_argument("--window", type=int, default=0,
                    help="sliding-window size baked into the arch "
                         "(0 = full attention; --long defaults 512)")

@@ -23,7 +23,7 @@ supervisor SIGTERM-drains its replica (serve.py finishes in-flight
 requests and exits via the preemption path, rc 75), and the process
 exits 0 with no orphans. ``--admin`` enables ``POST
 /admin/kill|drain?replica=rN`` — the chaos/rolling-restart hooks the
-bench and CI use. Prints ``READY http://host:port`` once the router
+tests use. Prints ``READY http://host:port`` once the router
 is bound; replica readiness is visible on ``GET /healthz``.
 
 Stdlib-only (the router manages jax processes, it is not one); run

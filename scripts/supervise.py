@@ -18,8 +18,8 @@ logs every lifecycle event to ``supervisor.jsonl``
     # arbitrary command (tests, non-train workloads)
     python scripts/supervise.py --raw -- python my_job.py
 
-Env compatibility with the old ``run_resilient.sh``: ``MAX_RESTARTS``
-and ``RESTART_DELAY_S`` seed the corresponding flags' defaults.
+Env: ``MAX_RESTARTS`` and ``RESTART_DELAY_S`` seed the corresponding
+flags' defaults.
 
 Child environment: ``PDT_ATTEMPT`` (1-based attempt number — the
 fault plan's attempt gate), ``PDT_HEARTBEAT_FILE`` (the trainer's

@@ -1,17 +1,12 @@
 #!/usr/bin/env python
-"""Offline telemetry analyzer + CI regression gate.
+"""Offline telemetry analyzer + service-model drift gate.
 
 Turns a run's observability artifacts — ``telemetry.jsonl`` (flight
 recorder), ``trace.json`` (host spans), ``anomaly_*.json`` (numerics
-forensics), and a bench final-line JSON — into one report, and gates CI
-on it:
+forensics) — into one report:
 
     # human/markdown report over a run dir
     python scripts/telemetry_report.py --run-dir saved/<exp>/train/<id>
-
-    # bench-smoke regression gate: nonzero exit on regression
-    python scripts/telemetry_report.py --bench /tmp/bench.out \
-        --compare bench_baseline.json --tolerance 0.1
 
 Report fields (JSON with ``--json``, markdown otherwise):
 
@@ -31,22 +26,15 @@ Report fields (JSON with ``--json``, markdown otherwise):
   ``--run-dir``): routed-by-policy counters, prefix-routed fraction,
   shed/dispatch errors, ejections/re-admissions with recovery times,
   and whether the fleet drained clean (no orphans);
-- top host spans by total time (from ``trace.json``);
-- the bench final line's headline numbers.
-
-``--compare BASELINE`` compares the current bench JSON against a
-committed baseline: for each metric (default ``steps/s,tokens/s``) the
-gate fails (exit 1) when ``current < baseline * (1 - tolerance)``.
-Improvements and same-or-better runs pass; metrics missing from either
-side are reported and skipped. Exit codes: 0 ok, 1 regression, 2 usage
-or unreadable input.
+- top host spans by total time (from ``trace.json``).
 
 ``--drift CURRENT BASELINE`` (ISSUE 14) is the DISTRIBUTION-level
 gate: two ``service_model.json`` files (observability/servicedist.py)
 compared per segment on p50/p99 with a relative
 ``--drift-tolerance`` — exit 1 on any shift in EITHER direction, so a
 p99 regression in ``admit`` fails CI even when aggregate tok/s held.
-A model self-compares clean at tolerance 0.
+A model self-compares clean at tolerance 0. Exit codes: 0 ok, 1 drift,
+2 usage or unreadable input.
 """
 from __future__ import annotations
 
@@ -56,40 +44,6 @@ import sys
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
-
-# metric name in the bench final line -> fallback path in its summary
-_BENCH_METRIC_FALLBACK = {
-    "steps/s": ("summary", "quick", "steps_per_sec"),
-    "tokens/s": ("summary", "quick", "tokens_per_sec"),
-    # serving rung gates (ISSUE 12 satellite): TP throughput, the
-    # disaggregated decode rate, and how well the role-split arm
-    # holds the decode-only tail (1.0 = perfectly flat) — all
-    # higher-is-better so the one-sided floor gate applies
-    "serve_tp_tok_s": ("summary", "serve_tp", "tokens_per_sec_tp1"),
-    "serve_disagg_decode_tok_s": ("summary", "serve_disagg",
-                                  "decode_tok_s_base"),
-    "serve_disagg_hold": ("summary", "serve_disagg", "disagg_hold"),
-    # tiered KV pool gates (ISSUE 13): warm-hit hold vs the
-    # infinite-pool oracle and the re-warm-beats-cold ratio — both
-    # higher-is-better for the one-sided floor gate
-    "serve_kvtier_hold": ("summary", "serve_kvtier", "warm_hit_hold"),
-    "serve_kvtier_rewarm": ("summary", "serve_kvtier",
-                            "rewarm_speedup"),
-    # long-context serving gates (ISSUE 15): the warm shared-document
-    # TTFT speedup and the chunked-vs-monolithic TPOT-p99 separation
-    # (monolithic_hold / chunked_hold) — both higher-is-better for the
-    # one-sided floor gate
-    "serve_longctx_ttft": ("summary", "serve_longctx",
-                           "warm_ttft_speedup"),
-    "serve_longctx_decode_hold": ("summary", "serve_longctx",
-                                  "chunk_separation"),
-    # autoscaling gate (ISSUE 19): replica-seconds saved by the
-    # policy vs the static peak-provisioned control arm on the same
-    # diurnal trace — higher-is-better for the one-sided floor gate
-    "serve_autoscale_saving": ("summary", "serve_autoscale",
-                               "replica_seconds_saving"),
-}
-
 
 # ---------------------------------------------------------------------------
 # input loading
@@ -107,27 +61,6 @@ def load_jsonl(path) -> list:
                 except json.JSONDecodeError:
                     pass  # a torn tail line (crash mid-write) is expected
     return records
-
-
-def load_bench_json(path) -> dict:
-    """A bench final line from either a plain JSON file (the committed
-    baseline) or a captured stdout stream (``tee /tmp/bench.out``) —
-    whole-file parse first, else the LAST parseable stdout line (the
-    bench contract: the final stdout line is always the JSON)."""
-    text = Path(path).read_text()
-    try:
-        return json.loads(text)
-    except json.JSONDecodeError:
-        pass
-    for line in reversed(text.strip().splitlines()):
-        line = line.strip()
-        if not line:
-            continue
-        try:
-            return json.loads(line)
-        except json.JSONDecodeError:
-            continue
-    raise ValueError(f"no parseable JSON line in {path}")
 
 
 # ---------------------------------------------------------------------------
@@ -264,7 +197,7 @@ def analyze_tp(records: list) -> dict:
     engine's per-chunk ``serve_chunk`` records: the TP degree and the
     per-decode-step collective accounting (compiled-HLO counted,
     engine-side constant — the LAST record is authoritative), plus the
-    analytic floor it is gated against in the ``serve_tp`` bench rung.
+    analytic floor beside it.
     Empty for single-chip runs (tp fields absent)."""
     serve = [r for r in records if r.get("event") == "serve_chunk"
              and r.get("tp_degree")]
@@ -599,103 +532,6 @@ def analyze_anomalies(run_dir) -> dict:
     return {"dump_count": len(dumps), "dumps": dumps}
 
 
-def bench_headline(bench: dict) -> dict:
-    out = {}
-    for key in ("metric", "value", "unit", "steps/s", "tokens/s"):
-        if bench.get(key) is not None:
-            out[key] = bench[key]
-    return out
-
-
-# ---------------------------------------------------------------------------
-# regression gate
-# ---------------------------------------------------------------------------
-
-
-def analyze_anatomy(records: list) -> dict:
-    """Step-anatomy section (ISSUE 16): the LAST flight record
-    carrying ``train_step_anatomy`` / ``decode_step_anatomy`` (the
-    engines attach the kernel-class breakdown when the background
-    analysis lands / per log window), re-shaped for the markdown
-    renderer. Empty when the run predates anatomy or PDT_ANATOMY=0."""
-    out: dict = {}
-    for field, label in (("train_step_anatomy", "train"),
-                         ("decode_step_anatomy", "decode")):
-        last = next((r[field] for r in reversed(records)
-                     if isinstance(r.get(field), dict)), None)
-        if not last:
-            continue
-        entry = {
-            k: last[k] for k in (
-                "est_step_time_ms", "wall_ms", "dispatch_gap_frac",
-                "total_flops", "observed_steps")
-            if last.get(k) is not None
-        }
-        classes = last.get("classes") or {}
-        entry["classes"] = [
-            {"class": cls, **c} for cls, c in sorted(
-                classes.items(),
-                key=lambda kv: -(kv[1].get("frac_time") or 0.0))
-        ]
-        out[label] = entry
-    return out
-
-
-def _bench_metric(bench: dict, key: str):
-    v = bench.get(key)
-    if isinstance(v, (int, float)):
-        return float(v)
-    node = bench
-    for part in _BENCH_METRIC_FALLBACK.get(key, ()):
-        if not isinstance(node, dict):
-            return None
-        node = node.get(part)
-    return float(node) if isinstance(node, (int, float)) else None
-
-
-def compare(current: dict, baseline: dict, tolerance: float,
-            metrics=("steps/s", "tokens/s")) -> dict:
-    """Throughput gate: fail when current < baseline * (1 - tolerance).
-
-    Returns ``{"compared": [...], "regressions": [...],
-    "skipped": [...], "missing": [...]}``; callers exit nonzero on any
-    regression. ``missing`` is the loud arm of the skip logic (ISSUE
-    16 satellite): the BASELINE carries the metric but the current
-    run's artifacts lack its rung — a silently skipped gate there
-    means a bench rung stopped running and nothing would ever fail, so
-    callers must treat it as a usage error naming the rung."""
-    compared, regressions, skipped, missing = [], [], [], []
-    for key in metrics:
-        cur = _bench_metric(current, key)
-        base = _bench_metric(baseline, key)
-        if cur is None and base is not None and base > 0:
-            path = _BENCH_METRIC_FALLBACK.get(key) or ()
-            missing.append({
-                "metric": key,
-                "rung": path[1] if len(path) > 1 else key,
-                "baseline": base,
-            })
-            continue
-        if cur is None or base is None or base <= 0:
-            skipped.append({"metric": key, "current": cur,
-                            "baseline": base})
-            continue
-        floor = base * (1.0 - tolerance)
-        row = {
-            "metric": key,
-            "current": cur,
-            "baseline": base,
-            "floor": round(floor, 4),
-            "ratio": round(cur / base, 4),
-            "ok": cur >= floor,
-        }
-        compared.append(row)
-        if not row["ok"]:
-            regressions.append(row)
-    return {"compared": compared, "regressions": regressions,
-            "skipped": skipped, "missing": missing}
-
-
 # ---------------------------------------------------------------------------
 # rendering
 # ---------------------------------------------------------------------------
@@ -720,33 +556,6 @@ def to_markdown(report: dict) -> str:
     table("Flight recorder", report.get("telemetry", {}))
     table("Prefix cache (serving)", report.get("prefix_cache", {}))
     table("Tensor parallel (serving)", report.get("tensor_parallel", {}))
-    anatomy = report.get("anatomy") or {}
-    for label in ("train", "decode"):
-        an = anatomy.get(label)
-        if not an:
-            continue
-        lines.append(f"## Step anatomy ({label})")
-        lines.append("")
-        head = [f"modeled {an.get('est_step_time_ms', '?')} ms"]
-        if an.get("wall_ms") is not None:
-            head.append(f"measured {an['wall_ms']} ms")
-        if an.get("dispatch_gap_frac") is not None:
-            head.append(
-                f"dispatch gap {an['dispatch_gap_frac']:.1%}")
-        lines.append("Step: " + ", ".join(head) + ".")
-        lines.append("")
-        lines.append("| kernel class | time frac | time ms | GFLOPs | "
-                     "MB | bound |")
-        lines.append("|---|---|---|---|---|---|")
-        for c in an.get("classes", [])[:8]:
-            time_ms = c.get("time_ms")
-            lines.append(
-                f"| {c['class']} | {c.get('frac_time', 0):.1%} | "
-                f"{time_ms if time_ms is not None else '-'} | "
-                f"{c.get('flops', 0) / 1e9:.3f} | "
-                f"{c.get('bytes', 0) / 2**20:.2f} | "
-                f"{c.get('bound', '-')} |")
-        lines.append("")
     table("Supervisor", report.get("supervisor", {}))
     table("Fleet (router)", report.get("fleet", {}))
     table("Disaggregation (serving)", report.get("disagg", {}))
@@ -798,31 +607,6 @@ def to_markdown(report: dict) -> str:
                 f"{', '.join(d['reasons'])}"
             )
         lines.append("")
-    table("Bench", report.get("bench", {}))
-    cmp_ = report.get("compare") or {}
-    if (cmp_.get("compared") or cmp_.get("skipped")
-            or cmp_.get("missing")):
-        lines.append("## Regression gate")
-        lines.append("")
-        lines.append("| metric | current | baseline | floor | verdict |")
-        lines.append("|---|---|---|---|---|")
-        for row in cmp_.get("compared", []):
-            verdict = "ok" if row["ok"] else "**REGRESSION**"
-            lines.append(
-                f"| {row['metric']} | {row['current']} | "
-                f"{row['baseline']} | {row['floor']} | {verdict} |"
-            )
-        for row in cmp_.get("skipped", []):
-            lines.append(
-                f"| {row['metric']} | {row['current']} | "
-                f"{row['baseline']} | - | skipped |"
-            )
-        for row in cmp_.get("missing", []):
-            lines.append(
-                f"| {row['metric']} | rung `{row['rung']}` absent | "
-                f"{row['baseline']} | - | **MISSING RUNG** |"
-            )
-        lines.append("")
     return "\n".join(lines)
 
 
@@ -833,7 +617,7 @@ def to_markdown(report: dict) -> str:
 
 def main(argv=None) -> int:
     p = argparse.ArgumentParser(
-        description="offline telemetry analyzer + regression gate"
+        description="offline telemetry analyzer + drift gate"
     )
     p.add_argument("--run-dir", type=str, default=None,
                    help="run directory: picks up telemetry.jsonl, "
@@ -857,17 +641,6 @@ def main(argv=None) -> int:
                         "auto-discovers every spans.jsonl under it; "
                         "scripts/trace_stitch.py renders the full "
                         "per-request tables + Perfetto trace)")
-    p.add_argument("--bench", type=str, default=None,
-                   help="bench output: final-line JSON file or a "
-                        "captured stdout stream (tee)")
-    p.add_argument("--compare", type=str, default=None, metavar="BASELINE",
-                   help="baseline bench JSON to gate against "
-                        "(requires --bench)")
-    p.add_argument("--tolerance", type=float, default=0.1,
-                   help="allowed fractional regression vs baseline "
-                        "(0.1 = fail below 90%% of baseline)")
-    p.add_argument("--metrics", type=str, default="steps/s,tokens/s",
-                   help="comma-separated bench metrics to gate on")
     p.add_argument("--drift", type=str, nargs=2, default=None,
                    metavar=("CURRENT", "BASELINE"),
                    help="distribution-level regression gate (ISSUE "
@@ -904,9 +677,6 @@ def main(argv=None) -> int:
             tp = analyze_tp(records)
             if tp:
                 report["tensor_parallel"] = tp
-            anatomy = analyze_anatomy(records)
-            if anatomy:
-                report["anatomy"] = anatomy
         trace_path = args.trace
         if trace_path is None and run_dir is not None:
             cand = run_dir / "trace.json"
@@ -952,17 +722,13 @@ def main(argv=None) -> int:
                 report["reqtrace"] = rt
         if run_dir is not None:
             report["anomalies"] = analyze_anomalies(run_dir)
-        bench = None
-        if args.bench is not None:
-            bench = load_bench_json(args.bench)
-            report["bench"] = bench_headline(bench)
     except (OSError, ValueError) as e:
         print(f"telemetry_report: {e}", file=sys.stderr)
         return 2
-    if not report and args.compare is None and args.drift is None:
+    if not report and args.drift is None:
         p.print_usage(sys.stderr)
         print("telemetry_report: nothing to analyze (pass --run-dir, "
-              "--telemetry, --bench and/or --drift)", file=sys.stderr)
+              "--telemetry and/or --drift)", file=sys.stderr)
         return 2
 
     rc = 0
@@ -983,50 +749,6 @@ def main(argv=None) -> int:
             rc = 1
             for s in result["shifts"]:
                 print(f"DRIFT: {json.dumps(s)}", file=sys.stderr)
-    if args.compare is not None:
-        if bench is None:
-            print("telemetry_report: --compare requires --bench",
-                  file=sys.stderr)
-            return 2
-        try:
-            baseline = load_bench_json(args.compare)
-        except (OSError, ValueError) as e:
-            print(f"telemetry_report: baseline: {e}", file=sys.stderr)
-            return 2
-        metrics = [m.strip() for m in args.metrics.split(",") if m.strip()]
-        result = compare(bench, baseline, args.tolerance, metrics)
-        report["compare"] = result
-        if result.get("missing"):
-            # LOUD failure, not a silent skip: the baseline gates a
-            # rung the current run never produced — most likely the
-            # bench rung stopped running (or its artifacts were not
-            # passed), and a skip here would let any regression in it
-            # ship forever
-            for row in result["missing"]:
-                print(
-                    f"telemetry_report: --compare: baseline metric "
-                    f"'{row['metric']}' references rung "
-                    f"'{row['rung']}' absent from the current run's "
-                    f"bench artifacts (baseline {row['baseline']}); "
-                    "run that rung or drop the metric from --metrics",
-                    file=sys.stderr,
-                )
-            return 2
-        if result["regressions"]:
-            rc = 1
-            for row in result["regressions"]:
-                print(
-                    f"REGRESSION: {row['metric']} = {row['current']} "
-                    f"< floor {row['floor']} "
-                    f"(baseline {row['baseline']}, "
-                    f"tolerance {args.tolerance})",
-                    file=sys.stderr,
-                )
-        elif not result["compared"]:
-            print("telemetry_report: no comparable metrics between "
-                  "current and baseline", file=sys.stderr)
-            return 2
-
     rendered = (json.dumps(report, indent=2) if args.json
                 else to_markdown(report))
     print(rendered)

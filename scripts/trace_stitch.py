@@ -31,8 +31,7 @@ against CLIENT-measured e2e.
 
 CI gates: ``--require-stitched N`` (at least N fully cross-process
 request timelines) and ``--min-coverage F`` (median attributed
-fraction of e2e) exit nonzero when violated — the fleet-smoke job
-runs both over its run dir.
+fraction of e2e) exit nonzero when violated.
 """
 from __future__ import annotations
 
@@ -46,39 +45,6 @@ sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 from pytorch_distributed_template_tpu.observability import (  # noqa: E402
     reqtrace,
 )
-
-
-def load_anatomy(path):
-    """A rendered ``decode_step_anatomy`` section from either a
-    ``telemetry.jsonl`` flight log (the LAST serve_chunk record
-    carrying the field — engine/continuous attaches it when the
-    background analysis lands) or a plain JSON file (a captured
-    ``/metrics?format=json`` body, or the section itself)."""
-    p = Path(path)
-    if p.name.endswith(".jsonl"):
-        last = None
-        for line in p.read_text().splitlines():
-            try:
-                rec = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            if isinstance(rec.get("decode_step_anatomy"), dict):
-                last = rec["decode_step_anatomy"]
-        return last
-    data = json.loads(p.read_text())
-    if isinstance(data.get("decode_step_anatomy"), dict):
-        return data["decode_step_anatomy"]
-    return data if "classes" in data else None
-
-
-def discover_anatomy(run_dir):
-    """Every ``telemetry.jsonl`` under the run dir, newest wins."""
-    found = None
-    for f in sorted(Path(run_dir).rglob("telemetry.jsonl")):
-        an = load_anatomy(f)
-        if an:
-            found = an
-    return found
 
 
 def load_client_e2e(path) -> dict:
@@ -171,14 +137,6 @@ def main(argv=None) -> int:
     p.add_argument("--perfetto", default=None, metavar="OUT.json",
                    help="write the merged Chrome/Perfetto trace "
                         "(flow events link processes per request)")
-    p.add_argument("--anatomy", default=None, metavar="SRC",
-                   help="step-anatomy source for the Perfetto kernel-"
-                        "class track (ISSUE 16): a telemetry.jsonl or "
-                        "a captured /metrics?format=json body; with "
-                        "--run-dir it is auto-discovered from any "
-                        "telemetry.jsonl underneath. The p99 "
-                        "request's decode window expands into modeled "
-                        "kernel-class slices on its own track")
     p.add_argument("--service-model", default=None,
                    metavar="OUT.json",
                    help="export the versioned per-segment "
@@ -222,26 +180,7 @@ def main(argv=None) -> int:
     report["span_files"] = files
 
     if args.perfetto:
-        anatomy = None
-        try:
-            if args.anatomy:
-                anatomy = load_anatomy(args.anatomy)
-            elif args.run_dir:
-                anatomy = discover_anatomy(args.run_dir)
-        except (OSError, ValueError) as e:
-            print(f"trace_stitch: --anatomy: {e}", file=sys.stderr)
-            return 2
-        if args.anatomy and anatomy is None:
-            print(f"trace_stitch: --anatomy: no decode_step_anatomy "
-                  f"in {args.anatomy}", file=sys.stderr)
-            return 2
-        # expand the p99 request's decode window only — one modeled
-        # track, not one per concurrent request
-        p99 = ((report.get("attribution") or {})
-               .get("p99_request") or {}).get("rid")
-        trace = reqtrace.to_perfetto(
-            spans, anatomy=anatomy,
-            anatomy_rids=[p99] if (anatomy and p99) else None)
+        trace = reqtrace.to_perfetto(spans)
         try:
             Path(args.perfetto).parent.mkdir(parents=True,
                                              exist_ok=True)

@@ -452,16 +452,6 @@ def service_metrics(service: GenerationService, auditor=None) -> dict:
     if hist:
         for k, h in hist.items():
             out[k] = h.snapshot()
-    # step anatomy (ISSUE 16): kernel-class breakdown of the decode
-    # chunk executable (XLA cost model x measured chunk wall EWMA).
-    # ?format=json carries the full nested section; the prometheus
-    # exposition keeps its top-level numeric leaves only (modeled step
-    # time, dispatch gap) — per-class drill-down is a JSON concern.
-    # Absent entirely when PDT_ANATOMY=0 or analysis hasn't landed.
-    if hasattr(service, "anatomy_snapshot"):
-        anatomy = service.anatomy_snapshot()
-        if anatomy:
-            out["decode_step_anatomy"] = anatomy
     if hasattr(service, "slo_stats"):
         out.update(service.slo_stats())
     # per-request path provenance (ISSUE 18): one flat counter per
@@ -472,8 +462,7 @@ def service_metrics(service: GenerationService, auditor=None) -> dict:
         for fp, n in sorted(service.path_counts_snapshot().items()):
             out[f"serve_path_{fp}_total"] = int(n)
     # shadow-replay auditor (ISSUE 18): verdict counters + queue gauge,
-    # and the per-fingerprint coverage split the serve_audit bench rung
-    # and the fleet dashboard read
+    # and the per-fingerprint coverage split the fleet dashboard reads
     if auditor is not None:
         out.update(auditor.stats())
         for fp, cov in auditor.coverage().items():

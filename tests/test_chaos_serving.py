@@ -4,8 +4,8 @@ manifest.
 
 The fleet-level composition (router deadline shed, hedging, wedged-
 replica detection) lives in test_fleet.py next to the router tests;
-the end-to-end walk of the whole fault grammar against a live fleet is
-the ``serve_chaos`` bench rung + the chaos-serve-smoke CI job. Here
+the end-to-end walk of the whole fault grammar against a live fleet has
+no test yet (ROADMAP D11). Here
 each primitive is pinned in isolation:
 
 - grammar: every new kind parses, validates its duration arg, fires

@@ -7,9 +7,8 @@ the continuous-engine handoff, the DP×TP facade, the fleet layer's
 role-filtered routing + two-queue admission + handoff accounting, the
 ``page_ship`` attribution segment, the loadgen bimodal knobs, and the
 offline analyzer section. The live wire path (serve.py /prefill +
-/admit_pages through the router's two-stage proxy) is exercised end
-to end by the ``serve_disagg`` bench rung and the disagg-smoke CI
-job.
+/admit_pages through the router's two-stage proxy) against subprocess
+replicas has no test yet (ROADMAP D11).
 """
 import json
 

@@ -375,6 +375,20 @@ def _get_json(url, path, timeout=10):
     return http_json(url + path, timeout)
 
 
+def _wait_for_span(tracer, path, rid, name, timeout=10.0):
+    """The router records a request's closing span after it has sent
+    the response, so a client that has its answer polls for the span."""
+    deadline = time.monotonic() + timeout
+    while True:
+        tracer.flush()
+        for line in path.read_text().splitlines():
+            rec = json.loads(line)
+            if rec.get("rid") == rid and rec.get("name") == name:
+                return rec
+        assert time.monotonic() < deadline, f"no {name} span for {rid}"
+        time.sleep(0.02)
+
+
 # ---------------------------------------------------------------------------
 # router behavior over fake replicas
 # ---------------------------------------------------------------------------
@@ -867,14 +881,13 @@ def test_router_unserved_requests_stay_out_of_latency_slo(tmp_path):
         except urllib.error.HTTPError as e:
             code = e.code
         assert code in (502, 503)
+        # the closing span is the last of the request's bookkeeping:
+        # once it is there, the counters below are final
+        req_span = _wait_for_span(tracer, tmp_path / "spans.jsonl",
+                                  "dead-1", "request")
         m = _get_json(url, "/metrics?format=json")
         assert m["router_e2e_seconds"]["count"] == 0
         assert m["slo_breach_total"] == 0
-        tracer.flush()
-        recs = [json.loads(l) for l in
-                (tmp_path / "spans.jsonl").read_text().splitlines()]
-        req_span = next(r for r in recs if r.get("rid") == "dead-1"
-                        and r["name"] == "request")
         assert req_span["attrs"]["outcome"] in ("unroutable",
                                                 "unreachable")
     finally:
@@ -910,11 +923,8 @@ def test_router_replica_timeout_is_proxy_failed_not_served(tmp_path):
         assert m["proxy_timeouts_total"] == 1
         assert m["router_e2e_seconds"]["count"] == 0
         assert m["slo_breach_total"] == 0
-        tracer.flush()
-        recs = [json.loads(l) for l in
-                (tmp_path / "spans.jsonl").read_text().splitlines()]
-        req_span = next(r for r in recs if r.get("rid") == "late-1"
-                        and r["name"] == "request")
+        req_span = _wait_for_span(tracer, tmp_path / "spans.jsonl",
+                                  "late-1", "request")
         assert req_span["attrs"]["outcome"] == "proxy_failed"
     finally:
         server.shutdown()
@@ -951,11 +961,8 @@ def test_router_upstream_error_is_relayed_but_not_served(tmp_path):
         m = _get_json(url, "/metrics?format=json")
         assert m["router_e2e_seconds"]["count"] == 0
         assert m["slo_breach_total"] == 0
-        tracer.flush()
-        recs = [json.loads(l) for l in
-                (tmp_path / "spans.jsonl").read_text().splitlines()]
-        req_span = next(r for r in recs if r.get("rid") == "flood-1"
-                        and r["name"] == "request")
+        req_span = _wait_for_span(tracer, tmp_path / "spans.jsonl",
+                                  "flood-1", "request")
         assert req_span["attrs"]["outcome"] == "upstream_error"
     finally:
         server.shutdown()
@@ -998,12 +1005,8 @@ def test_router_replica_death_mid_sse_is_not_served(tmp_path):
         # the first frame DID reach the client before the crash, so
         # the router-observed TTFT is real and stays
         assert m["router_ttft_seconds"]["count"] == 1
-        tracer.flush()
-        recs = [json.loads(l) for l in
-                (tmp_path / "spans.jsonl").read_text().splitlines()]
-        req_span = next(r for r in recs
-                        if r.get("rid") == "dead-sse-1"
-                        and r["name"] == "request")
+        req_span = _wait_for_span(tracer, tmp_path / "spans.jsonl",
+                                  "dead-sse-1", "request")
         assert req_span["attrs"]["outcome"] == "proxy_failed"
     finally:
         server.shutdown()

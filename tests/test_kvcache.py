@@ -717,8 +717,7 @@ def test_dry_pool_fallback_counts_the_lookup_once(stack):
     """A dry pool fails the paged arm's page reservation AFTER
     ``paged_plan`` recorded the request's lookup; the scatter
     fallback's own lookup must not record the SAME request again —
-    ``prefix_hit_tokens`` feeds /metrics, the fleet router, and the
-    bench gates."""
+    ``prefix_hit_tokens`` feeds /metrics and the fleet router."""
     model, params, _ = stack
     svc = _arm(model, params, True, pool_blocks=18)
     pc = svc._prefix

@@ -8,7 +8,7 @@ path; a corrupt spilled page is recomputed cold, never served; a full
 tier degrades to classic destroy-on-evict. Fleet side: the placement
 radix's re-warm plan extraction, the manager's miss-driven peer pull
 and readmission-gated restart re-warm (HTTP mocked — the real wire
-path is the serve_kvtier bench rung's job), and the export/evict race
+path has no test yet, ROADMAP D11), and the export/evict race
 audit the demote tier widens (refs held across an export pin blocks
 against eviction AND demotion).
 """

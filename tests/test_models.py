@@ -1,7 +1,7 @@
 """Model-zoo tests: shapes, batch_stats plumbing, learnability, registries.
 
 The reference has no tests at all (SURVEY.md §4); these cover the expanded
-model zoo the BASELINE.json ladder requires (ResNet / ViT / GPT-2) on the
+model zoo the seed's baseline file ladder requires (ResNet / ViT / GPT-2) on the
 8-device CPU mesh.
 """
 import jax

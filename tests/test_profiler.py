@@ -6,10 +6,12 @@ trace windows. CPU backend: peak FLOPs is unknown -> mfu None, but the
 mechanics (cost analysis, meters, capture files) are all testable.
 """
 import time
+import types
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from pytorch_distributed_template_tpu.observability.profiler import (
     OnDemandProfiler, ThroughputMeter, TraceCapture, compiled_flops,
@@ -50,9 +52,18 @@ def test_mfu_math():
     assert mfu(1e12, 0.0) is None
 
 
-def test_peak_flops_env_override(monkeypatch):
-    monkeypatch.setenv("PDT_TPU_PEAK_FLOPS", "123.5e12")
-    assert peak_flops_per_device() == 123.5e12
+@pytest.mark.parametrize("kind, peak", [
+    ("TPU v5 lite", 197e12),   # what the v5e reports; "v5" alone is the v5p's
+    ("TPU v5e", 197e12),
+    ("TPU v5p", 459e12),
+    ("TPU v4", 275e12),
+    ("TPU v6 lite", 918e12),
+])
+def test_peak_flops_by_device_kind(kind, peak):
+    """The table is searched by substring, first match wins: the lite
+    kinds must come before the generation they share a prefix with."""
+    device = types.SimpleNamespace(device_kind=kind)
+    assert peak_flops_per_device(device) == peak
 
 
 def test_peak_flops_cpu_unknown():
@@ -201,8 +212,11 @@ def test_on_demand_profiler_busy_second_caller(tmp_path):
     assert prof.captures == 1
 
 
-def test_trainer_profiler_integration(tmp_path):
-    """Profiler-enabled training run: mfu/examples_per_sec paths execute."""
+def test_trainer_profiler_integration(tmp_path, monkeypatch):
+    """Profiler-enabled training run: mfu/examples_per_sec paths execute,
+    the step is lowered and compiled ahead of time exactly once for the
+    FLOPs probe, and with ``peak_flops_per_device`` set (the table knows
+    no CPU) the epoch's log carries ``mfu``."""
     import json
     from pathlib import Path
 
@@ -213,7 +227,16 @@ def test_trainer_profiler_integration(tmp_path):
     import pytorch_distributed_template_tpu.models  # noqa: F401
     import pytorch_distributed_template_tpu.engine  # noqa: F401
     from pytorch_distributed_template_tpu.engine import Trainer
+    from pytorch_distributed_template_tpu.engine import trainer as trainer_mod
     from pytorch_distributed_template_tpu.parallel import mesh_from_config
+
+    probes = []
+
+    def counting_probe(jitted_fn, *args, **kwargs):
+        probes.append(jitted_fn)
+        return compiled_flops(jitted_fn, *args, **kwargs)
+
+    monkeypatch.setattr(trainer_mod, "compiled_flops", counting_probe)
 
     cfg = json.loads(
         (Path(__file__).parent.parent / "configs" / "mnist_debug.json")
@@ -223,6 +246,7 @@ def test_trainer_profiler_integration(tmp_path):
     cfg["trainer"]["epochs"] = 1
     cfg["trainer"]["profiler"] = {
         "enabled": True, "trace_start_step": 1, "trace_steps": 1,
+        "peak_flops_per_device": 1e12,
     }
     config = ConfigParser(cfg, run_id="prof")
     model = config.init_obj("arch", MODELS)
@@ -236,3 +260,5 @@ def test_trainer_profiler_integration(tmp_path):
     assert np.isfinite(log["loss"])
     # trace window wrote events into the run's log dir
     assert (config.log_dir / "profile").is_dir()
+    assert len(probes) == 1 and probes[0] is trainer._train_step
+    assert log["mfu"] > 0

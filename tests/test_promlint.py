@@ -1,8 +1,8 @@
 """Prometheus exposition self-lint (ISSUE 16).
 
 Every ``/metrics`` producer builds its dict by merging sources
-(engine stats, fleet manager counters, admission stats, goodput,
-anatomy) — so one renamed key can silently demote a counter to a
+(engine stats, fleet manager counters, admission stats, goodput)
+— so one renamed key can silently demote a counter to a
 gauge or collide two series after nested-dict flattening. These tests
 walk each REAL producer's rendered text through
 ``promtext.lint_exposition`` so the naming contract (counters end
@@ -106,7 +106,7 @@ def test_lint_undeclared_sample():
 def live_service():
     """A real continuous-batching service that has served traffic, so
     service_metrics walks every hasattr branch it has (histograms,
-    prefix cache, brownout, anatomy)."""
+    prefix cache, brownout)."""
     import numpy as np
 
     import pytorch_distributed_template_tpu.models  # noqa: F401
@@ -135,9 +135,6 @@ def test_serve_metrics_exposition_lints_clean(live_service):
     import serve
 
     metrics = serve.service_metrics(live_service)
-    # the new anatomy section must ride along (ISSUE 16) and stay
-    # lint-safe: nested classes are JSON-only, top-level numerics
-    # become gauges
     text = serve.prometheus_text(metrics)
     assert promtext.lint_exposition(text) == []
 

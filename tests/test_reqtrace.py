@@ -7,7 +7,7 @@ skew aligned causally, orphans reported — never silently dropped),
 and the SLO watcher turns thresholds into counters + BOUNDED forensic
 dumps. Fast tier: synthetic span files plus one tiny in-process
 continuous engine; the real fleet round-trip lives in
-test_fleet.py/test_serve.py and the serve_fleet bench rung.
+test_fleet.py/test_serve.py.
 """
 import json
 import sys
@@ -462,11 +462,17 @@ def test_continuous_engine_traces_requests_and_ttft(tmp_path):
                                max_new_tokens=6, request_id="eng-1")
         assert len(out["ids"]) == 6
         # the worker finalizes SLO/trace bookkeeping a hair AFTER the
-        # caller's event fires — wait for the dump, don't race it
+        # caller's event fires, and the dump exists before it is
+        # written: wait until it parses, don't race it
+        dump = None
         deadline = time.monotonic() + 10
-        while (time.monotonic() < deadline
-               and not (tmp_path / "slow_request_eng-1.json").exists()):
-            time.sleep(0.05)
+        while dump is None:
+            try:
+                dump = json.loads(
+                    (tmp_path / "slow_request_eng-1.json").read_text())
+            except (OSError, ValueError):
+                assert time.monotonic() < deadline, "no SLO dump"
+                time.sleep(0.02)
         tracer.flush()
         recs = [json.loads(l) for l in
                 (tmp_path / "spans.jsonl").read_text().splitlines()]
@@ -491,8 +497,6 @@ def test_continuous_engine_traces_requests_and_ttft(tmp_path):
         assert service.hist["e2e_seconds"].snapshot()["count"] == 1
         # the 1 ns SLO breached and dumped, carrying the timeline
         assert service.slo_stats()["slo_breach_total"] == 1
-        dump = json.loads(
-            (tmp_path / "slow_request_eng-1.json").read_text())
         assert {r["name"] for r in dump["timeline"]} >= \
             {"queue_wait", "admit", "complete"}
         # an untraced request (no rid) must not throw or record

@@ -262,7 +262,7 @@ def test_watchdog_heartbeat_throttle(tmp_path):
 
 def test_supervise_cli_raw_and_env_defaults(tmp_path):
     """scripts/supervise.py end to end in --raw mode, with the legacy
-    MAX_RESTARTS/RESTART_DELAY_S env contract of run_resilient.sh."""
+    MAX_RESTARTS/RESTART_DELAY_S env contract."""
     events = tmp_path / "sup.jsonl"
     r = subprocess.run(
         [sys.executable, str(REPO / "scripts" / "supervise.py"),
@@ -281,21 +281,6 @@ def test_supervise_cli_raw_and_env_defaults(tmp_path):
                  if e["event"] == "start")
     assert start["max_restarts"] == 2
     assert start["restart_delay_s"] == 0.05
-
-
-def test_run_resilient_wrapper_execs_supervisor(tmp_path):
-    """The deprecated bash wrapper is now a thin exec of supervise.py
-    (same flags/env contract)."""
-    text = (REPO / "scripts" / "run_resilient.sh").read_text()
-    assert "exec python" in text and "supervise.py" in text
-    r = subprocess.run(
-        ["bash", str(REPO / "scripts" / "run_resilient.sh"),
-         "--events-file", str(tmp_path / "e.jsonl"), "--raw", "--",
-         sys.executable, "-c", "raise SystemExit(0)"],
-        capture_output=True, text=True, timeout=120,
-    )
-    assert r.returncode == 0, r.stderr[-2000:]
-    assert read_supervisor_stats(tmp_path / "e.jsonl")["clean"]
 
 
 # ---------------------------------------------------------------------------

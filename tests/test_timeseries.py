@@ -439,45 +439,7 @@ class TestDashboard:
         finally:
             srv.shutdown()
         assert "no time-series store" in doc
-        # the step-anatomy panel degrades the same way: muted note,
-        # no table, page still renders
-        assert "no replica reports a decode" in doc
-        self._assert_well_formed(doc)
-
-    def test_dashboard_step_anatomy_panel(self, tmp_path):
-        """A replica whose polled /metrics body carries a rendered
-        decode_step_anatomy gets the kernel-class table (ISSUE 16) —
-        straight from poller state, no replica touch."""
-        from pytorch_distributed_template_tpu.fleet.dashboard import (
-            render_dashboard,
-        )
-
-        mgr = FleetManager(
-            [Replica("r0", url="http://127.0.0.1:1")],
-            run_dir=tmp_path)
-        mgr.replicas["r0"].polled = {
-            "decode_step_anatomy": {
-                "classes": {
-                    "attention": {"frac_time": 0.7, "time_ms": 2.1,
-                                  "flops": 3.2e9, "bytes": 1.5e8,
-                                  "bound": "hbm"},
-                    "dense_matmul": {"frac_time": 0.3,
-                                     "time_ms": 0.9,
-                                     "flops": 2.0e9,
-                                     "bytes": 4.0e7,
-                                     "bound": "compute"},
-                },
-                "est_step_time_ms": 3.0, "wall_ms": 4.0,
-                "dispatch_gap_frac": 0.25, "observed_steps": 12,
-            },
-        }
-        doc = render_dashboard(mgr, FairAdmission(lambda: 4),
-                               RouterStats())
-        assert "Step anatomy" in doc
-        assert "attention" in doc and "dense_matmul" in doc
-        assert "dispatch gap 25.0%" in doc
-        assert "hbm" in doc and "compute" in doc
-        assert "no replica reports a decode" not in doc
+        assert "anatomy" not in doc
         self._assert_well_formed(doc)
 
 
