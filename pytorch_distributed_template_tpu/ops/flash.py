@@ -9,8 +9,7 @@ in-tree Pallas kernel (see /opt/skills/guides/pallas_guide.md):
   HBM→VMEM copies against compute), and the online-softmax state (m, l,
   accumulator) lives in VMEM scratch carried across the KV grid steps. Only
   a [BQ, BK] score tile ever exists, and VMEM use is independent of T, so
-  sequence length is bounded by HBM, not VMEM. Causal programs predicate
-  away tiles beyond the diagonal (~2× fewer FLOPs). Outputs carry the
+  sequence length is bounded by HBM, not VMEM. Outputs carry the
   logsumexp rows (trailing unit lane axis: Mosaic tiling-legal).
 - **backward**: the standard two-kernel flash backward, also Pallas and
   also fully streamed. A dk/dv kernel (grid over KV blocks × q blocks, q
@@ -19,12 +18,22 @@ in-tree Pallas kernel (see /opt/skills/guides/pallas_guide.md):
   from the saved logsumexp in f32 so only [BQ, BK] tiles ever exist.
   ``_bwd_3d`` (plain-JAX blockwise) is kept as the oracle the Pallas
   kernels are tested against.
+- **what a tile pays** (``_tile_branches``): nothing where no query of it
+  sees a key of it; no mask where every query sees every key; on a square
+  tile that the diagonal or the band's lower edge crosses corner to
+  corner, the backward kernels compute strips that cover the seen half
+  and little more. ``flash/tiles`` (one INFO line and span a process and
+  call shape, ``tile_counts``) says what a call's kernels visit.
 
-Accumulation is float32 throughout regardless of input dtype.
+The MXU gets the operands in the dtype the arrays have; accumulation and
+the softmax statistics are float32 regardless of it. What the kernels
+take on this chip, and the blocks chosen from it, is under
+``pick_block_sizes``.
 """
 from __future__ import annotations
 
 import functools
+import logging
 import math
 
 import jax
@@ -34,36 +43,165 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..observability.trace import span
+
 NEG_INF = -1e30
-# Measured on TPU v5e (d=64): 512x512 beats 128x128 by 2.4x at t=2048 —
-# streaming K/V makes VMEM independent of T, so blocks this large are safe
-# and amortize the per-grid-step overhead. Sequences shorter than a block
-# fall back to one block. End-to-end vs XLA attention (in-jit chained
-# scan, the honest timing on this platform — see bench.py): ~2x on full
-# fwd+bwd (grads wrt q,k,v) at t=8192 (b=1, h=12), 1.6x on the full
-# GPT-2-small train step at t=1024; XLA attention additionally OOMs
-# where flash streams
-# (b=4, t=8192 materializes a ~12.9 GB float32 score tensor — scores
-# upcast to f32 for the softmax — plus a same-size probs tensor).
+# ``flash_attention_lse``'s blocks (the ring and zig-zag bodies of
+# ops/attention.py, whose blocks are square); ``flash_attention`` asks
+# ``pick_block_sizes``.
 DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
+logger = logging.getLogger(__name__)
+_logged: set = set()
 
-def _tile_mask(i, j, block_q, block_k, causal, t_valid, t, window=0):
-    """NEG_INF mask for score tile (q block i, kv block j); None if no-op.
 
-    ``window > 0`` adds the sliding-window band ``q_pos - k_pos < window``
-    (Mistral-style, combined with ``causal``)."""
-    need = causal or t_valid < t or window > 0
-    if not need:
-        return None
-    q_pos = i * block_q + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = j * block_k + lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
-    ok = jnp.full((block_q, block_k), True)
+# -- a score tile's place in the mask ---------------------------------------
+# Tile (i, j) holds queries i*block_q .. (i+1)*block_q - 1 and keys
+# j*block_k .. (j+1)*block_k - 1. A query sees key k when k <= q
+# (``causal``), q - k < window (``window > 0``) and k < t_valid (keys past
+# it are padding). The predicates below are scalar arithmetic and work
+# alike on Python integers (the counter, the tests) and on program ids;
+# ``geo`` is (block_q, block_k, causal, t_valid, t, window) throughout.
+
+# a triangular tile is computed in this many strips (``_strips``), of
+# queries in the dq kernel and of keys in the dkv kernel; the forward
+# computes it whole (``_tile_branches`` says why)
+STRIPS = 4
+STRIPS_OF = {"fwd": None, "dkv": "cols", "dq": "rows"}
+# the minor dimension of a TPU tile: a strip's keys are whole lanes, and
+# in the kernels' ``[heads, tokens, head size]`` layout a shorter head
+# size is padded to it in HBM
+LANES = 128
+
+
+def _tile_visible(i, j, block_q, block_k, causal, t_valid, t, window):
+    """Whether any query of the tile sees any key of it; None where every
+    tile of the call is (no diagonal, no band, no padding). It never says
+    no where a pair is seen."""
+    visible = None
+    if causal:
+        # tiles strictly beyond the diagonal
+        visible = j * block_k < (i + 1) * block_q
+    if window > 0:
+        # tiles entirely below the band
+        in_band = (j + 1) * block_k > i * block_q - window + 1
+        visible = in_band if visible is None else visible & in_band
+    if t_valid < t:
+        # tiles of padded keys alone
+        valid = j * block_k < t_valid
+        visible = valid if visible is None else visible & valid
+    return visible
+
+
+def _tile_is_edge(i, j, block_q, block_k, causal, t_valid, t, window):
+    """Whether the tile holds a (query, key) pair the query does not see:
+    the diagonal, the band's lower edge or the padding boundary crosses
+    it, and it needs its mask. None where no tile of the call does."""
+    edge = None
+    if causal:
+        # the tile's last key lies after its first query
+        edge = (j + 1) * block_k - 1 > i * block_q
+    if window > 0:
+        # its first key lies a window or more before its last query
+        low = (i + 1) * block_q - 1 - j * block_k >= window
+        edge = low if edge is None else edge | low
+    if t_valid < t:
+        # it holds padded keys
+        pad = (j + 1) * block_k > t_valid
+        edge = pad if edge is None else edge | pad
+    return edge
+
+
+def _tile_triangles(i, j, block_q, block_k, causal, t_valid, t, window):
+    """(lower, upper): whether the tile is a square one that nothing but
+    the diagonal crosses, corner to corner (its queries see the keys at or
+    before their own place in the tile), or nothing but the band's lower
+    edge (they see the keys after their own place). Half of such a tile
+    is seen, and ``_strips`` computes little more than that half. None
+    where the call has no such tile: blocks that are not square or do not
+    split into ``STRIPS`` strips of whole lanes, a band the blocks do not
+    divide."""
+    if not (causal and block_q == block_k
+            and block_q % (STRIPS * LANES) == 0):
+        return None, None
+    unpadded = True if t_valid >= t else (j + 1) * block_k <= t_valid
+    lower = upper = None
+    if window == 0 or window >= block_q:    # else the band crosses it too
+        lower = (i == j) & unpadded
+    if window > 0 and window % block_q == 0:
+        upper = ((i - j) * block_q == window) & unpadded
+    return lower, upper
+
+
+def _strips(triangle: str, by_rows: bool, block: int) -> list:
+    """The (rows, columns) rectangles that cover what is seen of a
+    ``lower`` or ``upper`` triangular tile: ``STRIPS`` strips of queries,
+    each with the keys up to (from) its last (first) query's place, for
+    the kernel that accumulates by query (dq); ``by_rows`` False gives
+    strips of keys, each with the queries that see them (dkv). 10 of a
+    tile's 16 squares: 1.25 times the seen half, not 2."""
+    size = block // STRIPS
+    cuts = [(n * size, (n + 1) * size) for n in range(STRIPS)]
+    if (triangle == "lower") == by_rows:
+        # the strip, and the other axis from 0 to the strip's end
+        pieces = [(slice(lo, hi), slice(0, hi)) for lo, hi in cuts]
+    else:
+        # the strip, and the other axis from the strip's start on
+        pieces = [(slice(lo, hi), slice(lo, block)) for lo, hi in cuts]
+    return pieces if by_rows else [(b, a) for a, b in pieces]
+
+
+def _not(x):
+    return not x if isinstance(x, bool) else jnp.logical_not(x)
+
+
+def _tile_branches(i, j, geo, strips=None, in_grid=None) -> list:
+    """What a kernel does on tile (i, j), as (name, predicate, rectangles)
+    branches of which at most one holds: nothing where no query
+    of the tile sees a key of it (or a banded grid's step lies past the
+    last block: ``in_grid``); the whole tile without a mask where every
+    pair is seen (an interior tile pays for no iota, compare or select);
+    the strips of a triangular tile, masked, where the kernel takes
+    ``strips`` (``"rows"``: of queries, ``"cols"``: of keys; the forward
+    takes none: its running maximum and sum make every strip a pass of
+    its own, which costs more than the spared scores, PERF.md section 6);
+    the whole tile, masked, on the other edge tiles. The names are
+    ``whole`` (no mask), ``lower``, ``upper`` and ``masked``; a predicate
+    is None where it always holds."""
+    block_q, block_k = geo[:2]
+    whole = [(slice(0, block_q), slice(0, block_k))]
+    visible = _tile_visible(i, j, *geo)
+    if in_grid is not None:
+        visible = in_grid if visible is None else visible & in_grid
+    edge = _tile_is_edge(i, j, *geo)
+    if edge is None:
+        return [("whole", visible, whole)]
+    branches = []
+    other = edge
+    triangles = _tile_triangles(i, j, *geo) if strips else ()
+    for name, triangle in zip(("lower", "upper"), triangles):
+        if triangle is not None:
+            branches.append(
+                (name, triangle, _strips(name, strips == "rows", block_q)))
+            other = other & _not(triangle)
+    branches += [("masked", other, whole), ("whole", _not(edge), whole)]
+    if visible is not None:
+        branches = [(name, visible & pred, pieces)
+                    for name, pred, pieces in branches]
+    return branches
+
+
+def _tile_mask(i, j, rows, cols, block_q, block_k, causal, t_valid, t,
+               window):
+    """The mask of rectangle (rows, cols) of an edge tile: True where the
+    query sees the key."""
+    shape = (rows.stop - rows.start, cols.stop - cols.start)
+    q_pos = i * block_q + rows.start + lax.broadcasted_iota(
+        jnp.int32, shape, 0)
+    k_pos = j * block_k + cols.start + lax.broadcasted_iota(
+        jnp.int32, shape, 1)
+    ok = jnp.full(shape, True)
     if causal:
         ok = q_pos >= k_pos
     if window > 0:
@@ -73,20 +211,36 @@ def _tile_mask(i, j, block_q, block_k, causal, t_valid, t, window=0):
     return ok
 
 
+def _on_tile(body, i, j, block_q, block_k, in_grid=None, *, strips=None,
+             live, causal, t_valid, t, window):
+    """Run ``body(rows, cols, mask)`` on the rectangles of score tile
+    (i, j) that ``_tile_branches`` names; ``mask`` puts NEG_INF on the
+    pairs no query sees, or is None. One body: each branch that some step
+    of the grid takes (``live``, from ``_grid_walk``) is a ``pl.when``
+    that traces it on its rectangles."""
+    geo = (block_q, block_k, causal, t_valid, t, window)
+
+    def run(name, pieces):
+        for rows, cols in pieces:
+            body(rows, cols, None if name == "whole" else (
+                lambda s, rows=rows, cols=cols: jnp.where(
+                    _tile_mask(i, j, rows, cols, *geo), s, NEG_INF)))
+
+    for name, pred, pieces in _tile_branches(i, j, geo, strips, in_grid):
+        if name not in live:
+            continue
+        if pred is None:
+            run(name, pieces)
+        else:
+            pl.when(pred)(functools.partial(run, name, pieces))
+
+
 def _band_start(i, block_q, block_k, window):
     """First KV tile that can intersect q block ``i``'s sliding band.
     Floor division of a possibly-negative numerator rounds toward -inf,
     which the max-with-0 absorbs."""
-    return jnp.maximum(0, (i * block_q - (window - 1)) // block_k)
-
-
-def _num_band_tiles(span_block, tile_block, window):
-    """Tiles of size ``tile_block`` intersecting a band that spans
-    ``span_block + window - 1`` positions, +1 slack for tile misalignment
-    (static). Used for the KV band per q block (span=block_q,
-    tile=block_k) and, with the roles swapped, the q band per KV block in
-    the dkv backward."""
-    return (span_block + window - 1 + tile_block - 1) // tile_block + 1
+    lo = (i * block_q - (window - 1)) // block_k
+    return max(lo, 0) if isinstance(lo, int) else jnp.maximum(lo, 0)
 
 
 def _q_band_start(j, block_q, block_k):
@@ -94,6 +248,21 @@ def _q_band_start(j, block_q, block_k):
     diagonal block. Shared by the dkv kernel and its index map so data
     placement and predication cannot desync."""
     return (j * block_k) // block_q
+
+
+def _band_steps(span_block, tile_block, num_tiles, t, causal, window):
+    """Steps of a banded grid axis (static): the tiles of size
+    ``tile_block`` that intersect a band spanning ``span_block + window -
+    1`` positions, +1 slack for tile misalignment; 0 where the call has
+    no band or the band covers the axis, and the plain grid is simpler.
+    The KV band of a q block (span=block_q, tile=block_k) in the forward
+    and dq kernels and, with the roles swapped, the q band of a KV block
+    in the dkv kernel: compute AND the HBM->VMEM streaming are then
+    O(T * window)."""
+    if not (causal and 0 < window < t):
+        return 0
+    steps = (span_block + window - 1 + tile_block - 1) // tile_block + 1
+    return steps if steps < num_tiles else 0
 
 
 def _banded_index(start_fn, num_blocks):
@@ -107,74 +276,126 @@ def _banded_index(start_fn, num_blocks):
     return index
 
 
+@functools.lru_cache(maxsize=None)
+def _grid_walk(kernel, t, t_valid, block_q, block_k, causal, window):
+    """One head's walk of ``kernel``'s own grid (``fwd``, ``dkv``, ``dq``;
+    the inner two axes, banded or plain) with the kernel's own predicates,
+    from shapes alone: (grid steps, tiles whose body runs, those of them
+    that take their mask, scores computed, the names of
+    ``_tile_branches``' branches that some step takes). The kernels leave
+    the branches no step takes out of the program: one diagonal tile a
+    head (1024 tokens in one block) compiles one body, not three."""
+    num_q, num_kv = t // block_q, t // block_k
+    geo = (block_q, block_k, causal, t_valid, t, window)
+    by_kv = kernel == "dkv"     # its outer axis is the KV block, q inner
+    if by_kv:
+        outer, inner = num_kv, num_q
+        band = _band_steps(block_k, block_q, num_q, t, causal, window)
+        start = functools.partial(_q_band_start, block_q=block_q,
+                                  block_k=block_k)
+    else:
+        outer, inner = num_q, num_kv
+        band = _band_steps(block_q, block_k, num_kv, t, causal, window)
+        start = functools.partial(_band_start, block_q=block_q,
+                                  block_k=block_k, window=window)
+    steps = visited = edge = computed = 0
+    live = set()
+    for a in range(outer):
+        lo = start(a) if band else 0
+        for b in range(lo, lo + (band or inner)):
+            steps += 1
+            i, j = (b, a) if by_kv else (a, b)
+            for name, pred, pieces in _tile_branches(
+                    i, j, geo, STRIPS_OF[kernel], b <= inner - 1):
+                if pred is None or pred:
+                    live.add(name)
+                    visited += 1
+                    edge += name != "whole"
+                    computed += sum(
+                        (rows.stop - rows.start) * (cols.stop - cols.start)
+                        for rows, cols in pieces)
+    return steps, visited, edge, computed, tuple(sorted(live))
+
+
+# -- the three kernels ------------------------------------------------------
+# The MXU gets q, k, v and g in the dtype the arrays have (bfloat16 in
+# training; the float32 of the CPU tests stays float32) and accumulates
+# in float32; the probabilities and their gradient are cast to that dtype
+# for the second matmuls. Running maximum, sum, log-sum-exp, delta, the
+# accumulators and the exponentials are float32. ``scale`` is paid once:
+# where ``d ** -0.5`` is a power of two it is exact on an operand in any
+# float type, and the operand that stays in VMEM through the inner grid
+# axis is scaled into a scratch on its first step; otherwise the float32
+# scores are multiplied. The backward's second ``scale`` (on ds) is
+# linear in the accumulators and is applied to them once, in finalize.
+
+def _scale_folds(d: int) -> bool:
+    """Whether ``d ** -0.5`` is a power of two (d a power of four)."""
+    return math.log2(d) % 2 == 0
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _scaled(x, scale):
+    return (x.astype(jnp.float32) * scale).astype(x.dtype)
+
+
+_NT = ((1,), (1,))      # a @ b.T
+_NN = ((1,), (0,))      # a @ b
+_TN = ((0,), (0,))      # a.T @ b
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, scale: float, causal: bool, t_valid: int, t: int,
-                num_kv: int, window: int = 0, banded: bool = False,
-                nb: int = 0):
-    # grid (BH, num_q, num_kv) — or (BH, num_q, nb) when ``banded`` (causal
+                q_scr=None, *, scale: float, num_kv: int, nb: int, live: tuple,
+                **geo):
+    # grid (BH, num_q, num_kv) — or (BH, num_q, nb) when ``nb`` (causal
     # sliding window: only the ~window-wide KV tile band per q block is in
-    # the grid at all, so both the compute AND the HBM->VMEM K/V streaming
-    # are O(T * window)). kv innermost. q_ref/o_ref: [1, BQ, D];
+    # the grid at all). kv innermost. q_ref/o_ref: [1, BQ, D];
     # k_ref/v_ref: [1, BK, D] (streamed); lse_ref: [1, BQ, 1] (the trailing
     # unit lane axis keeps the block shape legal under Mosaic's
     # (8, 128)-or-equal tiling rule). Scratch m/l: [BQ, 1] f32, acc:
-    # [BQ, D] f32 — the online-softmax state carried across the kv dim.
+    # [BQ, D] f32 — the online-softmax state carried across the kv dim;
+    # q_scr: [BQ, D], the scaled q block, where the scale folds.
     i = pl.program_id(1)
     jb = pl.program_id(2)
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
-    if banded:
-        j = _band_start(i, block_q, block_k, window) + jb
-        last = nb - 1
-    else:
-        j = jb
-        last = num_kv - 1
+    j = _band_start(i, block_q, block_k, geo["window"]) + jb if nb else jb
 
     @pl.when(jb == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
+        if q_scr is not None:
+            q_scr[...] = _scaled(q_ref[0], scale)
 
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * scale       # [BQ, D]
-        k_blk = k_ref[0].astype(jnp.float32)           # [BK, D]
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )                                              # [BQ, BK]
-        ok = _tile_mask(i, j, block_q, block_k, causal, t_valid, t,
-                        window)
-        if ok is not None:
-            s = jnp.where(ok, s, NEG_INF)
-        m = m_scr[...]
+    def _compute(rows, cols, mask):
+        if q_scr is None:
+            s = _dot(q_ref[0, rows], k_ref[0, cols], _NT) * scale
+        else:
+            s = _dot(q_scr[rows], k_ref[0, cols], _NT)
+        if mask is not None:
+            s = mask(s)                                    # [rows, cols]
+        m = m_scr[rows]
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)
         corr = jnp.exp(m - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_scr[...] = m_new
+        l_scr[rows] = l_scr[rows] * corr + jnp.sum(p, axis=1, keepdims=True)
+        v_blk = v_ref[0, cols]
+        acc_scr[rows] = acc_scr[rows] * corr + _dot(
+            p.astype(v_blk.dtype), v_blk, _NN)
+        m_scr[rows] = m_new
 
-    pred = None
-    if causal:
-        # tiles strictly beyond the diagonal are predicated away entirely
-        pred = j * block_k < (i + 1) * block_q
-    if window > 0:
-        # tiles entirely below the band contribute nothing
-        in_band = (j + 1) * block_k > i * block_q - window + 1
-        pred = in_band if pred is None else (pred & in_band)
-    if banded:
-        pred = pred & (j <= num_kv - 1)  # nb overshoot near the edges
-    if pred is not None:
-        pl.when(pred)(_compute)
-    else:
-        _compute()
+    # nb overshoots the last block near the sequence's end
+    _on_tile(_compute, i, j, block_q, block_k,
+             j <= num_kv - 1 if nb else None, strips=STRIPS_OF["fwd"],
+             live=live, **geo)
 
-    @pl.when(jb == last)
+    @pl.when(jb == (nb or num_kv) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l_safe).astype(o_ref.dtype)
@@ -185,29 +406,33 @@ def _flash_fwd_3d(q, k, v, *, causal: bool, block_q: int, block_k: int,
                   t_valid: int, interpret: bool, window: int = 0):
     """q,k,v: [BH, T, D] (T block-padded) -> (out, lse [BH, T])."""
     bh, t, d = q.shape
-    scale = d ** -0.5
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     assert t % block_q == 0 and t % block_k == 0, (t, block_q, block_k)
     num_kv = t // block_k
-    banded = causal and 0 < window < t
-    nb = min(_num_band_tiles(block_q, block_k, window), num_kv)
-    if banded and nb >= num_kv:
-        banded = False  # band covers everything: plain grid is simpler
+    nb = _band_steps(block_q, block_k, num_kv, t, causal, window)
+    walk = ("fwd", t, t_valid, block_q, block_k, causal, window)
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, t_valid=t_valid, t=t,
-        num_kv=num_kv, window=window, banded=banded, nb=nb,
+        _fwd_kernel, scale=d ** -0.5, num_kv=num_kv, nb=nb,
+        live=_grid_walk(*walk)[-1], causal=causal, t_valid=t_valid, t=t,
+        window=window,
     )
-    if banded:
-        kv_grid = nb
+    if nb:
         kv_index = _banded_index(
             lambda i: _band_start(i, block_q, block_k, window), num_kv
         )
     else:
-        kv_grid, kv_index = num_kv, (lambda b, i, j: (b, j, 0))
+        kv_index = lambda b, i, j: (b, j, 0)
+    scratch = [
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, 1), jnp.float32),
+        pltpu.VMEM((block_q, d), jnp.float32),
+    ]
+    if _scale_folds(d):
+        scratch.append(pltpu.VMEM((block_q, d), q.dtype))
     out, lse = pl.pallas_call(
         kernel,
-        grid=(bh, t // block_q, kv_grid),
+        grid=(bh, t // block_q, nb or num_kv),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, block_k, d), kv_index),
@@ -221,11 +446,7 @@ def _flash_fwd_3d(q, k, v, *, causal: bool, block_q: int, block_k: int,
             jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             jax.ShapeDtypeStruct((bh, t, 1), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
@@ -280,83 +501,56 @@ def _bwd_3d(causal, block_k, t_valid, residuals, g, window: int = 0):
 
 
 def _bwd_dkv_kernel(q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
-                    dk_ref, dv_ref, dk_scr, dv_scr, *, scale: float,
-                    causal: bool, t_valid: int, t: int, num_q: int,
-                    window: int = 0, banded: bool = False, nqb: int = 0):
-    # grid (BH, num_kv, num_q) — or (BH, num_kv, nqb) when ``banded``
+                    dk_ref, dv_ref, dk_scr, dv_scr, k_scr=None, *,
+                    scale: float, num_q: int, nqb: int, live: tuple, **geo):
+    # grid (BH, num_kv, num_q) — or (BH, num_kv, nqb) when ``nqb``
     # (sliding window: only q blocks within ``window`` above this KV block
     # are visited). q innermost (streamed). k/v/dk/dv refs:
     # [1, BK, D] (this program's KV block); q_ref/g_ref: [1, BQ, D];
-    # lse_ref/delta_ref: [1, BQ, 1]. Scratch dk/dv: [BK, D] f32.
+    # lse_ref/delta_ref: [1, BQ, 1]. Scratch dk/dv: [BK, D] f32; k_scr:
+    # [BK, D], the scaled KV block, where the scale folds.
     j = pl.program_id(1)
     ib = pl.program_id(2)
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
-    if banded:
-        i = _q_band_start(j, block_q, block_k) + ib
-        last = nqb - 1
-    else:
-        i = ib
-        last = num_q - 1
+    i = _q_band_start(j, block_q, block_k) + ib if nqb else ib
 
     @pl.when(ib == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
+        if k_scr is not None:
+            k_scr[...] = _scaled(k_ref[0], scale)
 
-    def _compute():
-        q_blk = q_ref[0].astype(jnp.float32)           # [BQ, D]
-        g_blk = g_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]                               # [BQ, 1]
-        delta = delta_ref[0]
-        k_blk = k_ref[0].astype(jnp.float32)           # [BK, D]
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                      # [BQ, BK]
-        ok = _tile_mask(i, j, block_q, block_k, causal, t_valid, t,
-                        window)
-        if ok is not None:
-            s = jnp.where(ok, s, NEG_INF)
-        p = jnp.exp(s - lse)                           # [BQ, BK]
-        dv_scr[...] += jax.lax.dot_general(
-            p, g_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            g_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
-        dk_scr[...] += jax.lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def _compute(rows, cols, mask):
+        q_blk = q_ref[0, rows]
+        g_blk = g_ref[0, rows]
+        if k_scr is None:
+            s = _dot(q_blk, k_ref[0, cols], _NT) * scale
+        else:
+            s = _dot(q_blk, k_scr[cols], _NT)
+        if mask is not None:
+            s = mask(s)                                # [rows, cols]
+        p = jnp.exp(s - lse_ref[0, rows])
+        dv_scr[cols] += _dot(p.astype(g_blk.dtype), g_blk, _TN)
+        dp = _dot(g_blk, v_ref[0, cols], _NT)
+        ds = p * (dp - delta_ref[0, rows])
+        dk_scr[cols] += _dot(ds.astype(q_blk.dtype), q_blk, _TN)
 
-    pred = None
-    if causal:
-        # q blocks strictly above this KV block's first row see none of it
-        pred = (i + 1) * block_q > j * block_k
-    if window > 0:
-        in_band = (j + 1) * block_k > i * block_q - window + 1
-        pred = in_band if pred is None else (pred & in_band)
-    if banded:
-        pred = pred & (i <= num_q - 1)
-    if pred is not None:
-        pl.when(pred)(_compute)
-    else:
-        _compute()
+    _on_tile(_compute, i, j, block_q, block_k,
+             i <= num_q - 1 if nqb else None, strips=STRIPS_OF["dkv"],
+             live=live, **geo)
 
-    @pl.when(ib == last)
+    @pl.when(ib == (nqb or num_q) - 1)
     def _finalize():
-        dk = dk_scr[...]
+        dk = dk_scr[...] * scale
         dv = dv_scr[...]
-        if t_valid < t:  # padded keys: their grads must be exactly 0
+        if geo["t_valid"] < geo["t"]:
+            # padded keys: their grads must be exactly 0
             kv_valid = (
                 j * block_k
                 + lax.broadcasted_iota(jnp.int32, (block_k, 1), 0)
-                < t_valid
+                < geo["t_valid"]
             )
             dk = jnp.where(kv_valid, dk, 0.0)
             dv = jnp.where(kv_valid, dv, 0.0)
@@ -365,119 +559,76 @@ def _bwd_dkv_kernel(q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 
 def _bwd_dq_kernel(q_ref, g_ref, lse_ref, delta_ref, k_ref, v_ref, dq_ref,
-                   dq_scr, *, scale: float, causal: bool, t_valid: int,
-                   t: int, num_kv: int, window: int = 0,
-                   banded: bool = False, nb: int = 0):
-    # grid (BH, num_q, num_kv) — or (BH, num_q, nb) when ``banded``
+                   dq_scr, q_scr=None, *, scale: float, num_kv: int,
+                   nb: int, live: tuple, **geo):
+    # grid (BH, num_q, num_kv) — or (BH, num_q, nb) when ``nb``
     # (sliding window: only the band's KV tiles are visited). kv innermost
     # (streamed). q/g/dq refs: [1, BQ, D]; k_ref/v_ref: [1, BK, D];
-    # lse_ref/delta_ref: [1, BQ, 1]. Scratch dq: [BQ, D] f32.
+    # lse_ref/delta_ref: [1, BQ, 1]. Scratch dq: [BQ, D] f32; q_scr as in
+    # the forward.
     i = pl.program_id(1)
     jb = pl.program_id(2)
     block_q = q_ref.shape[1]
     block_k = k_ref.shape[1]
-    if banded:
-        j = _band_start(i, block_q, block_k, window) + jb
-        last = nb - 1
-    else:
-        j = jb
-        last = num_kv - 1
+    j = _band_start(i, block_q, block_k, geo["window"]) + jb if nb else jb
 
     @pl.when(jb == 0)
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
+        if q_scr is not None:
+            q_scr[...] = _scaled(q_ref[0], scale)
 
-    def _compute():
-        q_blk = q_ref[0].astype(jnp.float32)
-        g_blk = g_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        k_blk = k_ref[0].astype(jnp.float32)
-        v_blk = v_ref[0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
-        ok = _tile_mask(i, j, block_q, block_k, causal, t_valid, t,
-                        window)
-        if ok is not None:
-            s = jnp.where(ok, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(
-            g_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta) * scale
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+    def _compute(rows, cols, mask):
+        g_blk = g_ref[0, rows]
+        k_blk = k_ref[0, cols]
+        if q_scr is None:
+            s = _dot(q_ref[0, rows], k_blk, _NT) * scale
+        else:
+            s = _dot(q_scr[rows], k_blk, _NT)
+        if mask is not None:
+            s = mask(s)
+        p = jnp.exp(s - lse_ref[0, rows])
+        dp = _dot(g_blk, v_ref[0, cols], _NT)
+        ds = p * (dp - delta_ref[0, rows])
+        dq_scr[rows] += _dot(ds.astype(k_blk.dtype), k_blk, _NN)
 
-    pred = None
-    if causal:
-        pred = j * block_k < (i + 1) * block_q
-    if window > 0:
-        in_band = (j + 1) * block_k > i * block_q - window + 1
-        pred = in_band if pred is None else (pred & in_band)
-    if banded:
-        pred = pred & (j <= num_kv - 1)
-    if pred is not None:
-        pl.when(pred)(_compute)
-    else:
-        _compute()
+    _on_tile(_compute, i, j, block_q, block_k,
+             j <= num_kv - 1 if nb else None, strips=STRIPS_OF["dq"],
+             live=live, **geo)
 
-    @pl.when(jb == last)
+    @pl.when(jb == (nb or num_kv) - 1)
     def _finalize():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
 
 
-def _bwd_pallas_3d(causal, block_q, block_k, t_valid, interpret,
-                   residuals, g, g_lse=None, window: int = 0):
-    """Pallas two-kernel flash backward. Same signature/result as _bwd_3d.
-
-    ``g_lse`` ([BH, T] or None): cotangent of the logsumexp output when the
-    caller consumed it (flash_attention_lse — e.g. the ring-merge weights).
-    d(lse)/ds is the normalized probability tile p, so its contribution is
-    ``ds += p * g_lse`` — which folds into the existing ``ds = p*(dp-delta)``
-    as ``delta' = delta - g_lse``. The kernels are unchanged.
-    """
-    q, k, v, out, lse = residuals
+def _flash_dkv_3d(q, g, lse, delta, k, v, *, causal: bool, block_q: int,
+                  block_k: int, t_valid: int, interpret: bool,
+                  window: int = 0):
+    """The dkv kernel's call: q, g, k, v [BH, T, D], lse and delta
+    [BH, T, 1] float32 -> (dk, dv)."""
     bh, t, d = q.shape
-    scale = d ** -0.5
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    num_q = t // block_q
-    num_kv = t // block_k
-    # delta_i = g_i . out_i (rowwise) — cheap, XLA-fused outside the kernels.
-    # Both row-stat tensors carry a trailing unit lane axis (see _fwd_kernel).
-    delta = jnp.sum(
-        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
-        keepdims=True,
-    )
-    if g_lse is not None:
-        delta = delta - g_lse.astype(jnp.float32)[..., None]
-    lse = lse.astype(jnp.float32)[..., None]
-
-    banded = causal and 0 < window < t
-    nqb = min(_num_band_tiles(block_k, block_q, window), num_q)
-    nb = min(_num_band_tiles(block_q, block_k, window), num_kv)
-    if banded and (nqb >= num_q and nb >= num_kv):
-        banded = False
-
-    if banded:
-        q_grid = nqb
+    num_q, num_kv = t // block_q, t // block_k
+    nqb = _band_steps(block_k, block_q, num_q, t, causal, window)
+    if nqb:
         q_index = _banded_index(
             lambda j: _q_band_start(j, block_q, block_k), num_q
         )
     else:
-        q_grid, q_index = num_q, (lambda b, j, i: (b, i, 0))
-
-    dk, dv = pl.pallas_call(
+        q_index = lambda b, j, i: (b, i, 0)
+    scratch = [
+        pltpu.VMEM((block_k, d), jnp.float32),
+        pltpu.VMEM((block_k, d), jnp.float32),
+    ]
+    if _scale_folds(d):
+        scratch.append(pltpu.VMEM((block_k, d), k.dtype))
+    return pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, t_valid=t_valid,
-            t=t, num_q=num_q, window=window, banded=banded, nqb=nqb,
+            _bwd_dkv_kernel, scale=d ** -0.5, num_q=num_q, nqb=nqb,
+            live=_grid_walk("dkv", t, t_valid, block_q, block_k, causal,
+                            window)[-1],
+            causal=causal, t_valid=t_valid, t=t, window=window,
         ),
-        grid=(bh, num_kv, q_grid),
+        grid=(bh, num_kv, nqb or num_q),
         in_specs=[
             pl.BlockSpec((1, block_q, d), q_index),                    # q
             pl.BlockSpec((1, block_q, d), q_index),                    # g
@@ -494,28 +645,36 @@ def _bwd_pallas_3d(causal, block_q, block_k, t_valid, interpret,
             jax.ShapeDtypeStruct((bh, t, d), k.dtype),
             jax.ShapeDtypeStruct((bh, t, d), v.dtype),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
-        ],
+        scratch_shapes=scratch,
         interpret=interpret,
         name="flash_dkv",
     )(q, g, lse, delta, k, v)
 
-    if banded:
-        kv_grid = nb
+
+def _flash_dq_3d(q, g, lse, delta, k, v, *, causal: bool, block_q: int,
+                 block_k: int, t_valid: int, interpret: bool,
+                 window: int = 0):
+    """The dq kernel's call, on the operands of ``_flash_dkv_3d`` -> dq."""
+    bh, t, d = q.shape
+    num_q, num_kv = t // block_q, t // block_k
+    nb = _band_steps(block_q, block_k, num_kv, t, causal, window)
+    if nb:
         kv_index = _banded_index(
             lambda i: _band_start(i, block_q, block_k, window), num_kv
         )
     else:
-        kv_grid, kv_index = num_kv, (lambda b, i, j: (b, j, 0))
-
-    dq = pl.pallas_call(
+        kv_index = lambda b, i, j: (b, j, 0)
+    scratch = [pltpu.VMEM((block_q, d), jnp.float32)]
+    if _scale_folds(d):
+        scratch.append(pltpu.VMEM((block_q, d), q.dtype))
+    return pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal, t_valid=t_valid,
-            t=t, num_kv=num_kv, window=window, banded=banded, nb=nb,
+            _bwd_dq_kernel, scale=d ** -0.5, num_kv=num_kv, nb=nb,
+            live=_grid_walk("dq", t, t_valid, block_q, block_k, causal,
+                            window)[-1],
+            causal=causal, t_valid=t_valid, t=t, window=window,
         ),
-        grid=(bh, num_q, kv_grid),
+        grid=(bh, num_q, nb or num_kv),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # q
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),  # g
@@ -526,16 +685,39 @@ def _bwd_pallas_3d(causal, block_q, block_k, t_valid, interpret,
         ],
         out_specs=[pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))],
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        scratch_shapes=scratch,
         interpret=interpret,
         name="flash_dq",
     )(q, g, lse, delta, k, v)[0]
+
+
+def _bwd_pallas_3d(causal, block_q, block_k, t_valid, interpret,
+                   residuals, g, g_lse=None, window: int = 0):
+    """Pallas two-kernel flash backward. Same signature/result as _bwd_3d.
+
+    ``g_lse`` ([BH, T] or None): cotangent of the logsumexp output when the
+    caller consumed it (flash_attention_lse — e.g. the ring-merge weights).
+    d(lse)/ds is the normalized probability tile p, so its contribution is
+    ``ds += p * g_lse`` — which folds into the existing ``ds = p*(dp-delta)``
+    as ``delta' = delta - g_lse``. The kernels are unchanged.
+    """
+    q, k, v, out, lse = residuals
+    t = q.shape[1]
+    # delta_i = g_i . out_i (rowwise) — cheap, XLA-fused outside the kernels.
+    # Both row-stat tensors carry a trailing unit lane axis (see _fwd_kernel).
+    delta = jnp.sum(
+        g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1,
+        keepdims=True,
+    )
+    if g_lse is not None:
+        delta = delta - g_lse.astype(jnp.float32)[..., None]
+    lse = lse.astype(jnp.float32)[..., None]
+    call = dict(causal=causal, block_q=min(block_q, t),
+                block_k=min(block_k, t), t_valid=t_valid,
+                interpret=interpret, window=window)
+    dk, dv = _flash_dkv_3d(q, g, lse, delta, k, v, **call)
+    dq = _flash_dq_3d(q, g, lse, delta, k, v, **call)
     return dq, dk, dv
-
-
-# the minor dimension of a TPU tile: in the kernels' ``[heads, tokens,
-# head size]`` layout a shorter head size is padded to it in HBM
-LANES = 128
 
 
 def named_residual_bytes(b: int, t: int, h: int, d: int, dtype) -> dict:
@@ -903,30 +1085,92 @@ def paged_attention(q, k_pool, v_pool, tables, row_starts, pad_lens,
 
 
 def pick_block_sizes(t: int, d: int) -> tuple:
-    """(block_q, block_k) for a [*, t, *, d] attention, from the round-3
-    measurement sweep on TPU v5e (full fwd+bwd through ``jax.grad``,
-    in-jit chained scan timing — the 7-point (bq, bk) grid at each of
-    (t, d) in {1024, 4096, 8192}x64 and 2048x128, causal):
+    """(block_q, block_k) for a [*, t, *, d] attention: 1024 x 1024 where
+    1024 divides ``t``, else the square 512 (clipped to ``t`` by the
+    callers, so a shorter sequence is one block).
 
-    - **(512, 1024)** is fastest or tied-fastest at every measured point
-      up to t=4096 — 30% over the old 512x512 default at t=1024
-      (11.7 vs 16.9 ms) and 16% at t=4096. Wide KV tiles suit the
-      KV-innermost forward stream; 1024x1024 gives the gain back.
-    - **(1024, 512)** wins at t=8192 with small batch (17.4 vs 21.5 ms):
-      once b*h programs no longer fill the chip, coarser q-grids put
-      more work in each program.
+    Measured on one TPU v5e by ``scripts/flash_sweep.py`` (PR 31; the
+    three kernels alone, each chained inside one jit, bfloat16, causal;
+    the whole table is in PERF.md section 6), ms a call at 1024 x 1024
+    against the pair the rule gave before (512 x 1024 up to 4096 tokens,
+    1024 x 512 from 8192), forward / dkv / dq:
 
-    Sequences shorter than a block fall back to one block (the ``min``
-    in the caller). Lengths that don't divide the asymmetric pair's
-    lcm (1024) keep the old square 512x512 — the caller pads to the
-    block lcm, and taxing a t=1536 call with 512 columns of masked
-    padding would cost more than the block win."""
-    del d  # same winner at d=64 and d=128 everywhere measured
+    - ``[8, 1024, 20, 64]``: 0.65 / 0.91 / 0.58 against 0.83 / 1.12 / 0.86;
+    - ``[1, 8192, 32, 128]``, band 4096: 4.59 / 6.35 / 4.89 against
+      7.94 / 7.74 / 5.68;
+    - ``[2, 4096, 32, 128]``, band inactive: 3.16 / 4.62 / 3.37 against
+      3.73 / 5.03 / 4.32.
+
+    A tile's time follows its queries far more than its keys (the
+    forward's reductions and running statistics, the backward's row
+    broadcasts, a grid step's 0.4-1 us), so the widest key block wins in
+    every kernel and smaller tiles lose although they compute fewer
+    unseen scores: at 1024 tokens 256 x 256 tiles compute 1.25 times the
+    seen scores and take 2.5 times as long as one 1024 x 1024 tile that
+    computes twice them. What the large tile computes for nothing the
+    backward kernels spare by strips (``_strips``), which only square
+    blocks have. The same pair won at both head sizes, with and without
+    the band, so the rule reads neither ``d`` nor the mask.
+
+    Lengths that 1024 does not divide keep 512 x 512: the callers pad to
+    the blocks' least common multiple, and 512 more columns of masked
+    padding on a 1536-token call would cost more than the larger tile
+    gains (not measured on this chip)."""
+    del d
     if t % 1024:
         return DEFAULT_BLOCK_Q, DEFAULT_BLOCK_K
-    if t >= 8192:
-        return 1024, 512
-    return 512, 1024
+    return 1024, 1024
+
+
+def tile_counts(t: int, t_valid: int, block_q: int, block_k: int,
+                causal: bool, window: int) -> dict:
+    """What one head's three kernels visit, from shapes alone: for ``fwd``,
+    ``dkv`` and ``dq`` the grid steps of the inner two axes, the tiles
+    whose body runs (``tiles_visited``), those of them that build their
+    mask (``tiles_edge``) and those that do not (``tiles_interior``), and
+    the scores computed over the scores a query sees
+    (``computed_over_useful``): ``_grid_walk``'s counts."""
+    useful = 0              # pairs (query, key it sees), padding left out
+    for q in range(t_valid):
+        lo = max(0, q - window + 1) if window > 0 else 0
+        useful += (q + 1 if causal else t_valid) - lo
+    counts = {}
+    for kernel in STRIPS_OF:
+        steps, visited, edge, computed, _ = _grid_walk(
+            kernel, t, t_valid, min(block_q, t), min(block_k, t), causal,
+            window)
+        counts[kernel] = dict(
+            grid_steps=steps, tiles_visited=visited, tiles_edge=edge,
+            tiles_interior=visited - edge,
+            computed_over_useful=round(computed / useful, 4))
+    return counts
+
+
+def _say_tiles(t, t_valid, d, block_q, block_k, causal, window):
+    """The tiles a call's kernels visit, once a process and distinct call
+    shape: a log line and a zero-length span, as ``remat/policy`` has.
+    What to read first when a cell's ``flash_ms_per_step`` moves."""
+    key = (t, t_valid, d, block_q, block_k, causal, window)
+    if key in _logged:
+        return
+    _logged.add(key)
+    counts = tile_counts(t, t_valid, block_q, block_k, causal, window)
+    record = dict(t=t_valid, d=d, window=window, causal=causal,
+                  block_q=min(block_q, t), block_k=min(block_k, t))
+    for kernel, c in counts.items():
+        record.update({f"{kernel}_{name}": n for name, n in c.items()})
+    with span("flash/tiles", **record):
+        pass
+    logger.info(
+        "flash/tiles: t %d d %d window %d causal %s blocks %d x %d; %s",
+        t_valid, d, window, causal, record["block_q"], record["block_k"],
+        "; ".join(
+            "%s visits %d of %d grid steps (%d edge, %d interior), "
+            "computed/useful %.4f" % (
+                kernel, c["tiles_visited"], c["grid_steps"],
+                c["tiles_edge"], c["tiles_interior"],
+                c["computed_over_useful"])
+            for kernel, c in counts.items()))
 
 
 def _padded_len(t: int, block_q: int, block_k: int) -> int:
@@ -965,6 +1209,7 @@ def flash_attention(q, k, v, causal: bool = True,
         block_q = block_q or auto_q
         block_k = block_k or auto_k
     t_pad = _padded_len(t, block_q, block_k)
+    _say_tiles(t_pad, t, d, block_q, block_k, causal, window)
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
     q, k, v = fold(q), fold(k), fold(v)
     if t_pad != t:
@@ -1009,6 +1254,7 @@ def flash_attention_lse(q, k, v, causal: bool = False,
         raise ValueError(f"flash_attention_lse needs Tq == Tk; "
                          f"{t} vs {k.shape[1]}")
     t_pad = _padded_len(t, block_q, block_k)
+    _say_tiles(t_pad, t, d, block_q, block_k, causal, window)
     fold = lambda x: jnp.moveaxis(x, 2, 1).reshape(b * h, t, d)
     qf, kf, vf = fold(q), fold(k), fold(v)
     if t_pad != t:

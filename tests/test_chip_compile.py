@@ -87,23 +87,34 @@ def _qkv(shape, sharding):
     return [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=sharding)] * 3
 
 
-# GPT-2-small attention (the chip_smoke.py width) and a Llama-style
-# head_dim-128 layer
-SHAPES = [(8, 1024, 12, 64), (8, 1024, 16, 128)]
+# [batch, tokens, heads, head size] and band of: GPT-2-small attention
+# (the chip_smoke.py width), a Llama-style head_dim-128 layer, the calls
+# of the benchmark's cells (gpt2_large.seq1k; mistral7b_l2.seq8k and
+# seq8k_dp4 on a chip) and the 4096-token shape where the band is
+# inactive. Each takes the blocks `pick_block_sizes` gives it, so a pair
+# Mosaic refuses fails here before it meets the chip.
+SHAPES = {
+    "8x1024x12x64": ((8, 1024, 12, 64), 0),
+    "8x1024x16x128": ((8, 1024, 16, 128), 0),
+    "gpt2_large.seq1k": ((8, 1024, 20, 64), 0),
+    "mistral7b_l2.seq8k": ((1, 8192, 32, 128), 4096),
+    "mistral7b_l2.seq4k": ((2, 4096, 32, 128), 4096),
+}
 
 
-@pytest.mark.parametrize("shape", SHAPES, ids=lambda s: "x".join(map(str, s)))
-def test_flash_forward_compiles_for_v5e(one_chip, shape):
+@pytest.mark.parametrize("shape,window", SHAPES.values(), ids=SHAPES.keys())
+def test_flash_forward_compiles_for_v5e(one_chip, shape, window):
     fwd = jax.jit(lambda q, k, v: flash_attention(
-        q, k, v, causal=True, interpret=False))
+        q, k, v, causal=True, window=window, interpret=False))
     text = fwd.lower(*_qkv(shape, one_chip)).compile().as_text()
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("shape", SHAPES, ids=lambda s: "x".join(map(str, s)))
-def test_flash_forward_backward_compiles_for_v5e(one_chip, shape):
+@pytest.mark.parametrize("shape,window", SHAPES.values(), ids=SHAPES.keys())
+def test_flash_forward_backward_compiles_for_v5e(one_chip, shape, window):
     def loss(q, k, v):
-        out = flash_attention(q, k, v, causal=True, interpret=False)
+        out = flash_attention(q, k, v, causal=True, window=window,
+                              interpret=False)
         return jnp.sum(out.astype(jnp.float32))
 
     step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
@@ -121,7 +132,8 @@ def test_flash_kernels_carry_their_names_for_v5e(one_chip):
         return jnp.sum(out.astype(jnp.float32))
 
     step = jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
-    text = step.lower(*_qkv(SHAPES[1], one_chip)).compile().as_text()
+    text = step.lower(
+        *_qkv(SHAPES["8x1024x16x128"][0], one_chip)).compile().as_text()
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
         (line,) = [ln for ln in calls
@@ -152,7 +164,8 @@ def test_checkpoint_policy_spares_the_second_flash_forward_for_v5e(
 
     # the loss too, or the forward pass itself has nothing to give
     step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
-    text = step.lower(*_qkv(SHAPES[1], one_chip)).compile().as_text()
+    text = step.lower(
+        *_qkv(SHAPES["8x1024x16x128"][0], one_chip)).compile().as_text()
     calls = [ln.split(" = ")[0] for ln in text.splitlines()
              if "tpu_custom_call" in ln]
     assert sum("flash_fwd" in c for c in calls) == forward_calls
