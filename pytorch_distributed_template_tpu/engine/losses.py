@@ -19,7 +19,7 @@ import optax
 
 from ..config.registry import LOSSES
 from ..models.remat_policy import HEADROOM_BYTES, token_shards
-from ..observability.trace import span
+from ..observability.trace import say_once
 
 logger = logging.getLogger(__name__)
 
@@ -132,7 +132,6 @@ SLICE_ROWS = 2048
 
 _step_mesh: contextvars.ContextVar = contextvars.ContextVar(
     "head_loss_step_mesh", default=None)
-_logged: set = set()
 
 
 @contextlib.contextmanager
@@ -177,13 +176,8 @@ def _say_slice(sequences, positions, turns, vocab, chunk):
     rows = sequences * positions
     record = dict(rows_per_device=rows, positions=positions, turns=turns,
                   slice_bytes=rows * vocab * 4, floor_positions=chunk)
-    key = tuple(record.items())
-    if key in _logged:
-        return
-    _logged.add(key)
-    with span("head_loss/slice", **record):
-        pass
-    logger.info(
+    say_once(
+        logger, "head_loss/slice", record,
         "head_loss/slice: %d rows on a device a turn (%d positions x %d "
         "sequences, the floor is %d positions), %d turns, %.1f MB of "
         "float32 logits a slice", rows, positions, sequences, chunk, turns,

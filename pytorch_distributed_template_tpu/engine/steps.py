@@ -26,6 +26,7 @@ from typing import Callable, Dict, Sequence
 import jax
 import jax.numpy as jnp
 import optax
+from flax.traverse_util import flatten_dict, unflatten_dict
 
 from ..models.remat_policy import step_holds
 from ..parallel.sharding import per_device_bytes
@@ -60,6 +61,53 @@ def _on_mesh_of(model, step):
             return step(*args, **kwargs)
 
     return told
+
+
+COUNTER_PREFIX = "counter/"
+
+
+def _step_counters(mutated, names, weight):
+    """What the model's layers sowed under ``counters`` this forward
+    (models/moe.ExpertLayer), summed by name over the layers, as entries
+    of the step's metrics: ``counter/<name>_sum`` times the valid count,
+    so that the metrics' divide-by-count reads a step's mean. The names
+    are the model's ``step_counters``; one no layer sowed reads 0."""
+    sums = dict.fromkeys(names, jnp.zeros((), jnp.float32))
+    flat, _ = jax.tree_util.tree_flatten_with_path(
+        mutated.get("counters", {}))
+    for path, value in flat:
+        name = str(getattr(path[-1], "key", path[-1]))
+        if name in sums:
+            sums[name] = sums[name] + jnp.sum(value)
+    return {f"{COUNTER_PREFIX}{name}_sum": v * weight
+            for name, v in sums.items()}
+
+
+BIAS_LEAF = "selection_bias"
+
+
+def _bias_loads(params, sown=None):
+    """``{path: load}`` for every ``selection_bias`` leaf of ``params``:
+    what the layers sowed under ``router_load`` this forward (the tokens
+    each published expert got, models/moe.ExpertLayer), zeros without."""
+    sown = flatten_dict(sown or {})
+    return {path: sown.get(path, jnp.zeros_like(p))
+            for path, p in flatten_dict(params).items()
+            if path[-1] == BIAS_LEAF}
+
+
+def selection_bias_step(params, loads, rate: float):
+    """The rule a router's selection bias trains by (no gradient reaches
+    it): after a step each expert's bias goes up by ``rate`` if the
+    expert got fewer tokens than the experts' mean over the step's whole
+    batch, down by ``rate`` if more (auxiliary-loss-free balancing, Wang
+    et al. arXiv:2408.15664, as DeepSeek-V3 and Megatron-LM's
+    ``moe_router_enable_expert_bias`` apply it; their rate is 1e-3)."""
+    flat = flatten_dict(params)
+    for path, load in loads.items():
+        flat[path] = flat[path] + rate * jnp.sign(
+            jnp.mean(load) - load).astype(flat[path].dtype)
+    return unflatten_dict(flat)
 
 
 def _accumulator_dtype(dtype):
@@ -160,6 +208,8 @@ def make_train_step(model, tx, criterion: Callable,
     targets ride the batch pytree through the microbatch split).
     """
     pass_example_mask = _accepts_example_mask(model)
+    counter_names = tuple(getattr(model, "step_counters", ()))
+    bias_rate = float(getattr(model, "selection_bias_rate", 0.0))
 
     def sumloss_and_output(params, batch_stats, batch, dropout_rng):
         """Masked SUM of per-example losses (normalized by the caller after
@@ -175,6 +225,10 @@ def make_train_step(model, tx, criterion: Callable,
         if batch_stats:
             variables["batch_stats"] = batch_stats
             mutable = ["batch_stats", "losses"]
+        if counter_names:
+            mutable.append("counters")
+        if bias_rate:
+            mutable.append("router_load")
         extra = (
             {"example_mask": batch["mask"]} if pass_example_mask else {}
         )
@@ -195,7 +249,11 @@ def make_train_step(model, tx, criterion: Callable,
         aux = jax.tree.leaves(mutated.get("losses", {}))
         if aux:
             loss_sum = loss_sum + sum(jnp.sum(a) for a in aux) * mask.sum()
-        return loss_sum, (output, new_stats, mask)
+        counters = _step_counters(mutated, counter_names, mask.sum())
+        if bias_rate:
+            counters["router_load"] = _bias_loads(
+                params, mutated.get("router_load"))
+        return loss_sum, (output, new_stats, mask, counters)
 
     grad_fn = jax.value_and_grad(sumloss_and_output, has_aux=True)
 
@@ -240,11 +298,11 @@ def make_train_step(model, tx, criterion: Callable,
 
         if k <= 1:
             with holds:
-                (loss_sum, (output, new_stats, mask)), grads = grad_fn(
-                    state.params, state.batch_stats, batch, dropout_rng
-                )
+                (loss_sum, (output, new_stats, mask, counters)), grads = \
+                    grad_fn(state.params, state.batch_stats, batch,
+                            dropout_rng)
             count = mask.sum()
-            metrics = {"loss_sum": loss_sum, "count": count}
+            metrics = {"loss_sum": loss_sum, "count": count, **counters}
             with jax.named_scope("metrics"):
                 metrics.update(
                     micro_metrics(output, batch[target_key], mask))
@@ -267,10 +325,9 @@ def make_train_step(model, tx, criterion: Callable,
                 stats, gsum, msum = carry
                 rng = jax.random.fold_in(dropout_rng, mb["_idx"])
                 mb = {kk: v for kk, v in mb.items() if kk != "_idx"}
-                (loss_sum, (output, new_stats, mask)), grads = grad_fn(
-                    state.params, stats, mb, rng
-                )
-                m = {"loss_sum": loss_sum, "count": mask.sum()}
+                (loss_sum, (output, new_stats, mask, counters)), grads = \
+                    grad_fn(state.params, stats, mb, rng)
+                m = {"loss_sum": loss_sum, "count": mask.sum(), **counters}
                 with jax.named_scope("metrics"):
                     m.update(micro_metrics(output, mb[target_key], mask))
                 gsum = jax.tree.map(jnp.add, gsum, grads)
@@ -286,11 +343,18 @@ def make_train_step(model, tx, criterion: Callable,
                        "count": jnp.zeros((), jnp.float32)}
             for fn in metric_fns:
                 zeros_m[f"{fn.__name__}_sum"] = jnp.zeros((), jnp.float32)
+            for name in counter_names:
+                zeros_m[f"{COUNTER_PREFIX}{name}_sum"] = jnp.zeros(
+                    (), jnp.float32)
+            if bias_rate:
+                zeros_m["router_load"] = _bias_loads(state.params)
             with holds:
                 (new_stats, grads, metrics), _ = jax.lax.scan(
                     body, (state.batch_stats, zeros_g, zeros_m), micro
                 )
             loss_sum, count = metrics["loss_sum"], metrics["count"]
+        # the whole batch's loads, the micro-batches' summed; not a metric
+        loads = metrics.pop("router_load", None)
 
         if inject_nan_grad_step is not None:
             poison = jnp.where(
@@ -381,6 +445,8 @@ def make_train_step(model, tx, criterion: Callable,
                 # here even when the gradients themselves were finite
                 health_update_norm = optax.global_norm(updates)
             new_params = optax.apply_updates(state.params, updates)
+            if bias_rate:
+                new_params = selection_bias_step(new_params, loads, bias_rate)
             if skip_nonfinite:
                 # branchless select: a suppressed step leaves params/opt_state/
                 # batch_stats bit-identical (no host round-trip, stays one XLA

@@ -58,7 +58,8 @@ from ..utils.watchdog import StepWatchdog
 from .optim import build_optimizer
 from .state import create_sharded_train_state
 from .steps import (
-    finalize_metrics, instrument_step, make_eval_step, make_train_step,
+    COUNTER_PREFIX, finalize_metrics, instrument_step, make_eval_step,
+    make_train_step,
 )
 
 
@@ -558,7 +559,9 @@ class Trainer(BaseTrainer):
         train_keys = self._metric_keys() + (
             ["skipped_sum"] if self.skip_nonfinite else []
         ) + (["grad_norm_sum"] if self.log_grad_norm else []
-             ) + self._health_keys
+             ) + self._health_keys + [
+            f"{COUNTER_PREFIX}{name}_sum"
+            for name in getattr(model, "step_counters", ())]
         # the options ride on the jitted function, so the warm-up's
         # ahead-of-time compile, the lazy first call and the profiler's
         # lower().compile() all build the same executable
@@ -1082,6 +1085,12 @@ class Trainer(BaseTrainer):
                     float(m["grad_norm_sum"])
                     / max(float(m["count"]), 1.0), 6,
                 )
+            # the model's own counters of this step (steps.COUNTER_PREFIX),
+            # fetched with the loss above
+            for key, value in m.items():
+                if key.startswith(COUNTER_PREFIX):
+                    rec[key[len(COUNTER_PREFIX):-len("_sum")]] = round(
+                        float(value) / max(float(m["count"]), 1.0), 6)
             if self.profile_enabled and step > 0:
                 rate = self.throughput.rate()
                 self.writer.add_scalar(

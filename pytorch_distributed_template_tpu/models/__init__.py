@@ -5,5 +5,6 @@ from .resnet import ResNet, ResNet18, ResNet34, ResNet50, ResNet101, ResNet152
 from .moe import MoeMlp, moe_lm, tiny_moe_lm
 from .pipelined import PipelinedLM, pipelined_lm, tiny_pipe_lm
 from .llama import LlamaLM, llama, tiny_llama
+from .nemotron_h import NemotronHLM, nemotron_h, tiny_nemotron_h
 from .transformer import TransformerLM, gpt2, tiny_lm
 from .vit import ViT, vit

@@ -45,7 +45,7 @@ from ..ops.attention import (
     grouped_query_attention, multihead_attention, ring_attention,
     sharded_flash_attention, ulysses_attention, zigzag_perm,
 )
-from .remat_policy import block_policy
+from .remat_policy import BlockKind, block_policy
 
 
 def _dense_init(stddev=0.02):
@@ -122,19 +122,20 @@ class LlamaAttention(nn.Module):
     attn_impl: str = "xla"
     mesh: Optional[Any] = None
     seq_layout: str = "natural"
-    rope_base: float = 10000.0
+    rope_base: float = 10000.0      # 0: no rotation (the model states none)
     window: int = 0                 # sliding-window size; 0 = full causal
     quant: str = ""                 # "" | "w8a16" (models/quant.py)
     kv_quant: str = ""              # "" | "int8" (decode cache; quant.py)
     lora_rank: int = 0              # >0: LoRA fine-tuning (models/lora.py)
     lora_alpha: float = 16.0
+    head_dim: int = 0               # 0 -> d_model // n_head
 
     @nn.compact
     def __call__(self, x, positions, train: bool, decode: bool = False,
                  decode_index=None, prefill: bool = False,
                  pad_lens=None, block_tables=None, row_starts=None):
         b, t, _ = x.shape
-        hd = self.d_model // self.n_head
+        hd = self.head_dim or self.d_model // self.n_head
         groups = self.n_head // self.n_kv_head
         dense = _dense_or_quant(self.dtype, self.quant, self.lora_rank,
                                 self.lora_alpha)
@@ -149,13 +150,18 @@ class LlamaAttention(nn.Module):
         v = proj("v_proj", self.n_kv_head)
 
         if decode:
+            if not self.rope_base:
+                raise NotImplementedError(
+                    "the decode caches rotate what they store: a model "
+                    "without rotation has no decode path yet")
             ctx = self._cached_attention(q, k, v, decode_index, groups,
                                          prefill, pad_lens, block_tables,
                                          row_starts)
         else:
-            cos, sin = rope_tables(positions, hd, self.rope_base)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+            if self.rope_base:
+                cos, sin = rope_tables(positions, hd, self.rope_base)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
             # GQA: the SP impls take COMPACT K/V (n_kv heads cross the
             # interconnect — groups x less traffic — and expand locally);
             # the single-device impls get the broadcast here
@@ -747,9 +753,10 @@ class LlamaLM(nn.Module):
                       "attn_proj": self.d_model}
             if self.moe_experts <= 0 or self.moe_every > 1:
                 widths.update(mlp_gate=d_ff, mlp_up=d_ff)
-            policy = block_policy(self, train and not decode, widths,
-                                  n_blocks=n_run, batch=b, seq_len=t,
-                                  block_key="layers_")
+            policy = block_policy(
+                self, train and not decode,
+                [BlockKind(widths, n_run, self.n_head, hd)],
+                batch=b, seq_len=t, block_key="layers_")
             # static_argnums count self as 0: train=3 / decode=5 are Python
             # bools; positions (2) and example_mask (4) are traced
             block_cls = nn.remat(

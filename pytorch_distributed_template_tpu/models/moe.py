@@ -18,17 +18,32 @@ the XLA SPMD partitioner understands natively):
   residual path carries them), keeping shapes static for XLA;
 - the Switch load-balancing auxiliary loss is emitted through flax's
   ``losses`` collection (``sow``), picked up by the train step.
+
+``ExpertLayer`` is the other expert layer, for the models whose experts
+outnumber the chips: it is told which experts it holds, routes over all
+the published ones, drops no token, and computes every expert it holds
+over every token, so that a step's time does not follow its routing.
+``MoeMlp`` stays for the GShard form: capacity slots, the einsum dispatch
+the SPMD partitioner turns into an all-to-all over an ``expert`` mesh
+axis, the load-balancing loss and padded-example masks, none of which the
+dropless layer has.
 """
 from __future__ import annotations
 
-from typing import Any, Optional
+import functools
+import logging
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config.registry import MODELS
+from ..observability.trace import say_once
+
+logger = logging.getLogger(__name__)
 
 
 def _init(stddev):
@@ -267,6 +282,175 @@ class MoeMlp(nn.Module):
             (r"moe/router/kernel", P()),
             (r"moe/router/bias", P()),
         ]
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer: experts held here, routed over all published
+# ---------------------------------------------------------------------------
+
+def held_experts(x, weight, up, down):
+    """What the experts held give: ``x [S, D]`` tokens, ``weight [S, E]``
+    the weight of token s at held expert e (0 where it did not choose
+    it), ``up [E, D, F]`` and ``down [E, F, D]``. Returns ``[S, D]`` in
+    float32: ``sum_e weight[s, e] * relu(x[s] @ up[e])**2 @ down[e]``,
+    every held expert over every token, two batched products."""
+    act = jnp.square(jax.nn.relu(
+        jnp.einsum("sd,edf->esf", x, up.astype(x.dtype))))
+    out = jnp.einsum("esf,efd->esd", act, down.astype(x.dtype))
+    # a product and a sum the compiler fuses: no float32 copy of `out`
+    return jnp.sum(weight.astype(jnp.float32).T[:, :, None]
+                   * out.astype(jnp.float32), axis=0)
+
+
+class ExpertLayer(nn.Module):
+    """Routed experts as a chip holds them, with what today's sparse
+    models put around them. Each part is there or not by its argument.
+
+    The router scores all ``n_routed`` published experts in float32
+    (``sigmoid`` or ``softmax``), takes the ``top_k`` with the largest
+    score plus ``selection_bias`` (a leaf no gradient reaches: it steers
+    the choice, not the weights, and the training step moves it against
+    each expert's load, ``engine/steps.selection_bias_step``; the choice
+    is a mask, every expert at or over the ``top_k``-th largest, so
+    experts tied exactly there are all taken), and weighs each chosen
+    expert by its score over the sum of the chosen scores, times
+    ``scale``. Of those, this chip holds experts ``held[0] .. held[0] + held[1]`` (``held[1]``
+    0: all of them) and adds their part: ``relu(l @ up_e)**2 @ down_e``
+    on ``l``, the token itself or its ``latent`` projection. What the
+    experts held elsewhere would add is theirs to add: on one chip the
+    layer runs without its exchange, and the sum over all shares of
+    ``held`` (the shared expert counted once) is the whole layer.
+
+    No token is dropped whatever the imbalance, and the step's time does
+    not follow it. The room for pairs of token and held expert is sized
+    from shapes alone at its bound, ``tokens x min(top_k, held)``, which
+    no routing can pass; with that much room every held expert has a
+    place for every token, so nothing is sorted or gathered: each held
+    expert's two products run over all the tokens, weighed 0 where a
+    token did not choose it (``held_experts``). That is ``held`` dense
+    products where routing needs ``top_k / n_routed`` of each: right for
+    a chip's share of a few experts, not for hundreds held at once. (On
+    a v5e at 16384 tokens, 8 held of 512, 22 a token, forward and
+    gradient: 27.1 ms a layer whatever the routing; a buffer of that
+    size sorted by expert under ``jax.lax.ragged_dot`` takes 24.8 ms at
+    uniform routing and 42.2 ms when three held experts take every
+    token, and three times the memory. PERF.md, PR 33.)
+
+    ``shared_d_ff`` adds one expert every token takes. Counters of the
+    step, sown under ``counters`` (engine/steps.py carries them):
+    ``moe_pairs_here``, ``moe_load_max_over_mean`` over the experts held
+    (``1 / n_layers`` of it, so that the layers' sum is their mean) and
+    ``moe_tokens_unserved``, the tokens none of whose experts is held.
+    """
+    d_model: int
+    d_ff: int
+    n_routed: int
+    top_k: int
+    held: Tuple[int, int] = (0, 0)
+    latent: int = 0
+    shared_d_ff: int = 0
+    router: str = "sigmoid"
+    selection_bias: bool = False
+    scale: float = 1.0
+    n_layers: int = 1               # expert layers in the model (counters)
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        b, t, d = x.shape
+        s = b * t
+        lo, n_held = self.held[0], self.held[1] or self.n_routed
+        if not 0 <= lo <= lo + n_held <= self.n_routed:
+            raise ValueError(f"held {self.held} lies outside the "
+                             f"{self.n_routed} routed experts")
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(f"router={self.router!r}; expected "
+                             "'sigmoid'/'softmax'")
+        k = min(self.top_k, self.n_routed)
+        xf = x.reshape(s, d)
+        xc = xf.astype(self.dtype)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype,
+                                  kernel_init=_init(0.02))
+
+        with jax.named_scope("moe_route"):
+            w_r = self.param("router", _init(0.02), (d, self.n_routed),
+                             jnp.float32)
+            logits = checkpoint_name(jnp.matmul(
+                xf.astype(jnp.float32), w_r,
+                precision=jax.lax.Precision.HIGHEST), "moe_router")
+            scores = (jax.nn.sigmoid(logits) if self.router == "sigmoid"
+                      else jax.nn.softmax(logits, axis=-1))
+            choice = scores
+            if self.selection_bias:
+                bias = self.param("selection_bias", nn.initializers.zeros,
+                                  (self.n_routed,), jnp.float32)
+                choice = scores + jax.lax.stop_gradient(bias)
+            # the k-th largest is the bar: a mask over all the experts
+            # and a slice of the held ones, and nothing is gathered
+            bar = jax.lax.top_k(jax.lax.stop_gradient(choice), k)[0][:, -1:]
+            took = jax.lax.stop_gradient(choice) >= bar            # [S, E]
+            chosen_sum = jnp.sum(jnp.where(took, scores, 0.0), axis=1,
+                                 keepdims=True)
+            if self.selection_bias:
+                # tokens each published expert got, under the bias's own
+                # path: what the step's rule for it reads (engine/steps.py)
+                self.sow("router_load", "selection_bias",
+                         jnp.sum(took, axis=0, dtype=jnp.float32),
+                         reduce_fn=lambda a, b: a + b,
+                         init_fn=lambda: jnp.zeros((self.n_routed,),
+                                                   jnp.float32))
+            hit = took[:, lo:lo + n_held]
+            weight = jnp.where(hit, self.scale * scores[:, lo:lo + n_held]
+                               / (chosen_sum + 1e-20), 0.0)
+            counts = jnp.sum(hit, axis=0)
+            pairs = jnp.sum(counts)
+            self._count("moe_pairs_here", pairs)
+            self._count("moe_load_max_over_mean",
+                        jnp.max(counts) * n_held / jnp.maximum(pairs, 1)
+                        / self.n_layers)
+            self._count("moe_tokens_unserved",
+                        s - jnp.sum(jnp.any(hit, axis=1)))
+
+        with jax.named_scope("moe_shared"):
+            tokens = xc
+            if self.latent:
+                tokens = checkpoint_name(
+                    dense(self.latent, name="latent_down")(tokens),
+                    "moe_latent")
+        width = self.latent or d
+        up = self.param("experts_up", _init(0.02),
+                        (n_held, width, self.d_ff), jnp.float32)
+        down = self.param("experts_down", _init(0.02),
+                          (n_held, self.d_ff, width), jnp.float32)
+
+        say_once(
+            logger, "moe/dispatch",
+            dict(tokens=s, held=n_held, routed=self.n_routed, top_k=k,
+                 expected=s * k * n_held / self.n_routed,
+                 rows=s * min(k, n_held)),
+            "moe/dispatch: %(tokens)d tokens, %(held)d of %(routed)d experts "
+            "held, %(top_k)d a token: %(expected).0f pairs a layer a step at "
+            "uniform routing, room for %(rows)d, which no routing passes: "
+            "every held expert over every token")
+        with jax.named_scope("moe_experts"):
+            routed = held_experts(tokens, weight, up, down)
+
+        with jax.named_scope("moe_shared"):
+            out = routed.astype(self.dtype)
+            if self.latent:
+                out = dense(d, name="latent_up")(out)
+            if self.shared_d_ff:
+                mid = checkpoint_name(
+                    dense(self.shared_d_ff, name="shared_up")(xc),
+                    "moe_shared_up")
+                out = out + dense(d, name="shared_down")(
+                    jnp.square(jax.nn.relu(mid)))
+        return out.reshape(b, t, d)
+
+    def _count(self, name, value):
+        self.sow("counters", name, jnp.asarray(value, jnp.float32),
+                 reduce_fn=lambda a, b: a + b,
+                 init_fn=lambda: jnp.zeros((), jnp.float32))
 
 
 @MODELS.register("MoeLM")

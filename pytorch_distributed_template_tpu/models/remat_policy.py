@@ -37,6 +37,22 @@ which is the order of recomputation spared for a byte kept:
    repeats and folds again (2.2 ms for 201 MB there) and needs
    ``qkv_proj`` no more, which jax then drops from what is kept.
 
+A stack whose layers are of several kinds (models/nemotron_h.py: one
+mixer a layer, by a pattern) names besides, each where its mixer makes it:
+
+- ``moe_router``: an expert layer's router logits, float32 and as wide as
+  the experts published; they come from a float32 product of six passes,
+  so a byte of them spares the most, and they go second;
+- ``ssm_in_proj``, ``moe_latent``, ``moe_shared_up``: a state-space
+  layer's input projection, an expert layer's latent projection and its
+  shared expert's first product. Each contracts over ``d_model`` like
+  ``qkv_proj``, and they stand with the projections, the widest last.
+
+Neither the scan's output nor the routed experts' has a name: their
+backward needs what lies inside them, so keeping the result would spare
+next to nothing. The bytes are reckoned by kind of block and summed over
+the kinds' counts; a name a kind lacks costs it nothing.
+
 One policy serves every block of a model. An empty prefix is
 ``nothing_saveable``; so is a device whose capacity is unknown (the CPU),
 and a gradient taken outside a step that says what it holds.
@@ -46,12 +62,12 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import logging
-from typing import Mapping, Optional, Sequence, Tuple
+from typing import Mapping, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
-from ..observability.trace import span
+from ..observability.trace import say_once
 from ..ops.flash import named_residual_bytes
 from ..parallel.mesh import axis_size
 from ..parallel.sharding import DATA_AXES, per_device_bytes
@@ -62,18 +78,34 @@ logger = logging.getLogger(__name__)
 # would still cost the kernel call
 PREFERENCE: Tuple[Tuple[str, ...], ...] = (
     ("attn_out", "attn_lse"),
+    ("moe_router",),
     ("qkv_proj",),
     ("attn_proj",),
+    ("ssm_in_proj",),
+    ("moe_latent",),
     ("mlp_gate",),
     ("mlp_up",),
+    ("moe_shared_up",),
     ("attn_qkv",),
 )
+
+
+class BlockKind(NamedTuple):
+    """One kind of block of a stack: ``widths`` maps a name to the bytes
+    a token of it takes divided by the model's item size (the features of
+    a matmul output in the compute type; twice them for float32), and
+    ``count`` says how many such blocks there are. ``attn_heads`` > 0 says
+    the block calls the model's attention once, with that many query
+    heads of ``head_dim``."""
+    widths: Mapping[str, int]
+    count: int
+    attn_heads: int = 0
+    head_dim: int = 0
 # left free under the device's limit: the allocator's fragmentation, the
 # compiler's own copies, and room for the step's peak to be read at least
 # 1 GiB under ``bytes_limit``
 HEADROOM_BYTES = 1 << 30
 
-_logged: set = set()
 _held: contextvars.ContextVar = contextvars.ContextVar(
     "remat_policy_step_holds", default=None)
 
@@ -105,18 +137,20 @@ def device_capacity_bytes(mesh=None) -> Optional[int]:
     return int(stats["bytes_limit"]) if stats else None
 
 
-def choose_names(block_bytes: Mapping[str, int], n_blocks: int,
+def choose_names(blocks: Sequence[Tuple[Mapping[str, int], int]],
                  budget: int) -> Tuple[str, ...]:
     """The names to keep: the longest prefix of ``PREFERENCE`` whose bytes,
-    kept in each of ``n_blocks`` blocks, stay inside ``budget``.
-    ``block_bytes`` maps a name to its bytes on one device in one block; a
-    name it lacks (no gate in a GELU MLP, no log-sum-exp from the XLA
-    attention) is passed over. A prefix, not a knapsack: a smaller budget
-    never keeps what a larger one leaves out."""
+    kept in every block, stay inside ``budget``. ``blocks`` holds, for
+    each kind of block, a map from a name to its bytes on one device in
+    one such block, and the number of such blocks. A name no kind has (no
+    gate in a GELU MLP, no log-sum-exp from the XLA attention) is passed
+    over. A prefix, not a knapsack: a smaller budget never keeps what a
+    larger one leaves out."""
     kept, total = [], 0
     for group in PREFERENCE:
-        names = [n for n in group if n in block_bytes]
-        total += n_blocks * sum(block_bytes[n] for n in names)
+        names = [n for n in group if any(n in table for table, _ in blocks)]
+        total += sum(count * table.get(n, 0)
+                     for table, count in blocks for n in names)
         if total > budget:
             break
         kept += names
@@ -148,7 +182,7 @@ def token_shards(mesh, batch: int, seq_len: int,
 
 def budget_bytes(capacity: int, held_bytes: int, outside_param_bytes: int,
                  block_input_bytes: int, head_bytes: int,
-                 block_bytes: Mapping[str, int], n_blocks: int) -> int:
+                 blocks: Sequence[Tuple[Mapping[str, int], int]]) -> int:
     """Bytes one device can give to kept intermediates.
 
     What is kept is all alive when the backward starts, and that is the
@@ -170,73 +204,78 @@ def budget_bytes(capacity: int, held_bytes: int, outside_param_bytes: int,
     - one block's backward: the block's recomputed intermediates and as
       much again for their cotangents, reckoned as twice all its named
       bytes (the flash kernels' scratch is on-chip; their folded operands
-      are of the size of ``qkv_proj``);
+      are of the size of ``qkv_proj``), of the kind of block with most;
     - ``HEADROOM_BYTES``.
     """
+    n_blocks = sum(count for _, count in blocks)
     margin = (n_blocks * block_input_bytes + head_bytes
-              + 2 * sum(block_bytes.values()) + HEADROOM_BYTES)
+              + 2 * max(sum(table.values()) for table, _ in blocks)
+              + HEADROOM_BYTES)
     return capacity - held_bytes - outside_param_bytes - margin
 
 
-def block_policy(model, training: bool, widths: Mapping[str, int],
-                 n_blocks: int, batch: int, seq_len: int, block_key: str):
+def block_policy(model, training: bool, kinds: Sequence[BlockKind],
+                 batch: int, seq_len: int, block_key: str):
     """The checkpoint policy for the blocks of ``model`` (a bound
-    ``TransformerLM`` or ``LlamaLM`` inside its call, whose blocks'
-    parameters are under keys that start with ``block_key``).
+    ``TransformerLM``, ``LlamaLM`` or ``NemotronHLM`` inside its call,
+    whose blocks' parameters are under keys that start with ``block_key``).
 
     Everything comes from the shapes traced there (``batch``, ``seq_len``,
-    and ``widths``: the features a token of each matmul output a block
-    names), from the model's fields, mesh, partition rules and parameters,
-    from ``step_holds`` and from the device's capacity. A call that is not
-    ``training`` (evaluation, decode, init) takes no gradient: it chooses
-    nothing and logs nothing. A training call logs its choice, once per
-    distinct choice in a process."""
+    and ``kinds``: for each kind of block the features a token of each
+    matmul output it names, how many such blocks there are and the heads
+    it attends with; one kind for a stack of equal blocks), from the
+    model's fields, mesh, partition rules and parameters, from
+    ``step_holds`` and from the device's capacity. A call that is not ``training`` (evaluation,
+    decode, init) takes no gradient: it chooses nothing and logs nothing.
+    A training call logs its choice, once per distinct choice in a
+    process."""
     mesh, held = model.mesh, _held.get()
     capacity = None
     if training and held is not None and not model.is_initializing():
         capacity = device_capacity_bytes(mesh)
     if capacity is None:
         return policy_of(())
+    n_blocks = sum(kind.count for kind in kinds)
     tok = batch * seq_len * np.dtype(model.dtype).itemsize
-    named = {name: tok * width for name, width in widths.items()}
     hidden = tok * model.d_model
-    # the attention's own names, where one call a block makes them in the
-    # policy's sight; a ring's steps and the all-to-all's share of heads
-    # are not reckoned, and keep nothing
-    if model.attn_impl == "xla":
-        named["attn_out"] = hidden
-    elif model.attn_impl == "flash":
-        named.update(named_residual_bytes(
-            batch, seq_len, model.n_head, model.d_model // model.n_head,
-            model.dtype))
-    head = (2 * hidden if model.fused_head
-            else 3 * batch * seq_len * model.vocab_size * 4)
     shards = token_shards(
         mesh, batch, seq_len,
         seq_sharded=model.attn_impl.split("_")[0] in ("ring", "ulysses"))
-    table = {name: b // shards for name, b in named.items()}
+    blocks = []
+    for kind in kinds:
+        named = {name: tok * width for name, width in kind.widths.items()}
+        # the attention's own names, where one call a block makes them in
+        # the policy's sight; a ring's steps and the all-to-all's share of
+        # heads are not reckoned, and keep nothing
+        if kind.attn_heads and model.attn_impl == "xla":
+            named["attn_out"] = tok * kind.attn_heads * kind.head_dim
+        elif kind.attn_heads and model.attn_impl == "flash":
+            named.update(named_residual_bytes(
+                batch, seq_len, kind.attn_heads, kind.head_dim, model.dtype))
+        blocks.append(({name: b // shards for name, b in named.items()},
+                       kind.count))
+    head = (2 * hidden if model.fused_head
+            else 3 * batch * seq_len * model.vocab_size * 4)
     params = model.variables["params"]
     outside = per_device_bytes(
         {k: v for k, v in params.items() if not k.startswith(block_key)},
         mesh, model.partition_rules())
     budget = budget_bytes(capacity, held, outside, hidden // shards,
-                          head // shards, table, n_blocks)
-    names = choose_names(table, n_blocks, budget)
-    kept_block = sum(table[n] for n in names)
+                          head // shards, blocks)
+    names = choose_names(blocks, budget)
+    kept = sum(count * table.get(n, 0) for table, count in blocks
+               for n in names)
+    kept_block = kept // n_blocks
     record = dict(
         names=",".join(names), kept_bytes_per_block=kept_block,
-        kept_bytes=kept_block * n_blocks, budget_bytes=budget,
+        kept_bytes=kept, budget_bytes=budget,
         capacity_bytes=capacity, blocks=n_blocks, held_bytes=held,
     )
-    key = tuple(record.items())
-    if key not in _logged:
-        _logged.add(key)
-        with span("remat/policy", **record):
-            pass
-        logger.info(
-            "remat/policy: keeping [%s] in each of %d blocks: %.1f MB a "
-            "block, %.3f GB in all on a device, of a budget of %.3f GB "
-            "(capacity %.3f GB, the step holds %.3f GB)", record["names"],
-            n_blocks, kept_block / 1e6, kept_block * n_blocks / 1e9,
-            budget / 1e9, capacity / 1e9, held / 1e9)
+    say_once(
+        logger, "remat/policy", record,
+        "remat/policy: keeping [%s] in each of %d blocks: %.1f MB a "
+        "block, %.3f GB in all on a device, of a budget of %.3f GB "
+        "(capacity %.3f GB, the step holds %.3f GB)", record["names"],
+        n_blocks, kept_block / 1e6, kept / 1e9,
+        budget / 1e9, capacity / 1e9, held / 1e9)
     return policy_of(names)

@@ -42,7 +42,7 @@ from ..ops.attention import (
     multihead_attention, ring_attention, sharded_flash_attention,
     ulysses_attention, zigzag_perm,
 )
-from .remat_policy import block_policy
+from .remat_policy import BlockKind, block_policy
 
 
 def _dense_init(stddev):
@@ -401,9 +401,11 @@ class TransformerLM(nn.Module):
                       "attn_proj": self.d_model}
             if self.moe_experts <= 0 or self.moe_every > 1:
                 widths["mlp_up"] = d_ff
-            policy = block_policy(self, train and not decode, widths,
-                                  n_blocks=self.n_layer, batch=b, seq_len=t,
-                                  block_key="h_")
+            policy = block_policy(
+                self, train and not decode,
+                [BlockKind(widths, self.n_layer, self.n_head,
+                           self.d_model // self.n_head)],
+                batch=b, seq_len=t, block_key="h_")
             # static_argnums count `self` as 0: train=2 and decode=4 are
             # Python bools and must stay static; example_mask (3) is a
             # traced [B] array and must NOT be listed

@@ -154,6 +154,7 @@ class SpanRecorder:
 
 
 _default = SpanRecorder()
+_said: set = set()
 
 
 def get_recorder() -> SpanRecorder:
@@ -164,3 +165,18 @@ def get_recorder() -> SpanRecorder:
 def span(name: str, **attrs):
     """``with span("checkpoint/save"): ...`` on the default recorder."""
     return _default.span(name, **attrs)
+
+
+def say_once(logger, name: str, record: dict, text: str, *args) -> None:
+    """A choice the program makes from shapes, told once a process and
+    distinct choice: one zero-length span ``name`` that carries
+    ``record``, and one ``INFO`` line, ``text`` filled from ``args`` or,
+    without any, from ``record`` by name. Read by no metric; the first
+    thing to read when the metric it moves does."""
+    key = (name, tuple(record.items()))
+    if key in _said:
+        return
+    _said.add(key)
+    with span(name, **record):
+        pass
+    logger.info(text, *(args or (record,)))

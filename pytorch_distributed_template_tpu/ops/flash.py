@@ -43,7 +43,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..observability.trace import span
+from ..observability.trace import say_once
 
 NEG_INF = -1e30
 # ``flash_attention_lse``'s blocks (the ring and zig-zag bodies of
@@ -53,7 +53,6 @@ DEFAULT_BLOCK_Q = 512
 DEFAULT_BLOCK_K = 512
 
 logger = logging.getLogger(__name__)
-_logged: set = set()
 
 
 # -- a score tile's place in the mask ---------------------------------------
@@ -1150,18 +1149,13 @@ def _say_tiles(t, t_valid, d, block_q, block_k, causal, window):
     """The tiles a call's kernels visit, once a process and distinct call
     shape: a log line and a zero-length span, as ``remat/policy`` has.
     What to read first when a cell's ``flash_ms_per_step`` moves."""
-    key = (t, t_valid, d, block_q, block_k, causal, window)
-    if key in _logged:
-        return
-    _logged.add(key)
     counts = tile_counts(t, t_valid, block_q, block_k, causal, window)
     record = dict(t=t_valid, d=d, window=window, causal=causal,
                   block_q=min(block_q, t), block_k=min(block_k, t))
     for kernel, c in counts.items():
         record.update({f"{kernel}_{name}": n for name, n in c.items()})
-    with span("flash/tiles", **record):
-        pass
-    logger.info(
+    say_once(
+        logger, "flash/tiles", record,
         "flash/tiles: t %d d %d window %d causal %s blocks %d x %d; %s",
         t_valid, d, window, causal, record["block_q"], record["block_k"],
         "; ".join(

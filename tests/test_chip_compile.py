@@ -193,6 +193,7 @@ def test_what_the_policy_keeps_fits_the_capacity_for_v5e(
     from pytorch_distributed_template_tpu.engine.losses import resolve_loss
     from pytorch_distributed_template_tpu.engine.steps import make_train_step
     from pytorch_distributed_template_tpu.models import remat_policy
+    from pytorch_distributed_template_tpu.observability import trace
     from pytorch_distributed_template_tpu.observability.trace import (
         get_recorder,
     )
@@ -201,7 +202,7 @@ def test_what_the_policy_keeps_fits_the_capacity_for_v5e(
     monkeypatch.setattr(flash, "_on_tpu", lambda: True)
     monkeypatch.setattr(remat_policy, "device_capacity_bytes",
                         lambda mesh=None: capacity)
-    remat_policy._logged.clear()
+    trace._said.clear()
     get_recorder().clear()
     model = MODELS.get("GPT2")(
         size="gpt2-large", n_layer=6, bfloat16=True, attn_impl="flash",
@@ -396,16 +397,15 @@ def _said(name):
 def fresh_records(monkeypatch):
     """The v5e's capacity supplied to the checkpoint policy, and nothing
     said yet by it or by the fused loss."""
-    from pytorch_distributed_template_tpu.engine import losses
     from pytorch_distributed_template_tpu.models import remat_policy
+    from pytorch_distributed_template_tpu.observability import trace
     from pytorch_distributed_template_tpu.observability.trace import (
         get_recorder,
     )
 
     monkeypatch.setattr(remat_policy, "device_capacity_bytes",
                         lambda mesh=None: V5E_BYTES_LIMIT)
-    remat_policy._logged.clear()
-    losses._logged.clear()
+    trace._said.clear()
     get_recorder().clear()
 
 
@@ -514,6 +514,47 @@ def test_gpt2_large_step_is_left_as_it_was_for_v5e(
     assert said == dict(rows_per_device=2048, positions=256, turns=4,
                         slice_bytes=2048 * 50257 * 4, floor_positions=256)
     assert texts[0] == texts[1]
+
+
+NEMOTRON = dict(
+    vocab_size=16384, pattern="EMEMEMEMEM*", d_model=4096, n_head=4,
+    n_kv_head=1, head_dim=128, ssm_n_head=16, ssm_head_dim=64, ssm_n_group=1,
+    ssm_state=128, ssm_conv=4, ssm_chunk=128, moe_n_routed=512,
+    moe_held=(0, 8), moe_top_k=22, moe_latent=1024, moe_d_ff=2688,
+    moe_shared_d_ff=5376, moe_scale=5.0, rms_eps=1e-5, bfloat16=True,
+    attn_impl="flash", remat=True, fused_head=True)
+
+
+def test_hybrid_step_lowers_and_fits_for_v5e(topo, monkeypatch,
+                                             fresh_records):
+    """`nemotron3_super_l11.seq8k`'s step (2 x 8192 on one chip): the
+    pattern-built stack with its scan, its dropless expert layers (every
+    held expert over every token: no branch, no sorted buffer) and the
+    flash kernels compiles for the v5e, the checkpoint policy reckons
+    three kinds of block, and the step stays under the chip's
+    `bytes_limit`."""
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.parallel import build_mesh
+
+    mesh = build_mesh({"data": 1}, devices=topo.devices[:1])
+    _, compiled = _compiled_train_step(
+        MODELS.get("NemotronH")(**NEMOTRON), mesh, 2, 8192, monkeypatch)
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text)
+    (policy,) = _said("remat/policy")
+    assert policy["blocks"] == 11
+    assert policy["names"].startswith("attn_out,attn_lse,moe_router")
+    # the state's init traces one sequence, the step two
+    dispatch = [d for d in _said("moe/dispatch") if d["tokens"] == 16384]
+    assert dispatch and all(d["rows"] == 131072 and d["expected"] == 5632
+                            for d in dispatch)
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < V5E_BYTES_LIMIT - (1 << 30)
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError,

@@ -1,0 +1,248 @@
+"""models/moe.ExpertLayer: experts held here, routed over all published,
+no token dropped; its counters and its biases' rule through the training
+step."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+
+import pytorch_distributed_template_tpu.models  # noqa: F401
+from pytorch_distributed_template_tpu.config.registry import MODELS
+from pytorch_distributed_template_tpu.engine.state import create_train_state
+from pytorch_distributed_template_tpu.engine.losses import lm_cross_entropy
+from pytorch_distributed_template_tpu.engine.steps import (
+    COUNTER_PREFIX, finalize_metrics, make_train_step, selection_bias_step,
+)
+from pytorch_distributed_template_tpu.models.moe import (
+    ExpertLayer, held_experts,
+)
+
+D, F, LATENT = 16, 24, 12
+
+
+def layer(**kw):
+    return ExpertLayer(**{**dict(
+        d_model=D, d_ff=F, n_routed=16, top_k=2, held=(4, 2), latent=LATENT,
+        shared_d_ff=20, selection_bias=True, scale=2.5), **kw})
+
+
+def plain(params, x, lo, n_held, top_k, scale, softmax=False):
+    """Token by token, expert by expert, in numpy."""
+    p = jax.tree.map(np.asarray, params)
+    out = np.zeros_like(x)
+    for i, tok in enumerate(x):
+        logits = tok @ p["router"]
+        s = (np.exp(logits) / np.exp(logits).sum() if softmax
+             else 1 / (1 + np.exp(-logits)))
+        bias = p.get("selection_bias", 0.0)
+        chosen = np.argsort(-(s + bias), kind="stable")[:top_k]
+        w = scale * s[chosen] / (s[chosen].sum() + 1e-20)
+        lat = tok @ p["latent_down"]["kernel"] if "latent_down" in p else tok
+        routed = np.zeros(lat.shape)
+        for e, w_e in zip(chosen, w):
+            if lo <= e < lo + n_held:
+                h = np.maximum(lat @ p["experts_up"][e - lo], 0) ** 2
+                routed += w_e * (h @ p["experts_down"][e - lo])
+        if "latent_up" in p:
+            routed = routed @ p["latent_up"]["kernel"]
+        if "shared_up" in p:
+            routed = routed + (np.maximum(tok @ p["shared_up"]["kernel"], 0)
+                               ** 2) @ p["shared_down"]["kernel"]
+        out[i] = routed
+    return out
+
+
+def init(module, x, seed=0, bias=0.3):
+    params = module.init(jax.random.key(seed), x)["params"]
+    if "selection_bias" in params:     # a bias that changes the choice
+        params = dict(params, selection_bias=bias * jax.random.normal(
+            jax.random.key(seed + 1), params["selection_bias"].shape))
+    return params
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"held": (0, 0)}, {"latent": 0}, {"shared_d_ff": 0},
+    {"selection_bias": False}, {"router": "softmax", "scale": 1.0},
+    {"top_k": 5, "held": (9, 7)},
+], ids=["share", "all-held", "no-latent", "no-shared", "no-bias", "softmax",
+        "held-more-than-chosen"])
+def test_layer_is_the_plain_sum_over_the_experts_held(kw):
+    module = layer(**kw)
+    x = jax.random.normal(jax.random.key(2), (3, 20, D))
+    params = init(module, x)
+    with jax.default_matmul_precision("highest"):
+        got = module.apply({"params": params}, x)
+    lo, n_held = module.held[0], module.held[1] or module.n_routed
+    want = plain(params, np.asarray(x).reshape(-1, D), lo, n_held,
+                 module.top_k, module.scale, module.router == "softmax")
+    np.testing.assert_allclose(np.asarray(got).reshape(-1, D), want,
+                               rtol=0, atol=2e-5 * np.abs(want).max())
+
+
+def skewed(module, x, expert):
+    """Parameters under which every token's first choice is `expert`."""
+    params = init(module, x)
+    return dict(params, selection_bias=jnp.zeros(
+        module.n_routed).at[expert].set(10.0))
+
+
+@pytest.mark.parametrize("expert", [4, 5])
+def test_no_token_is_dropped_when_every_token_picks_the_same_expert(expert):
+    """60 tokens all choose one held expert (and one other each): 60
+    pairs or more where uniform routing gives 15, every one of them at
+    one expert. Every token gets that expert's part, in full."""
+    module = layer(shared_d_ff=0)
+    x = jax.random.normal(jax.random.key(3), (3, 20, D))
+    params = skewed(module, x, expert)
+    with jax.default_matmul_precision("highest"):
+        got, sown = module.apply({"params": params}, x, mutable=["counters"])
+    counters = sown["counters"]
+    assert float(counters["moe_pairs_here"]) >= 60
+    assert float(counters["moe_tokens_unserved"]) == 0
+    assert float(counters["moe_load_max_over_mean"]) > 1.5
+    want = plain(params, np.asarray(x).reshape(-1, D), 4, 2, 2, 2.5)
+    np.testing.assert_allclose(np.asarray(got).reshape(-1, D), want,
+                               rtol=0, atol=2e-5 * np.abs(want).max())
+    assert np.all(np.abs(want).sum(axis=1) > 0)
+
+
+def test_held_experts_is_the_sum_expert_by_expert_and_has_its_gradient():
+    k = jax.random.split(jax.random.key(4), 5)
+    s, e = 40, 3
+    x = jax.random.normal(k[0], (s, LATENT))
+    hit = jax.random.bernoulli(k[1], 0.4, (s, e))
+    weight = jnp.where(hit, jax.random.uniform(k[2], (s, e)), 0.0)
+    up = 0.3 * jax.random.normal(k[3], (e, LATENT, F))
+    down = 0.3 * jax.random.normal(k[4], (e, F, LATENT))
+
+    def one_by_one(x, w, up, down):
+        return sum(w[:, i:i + 1] * (jnp.maximum(x @ up[i], 0) ** 2 @ down[i])
+                   for i in range(e))
+
+    def through(f):
+        return lambda *a: jnp.sum(jnp.sin(f(*a)))
+
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(held_experts(x, weight, up, down),
+                                   one_by_one(x, weight, up, down), atol=2e-5)
+        got = jax.grad(through(held_experts), argnums=range(4))(
+            x, weight, up, down)
+        want = jax.grad(through(one_by_one), argnums=range(4))(
+            x, weight, up, down)
+    for name, g, w in zip(("x", "weight", "up", "down"), got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()),
+                                   err_msg=name)
+
+
+def test_the_bias_steers_the_choice_and_gets_no_gradient():
+    module = layer(shared_d_ff=0)
+    x = jax.random.normal(jax.random.key(5), (2, 16, D))
+    params = init(module, x)
+
+    def loss(p):
+        return jnp.sum(module.apply({"params": p}, x) ** 2)
+
+    grads = jax.grad(loss)(params)
+    assert not np.any(np.asarray(grads["selection_bias"]))
+    assert np.any(np.asarray(grads["router"]))      # by the weights
+    moved = dict(params, selection_bias=-params["selection_bias"])
+    was = module.apply({"params": params}, x)
+    assert float(jnp.abs(module.apply({"params": moved}, x) - was).max()) \
+        > 0.1 * float(jnp.abs(was).max())
+
+
+def test_a_share_outside_the_experts_is_refused():
+    x = jnp.zeros((1, 4, D))
+    with pytest.raises(ValueError, match="lies outside"):
+        layer(held=(12, 8)).init(jax.random.key(0), x)
+    with pytest.raises(ValueError, match="router="):
+        layer(router="top").init(jax.random.key(0), x)
+
+
+@pytest.mark.parametrize("accum", [1, 2])
+def test_the_steps_metrics_carry_the_models_counters(accum):
+    """Summed over the expert layers, times the valid count, so that the
+    metrics' divide-by-count reads a step's mean; the trainer writes the
+    same keys to the flight record at the log flush."""
+    model = MODELS.get("TinyNemotronH")(pattern="EME*", moe_held=(2, 4))
+    tokens = jax.random.randint(jax.random.key(6), (4, 32), 0, 256)
+    tx = optax.adamw(1e-3)
+    state = create_train_state(model, tx, np.zeros((1, 32), np.int32), seed=0)
+    step = jax.jit(make_train_step(
+        model, tx, lm_cross_entropy, [], input_key="tokens",
+        target_key="tokens", grad_accum_steps=accum))
+    _, metrics = step(state, {"tokens": tokens,
+                              "mask": jnp.ones((4,), bool)})
+    names = {k for k in metrics if k.startswith(COUNTER_PREFIX)}
+    assert names == {f"{COUNTER_PREFIX}{n}_sum" for n in model.step_counters}
+    read = finalize_metrics(jax.tree.map(float, metrics))
+    # 128 tokens x 2 of 8 experts a token, 4 held, 2 layers: 128 pairs a
+    # layer at uniform routing (each micro-batch counts its own pairs)
+    pairs = read[f"{COUNTER_PREFIX}moe_pairs_here"]
+    assert 0.5 * 256 < pairs * accum < 1.5 * 256
+    assert read[f"{COUNTER_PREFIX}moe_load_max_over_mean"] >= 1.0
+    assert read[f"{COUNTER_PREFIX}moe_tokens_unserved"] >= 0
+
+
+def test_a_model_without_counters_gets_none():
+    model = MODELS.get("TinyLlama")()
+    tx = optax.adamw(1e-3)
+    state = create_train_state(model, tx, np.zeros((1, 16), np.int32), seed=0)
+    step = jax.jit(make_train_step(model, tx, lm_cross_entropy, [],
+                                   input_key="tokens", target_key="tokens"))
+    _, metrics = step(state, {"tokens": jnp.zeros((2, 16), jnp.int32),
+                              "mask": jnp.ones((2,), bool)})
+    assert set(metrics) == {"loss_sum", "count"}
+
+
+def test_the_biases_rule_by_hand():
+    """Up by the rate where an expert got fewer tokens than the mean,
+    down where more, still where it got the mean; other leaves stay."""
+    params = {"a": {"selection_bias": jnp.array([0.5, 0.0, -0.5, 0.1]),
+                    "router": jnp.ones((2, 4))}}
+    loads = {("a", "selection_bias"): jnp.array([10.0, 2.0, 4.0, 0.0])}
+    new = selection_bias_step(params, loads, 0.25)
+    np.testing.assert_allclose(new["a"]["selection_bias"],
+                               [0.25, 0.25, -0.5, 0.35])
+    np.testing.assert_array_equal(new["a"]["router"], params["a"]["router"])
+
+
+def step_of(model, accum=1):
+    tx = optax.adamw(1e-3)
+    state = create_train_state(model, tx, np.zeros((1, 32), np.int32), seed=0)
+    return state, jax.jit(make_train_step(
+        model, tx, lm_cross_entropy, [], input_key="tokens",
+        target_key="tokens", grad_accum_steps=accum))
+
+
+@pytest.mark.parametrize("accum", [1, 2])
+def test_a_step_moves_each_bias_against_its_experts_load(accum):
+    """The load is the whole batch's, the micro-batches' summed, over all
+    the published experts and not the held ones alone; the loads do not
+    ride out with the metrics."""
+    model = MODELS.get("TinyNemotronH")(pattern="EME", moe_held=(2, 4),
+                                        selection_bias_rate=1e-3)
+    tokens = jax.random.randint(jax.random.key(7), (4, 32), 0, 256)
+    state, step = step_of(model, accum)
+    new, metrics = step(state, {"tokens": tokens,
+                                "mask": jnp.ones((4,), bool)})
+    assert "router_load" not in metrics
+    _, sown = model.apply({"params": state.params}, tokens, train=True,
+                          mutable=["router_load"])
+    for name in ("layers_0", "layers_2"):
+        load = np.asarray(sown["router_load"][name]["mixer"]["selection_bias"])
+        assert load.shape == (8,) and load.sum() == 128 * 2
+        np.testing.assert_allclose(
+            new.params[name]["mixer"]["selection_bias"],
+            1e-3 * np.sign(load.mean() - load))
+
+
+def test_without_a_rate_the_biases_stay():
+    model = MODELS.get("TinyNemotronH")(pattern="EM")
+    state, step = step_of(model)
+    new, _ = step(state, {"tokens": jnp.ones((2, 32), jnp.int32),
+                          "mask": jnp.ones((2,), bool)})
+    assert not np.any(new.params["layers_0"]["mixer"]["selection_bias"])
+    assert np.any(new.params["layers_0"]["mixer"]["router"]
+                  != state.params["layers_0"]["mixer"]["router"])

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from pytorch_distributed_template_tpu.observability import trace
 from pytorch_distributed_template_tpu.observability.trace import get_recorder
 from pytorch_distributed_template_tpu.ops import flash
 
@@ -265,14 +266,14 @@ def test_flash_tiles_line_once_a_call_shape(caplog):
     """One `flash/tiles` INFO line and one zero-length span a process and
     distinct call shape; GPT-2-large's call computes under 1.5 times the
     scores its queries see."""
-    flash._logged.clear()
+    trace._said.clear()
     get_recorder().clear()
     shape = jax.ShapeDtypeStruct((8, 1024, 20, 64), jnp.bfloat16)
-    trace = lambda **kw: jax.eval_shape(functools.partial(
+    shaped = lambda **kw: jax.eval_shape(functools.partial(
         flash.flash_attention, interpret=True, **kw), shape, shape, shape)
     with caplog.at_level("INFO", logger=flash.logger.name):
-        trace(causal=True)
-        trace(causal=True)
+        shaped(causal=True)
+        shaped(causal=True)
         said = _tiles_said()
         assert len(said) == 1
         block_q, block_k = flash.pick_block_sizes(1024, 64)
@@ -285,7 +286,7 @@ def test_flash_tiles_line_once_a_call_shape(caplog):
             assert (said[0][f"{kernel}_tiles_edge"]
                     + said[0][f"{kernel}_tiles_interior"]
                     == said[0][f"{kernel}_tiles_visited"])
-        trace(causal=True, window=256)
+        shaped(causal=True, window=256)
         assert len(_tiles_said()) == 2
     lines = [r.getMessage() for r in caplog.records
              if r.getMessage().startswith("flash/tiles")]

@@ -23,6 +23,7 @@ from pytorch_distributed_template_tpu.models.base import inject_mesh
 from pytorch_distributed_template_tpu.models.remat_policy import (
     HEADROOM_BYTES, token_shards,
 )
+from pytorch_distributed_template_tpu.observability import trace
 from pytorch_distributed_template_tpu.observability.trace import get_recorder
 from pytorch_distributed_template_tpu.parallel import (
     apply_rules, batch_sharding, build_mesh,
@@ -36,7 +37,7 @@ def _slices_said():
 
 @pytest.fixture
 def fresh_record():
-    losses._logged.clear()
+    trace._said.clear()
     get_recorder().clear()
 
 

@@ -22,6 +22,7 @@ from pytorch_distributed_template_tpu.config.registry import MODELS
 from pytorch_distributed_template_tpu.engine.state import create_train_state
 from pytorch_distributed_template_tpu.engine.steps import make_train_step
 from pytorch_distributed_template_tpu.models import remat_policy as rp
+from pytorch_distributed_template_tpu.observability import trace
 from pytorch_distributed_template_tpu.observability.trace import get_recorder
 from pytorch_distributed_template_tpu.ops.flash import named_residual_bytes
 from pytorch_distributed_template_tpu.parallel.mesh import build_mesh
@@ -60,11 +61,11 @@ MATMULS = ("qkv_proj", "attn_proj", "mlp_gate", "mlp_up")
         "all-but-operands", "all-but-up", "gpt2-attention", "gpt2-part",
         "gpt2-all", "absent-names"])
 def test_choose_names_is_a_prefix_that_fits(table, blocks, budget, want):
-    got = rp.choose_names(table, blocks, budget)
+    got = rp.choose_names([(table, blocks)], budget)
     assert got == want
     assert blocks * sum(table[n] for n in got) <= max(budget, 0)
     # the same answer on every call, whatever the table's order
-    assert rp.choose_names(dict(reversed(table.items())), blocks,
+    assert rp.choose_names([(dict(reversed(table.items())), blocks)],
                            budget) == got
 
 
@@ -74,14 +75,14 @@ def test_preference_stops_at_the_first_group_that_does_not_fit():
     # larger one leaves out
     table = {"attn_out": 1, "qkv_proj": 1, "attn_proj": 1, "mlp_gate": 100,
              "mlp_up": 1}
-    assert rp.choose_names(table, 1, 50) == ("attn_out", "qkv_proj",
-                                             "attn_proj")
+    assert rp.choose_names([(table, 1)], 50) == ("attn_out", "qkv_proj",
+                                                 "attn_proj")
 
 
 def test_attention_output_is_kept_with_its_log_sum_exp_or_not_at_all():
     table = {"attn_out": 10, "attn_lse": 2, "qkv_proj": 1}
-    assert rp.choose_names(table, 1, 11) == ()
-    assert rp.choose_names(table, 1, 12) == ATTN
+    assert rp.choose_names([(table, 1)], 11) == ()
+    assert rp.choose_names([(table, 1)], 12) == ATTN
 
 
 def test_empty_choice_is_todays_policy():
@@ -95,19 +96,19 @@ def test_budget_is_capacity_less_what_is_held_and_margin():
     # 36 inputs of 8 x 1024 x 1280
     args = dict(held_bytes=3 * 3096120320, outside_param_bytes=262568960,
                 block_input_bytes=20971520, head_bytes=2 * 20971520,
-                block_bytes=GPT2_LARGE, n_blocks=36)
+                blocks=[(GPT2_LARGE, 36)])
     budget = rp.budget_bytes(16_900_000_000, **args)
     assert budget == (16_900_000_000 - 3 * 3096120320 - 262568960
                       - 36 * 20971520 - 2 * 20971520
                       - 2 * sum(GPT2_LARGE.values()) - rp.HEADROOM_BYTES)
     assert rp.budget_bytes(17_900_000_000, **args) == budget + 10 ** 9
-    assert rp.choose_names(GPT2_LARGE, 36, budget) == ATTN + (
+    assert rp.choose_names([(GPT2_LARGE, 36)], budget) == ATTN + (
         "qkv_proj", "attn_proj")
     # shadow weights beside them leave room for the attention's output
     # alone; accumulation's gradient sum and micro-batch gradient for none
     for copies, want in ((1, ATTN), (2, ())):
         held = args["held_bytes"] + copies * 3096120320
-        assert rp.choose_names(GPT2_LARGE, 36, rp.budget_bytes(
+        assert rp.choose_names([(GPT2_LARGE, 36)], rp.budget_bytes(
             16_900_000_000, **{**args, "held_bytes": held})) == want
 
 
@@ -164,7 +165,7 @@ def capacity(monkeypatch):
     patch the one function that reads it)."""
     def supply(n):
         monkeypatch.setattr(rp, "device_capacity_bytes", lambda mesh=None: n)
-    rp._logged.clear()
+    trace._said.clear()
     get_recorder().clear()
     return supply
 
@@ -255,7 +256,7 @@ def test_policy_record_and_determinism(capacity, family, caplog):
     (line,) = [r.getMessage() for r in caplog.records]
     assert "remat/policy" in line and rec["names"] in line
     # evaluation and init take no gradient and choose nothing
-    rp._logged.clear()
+    trace._said.clear()
     get_recorder().clear()
     model.apply({"params": params}, tokens, train=False)
     assert not _record()
@@ -295,7 +296,7 @@ def test_data_parallel_mesh_picks_what_one_device_picks(capacity, family):
     jax.make_jaxpr(jax.grad(_loss_fn(one, tokens1)))(params)
     (full,) = _record()
     tight = 64 * GIB - full["budget_bytes"] + full["kept_bytes"] // 2
-    rp._logged.clear()
+    trace._said.clear()
     get_recorder().clear()
     capacity(tight)
     jax.make_jaxpr(jax.grad(_loss_fn(one, tokens1)))(params)
@@ -304,7 +305,7 @@ def test_data_parallel_mesh_picks_what_one_device_picks(capacity, family):
 
     mesh = build_mesh({"data": 4}, devices=jax.devices()[:4])
     four = MODELS.get(family)(remat=True, attn_impl="flash", mesh=mesh)
-    rp._logged.clear()
+    trace._said.clear()
     get_recorder().clear()
     step = jax.jit(jax.grad(_loss_fn(
         four, jax.device_put(tokens4, batch_sharding(mesh)))))
@@ -380,7 +381,7 @@ def test_the_step_says_what_it_holds(capacity, family, setting, tx, extra):
     model = MODELS.get(family)(remat=True)
 
     def record(tx, batch, **kw):
-        rp._logged.clear()
+        trace._said.clear()
         get_recorder().clear()
         tokens = np.zeros((batch, 32), np.int32)
         state = create_train_state(model, tx, tokens[:1], seed=0,
@@ -403,3 +404,130 @@ def test_the_step_says_what_it_holds(capacity, family, setting, tx, extra):
         assert sign * (got[field] - base[field]) == pytest.approx(
             extra * param_bytes, abs=64), field
     assert got["kept_bytes"] == base["kept_bytes"] > 0
+
+
+# -- blocks of unequal bytes (a stack whose layers are of several kinds) ----
+
+# nemotron3_super_l11.seq8k's three kinds at 2 x 8192 tokens in bfloat16:
+# 5 state-space layers, 5 expert layers (the router's logits float32), one
+# attention layer of 4 heads of 128
+TOK = 2 * 8192 * 2
+SSM = {"ssm_in_proj": TOK * 2320}
+EXPERTS = {"moe_router": TOK * 1024, "moe_latent": TOK * 1024,
+           "moe_shared_up": TOK * 5376}
+ATTENDS = {"attn_out": 16777216, "attn_lse": 262144, "qkv_proj": TOK * 768,
+           "attn_proj": TOK * 4096, "attn_qkv": 3 * 16777216}
+HYBRID = [(SSM, 5), (EXPERTS, 5), (ATTENDS, 1)]
+HYBRID_ORDER = ("attn_out", "attn_lse", "moe_router", "qkv_proj",
+                "attn_proj", "ssm_in_proj", "moe_latent", "moe_shared_up",
+                "attn_qkv")
+
+
+def kept_bytes(blocks, names):
+    return sum(count * table.get(n, 0) for table, count in blocks
+               for n in names)
+
+
+@pytest.mark.parametrize("budget,n_kept", [
+    (0, 0), (17039360 - 1, 0), (17039360, 2),
+    (17039360 + 5 * TOK * 1024, 3), (GIB // 2, 5), (GIB, 7), (4 * GIB, 9)],
+    ids=["empty", "one-byte-short", "attention", "router", "projections",
+         "latent", "all"])
+def test_unequal_blocks_are_summed_by_kind_and_count(budget, n_kept):
+    got = rp.choose_names(HYBRID, budget)
+    assert got == HYBRID_ORDER[:n_kept]
+    assert kept_bytes(HYBRID, got) <= budget
+    if n_kept < len(HYBRID_ORDER):      # the next group would not fit
+        step = 2 if n_kept == 0 else 1
+        assert kept_bytes(HYBRID, HYBRID_ORDER[:n_kept + step]) > budget
+    # the kinds' order is nothing to the choice
+    assert rp.choose_names(HYBRID[::-1], budget) == got
+
+
+def test_a_name_costs_only_the_kinds_that_make_it():
+    """`moe_router` is kept in 5 of 11 blocks and costs 5 blocks' bytes;
+    a kind without the name is passed over, not counted at another's."""
+    assert kept_bytes(HYBRID, ("moe_router",)) == 5 * TOK * 1024
+    assert kept_bytes(HYBRID, ("qkv_proj",)) == TOK * 768
+    one_kind = rp.choose_names([(EXPERTS, 5)], GIB)
+    assert one_kind == ("moe_router", "moe_latent")
+    assert rp.choose_names([(EXPERTS, 5), ({}, 6)], GIB) == one_kind
+
+
+def test_equal_blocks_as_kinds_choose_what_one_table_chooses():
+    """The two dense configurations are told the same names: a stack of
+    n equal blocks is one kind counted n times, however it is split."""
+    for table, n in ((MISTRAL, 2), (GPT2_LARGE, 36)):
+        for budget in (0, GIB // 4, GIB, 2 * GIB, 5 * GIB, 12 * GIB):
+            whole = rp.choose_names([(table, n)], budget)
+            assert rp.choose_names([(table, 1), (table, n - 1)],
+                                   budget) == whole
+    assert rp.choose_names([(MISTRAL, 2)], 2 * GIB) == ATTN + MATMULS + (
+        "attn_qkv",)
+    assert rp.choose_names([(GPT2_LARGE, 36)], 5 * GIB) == ATTN + (
+        "qkv_proj", "attn_proj")
+
+
+def test_the_margin_is_the_largest_kinds_backward():
+    args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
+                block_input_bytes=TOK * 4096, head_bytes=2 * TOK * 4096)
+    budget = rp.budget_bytes(16_909_336_064, blocks=HYBRID, **args)
+    assert budget == (16_909_336_064 - 8410386260 - 536887296
+                      - 11 * TOK * 4096 - 2 * TOK * 4096
+                      - 2 * sum(EXPERTS.values()) - rp.HEADROOM_BYTES)
+    # 5 + 5 + 1 blocks of input, whatever the kinds' order
+    assert rp.budget_bytes(16_909_336_064, blocks=HYBRID[::-1],
+                           **args) == budget
+
+
+def test_new_names_leave_the_old_names_order_as_it_was():
+    old = [g for g in rp.PREFERENCE
+           if not any(n.startswith(("moe_", "ssm_")) for n in g)]
+    assert old == [("attn_out", "attn_lse"), ("qkv_proj",), ("attn_proj",),
+                   ("mlp_gate",), ("mlp_up",), ("attn_qkv",)]
+    new = [n for g in rp.PREFERENCE for n in g
+           if n.startswith(("moe_", "ssm_"))]
+    assert new == ["moe_router", "ssm_in_proj", "moe_latent",
+                   "moe_shared_up"]
+
+
+def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
+    """The pattern-built stack under a training step on a device of known
+    capacity: one `remat/policy` record for its 5 blocks of three kinds,
+    names from every kind, and the same loss and gradient as with nothing
+    kept."""
+    from pytorch_distributed_template_tpu.engine.losses import (
+        lm_cross_entropy,
+    )
+
+    trace._said.clear()
+    get_recorder().clear()
+    model = MODELS.get("TinyNemotronH")(pattern="EMEM*", remat=True)
+    tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, 256)
+    params = model.init(jax.random.key(1), tokens)["params"]
+
+    def loss(p):
+        logits = model.apply({"params": p}, tokens, train=True)
+        return jnp.mean(lm_cross_entropy(logits, tokens))
+
+    want = jax.value_and_grad(loss)(params)     # outside a step: nothing
+    monkeypatch.setattr(rp, "device_capacity_bytes",
+                        lambda mesh=None: 2 * GIB)
+    with caplog.at_level(logging.INFO), rp.step_holds(1 << 20):
+        got = jax.value_and_grad(loss)(params)
+    (said,) = [e["args"] for e in get_recorder().snapshot()
+               if e["name"] == "remat/policy"]
+    assert said["blocks"] == 5
+    assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_proj,"
+                             "ssm_in_proj,moe_latent,moe_shared_up")
+    tok = 2 * 32 * 4
+    d_in, heads = 4 * 16, 4
+    assert said["kept_bytes"] == tok * (
+        heads * 16                                  # attn_out, one block
+        + 2 * (8 + 32 + 96)                         # two expert layers
+        + (heads + 2 * 2) * 16 + 64                 # qkv_proj, attn_proj
+        + 2 * (2 * d_in + heads + 2 * 2 * 16))      # two in_proj outputs
+    assert "remat/policy: keeping [attn_out,moe_router" in caplog.text
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)

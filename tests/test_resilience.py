@@ -135,8 +135,11 @@ def test_without_guard_nan_poisons_params():
     assert any(not np.isfinite(p).all() for p in leaves)
 
 
-def test_sigterm_sets_flag_and_consensus():
+def test_sigterm_sets_flag_and_consensus(monkeypatch):
     preemption.reset()
+    # install anew whatever an earlier test of this worker left on SIGTERM
+    # (`install` is idempotent by a flag, not by what the signal holds)
+    monkeypatch.setattr(preemption, "_installed", False)
     preemption.install()
     assert not preemption.requested()
     assert not preemption.sync_requested()

@@ -203,3 +203,112 @@ def test_compiled_step_carries_the_scopes(grad_accum_steps):
                and "rematted_computation" not in n for n in names)
     # nothing of the optimizer is inside forward or backward
     assert not some(r"jvp\(.*optimizer")
+
+
+# -- the hybrid stack's scopes, lines and counters (PR 33) -----------------
+
+
+def _hybrid_trainer(tmp_path, steps=6):
+    import pytorch_distributed_template_tpu.data  # noqa: F401
+    import pytorch_distributed_template_tpu.engine  # noqa: F401
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.config import (
+        ConfigParser, LOADERS, LOSSES, METRICS, MODELS,
+    )
+    from pytorch_distributed_template_tpu.engine import Trainer
+    from pytorch_distributed_template_tpu.parallel import mesh_from_config
+
+    cfg = json.loads((REPO / "configs" / "nemotron_h_debug.json").read_text())
+    cfg["trainer"].update(save_dir=str(tmp_path), epochs=1, save_period=100,
+                          tensorboard=False, monitor="off")
+    cfg["arch"]["args"]["moe_held"] = [2, 4]
+    cfg["train_loader"]["args"].update(n=16 * steps, batch_size=16)
+    cfg.pop("valid_loader")
+    config = ConfigParser(cfg, run_id="hybrid")
+    return Trainer(
+        config.init_obj("arch", MODELS), LOSSES.get(config["loss"]),
+        [METRICS.get(m) for m in config["metrics"]], config=config,
+        train_loader=config.init_obj("train_loader", LOADERS),
+        mesh=mesh_from_config(config))
+
+
+def test_model_counters_reach_the_flight_record(tmp_path):
+    """What the expert layers count rides the step's metrics to the log
+    flush that fetches the loss anyway, and lands beside it."""
+    trainer = _hybrid_trainer(tmp_path)
+    trainer._train_epoch(1)     # the loop alone: no signal handler, no save
+    logged = [r for r in trainer.recorder.last() if "loss" in r]
+    assert logged
+    for r in logged:
+        # 16 x 32 tokens, 2 of 8 experts a token, 4 of them held: 512
+        # pairs a layer at uniform routing, 2 layers
+        assert 0.5 * 1024 < r["moe_pairs_here"] < 1.5 * 1024
+        assert r["moe_load_max_over_mean"] >= 1.0
+        assert 0 <= r["moe_tokens_unserved"] <= 512
+    assert all("moe_pairs_here" not in r
+               for r in trainer.recorder.last() if "loss" not in r)
+    # the configuration states a selection_bias_rate: six steps have moved
+    # each router's biases by whole rates, against the experts' loads
+    for name in ("layers_0", "layers_2"):
+        bias = np.asarray(
+            trainer.state.params[name]["mixer"]["selection_bias"]) / 1e-3
+        assert np.any(bias) and np.abs(bias).max() <= 6.001
+        np.testing.assert_allclose(bias, np.round(bias), atol=1e-3)
+
+
+def test_hybrid_step_carries_its_scopes():
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    from pytorch_distributed_template_tpu.engine.losses import (
+        lm_cross_entropy,
+    )
+    from pytorch_distributed_template_tpu.engine.state import (
+        create_train_state,
+    )
+    from pytorch_distributed_template_tpu.engine.steps import make_train_step
+
+    model = MODELS.get("TinyNemotronH")(pattern="EM*", remat=True)
+    tx = optax.adamw(1e-3)
+    state = create_train_state(model, tx, np.zeros((1, 32), np.int32), seed=0)
+    step = make_train_step(model, tx, lm_cross_entropy, [],
+                           input_key="tokens", target_key="tokens")
+    batch = {"tokens": jnp.zeros((2, 32), jnp.int32),
+             "mask": jnp.ones((2,), jnp.float32)}
+    names = set(re.findall(
+        r'op_name="([^"]*)"',
+        jax.jit(step).lower(state, batch).compile().as_text()))
+
+    def some(pattern):
+        return any(re.search(pattern, n) for n in names)
+
+    for scope in ("ssm_scan", "ssm_scan/ssm_conv", "moe_route",
+                  "moe_experts", "moe_shared"):
+        assert some(rf"jvp\(.*/{scope}/"), scope
+        assert some(rf"transpose\(jvp\(.*/{scope}/"), scope
+    assert some(r"layers_0/mixer/moe_route") and some(r"layers_1/mixer/ssm_")
+    # the convolution is inside the scan's scope, the projections outside
+    assert not some(r"ssm_scan/.*in_proj") and not some(r"ssm_scan/.*out_proj")
+
+
+def test_a_choice_is_said_once_a_process_and_distinct_record(caplog):
+    import logging
+
+    from pytorch_distributed_template_tpu.observability import trace
+
+    trace._said.clear()
+    trace.get_recorder().clear()
+    log = logging.getLogger("said")
+    with caplog.at_level(logging.INFO, logger="said"):
+        for rows in (64, 64, 128):
+            trace.say_once(log, "moe/dispatch", dict(rows=rows, held=8),
+                           "buffer of %(rows)d rows")
+        # a line whose text takes its own arguments, as remat/policy's
+        trace.say_once(log, "moe/dispatch", dict(rows=256, held=8),
+                       "buffer of %.1f k rows", 0.256)
+    spans = [e for e in trace.get_recorder().snapshot()
+             if e["name"] == "moe/dispatch"]
+    assert [e["args"] for e in spans] == [{"rows": 64, "held": 8},
+                                          {"rows": 128, "held": 8},
+                                          {"rows": 256, "held": 8}]
+    assert [r.getMessage() for r in caplog.records] == [
+        "buffer of 64 rows", "buffer of 128 rows", "buffer of 0.3 k rows"]
