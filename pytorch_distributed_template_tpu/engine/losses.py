@@ -119,15 +119,17 @@ def chunk_shifted_sequence(h, labels, chunk: int, pad_label: int = 0):
 
 
 # Rows (positions x sequences on ONE device) that a slice of the fused head
-# and loss is made of, where the sequence is long enough. The backward adds
-# every slice's share into the head's whole weight gradient, [D, V] in the
-# compute dtype: read once and written once a turn, 2 x 2 D V bytes, beside
-# a matmul of 2 x rows x D x V operations. The two take the same time at
-# rows = 2 x peak / bandwidth: 481 on a v5e (197 TFLOP/s, 819 GB/s), and
-# of that order on the v4, v5p and v6e by their published figures. At four
-# times that, rewriting the accumulator is under a quarter of the matmul
-# (and the forward's re-read of the weight an eighth). A constant of the
-# arithmetic, not a knob: nothing reads a table or a device.
+# and loss is made of, where the sequence is long enough. The loop that
+# makes the gradients (the forward's in a train step, the backward's for a
+# bare per-example gradient) adds every slice's share into the head's whole
+# weight gradient, [D, V] in the compute dtype: read once and written once a
+# turn, 2 x 2 D V bytes, beside a matmul of 2 x rows x D x V operations.
+# The two take the same time at rows = 2 x peak / bandwidth: 481 on a v5e
+# (197 TFLOP/s, 819 GB/s), and of that order on the v4, v5p and v6e by
+# their published figures. At four times that, rewriting the accumulator
+# is under a quarter of the matmul (and the logits pass's re-read of the
+# weight an eighth). A constant of the arithmetic, not a knob: nothing
+# reads a table or a device.
 SLICE_ROWS = 2048
 
 _step_mesh: contextvars.ContextVar = contextvars.ContextVar(
@@ -159,7 +161,9 @@ def slice_positions(sequences: int, chunk: int, seq_len: int,
     padded to one; then halved, not below ``chunk``, while the slice's
     float32 logits on a device exceed half of the checkpoint policy's
     ``HEADROOM_BYTES`` (models/remat_policy.py leaves that room free and
-    reckons nothing of the loss's slices, so they have to fit in it)."""
+    reckons nothing of the loss's slices, so they have to fit in it: the
+    float32 logits and, where the gradients are made in the same turn,
+    their gradient in the compute dtype beside them)."""
     def up(n):
         return -(-n // chunk) * chunk
 
@@ -170,18 +174,36 @@ def slice_positions(sequences: int, chunk: int, seq_len: int,
     return positions
 
 
-def _say_slice(sequences, positions, turns, vocab, chunk):
+def _say_slice(sequences, positions, turns, vocab, chunk, gradients):
     """The choice, once a process and distinct choice: a log line and a
-    zero-length span, as ``remat/policy`` has."""
+    zero-length span, as ``remat/policy`` has. ``gradients`` says which
+    loop makes the head's two gradients: the ``"forward"`` one (the summed
+    form under differentiation) or the ``"backward"`` one (the per-example
+    form, if anything differentiates it)."""
     rows = sequences * positions
     record = dict(rows_per_device=rows, positions=positions, turns=turns,
-                  slice_bytes=rows * vocab * 4, floor_positions=chunk)
+                  slice_bytes=rows * vocab * 4, floor_positions=chunk,
+                  gradients=gradients)
     say_once(
         logger, "head_loss/slice", record,
         "head_loss/slice: %d rows on a device a turn (%d positions x %d "
         "sequences, the floor is %d positions), %d turns, %.1f MB of "
-        "float32 logits a slice", rows, positions, sequences, chunk, turns,
-        record["slice_bytes"] / 1e6)
+        "float32 logits a slice, gradients made in the %s loop", rows,
+        positions, sequences, chunk, turns, record["slice_bytes"] / 1e6,
+        gradients)
+
+
+def _slice_nll(hc, w, lc):
+    """One slice, logits to per-token loss: the body both entrances of the
+    fused loss share. Operands in the compute dtype, logits, softmax and
+    loss in float32 (``optax.softmax_cross_entropy_with_integer_labels``,
+    spelled out for the log-normalizer). Returns ``(loss, logits,
+    log-normalizer)``."""
+    logits = (hc @ w).astype(jnp.float32)           # [..., positions, V]
+    label = jnp.take_along_axis(logits, lc[..., None], axis=-1).take(
+        0, axis=-1)
+    lse = jax.nn.logsumexp(logits, axis=-1)
+    return lse - label, logits, lse
 
 
 @LOSSES.register("fused_lm_cross_entropy")
@@ -191,10 +213,33 @@ def fused_lm_cross_entropy(chunk: int = 256):
     Pairs with a model built with ``fused_head: true`` (models/transformer
     TransformerLM): ``output`` is ``(hidden [B,T,D], head_w [D,V])`` and
     the [B, T, V] logits tensor NEVER materializes — a ``lax.scan`` over
-    slices of the sequence computes each slice's logits, its CE, and (via
-    ``jax.checkpoint`` on the body) recomputes them in backward, so peak
-    HBM holds one slice's logits instead of the full T. At GPT-2 vocab
-    (50257) and long T this is the dominant activation saved.
+    slices of the sequence computes each slice's logits and its CE, so
+    peak HBM holds one slice's logits instead of the full T. At GPT-2
+    vocab (50257) and long T this is the dominant activation saved.
+
+    One algorithm with two entrances that share the slice's body
+    (``_slice_nll``):
+
+    - ``loss(output, target) -> [B]``, per example, as every loss here. A
+      gradient through it runs the loop again in the backward
+      (``jax.checkpoint`` on the body recomputes each slice's logits): a
+      second ``rows x D x V`` pass, because the per-example cotangent is
+      not known before the backward. The eval step, the metrics, mixup
+      and ``models/pipelined.py`` enter here.
+    - ``loss.summed(output, target, weights) -> (sum_b weights[b] *
+      per_example[b], per_example)``, for a caller that only sums (the
+      train step: ``weights`` is its ``batch["mask"]``). The cotangent of
+      every example is then ``weights[b]`` times one scalar, known before
+      the loop starts, so under differentiation (a ``jax.custom_vjp``)
+      each turn makes the slice's logits, its loss, the softmax's gradient
+      ``weights[b] * valid / (T-1) * (softmax - onehot)`` in the compute
+      dtype and at once both of the head's gradients from it: three
+      matmul passes a step where the other entrance runs four, and no
+      loop left in the backward, which multiplies the two by the incoming
+      scalar. What is kept from forward to backward is the hidden state's
+      gradient in place of the hidden state. ``per_example`` comes back
+      as a value nothing differentiates. Not under differentiation it is
+      the first entrance and a weighted sum.
 
     ``chunk`` is a FLOOR, in positions of one sequence. The slice itself
     is reckoned in rows on one device (``slice_positions``): at least
@@ -203,7 +248,8 @@ def fused_lm_cross_entropy(chunk: int = 256):
     cap on the slice's bytes. A step that runs one or two long sequences
     a device would otherwise rewrite the head's whole weight gradient
     once per ``chunk`` rows, which costs more than their matmul. The
-    choice is one ``head_loss/slice`` log line and span a process.
+    choice, and which loop makes the gradients, is one ``head_loss/slice``
+    log line and span a process.
 
     Numerically identical to ``lm_cross_entropy`` on the same params
     (same shift, per-sequence mean) up to float reassociation.
@@ -211,17 +257,24 @@ def fused_lm_cross_entropy(chunk: int = 256):
     if chunk <= 0:
         raise ValueError(f"chunk must be positive, got {chunk}")
 
-    @jax.named_scope("head_loss")   # the trace's head-and-loss share
-    def loss(output, target):
+    def slices(output, target, gradients):
+        """The shifted pair cut into scan-ready slices."""
         h, w = output                       # [B, T, D], [D, V]
-        tm1 = h.shape[1] - 1
-        b = h.shape[0]
+        b, tm1 = h.shape[0], h.shape[1] - 1
         on_device = b // token_shards(_step_mesh.get(), b, h.shape[1])
         positions = slice_positions(on_device, chunk, tm1, w.shape[1])
         h_c, l_c, v_c = chunk_shifted_sequence(
             h[:, :-1], target[:, 1:], positions
         )
-        _say_slice(on_device, positions, h_c.shape[0], w.shape[1], chunk)
+        _say_slice(on_device, positions, h_c.shape[0], w.shape[1], chunk,
+                   gradients)
+        return h_c, l_c, v_c
+
+    @jax.named_scope("head_loss")   # the trace's head-and-loss share
+    def loss(output, target):
+        h, w = output
+        b, tm1 = h.shape[0], h.shape[1] - 1
+        h_c, l_c, v_c = slices(output, target, "backward")
         if b == 1:
             # a batch of one is folded away around the softmax (the sum
             # below broadcasts it back): jnp.take_along_axis reads a
@@ -235,10 +288,7 @@ def fused_lm_cross_entropy(chunk: int = 256):
         @jax.checkpoint
         def body(carry, inp):
             hc, lc, vc = inp
-            logits = (hc @ w).astype(jnp.float32)       # [B, positions, V]
-            tok = optax.softmax_cross_entropy_with_integer_labels(
-                logits, lc
-            )
+            tok = _slice_nll(hc, w, lc)[0]              # [B, positions]
             return carry + jnp.sum(tok * vc[None, :], axis=-1), None
 
         total, _ = jax.lax.scan(
@@ -246,6 +296,71 @@ def fused_lm_cross_entropy(chunk: int = 256):
         )
         return total / tm1
 
+    @jax.custom_vjp
+    def summed(output, target, weights):
+        per_ex = loss(output, target)
+        return jnp.sum(per_ex * weights), per_ex
+
+    @jax.named_scope("head_loss")
+    def summed_fwd(output, target, weights):
+        h, w = output
+        b, t, d = h.shape
+        vocab = w.shape[1]
+        h_c, l_c, v_c = slices(output, target, "forward")
+        # the cotangent of a token's loss, but for the incoming scalar
+        per_token = weights.astype(jnp.float32)[:, None] / (t - 1)  # [B, 1]
+        compute = jnp.result_type(h.dtype, w.dtype)
+
+        def body(carry, inp):
+            total, dw = carry
+            hc, lc, vc = inp
+            # every sequence's positions as rows of one matrix, [rows, .]
+            # (a batch of one is folded away with them): plain matmuls
+            # for all three passes
+            hc, lc = hc.reshape(-1, d), lc.reshape(-1)
+            tok, logits, lse = _slice_nll(hc, w, lc)
+            ct = (per_token * vc[None, :]).reshape(-1)
+            soft = jnp.exp(logits - lse[:, None])
+            hit = lc[:, None] == jnp.arange(vocab, dtype=lc.dtype)
+            # rounded where autodiff rounds it: behind the float32 logits
+            dlogits = (jnp.where(hit, soft - 1.0, soft)
+                       * ct[:, None]).astype(compute)
+            dh = (dlogits @ w.T).astype(h.dtype)
+            # added in float32 and rounded once a turn, as the compiler
+            # fuses the add into the matmul. Summed over the whole batch:
+            # where the batch is spread over chips the partitioner keeps
+            # each chip's partial sum through the loop and crosses once
+            # behind it (tests/test_chip_compile.py reads that it does)
+            dw = (dw + jnp.einsum(
+                "rd,rv->dv", hc, dlogits,
+                preferred_element_type=jnp.float32)).astype(dw.dtype)
+            total = total + jnp.sum(
+                tok.reshape(b, -1) * vc[None, :], axis=-1)
+            return (total, dw), dh
+
+        (total, dw), dh_c = jax.lax.scan(
+            body, (jnp.zeros((b,), jnp.float32), jnp.zeros_like(w)),
+            (h_c, l_c, v_c))
+        # back from slices: [n, B x positions, D] -> [B, T, D], the padded
+        # tail dropped and the last position, which predicts nothing, zero
+        dh = jnp.moveaxis(dh_c.reshape(len(v_c), b, -1, d), 0, 1)
+        dh = jnp.pad(dh.reshape(b, -1, d)[:, :t - 1],
+                     ((0, 0), (0, 1), (0, 0)))
+        per_ex = total / (t - 1)
+        return (jnp.sum(per_ex * weights), per_ex), (dh, dw, per_ex)
+
+    @jax.named_scope("head_loss")
+    def summed_bwd(kept, cotangents):
+        dh, dw, per_ex = kept
+        g = cotangents[0]       # per_example's own is dropped: see above
+
+        def times_g(x):
+            return (x.astype(jnp.float32) * g).astype(x.dtype)
+
+        return (times_g(dh), times_g(dw)), None, g * per_ex
+
+    summed.defvjp(summed_fwd, summed_bwd)
+    loss.summed = summed
     return loss
 
 

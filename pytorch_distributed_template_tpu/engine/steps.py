@@ -210,6 +210,10 @@ def make_train_step(model, tx, criterion: Callable,
     pass_example_mask = _accepts_example_mask(model)
     counter_names = tuple(getattr(model, "step_counters", ()))
     bias_rate = float(getattr(model, "selection_bias_rate", 0.0))
+    # a criterion may offer ``summed(output, target, weights) -> (sum_b
+    # weights[b] * per_example[b], per_example)``
+    # (engine/losses.fused_lm_cross_entropy)
+    summed = getattr(criterion, "summed", None)
 
     def sumloss_and_output(params, batch_stats, batch, dropout_rng):
         """Masked SUM of per-example losses (normalized by the caller after
@@ -237,15 +241,21 @@ def make_train_step(model, tx, criterion: Callable,
             mutable=mutable, rngs={"dropout": dropout_rng}, **extra,
         )
         new_stats = mutated.get("batch_stats", batch_stats)
-        per_ex = criterion(output, batch[target_key])
-        if mixup_alpha > 0:
-            lam = batch["_mix_lam"].astype(per_ex.dtype)
-            per_ex = (
-                lam * per_ex
-                + (1.0 - lam) * criterion(output, batch["_mix_target"])
-            )
-        mask = batch["mask"].astype(per_ex.dtype)
-        loss_sum = _masked_sum(per_ex, mask)
+        if summed is not None and mixup_alpha <= 0:
+            # the criterion sums for itself, told the weights: it then
+            # knows every example's cotangent before its own forward
+            mask = batch["mask"].astype(jnp.float32)
+            loss_sum, _ = summed(output, batch[target_key], mask)
+        else:
+            per_ex = criterion(output, batch[target_key])
+            if mixup_alpha > 0:
+                lam = batch["_mix_lam"].astype(per_ex.dtype)
+                per_ex = (
+                    lam * per_ex
+                    + (1.0 - lam) * criterion(output, batch["_mix_target"])
+                )
+            mask = batch["mask"].astype(per_ex.dtype)
+            loss_sum = _masked_sum(per_ex, mask)
         aux = jax.tree.leaves(mutated.get("losses", {}))
         if aux:
             loss_sum = loss_sum + sum(jnp.sum(a) for a in aux) * mask.sum()

@@ -409,11 +409,46 @@ def fresh_records(monkeypatch):
     get_recorder().clear()
 
 
+def _body_and_called(text, body):
+    """The text of a loop's body and of every computation it calls."""
+    own = _computation(text, body)
+    return [own] + [_computation(text, c)
+                    for c in set(re.findall(r"calls=%([\w\.\-]+)", own))]
+
+
+def _matmuls_of(text, body):
+    """Result shapes of the matmuls (`convolution`, on the TPU) a loop's
+    body runs a turn: its own and those inside the fusions it calls."""
+    return [shape for part in _body_and_called(text, body)
+            for shape in re.findall(
+                r"= (\w+\[[\d,]+\])\S* convolution\(", part)]
+
+
+def _head_loss_loops(text):
+    """The entry computation's loops that are the fused loss's: under
+    `head_loss` by their own name or, where the partitioner rebuilt the
+    loop and left it none, by the name of what their body runs."""
+    return [(op, shapes, body) for op, shapes, body in _while_loops(text)
+            if "head_loss" in op
+            or "head_loss)/while/body" in _computation(text, body)]
+
+
+def _collectives_in(text, body):
+    """The lines of a loop's body, and of what it calls, that cross chips."""
+    return [ln for part in _body_and_called(text, body)
+            for ln in part.splitlines()
+            if re.search(r"all-reduce|async_collective|all-gather|"
+                         r"reduce-scatter|collective-permute", ln)]
+
+
 def test_mistral_head_and_loss_take_four_turns_inside_the_chip_for_v5e(
         topo, monkeypatch, fresh_records):
     """`mistral7b_l2.seq8k`'s step (1 x 8192 on one chip, the floor 256
-    positions): the fused loss's two loops run over 4 slices of 2048 rows,
-    not 32 of 256; the blocks' checkpoint policy chooses what it chose (all
+    positions): the train step sums through the fused loss, so the head
+    and loss are ONE loop of 4 slices of 2048 rows, in the forward, whose
+    turn runs three matmuls of the slice's shape (the logits and the two
+    gradients made of them at once) and nothing of the loss is
+    recomputed; the blocks' checkpoint policy chooses what it chose (all
     seven names: its arithmetic leaves the slices to its headroom), and
     the compiled step stays under the chip's `bytes_limit`."""
     from pytorch_distributed_template_tpu.config.registry import MODELS
@@ -425,19 +460,26 @@ def test_mistral_head_and_loss_take_four_turns_inside_the_chip_for_v5e(
         MODELS.get("Mistral")(**MISTRAL), mesh, 1, 8192, monkeypatch)
     (said,) = _said("head_loss/slice")
     assert said == dict(rows_per_device=2048, positions=2048, turns=4,
-                        slice_bytes=2048 * 32000 * 4, floor_positions=256)
+                        slice_bytes=2048 * 32000 * 4, floor_positions=256,
+                        gradients="forward")
     text = compiled.as_text()
-    loops = [(op, shapes) for op, shapes, _ in _while_loops(text)
-             if "head_loss" in op]
-    assert len(loops) == 2      # the forward's and the backward's
-    for _, shapes in loops:     # the batch of one is folded away
-        assert "bf16[4,2048,4096]" in shapes
-        assert "bf16[32," not in shapes
-    # the label's gather goes back as a one-hot select inside the matmuls'
-    # operands, not as a scatter over a slice's float32 logits (a batch
-    # of one is folded away around the softmax for that)
+    ((op, shapes, body),) = _head_loss_loops(text)
+    assert "jvp(head_loss)" in op and "transpose" not in op
+    assert "bf16[4,2048,4096]" in shapes    # the batch of one folded away
+    assert "bf16[32," not in shapes
+    assert "bf16[4096,32000]" in shapes     # the accumulator, carried
+    assert sorted(_matmuls_of(text, body)) in (
+        # logits, the hidden state's gradient, the weight's share
+        ["bf16[2048,32000]", "bf16[2048,4096]", "f32[4096,32000]"],
+        ["bf16[2048,4096]", "f32[2048,32000]", "f32[4096,32000]"])
+    scopes = re.findall(r'op_name="([^"]*)"', text)
+    assert not [sc for sc in scopes
+                if "head_loss" in sc and "rematted_computation" in sc]
+    # the label goes into the softmax's gradient as a one-hot select
+    # inside the matmuls' operands, never as a scatter over a slice
     assert not re.search(r"= f32\[[\d,]+\]\S* scatter\(", text)
-    assert "take_along_axis)/scatter-add" in text
+    assert not [sc for sc in scopes
+                if "head_loss" in sc and "scatter" in sc]
     (policy,) = _said("remat/policy")
     assert policy["names"] == ("attn_out,attn_lse,qkv_proj,attn_proj,"
                                "mlp_gate,mlp_up,attn_qkv")
@@ -451,28 +493,27 @@ def test_head_crossing_rides_the_four_turn_loss_loop_for_v5e(
         four_chips, monkeypatch, fresh_records):
     """`mistral7b_l2.seq8k_dp4`'s step (4 x 8192 over four chips): the
     step traces the global batch and the slice is still reckoned a chip
-    (2048 rows, 4 turns); the head's weight gradient does not cross in a
-    bare all-reduce: it is started before the loss's forward loop, carried
-    by the loop's logits matmul and finished after it, as it was carried
-    by the loop of 32 turns."""
+    (2048 rows, 4 turns). The head's weight gradient is summed in the
+    forward's loop, and a sum over a batch that is spread over chips is
+    a partial sum on each: the partitioner keeps the partial sum through
+    the loop (nothing in the loop's body crosses) and the whole
+    `[4096, 32000]` crosses ONCE a step, behind the loop. Not four times,
+    which a crossing inside the body would be."""
     from pytorch_distributed_template_tpu.config.registry import MODELS
     import pytorch_distributed_template_tpu.models  # noqa: F401
 
     _, text = _compile_train_step(
         MODELS.get("Mistral")(**MISTRAL), four_chips, 4, 8192, monkeypatch)
     (said,) = _said("head_loss/slice")
-    assert (said["rows_per_device"], said["positions"], said["turns"]) == (
-        2048, 2048, 4)
-    bare, carried = _crossings(text)
-    assert not [s for s in bare if "[4096,32000]" in s], bare
-    assert len(bare) <= 2 and len(carried) >= 14
-    (forward,) = [(shapes, body) for op, shapes, body in _while_loops(text)
-                  if "jvp(head_loss)" in op and "transpose" not in op]
-    shapes, body = forward
+    assert (said["rows_per_device"], said["positions"], said["turns"],
+            said["gradients"]) == (2048, 2048, 4, "forward")
+    ((_, shapes, body),) = _head_loss_loops(text)
     assert "bf16[4,1,2048,4096]" in shapes
-    carriers = [ln for ln in _computation(text, body).splitlines()
-                if "calls=%async_collective_fusion" in ln]
-    assert any(re.search(r"= \(bf16\[2048,32000\]", ln) for ln in carriers)
+    assert len(_matmuls_of(text, body)) == 3
+    assert not _collectives_in(text, body)
+    bare, carried = _crossings(text)
+    assert len([s for s in bare if "[4096,32000]" in s]) <= 1, bare
+    assert len(bare) <= 3 and len(carried) >= 14
 
 
 def test_gpt2_large_step_is_left_as_it_was_for_v5e(
@@ -512,7 +553,8 @@ def test_gpt2_large_step_is_left_as_it_was_for_v5e(
         texts.append(text())    # one line: the text holds its caller's
     (said,) = _said("head_loss/slice")
     assert said == dict(rows_per_device=2048, positions=256, turns=4,
-                        slice_bytes=2048 * 50257 * 4, floor_positions=256)
+                        slice_bytes=2048 * 50257 * 4, floor_positions=256,
+                        gradients="forward")
     assert texts[0] == texts[1]
 
 

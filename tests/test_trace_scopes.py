@@ -189,7 +189,13 @@ def test_compiled_step_carries_the_scopes(grad_accum_steps):
         return any(re.search(pattern, n) for n in names)
 
     assert some(r"/optimizer/")
-    assert some(r"jvp\(head_loss\)") and some(r"transpose\(jvp\(head_loss\)\)")
+    # the step sums through the fused loss, whose gradient rule makes the
+    # head's gradients in the forward's loop: head and loss are under the
+    # forward's scope alone, and none of it is recomputed
+    assert some(r"jvp\(head_loss\)/while/body")
+    assert not some(r"transpose\(jvp\(head_loss\)\)/while")
+    assert not some(r"rematted_computation.*head_loss")
+    assert not some(r"head_loss.*rematted_computation")
     assert some(r"rematted_computation")             # the remat marker
     assert some(r"/health_summary/") and some(r"/metrics/")
     assert some(r"/grad_accum/") == (grad_accum_steps > 1)
