@@ -6,5 +6,8 @@ from .moe import MoeMlp, moe_lm, tiny_moe_lm
 from .pipelined import PipelinedLM, pipelined_lm, tiny_pipe_lm
 from .llama import LlamaLM, llama, tiny_llama
 from .nemotron_h import NemotronHLM, nemotron_h, tiny_nemotron_h
+from .granite_hybrid import (
+    GraniteHybridLM, granite_hybrid, tiny_granite_hybrid,
+)
 from .transformer import TransformerLM, gpt2, tiny_lm
 from .vit import ViT, vit

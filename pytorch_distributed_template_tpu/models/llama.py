@@ -129,6 +129,10 @@ class LlamaAttention(nn.Module):
     lora_rank: int = 0              # >0: LoRA fine-tuning (models/lora.py)
     lora_alpha: float = 16.0
     head_dim: int = 0               # 0 -> d_model // n_head
+    # scores are q . k times this; 0 -> head_dim ** -0.5. Every attention
+    # path and kernel fixes head_dim ** -0.5, so q is scaled by what is
+    # left, multiplier * sqrt(head_dim), in q_proj's epilogue
+    attention_multiplier: float = 0.0
 
     @nn.compact
     def __call__(self, x, positions, train: bool, decode: bool = False,
@@ -146,6 +150,9 @@ class LlamaAttention(nn.Module):
             return y.reshape(b, t, heads, hd)
 
         q = proj("q_proj", self.n_head)
+        if self.attention_multiplier:
+            q = q * jnp.asarray(self.attention_multiplier * hd ** 0.5,
+                                q.dtype)
         k = proj("k_proj", self.n_kv_head)
         v = proj("v_proj", self.n_kv_head)
 

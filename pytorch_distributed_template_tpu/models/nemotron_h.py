@@ -24,6 +24,11 @@ heads on ``n_kv_head`` key-value heads of ``head_dim``, and ``moe_held``
 ``(offset, count)`` of the routed experts. The layer runs without its
 exchange; nothing here stands in for absent chips.
 
+A family whose layer is a mixer AND a gated MLP, each behind its own
+norm, with scaled residuals and a tied scaled head (``granitemoehybrid``)
+is the sibling stack, models/granite_hybrid.py, which imports
+``Mamba2Mixer`` from here.
+
 Training only: a decode path needs the scan's state beside the attention
 layers' pages (ROADMAP R5), and there is none yet.
 """
@@ -73,7 +78,11 @@ class Mamba2Mixer(nn.Module):
     ``B``, ``C [T, G, N]``; ``dt = softplus(dt + dt_bias)``,
     ``A = -exp(A_log)``; the scan of ops/ssm.py with skip ``D``; then
     ``y = RMSNorm(y * silu(z)) * w`` with the mean square over each
-    group's ``d_in / G`` channels, and ``out_proj``."""
+    group's ``d_in / G`` channels, and ``out_proj``.
+
+    For the trace: ``ssm_proj`` holds ``in_proj``, the gated norm and
+    ``out_proj``; ``ssm_scan`` everything between (``ssm_conv``, and
+    inside ops/ssm.py ``ssm_intra`` and ``ssm_state``)."""
     d_model: int
     n_head: int
     head_dim: int
@@ -93,8 +102,17 @@ class Mamba2Mixer(nn.Module):
         dense = lambda width, name: nn.Dense(            # noqa: E731
             width, use_bias=False, dtype=self.dtype,
             kernel_init=_dense_init(), name=name)
-        zxd = checkpoint_name(dense(2 * d_in + d_bc + h, "in_proj")(u),
-                              "ssm_in_proj")
+        chunks = -(-t // self.chunk)
+        say_once(
+            logger, "ssm/chunks",
+            dict(chunk=self.chunk, chunks=chunks, heads=h, groups=g,
+                 mask_bytes=b * chunks * h * self.chunk ** 2 * 4),
+            "ssm/chunks: %(chunks)d chunks of %(chunk)d positions a row, "
+            "%(heads)d heads in %(groups)d group(s); one layer's float32 "
+            "decay mask is %(mask_bytes)d bytes")
+        with jax.named_scope("ssm_proj"):
+            zxd = checkpoint_name(
+                dense(2 * d_in + d_bc + h, "in_proj")(u), "ssm_in_proj")
         z, xbc, dt = jnp.split(zxd, [d_in, 2 * d_in + d_bc], axis=-1)
         with jax.named_scope("ssm_scan"):
             with jax.named_scope("ssm_conv"):
@@ -116,13 +134,27 @@ class Mamba2Mixer(nn.Module):
             y = ssd_scan(x.reshape(b, t, h, p), dt, a,
                          bm.reshape(b, t, g, n), cm.reshape(b, t, g, n),
                          skip, self.chunk)
-        gated = (y.reshape(b, t, g, d_in // g).astype(f32)
-                 * nn.silu(z.astype(f32)).reshape(b, t, g, d_in // g))
-        gated = gated * jax.lax.rsqrt(
-            jnp.mean(gated * gated, axis=-1, keepdims=True) + self.rms_eps)
-        w = self.param("norm_weight", nn.initializers.ones, (d_in,), f32)
-        y = (gated.reshape(b, t, d_in) * w).astype(self.dtype)
-        return dense(self.d_model, "out_proj")(y)
+        with jax.named_scope("ssm_proj"):
+            gated = (y.reshape(b, t, g, d_in // g).astype(f32)
+                     * nn.silu(z.astype(f32)).reshape(b, t, g, d_in // g))
+            gated = gated * jax.lax.rsqrt(
+                jnp.mean(gated * gated, axis=-1, keepdims=True)
+                + self.rms_eps)
+            w = self.param("norm_weight", nn.initializers.ones, (d_in,), f32)
+            y = (gated.reshape(b, t, d_in) * w).astype(self.dtype)
+            return dense(self.d_model, "out_proj")(y)
+
+
+def mamba_block_sizes(n_head: int, head_dim: int, n_group: int, state: int,
+                      chunk: int, itemsize: int) -> Tuple[dict, int]:
+    """What a block with a ``Mamba2Mixer`` tells models/remat_policy.py, in
+    features a token of the compute type: the name the mixer makes
+    (``ssm_in_proj``), and the scan's scratch, the float32 decay mask
+    ``[chunks, heads, chunk, chunk]`` and its product with ``C . B`` in the
+    compute type (``heads x chunk`` entries a token each)."""
+    widths = {"ssm_in_proj": 2 * n_head * head_dim + n_head
+              + 2 * n_group * state}
+    return widths, n_head * chunk * (4 + itemsize) // itemsize
 
 
 class LayerSizes(NamedTuple):
@@ -229,8 +261,10 @@ class NemotronHLM(nn.Module):
                 "NemotronH has no decode path: the scan's state would have "
                 "to live beside the attention layers' cache")
         if not self.pattern or set(self.pattern) - set(KINDS):
-            raise ValueError(f"pattern {self.pattern!r}: one of {KINDS!r} "
-                             "a layer")
+            raise ValueError(
+                f"pattern {self.pattern!r}: one of {KINDS!r} a layer, each "
+                "ONE mixer behind a pre-norm (a stack whose every layer is "
+                "a mixer and a gated MLP is models/granite_hybrid.py)")
         for heads, groups, what in (
                 (self.n_head, self.n_kv_head, "n_head over n_kv_head"),
                 (self.ssm_n_head, self.ssm_n_group,
@@ -285,10 +319,11 @@ class NemotronHLM(nn.Module):
         """The names each kind of layer makes, in features a token (the
         float32 router logits count twice a 16-bit model's item)."""
         item = jnp.dtype(self.dtype).itemsize
-        d_in = self.ssm_n_head * self.ssm_head_dim
+        ssm, scratch = mamba_block_sizes(
+            self.ssm_n_head, self.ssm_head_dim, self.ssm_n_group,
+            self.ssm_state, self.ssm_chunk, item)
         table = {
-            "M": BlockKind({"ssm_in_proj": 2 * d_in + self.ssm_n_head
-                            + 2 * self.ssm_n_group * self.ssm_state}, 0),
+            "M": BlockKind(ssm, 0, scratch=scratch),
             "E": BlockKind({"moe_router": self.moe_n_routed * 4 // item,
                             "moe_latent": self.moe_latent,
                             "moe_shared_up": self.moe_shared_d_ff}, 0),

@@ -51,7 +51,10 @@ mixer a layer, by a pattern) names besides, each where its mixer makes it:
 Neither the scan's output nor the routed experts' has a name: their
 backward needs what lies inside them, so keeping the result would spare
 next to nothing. The bytes are reckoned by kind of block and summed over
-the kinds' counts; a name a kind lacks costs it nothing.
+the kinds' counts; a name a kind lacks costs it nothing. What a scan makes
+inside itself (its float32 decay masks and their product with ``C . B``)
+is the block's ``scratch``: kept by no name, alive in that block's
+backward, and so part of the room one block's backward is left.
 
 One policy serves every block of a model. An empty prefix is
 ``nothing_saveable``; so is a device whose capacity is unknown (the CPU),
@@ -96,11 +99,15 @@ class BlockKind(NamedTuple):
     a matmul output in the compute type; twice them for float32), and
     ``count`` says how many such blocks there are. ``attn_heads`` > 0 says
     the block calls the model's attention once, with that many query
-    heads of ``head_dim``."""
+    heads of ``head_dim``. ``scratch``, in the same unit as a width, is
+    what the block's backward makes beside its names and no name can
+    keep: a scan's decay masks, 537 MB of float32 a layer at 64 heads and
+    a chunk of 256 where the block's names come to 408 MB."""
     widths: Mapping[str, int]
     count: int
     attn_heads: int = 0
     head_dim: int = 0
+    scratch: int = 0
 # left free under the device's limit: the allocator's fragmentation, the
 # compiler's own copies, and room for the step's peak to be read at least
 # 1 GiB under ``bytes_limit``
@@ -182,7 +189,8 @@ def token_shards(mesh, batch: int, seq_len: int,
 
 def budget_bytes(capacity: int, held_bytes: int, outside_param_bytes: int,
                  block_input_bytes: int, head_bytes: int,
-                 blocks: Sequence[Tuple[Mapping[str, int], int]]) -> int:
+                 blocks: Sequence[Tuple[Mapping[str, int], int]],
+                 scratch: Sequence[int] = ()) -> int:
     """Bytes one device can give to kept intermediates.
 
     What is kept is all alive when the backward starts, and that is the
@@ -204,12 +212,16 @@ def budget_bytes(capacity: int, held_bytes: int, outside_param_bytes: int,
     - one block's backward: the block's recomputed intermediates and as
       much again for their cotangents, reckoned as twice all its named
       bytes (the flash kernels' scratch is on-chip; their folded operands
-      are of the size of ``qkv_proj``), of the kind of block with most;
+      are of the size of ``qkv_proj``) and twice its ``scratch`` bytes
+      (one entry a kind, in ``blocks``' order; none: no kind has any),
+      of the kind of block with most;
     - ``HEADROOM_BYTES``.
     """
     n_blocks = sum(count for _, count in blocks)
+    scratch = tuple(scratch) or (0,) * len(blocks)
     margin = (n_blocks * block_input_bytes + head_bytes
-              + 2 * max(sum(table.values()) for table, _ in blocks)
+              + 2 * max(sum(table.values()) + extra
+                        for (table, _), extra in zip(blocks, scratch))
               + HEADROOM_BYTES)
     return capacity - held_bytes - outside_param_bytes - margin
 
@@ -261,7 +273,8 @@ def block_policy(model, training: bool, kinds: Sequence[BlockKind],
         {k: v for k, v in params.items() if not k.startswith(block_key)},
         mesh, model.partition_rules())
     budget = budget_bytes(capacity, held, outside, hidden // shards,
-                          head // shards, blocks)
+                          head // shards, blocks,
+                          [tok * kind.scratch // shards for kind in kinds])
     names = choose_names(blocks, budget)
     kept = sum(count * table.get(n, 0) for table, count in blocks
                for n in names)

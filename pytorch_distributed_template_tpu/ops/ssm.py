@@ -23,7 +23,11 @@ the ``H / G`` heads of a group. ``ssd_scan`` computes it in chunks of
 The products take the operands' type (bfloat16 in a bfloat16 model, on
 the MXU) and accumulate in float32; ``dt``, the running decays and the
 state between chunks are float32. All of it is plain ``jax.numpy``, so
-the backward is jax's own; the step gains no kernel from it.
+the backward is jax's own; the step gains no kernel from it. Two
+``jax.named_scope``s split it for the trace: ``ssm_intra`` (the running
+decays, ``C . B``, the decay mask, its product with the inputs)
+and ``ssm_state`` (each chunk's state, its passage from chunk to chunk,
+the entering state read by ``C``).
 ``ssd_recurrence`` is the recurrence as written above, one position at a
 time: the oracle of the tests.
 
@@ -69,36 +73,40 @@ def ssd_scan(x, dt, a, b, c, d, chunk: int):
     xc = chunks(x).reshape(bsz, nc, chunk, g, r, p)
     dtc = chunks(dt).reshape(bsz, nc, chunk, g, r)
     bc, cc = chunks(b), chunks(c)
-    # running decay inside each chunk, [B, nc, G, R, L]
-    da = jnp.moveaxis(dtc * a.astype(jnp.float32).reshape(g, r), 2, -1)
-    cs = jnp.cumsum(da, axis=-1)
-    xdt = xc.astype(jnp.float32) * dtc[..., None]          # dt_s * x_s
 
     # inside the chunks
-    cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc,
-                    preferred_element_type=jnp.float32)
-    mix = (cb[:, :, :, None] * _segment_decays(da)).astype(dtype)
-    y = jnp.einsum("bcgrls,bcsgrp->bclgrp", mix, xdt.astype(dtype),
-                   preferred_element_type=jnp.float32)
-
-    # each chunk's own state, decayed to the chunk's end: [B, nc, G, R, P, N]
-    to_end = jnp.exp(cs[..., -1:] - cs)                    # [B, nc, G, R, L]
-    decayed = (xdt * jnp.moveaxis(to_end, -1, 2)[..., None]).astype(dtype)
-    states = jnp.einsum("bcsgn,bcsgrp->bcgrpn", bc, decayed,
+    with jax.named_scope("ssm_intra"):
+        # running decay inside each chunk, [B, nc, G, R, L]
+        da = jnp.moveaxis(dtc * a.astype(jnp.float32).reshape(g, r), 2, -1)
+        cs = jnp.cumsum(da, axis=-1)
+        xdt = xc.astype(jnp.float32) * dtc[..., None]      # dt_s * x_s
+        cb = jnp.einsum("bclgn,bcsgn->bcgls", cc, bc,
                         preferred_element_type=jnp.float32)
+        mix = (cb[:, :, :, None] * _segment_decays(da)).astype(dtype)
+        y = jnp.einsum("bcgrls,bcsgrp->bclgrp", mix, xdt.astype(dtype),
+                       preferred_element_type=jnp.float32)
 
-    # the state each chunk starts from: float32 across the sequence
-    total = jnp.moveaxis(cs[..., -1], 1, -1)               # [B, G, R, nc]
-    across = _segment_decays(jnp.pad(total, ((0, 0),) * 3 + ((1, 0),)))
-    entering = jnp.einsum("bgrzc,bcgrpn->bzgrpn", across[..., :-1, 1:],
-                          states, precision=HIGHEST)
-    # row z of `across[..., :-1, 1:]` holds, for every chunk c < z, the
-    # decay from c's end to z's start; row 0 is empty: no state enters
+    with jax.named_scope("ssm_state"):
+        # each chunk's own state, decayed to the chunk's end:
+        # [B, nc, G, R, P, N]
+        to_end = jnp.exp(cs[..., -1:] - cs)                # [B, nc, G, R, L]
+        decayed = (xdt * jnp.moveaxis(to_end, -1, 2)[..., None]
+                   ).astype(dtype)
+        states = jnp.einsum("bcsgn,bcsgrp->bcgrpn", bc, decayed,
+                            preferred_element_type=jnp.float32)
 
-    from_start = jnp.moveaxis(jnp.exp(cs), -1, 2)          # [B, nc, L, G, R]
-    y = y + from_start[..., None] * jnp.einsum(
-        "bclgn,bcgrpn->bclgrp", cc, entering.astype(dtype),
-        preferred_element_type=jnp.float32)
+        # the state each chunk starts from: float32 across the sequence
+        total = jnp.moveaxis(cs[..., -1], 1, -1)           # [B, G, R, nc]
+        across = _segment_decays(jnp.pad(total, ((0, 0),) * 3 + ((1, 0),)))
+        entering = jnp.einsum("bgrzc,bcgrpn->bzgrpn", across[..., :-1, 1:],
+                              states, precision=HIGHEST)
+        # row z of `across[..., :-1, 1:]` holds, for every chunk c < z, the
+        # decay from c's end to z's start; row 0 is empty: no state enters
+
+        from_start = jnp.moveaxis(jnp.exp(cs), -1, 2)      # [B, nc, L, G, R]
+        y = y + from_start[..., None] * jnp.einsum(
+            "bclgn,bcgrpn->bclgrp", cc, entering.astype(dtype),
+            preferred_element_type=jnp.float32)
     y = y + d.astype(jnp.float32).reshape(g, r)[..., None] \
         * xc.astype(jnp.float32)
     return y.reshape(bsz, t + pad, h, p)[:, :t].astype(dtype)

@@ -599,6 +599,55 @@ def test_hybrid_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     assert total < V5E_BYTES_LIMIT - (1 << 30)
 
 
+def test_granite_step_lowers_and_fits_for_v5e(topo, monkeypatch,
+                                              fresh_records):
+    """`granite4_h_micro_l10.seq8k`'s step (1 x 8192 on one chip): the
+    stack of a mixer and a gated MLP a layer, nine scans of 64 heads in
+    one group at the published chunk of 256 (537 MB of float32 decay mask
+    a layer), one attention at head 64 over 8192 positions through the
+    three flash kernels, and the tied scaled head through the fused loss
+    compiles for the v5e; the checkpoint policy reckons both kinds of
+    block; the step stays 1 GiB under the chip's `bytes_limit`."""
+    import json
+    from pathlib import Path
+
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.parallel import build_mesh
+
+    arch = json.loads((
+        Path(__file__).resolve().parent.parent / "benchmarks" / "configs"
+        / "granite4_h_micro_l10.json").read_text())["experiment"]["arch"]
+    mesh = build_mesh({"data": 1}, devices=topo.devices[:1])
+    _, compiled = _compiled_train_step(
+        MODELS.get(arch["type"])(**arch["args"]), mesh, 1, 8192, monkeypatch)
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert re.search(rf"%{kernel}(\.\d+)? = ", text)
+    (policy,) = _said("remat/policy")
+    assert policy["blocks"] == 10
+    # parameters and both moments; the gradient is the backward's own
+    assert abs(policy["held_bytes"] - 772_160_448 * 12) < 64
+    kept = policy["names"].split(",")
+    assert kept[:2] == ["attn_out", "attn_lse"]
+    assert set(kept) <= {"attn_out", "attn_lse", "qkv_proj", "attn_proj",
+                         "ssm_in_proj", "mlp_gate", "mlp_up", "attn_qkv"}
+    chunk = arch["args"]["ssm_chunk"]
+    (chunks,) = _said("ssm/chunks")
+    assert chunks == dict(chunk=chunk, chunks=8192 // chunk, heads=64,
+                          groups=1, mask_bytes=8192 * 64 * chunk * 4)
+    (pattern,) = _said("model/pattern")
+    assert pattern["pattern"] == "mmmmmammmm"
+    assert (pattern["rows"], pattern["of_rows"]) == (12544, 100352)
+    (said,) = _said("head_loss/slice")
+    assert said["gradients"] == "forward" and said["rows_per_device"] == 2048
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"granite step: {total} bytes compiled, policy {policy}")
+    assert total < V5E_BYTES_LIMIT - (1 << 30)
+
+
 @pytest.mark.xfail(strict=True, raises=ValueError,
                    reason="paged decode kernel refused: 'the last two "
                           "dimensions of your block shape [must be] divisible "

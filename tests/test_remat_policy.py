@@ -480,6 +480,37 @@ def test_the_margin_is_the_largest_kinds_backward():
                            **args) == budget
 
 
+def test_a_kinds_scratch_is_part_of_its_backwards_room():
+    """What a scan makes inside itself (its decay masks) no name keeps;
+    the kind of block says it as `scratch`, and the room left for one
+    block's backward is the largest kind's names AND scratch, twice. A
+    kind that is not the largest with its scratch changes nothing."""
+    from pytorch_distributed_template_tpu.models.nemotron_h import (
+        mamba_block_sizes,
+    )
+
+    args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
+                block_input_bytes=TOK * 4096, head_bytes=2 * TOK * 4096)
+    plain = rp.budget_bytes(16_909_336_064, blocks=HYBRID, **args)
+    assert rp.budget_bytes(16_909_336_064, blocks=HYBRID,
+                           scratch=[0, 0, 0], **args) == plain
+    # the hybrid cell's scan: 16 heads, chunk 128: 2320 + 6144 features a
+    # token, over the expert layers' 7424
+    widths, scratch = mamba_block_sizes(16, 64, 1, 128, 128, 2)
+    assert widths == {"ssm_in_proj": 2320} and scratch == 16 * 128 * 3
+    with_masks = rp.budget_bytes(16_909_336_064, blocks=HYBRID,
+                                 scratch=[TOK * scratch, 0, 0], **args)
+    assert plain - with_masks == 2 * TOK * (2320 + 6144 - 7424)
+    small = rp.budget_bytes(16_909_336_064, blocks=HYBRID,
+                            scratch=[TOK * 1024, 0, 0], **args)
+    assert small == plain
+    # granite-4.0-h-micro's: 64 heads, the published chunk of 256: the
+    # float32 mask 537 MB and its bfloat16 product 268 MB at 8192 tokens
+    widths, scratch = mamba_block_sizes(64, 64, 1, 128, 256, 2)
+    assert widths == {"ssm_in_proj": 8512}
+    assert 8192 * 2 * scratch == 8192 * 64 * 256 * (4 + 2)
+
+
 def test_new_names_leave_the_old_names_order_as_it_was():
     old = [g for g in rp.PREFERENCE
            if not any(n.startswith(("moe_", "ssm_")) for n in g)]

@@ -32,6 +32,33 @@ def test_chunked_scan_is_the_recurrence(t, chunk):
                                atol=2e-5 * float(jnp.max(jnp.abs(want))))
 
 
+# granite-4.0-h-micro's layout: 64 heads that all read ONE group's B and
+# C (r = 64), the published chunk of 256; two chunks and a part, and two
+# whole ones
+@pytest.mark.parametrize("t", [600, 512])
+def test_sixty_four_heads_in_one_group_at_chunk_256(t):
+    args = operands(t, b=1, h=64, p=16, g=1, n=32, seed=11)
+    with jax.default_matmul_precision("highest"):
+        got = ssd_scan(*args, 256)
+        want = ssd_recurrence(*args)
+    assert got.shape == want.shape == (1, t, 64, 16)
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=2e-5 * float(jnp.max(jnp.abs(want))))
+
+
+def test_sixty_four_heads_in_one_group_have_the_recurrences_gradient():
+    args = operands(300, b=1, h=64, p=8, g=1, n=16, seed=13)
+    loss = lambda scan: lambda *a: jnp.sum(jnp.sin(scan(*a)))  # noqa: E731
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(loss(lambda *a: ssd_scan(*a, 256)),
+                       argnums=range(6))(*args)
+        want = jax.grad(loss(ssd_recurrence), argnums=range(6))(*args)
+    for name, g, w in zip("x dt a b c d".split(), got, want):
+        np.testing.assert_allclose(
+            g, w, rtol=0, atol=2e-5 * float(jnp.max(jnp.abs(w))),
+            err_msg=name)
+
+
 @pytest.mark.parametrize("t,chunk", [(37, 16), (16, 16)])
 def test_chunked_scan_has_the_recurrences_gradient(t, chunk):
     args = operands(t, seed=3)
