@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import functools
 import inspect
+import logging
 from typing import Callable, Dict, Sequence
 
 import jax
@@ -28,9 +29,13 @@ import jax.numpy as jnp
 import optax
 from flax.traverse_util import flatten_dict, unflatten_dict
 
+from ..models.base import param_count
 from ..models.remat_policy import step_holds
+from ..observability.trace import say_once
 from ..parallel.sharding import per_device_bytes
 from .losses import step_mesh
+
+logger = logging.getLogger(__name__)
 
 
 def _masked_sum(per_example, mask):
@@ -135,6 +140,56 @@ def _held_through_backward(state, model, grad_accum_steps: int) -> int:
     return held
 
 
+def _state_names(opt_state) -> str:
+    """An optimizer by what it keeps: the names of its state's records
+    (optax's named tuples, ``State`` and the empty ones dropped), outermost
+    first."""
+    names = []
+
+    def walk(node):
+        if hasattr(node, "_fields"):
+            name = type(node).__name__.removesuffix("State")
+            if name != "Empty" and name not in names:
+                names.append(name)
+        if isinstance(node, (tuple, list)):
+            for child in node:
+                walk(child)
+        elif isinstance(node, dict):
+            for child in node.values():
+                walk(child)
+
+    walk(opt_state)
+    return "/".join(names) or "stateless"
+
+
+def _say_pass(state, grads, skip_nonfinite, have_norm, clip):
+    """The optimizer pass, once a process and distinct choice: a log line
+    and a zero-length span, as ``remat/policy`` and ``head_loss/slice``
+    have. ``ok`` says where the skip rule's verdict comes from: the
+    gradients' ``norm``, a ``scan`` of every leaf where no norm exists, or
+    ``none`` without the rule. The bytes a parameter are what the pass has
+    to move if each leaf's state crosses memory once: the gradient read,
+    the parameter and the optimizer's state read and written."""
+    n = param_count(state.params)
+    moved = (per_device_bytes(grads, None)
+             + 2 * per_device_bytes(state.params, None)
+             + 2 * per_device_bytes(state.opt_state, None))
+    record = dict(
+        leaves=len(jax.tree.leaves(grads)), parameters=n,
+        optimizer=_state_names(state.opt_state),
+        ok=("none" if not skip_nonfinite
+            else "norm" if have_norm else "scan"),
+        scalar="clip/count" if clip else "1/count",
+        bytes_per_parameter=round(moved / max(n, 1), 2))
+    say_once(
+        logger, "optimizer/pass", record,
+        "optimizer/pass: %d leaves, %d parameters, %s; ok from %s; each "
+        "gradient leaf is read once and multiplied by %s; %.2f bytes a "
+        "parameter if each leaf's state crosses memory once",
+        record["leaves"], n, record["optimizer"], record["ok"],
+        record["scalar"], record["bytes_per_parameter"])
+
+
 def make_train_step(model, tx, criterion: Callable,
                     metric_fns: Sequence[Callable] = (),
                     input_key: str = "image", target_key: str = "label",
@@ -167,10 +222,32 @@ def make_train_step(model, tx, criterion: Callable,
     ``ema_decay > 0`` maintains ``state.ema_params`` (shadow weights) with
     ``ema = d*ema + (1-d)*params`` after each update.
 
-    ``skip_nonfinite`` guards the update in-graph: when any gradient leaf
-    (or the loss) is non-finite the whole update is suppressed via
+    The optimizer pass reads each gradient leaf ONCE, as the backward (or
+    the accumulation, or the all-reduce) left it. The division by the
+    global valid count and the clip ride one float32 scalar,
+    ``min(1, clip / (gnorm + 1e-6)) / count``, multiplied into the leaf
+    (in float32, handed on at the leaf's own dtype) in the map that feeds
+    ``tx.update``; ``gnorm`` is the summed gradients' norm over the count
+    (the norm is homogeneous). Nothing else lies between the summed
+    gradients and the new state but scalars, so the compiler makes one
+    fusion a leaf and the leaf's state crosses memory once
+    (``tests/test_chip_compile.py`` holds the v5e compile to it; the
+    ``optimizer/pass`` line says what was built). The division sits on
+    the scalar: exact where the count is a power of two, else a leaf
+    differs from ``g / count`` in the last place.
+
+    ``skip_nonfinite`` guards the update in-graph: when the loss or any
+    gradient leaf is non-finite the whole update is suppressed via
     ``jnp.where`` — params/opt_state/EMA keep their old values and
     ``skipped_sum`` counts the event — instead of poisoning the weights.
+    Where the step has the gradients' norm (a clip, ``log_grad_norm`` or
+    ``health``), the verdict is ``isfinite(loss) & isfinite(gnorm)``: a
+    non-finite element makes the norm non-finite, so no leaf is read a
+    second time to learn it. A tree of finite gradients whose squares
+    overflow float32 (an element above 1.8e19) has an infinite norm and
+    is skipped too; a per-leaf scan would let it through to a clip scale
+    of 0 and an update of decay alone. Without a norm every leaf is
+    scanned.
     A branchless select keeps the step a single static XLA program (no
     host round-trip, unlike torch-style ``if not torch.isfinite(loss)``
     Python checks). The step counter still advances so dropout keys and
@@ -183,8 +260,10 @@ def make_train_step(model, tx, criterion: Callable,
     (observability/health) as ONE packed f32 vector under
     ``metrics["health"]``: per-example loss, global grad/update norms,
     and non-finite element counts for the post-update params and the
-    raw gradients per top-level param group (field order:
-    ``health_layout(params)``). A handful of scalar reductions and a
+    summed gradients per top-level param group (field order:
+    ``health_layout(params)``; the count of a float32 leaf is taken of
+    its bfloat16 rounding, so that the compiler hands the counting branch
+    the backward's own buffer). A handful of scalar reductions and a
     single tiny output, so the summary rides the dispatch pipeline
     instead of stalling it. Appended AFTER the ``skip_nonfinite``
     zeroing so a suppressed step still reports the non-finite counts
@@ -379,12 +458,10 @@ def make_train_step(model, tx, criterion: Callable,
         # one name in the compiled step's op_name metadata (the device
         # trace's optimizer share reads it); names only, same program
         with jax.named_scope("optimizer"):
-            # Normalize the summed gradients by the global valid count
-            # (matches grad-of-mean on the full batch exactly).
+            # the global valid count: the summed gradients over it are the
+            # gradient of the mean on the full batch. Nothing divides a
+            # leaf by it; it rides the one scalar below
             denom = jnp.maximum(count.astype(jnp.float32), 1.0)
-            grads = jax.tree.map(
-                lambda g: (g / denom).astype(g.dtype), grads
-            )
 
             if trainable_patterns:
                 # Mirror the optimizer's ``trainable`` freeze (optim.py
@@ -408,38 +485,74 @@ def make_train_step(model, tx, criterion: Callable,
 
                 grads = jax.tree_util.tree_map_with_path(_freeze, grads)
 
-            # hold the PRE-CLIP gradients for the health summary, AFTER the
-            # normalize/freeze transforms: clipping can smear one NaN over
-            # every group (NaN global norm -> NaN scale), destroying the
-            # per-module attribution the dump exists for, while capturing
-            # after the freeze keeps the counted tree identical to the one
-            # gnorm below is computed on — the lax.cond fast path in
+            # the health summary counts the SUMMED gradients, pre-clip and
+            # post-freeze: clipping can smear one NaN over every group (NaN
+            # global norm -> NaN scale), destroying the per-module
+            # attribution the dump exists for. A positive finite scalar
+            # changes no element's finiteness, so the counts are those of
+            # the mean gradient, and the tree is the very one gnorm below
+            # is computed on — the lax.cond fast path in
             # pack_health_summary is only sound when they match (a NaN in a
             # frozen — training-inert — leaf is deliberately out of scope
-            # for both)
-            health_grads = grads if health else None
+            # for both).
+            # Its counting branch runs on a bad step alone, but a
+            # conditional's operands are buffers: handed a float32 leaf
+            # that the backward made as a bfloat16 sum and widened, the
+            # compiler writes the widened copy out every step for it (2.8
+            # GB a Mistral step). Narrowed, the widening folds away and the
+            # branch is handed the backward's own buffer. bfloat16 has
+            # float32's exponent, so finite stays finite and the counts
+            # stand, up to an element within 0.4% of float32's largest,
+            # which rounds to inf (its square overflowed long before).
+            health_grads = jax.tree.map(
+                lambda g: g.astype(jnp.bfloat16)
+                if g.dtype == jnp.float32 else g, grads) if health else None
 
-            if log_grad_norm or grad_clip_norm > 0 or health:
-                # pre-clip global norm of the mean gradient
-                gnorm = optax.global_norm(grads)
+            have_norm = log_grad_norm or grad_clip_norm > 0 or health
+            if have_norm:
+                # pre-clip global norm of the mean gradient (the norm is
+                # homogeneous: the count divides the scalar)
+                gnorm = optax.global_norm(grads) / denom
             if log_grad_norm:
                 # count-weighted so finalize_metrics' divide-by-count yields
                 # the epoch's mean per-step grad norm
                 metrics["grad_norm_sum"] = gnorm * jnp.maximum(count, 1.0)
+            # the ONE scalar between the summed gradients and the update:
+            # the clip's scale, where there is a clip, over the count
+            scale = 1.0
             if grad_clip_norm > 0:
                 scale = jnp.minimum(1.0, grad_clip_norm / (gnorm + 1e-6))
-                grads = jax.tree.map(lambda g: g * scale, grads)
+            factor = scale / denom
 
             ok = jnp.array(True)
             if skip_nonfinite:
                 ok = jnp.isfinite(loss_sum)
-                for g in jax.tree.leaves(grads):
-                    ok = ok & jnp.all(jnp.isfinite(g))
-                # zero the grads on a bad step so the (discarded) optimizer
-                # update below is NaN-free even under jax_debug_nans
-                grads = jax.tree.map(
-                    lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads
-                )
+                if have_norm:
+                    # a non-finite element makes the norm non-finite (NaN
+                    # propagates through the squared sum; inf squares to
+                    # inf): no leaf is read a second time to learn it
+                    ok = ok & jnp.isfinite(gnorm)
+                else:
+                    for g in jax.tree.leaves(grads):
+                        ok = ok & jnp.all(jnp.isfinite(g))
+            _say_pass(state, grads, skip_nonfinite, have_norm,
+                      grad_clip_norm > 0)
+
+            def _scaled(g):
+                # each leaf is read once, as the backward (or the
+                # accumulation, or the all-reduce) left it; the product is
+                # made in float32 and handed on at the leaf's own dtype (a
+                # no-op for float32 leaves; a bfloat16 parameter's moments
+                # stay bfloat16, as optax set them up); zero on a bad step,
+                # so that the (discarded) optimizer update below is
+                # NaN-free even under jax_debug_nans
+                g = (g.astype(_accumulator_dtype(g.dtype))
+                     * factor).astype(g.dtype)
+                if skip_nonfinite:
+                    g = jnp.where(ok, g, jnp.zeros_like(g))
+                return g
+
+            grads = jax.tree.map(_scaled, grads)
 
             updates, new_opt_state = tx.update(
                 grads, state.opt_state, state.params)
