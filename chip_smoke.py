@@ -422,8 +422,6 @@ class Smoke:
         prog = dp["step_program"] or {}
         check(prog.get("batch_devices") == 4 and prog.get("param_devices") == 4,
               f"batch/params are not on four distinct devices: {prog}")
-        check(prog.get("all_reduce", 0) > 0,
-              f"no all-reduce in the compiled four-chip step: {prog}")
         check(len(dp["losses"]) == len(one["losses"]), "loss curves differ "
               "in length")
         gap = max(abs(a - b) for a, b in zip(dp["losses"], one["losses"]))

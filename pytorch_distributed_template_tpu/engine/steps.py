@@ -718,11 +718,15 @@ def instrument_step(jitted_fn, name: str, warmup=None):
     wrapper records the first call as ``<name>/compile+execute`` and
     every later one as ``<name>/dispatch`` (dispatch spans measure jit
     dispatch + donation backpressure, not device runtime — device time
-    belongs to ``jax.profiler``). These are the only spans around the
-    call: the trainer's loop opens none of its own and times the call
-    into the flight record's ``dispatch_ms``. Like every ``span()`` they
-    are ``TraceAnnotation``s too, so a profiler capture shows them on
-    the dispatching thread's line beside the device's. A shape change
+    belongs to ``jax.profiler``). With a ``warmup``, the first call's
+    wait for the warmed executable is ``<name>/await_warmup``: the part
+    of ``warmup/<name>/trace|lower|compile`` (engine/warmup.py, on the
+    warm-up's thread) that the calling thread did not get to hide. The
+    trainer's loop opens no span of its own round the call and times
+    it, wait and all, into the flight record's ``dispatch_ms``. Like
+    every ``span()`` they are ``TraceAnnotation``s too, so a profiler
+    capture shows them on the dispatching thread's line beside the
+    device's. A shape change
     mid-run recompiles inside a ``dispatch`` span; the recompilation
     still surfaces, as a ``compile_events`` entry on the next
     flight-recorder record (observability/telemetry).
@@ -747,8 +751,10 @@ def instrument_step(jitted_fn, name: str, warmup=None):
     def wrapped(*args, **kwargs):
         if state["first"]:
             state["first"] = False
-            compiled = (warmup.result(name)
-                        if warmup is not None else None)
+            compiled = None
+            if warmup is not None:
+                with span(f"{name}/await_warmup"):
+                    compiled = warmup.result(name)
             if compiled is not None:
                 try:
                     with span(f"{name}/dispatch", warm=True):

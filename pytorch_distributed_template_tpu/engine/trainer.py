@@ -40,7 +40,9 @@ from ..observability.crosshost import CrossHostAggregator
 from ..observability.health import (
     HealthMonitor, health_counters, health_layout, health_metric_keys,
 )
-from ..observability.telemetry import drain_compile_events
+from ..observability.telemetry import (
+    IterationAccount, drain_compile_events,
+)
 from ..observability.trace import get_recorder as get_span_recorder
 from ..observability.trace import span
 from ..ops.augment import build_augment
@@ -381,6 +383,16 @@ class Trainer(BaseTrainer):
     def __init__(self, model, criterion, metric_ftns, config,
                  train_loader, valid_loader=None, len_epoch: Optional[int] = None,
                  mesh=None, seed: int = 0):
+        # everything between the caller's config and a trainer that can
+        # step; the first flight record's ``setup`` reads the ring from
+        # where this span began
+        with span("setup/trainer_init") as frame:
+            self._setup_t0 = frame["t0"]
+            self._build(model, criterion, metric_ftns, config,
+                        train_loader, valid_loader, len_epoch, mesh, seed)
+
+    def _build(self, model, criterion, metric_ftns, config, train_loader,
+               valid_loader, len_epoch, mesh, seed) -> None:
         super().__init__(config)
         configure_debug(config["trainer"].get("debug"))
         # deterministic fault plan (resilience/faults): PDT_FAULTS env
@@ -454,10 +466,12 @@ class Trainer(BaseTrainer):
                     self.input_key
                 ]
             )
-        self.state, self.state_sharding = create_sharded_train_state(
-            model, self.tx, template,
-            self.mesh, seed=seed, with_ema=ema_decay > 0,
-        )
+        # the init program: trace, compile or cache read, run, placement
+        with span("setup/state_init", seed=self._seed):
+            self.state, self.state_sharding = create_sharded_train_state(
+                model, self.tx, template,
+                self.mesh, seed=seed, with_ema=ema_decay > 0,
+            )
         self.batch_sharding = batch_sharding(self.mesh)
         if dist.is_main_process():
             self.logger.info(describe(model, self.state.params))
@@ -663,6 +677,10 @@ class Trainer(BaseTrainer):
             capacity=int(tel_cfg.get("capacity", 512)),
             memory_every=int(tel_cfg.get("memory_every", 16)),
         )
+        # each iteration's account, and what the host was doing in a
+        # stalled one (observability/telemetry.IterationAccount)
+        self._account = IterationAccount(spans=get_span_recorder())
+        self._record_ms = None  # the last record(), until a record takes it
         # anomaly detection over the deferred health summaries; dumps
         # (anomaly_<step>.json) on process 0 only, detection everywhere
         self.health = HealthMonitor(
@@ -712,29 +730,53 @@ class Trainer(BaseTrainer):
                             or config["trainer"].get("heartbeat_file")),
         )
 
+    def _record(self, step: int, rec: dict) -> None:
+        """One flight record, timed: the JSON line, and host RSS and the
+        devices' memory every sixteenth. ``record_ms`` rides the next
+        record built, whose ``wall_ms`` holds it."""
+        t_call = time.perf_counter()
+        self.recorder.record(step, **rec)
+        self._record_ms = (time.perf_counter() - t_call) * 1e3
+
+    def _setup_facts(self, first_iteration_s: float) -> dict:
+        """This trainer's set-up by phase, for the first flight record:
+        ``setup`` is seconds inside each ``setup/*``, ``warmup/*`` and
+        ``*/await_warmup`` span that has finished since this trainer's
+        ``setup/trainer_init`` began (a process may build many), with
+        ``first_iteration_s`` (the loop's first data wait to the first
+        step's results on the host; it holds the await); ``setup_at``
+        is each phase's start in seconds after ``setup/trainer_init``'s,
+        so the record alone shows how far the warm-up's thread ran
+        beside this one."""
+        events = [e for e in get_span_recorder().since(self._setup_t0)
+                  if e["name"].startswith(("setup/", "warmup/"))
+                  or e["name"].endswith("/await_warmup")]
+        origin = min((e["ts"] for e in events), default=0.0)
+        setup: dict = {}
+        at: dict = {}
+        for e in events:
+            name = e["name"]
+            setup[name] = setup.get(name, 0.0) + e["dur"] / 1e6
+            at[name] = min(at.get(name, math.inf), (e["ts"] - origin) / 1e6)
+        return {
+            "setup": {**{k: round(v, 6) for k, v in setup.items()},
+                      "first_iteration_s": round(first_iteration_s, 6)},
+            "setup_at": {k: round(v, 6) for k, v in at.items()},
+        }
+
     def _step_program_facts(self, batch) -> dict:
-        """Where the first batch and the params really live, and what
-        the compiled step holds — on the first flight record, so a
-        multi-chip run can be checked from its telemetry (code that has
-        only ever seen one chip may put everything on the first)."""
+        """Where the first batch and the params really live — on the
+        first flight record, so a multi-chip run can be checked from its
+        telemetry (code that has only ever seen one chip may put
+        everything on the first). Until PR 38 it also counted two
+        strings in the compiled step's text: 1.4 s of a 36-layer run's
+        start to make 20 MB of text."""
         def n_devices(tree):
             return len({s.device for leaf in jax.tree.leaves(tree)
                         for s in leaf.addressable_shards})
 
-        facts = {"batch_devices": n_devices(batch),
-                 "param_devices": n_devices(self.state.params)}
-        compiled = (self._warmup.result("train_step")
-                    if self._warmup is not None else None)
-        if compiled is not None:
-            try:
-                text = compiled.as_text()
-            except Exception as e:  # noqa: BLE001 — say so, keep training
-                facts["hlo"] = f"unavailable: {e}"
-            else:
-                facts["all_reduce"] = (text.count(" all-reduce(")
-                                       + text.count(" all-reduce-start("))
-                facts["tpu_custom_call"] = text.count("tpu_custom_call")
-        return facts
+        return {"batch_devices": n_devices(batch),
+                "param_devices": n_devices(self.state.params)}
 
     def _metric_keys(self):
         return ["loss_sum", "count"] + [
@@ -848,12 +890,12 @@ class Trainer(BaseTrainer):
                        if epoch == self.start_epoch else 0)
         self._cursor = (epoch, start_batch)
         batch_idx = start_batch - 1
-        # Sync-free stepping: log-step metric fetches are DEFERRED by one
-        # log window. The entry enqueued at step N is completed at step
-        # N + log_step, when its device buffers have long resolved — so
-        # the host never float()-blocks on the step it just dispatched
-        # (the old per-log-step pipeline bubble). Holds at most one
-        # entry (a handful of scalar metric buffers).
+        # Log-step metric fetches are DEFERRED by one log window: the
+        # entry enqueued at step N is completed at step N + log_step,
+        # when its device buffers have long resolved, so the fetch of
+        # the metrics does not wait for the step just dispatched. (The
+        # flush still does, under train/log_lr: see _flush_log_entry.)
+        # Holds at most one entry (a handful of scalar metric buffers).
         pending_log = deque()
         log_flush_ms = None  # the last flush, until a record takes it
         t_iter = time.perf_counter()
@@ -910,7 +952,11 @@ class Trainer(BaseTrainer):
             # dispatch_ms, health_fetch_ms and log_flush_ms say where it
             # went, each on the record of the iteration whose wall_ms
             # holds it: a log flush runs after this record's clock has
-            # been read, so it is the next record's
+            # been read, so it is the next record's, and so is record_ms,
+            # the recorder's own write. unattributed_ms closes the sum:
+            # the fault hook, the watchdog's beat, the meters, the
+            # preemption poll, the recorder's write
+            # (self._account.settle, below)
             rec = {
                 "wall_ms": round((time.perf_counter() - t_iter) * 1e3, 3),
                 "data_wait_ms": round(data_wait_ms, 3),
@@ -918,11 +964,32 @@ class Trainer(BaseTrainer):
                 "examples": self.train_loader.batch_size,
             }
             t_iter = time.perf_counter()
+            # a capture's start or stop (which writes the trace:
+            # seconds), on the iterations that hold one
+            profile_ms = self.trace.take_ms()
+            if profile_ms is not None:
+                rec["profile_ms"] = round(profile_ms, 3)
             if health_fetch_ms is not None:
                 rec["health_fetch_ms"] = round(health_fetch_ms, 3)
             if log_flush_ms is not None:
                 rec["log_flush_ms"] = round(log_flush_ms, 3)
                 log_flush_ms = None
+            if self._record_ms is not None:
+                rec["record_ms"] = round(self._record_ms, 3)
+                self._record_ms = None
+            stall = self._account.settle(
+                rec, first=not self._first_step_timed)
+            if stall is not None:
+                self.logger.warning(
+                    "Iteration stalled at step %d: %.1f ms, %.1f over the "
+                    "trailing median, most of it in %s (gc %.1f ms, %d of "
+                    "generation 2; context switches %d voluntary, %d "
+                    "involuntary; major page faults %d; other threads "
+                    "inside %s)", step, rec["wall_ms"], stall["over_ms"],
+                    stall["in"], stall["gc_ms"], stall["gc_gen2"],
+                    stall["nvcsw"], stall["nivcsw"], stall["majflt"],
+                    stall["threads"] or "no span",
+                )
             if self._tokens_per_example:
                 rec["tokens"] = (self._tokens_per_example
                                  * self.train_loader.batch_size)
@@ -946,6 +1013,8 @@ class Trainer(BaseTrainer):
                         self._train_step, self.state, batch)
                 jax.block_until_ready(m)
                 rec["step_program"] = self._step_program_facts(batch)
+                rec.update(self._setup_facts(
+                    rec["wall_ms"] / 1e3 + time.perf_counter() - t_iter))
                 self.throughput.reset()  # exclude compilation from rates
                 self.epoch_meter.reset()
 
@@ -985,7 +1054,7 @@ class Trainer(BaseTrainer):
                 self._log_input_images(batch)
                 pending_log.append((step, epoch, batch_idx, m, rec))
             else:
-                self.recorder.record(step, **rec)
+                self._record(step, rec)
 
             if ((single_host or (batch_idx + 1) % check_every == 0)
                     and preemption.sync_requested()):
@@ -1051,17 +1120,21 @@ class Trainer(BaseTrainer):
         return log
 
     def _flush_log_entry(self, entry) -> None:
-        """Complete one deferred log-step record (sync-free stepping).
+        """Complete one deferred log-step record.
 
         Called one log window after the entry's step was dispatched —
         by then ``log_step`` further steps have been queued behind it,
-        so ``jax.device_get`` reads already-resolved buffers instead of
-        blocking the dispatch pipeline on the newest step (the old
-        ``float()``-per-log-step host sync). The entry's flight record
-        lands in the JSONL one window late but under its own step id;
-        window throughput is dispatch-rate (bounded-queue steady state
-        tracks completion rate; epoch numbers still come from the
-        synced ``finalize_metrics`` path).
+        so ``jax.device_get`` under ``train/log_fetch`` reads buffers
+        that have resolved. The flush is not free of the device all the
+        same: the schedule under ``train/log_lr`` is jnp arithmetic,
+        whose small programs queue behind the step dispatched just
+        before, so ``float()`` there waits that step out and the device
+        then idles until the next dispatch (``idle_log_flush_ms``,
+        1-2 ms a step on the chip; PERF.md section 5). The entry's
+        flight record lands in the JSONL one window late but under its
+        own step id; window throughput is dispatch-rate (bounded-queue
+        steady state tracks completion rate; epoch numbers still come
+        from the synced ``finalize_metrics`` path).
         """
         step, epoch, batch_idx, m, rec = entry
         with span("train/log", step=step):
@@ -1119,7 +1192,7 @@ class Trainer(BaseTrainer):
             rec["anomaly_total"] = hc["anomaly_total"]
         if hc["straggler_windows_total"]:
             rec["straggler_windows_total"] = hc["straggler_windows_total"]
-        self.recorder.record(step, **rec)
+        self._record(step, rec)
 
     def _plateau_step(self, log: dict) -> None:
         """Per-epoch ReduceLROnPlateau update of ``state.lr_scale``.

@@ -94,9 +94,11 @@ class StepWarmup:
     ``add`` arguments may mix concrete arrays (the real state — its
     avals and shardings are exactly what the first call passes) with
     ``ShapeDtypeStruct``s; nothing is executed, only
-    ``lower(*args).compile()``. Jobs compile in registration order on
-    one thread (the compiler parallelizes internally; a second host
-    thread would just contend). ``result`` blocks until that job
+    ``trace(*args).lower().compile()``, each stage under its span
+    ``warmup/<name>/trace|lower|compile`` on this thread. Jobs compile
+    in registration order on one thread (the compiler parallelizes
+    internally; a second host thread would just contend). ``result``
+    blocks until that job
     settles — by the first step the compile is normally long done, and
     when it is not, waiting on the in-flight compile is strictly no
     worse than starting the same compile lazily.
@@ -122,9 +124,19 @@ class StepWarmup:
         return self
 
     def _run(self) -> None:
+        from ..observability.trace import span
+
         for name, fn, args in self._jobs:
             try:
-                self._compiled[name] = fn.lower(*args).compile()
+                # fn.lower(*args).compile() by its stages, one span
+                # each, since each has its own remedy: Python tracing,
+                # lowering to MLIR, the backend's compile or cache read
+                with span(f"warmup/{name}/trace"):
+                    traced = fn.trace(*args)
+                with span(f"warmup/{name}/lower"):
+                    lowered = traced.lower()
+                with span(f"warmup/{name}/compile"):
+                    self._compiled[name] = lowered.compile()
             except Exception:  # noqa: BLE001 — degrade to lazy compile
                 logger.warning(
                     "AOT warmup of %s failed; falling back to lazy "

@@ -158,6 +158,7 @@ class TraceCapture:
         self._requested: Optional[int] = None
         self.captures = 0
         self.recorder = None
+        self._spent_ms: Optional[float] = None  # see take_ms()
 
     def attach_recorder(self, recorder) -> None:
         """Optional FlightRecorder that capture completions get noted on."""
@@ -173,6 +174,20 @@ class TraceCapture:
             return
         self._requested = max(int(num_steps), 1)
 
+    def take_ms(self) -> Optional[float]:
+        """Milliseconds spent starting or stopping a capture (the stop
+        waits for the captured steps and writes the trace: seconds)
+        since the last call, or None where neither happened: the
+        trainer's ``profile_ms``, on the iterations that hold one."""
+        spent, self._spent_ms = self._spent_ms, None
+        return spent
+
+    def _timed(self, call, *args) -> None:
+        t0 = time.perf_counter()
+        call(*args)
+        self._spent_ms = ((self._spent_ms or 0.0)
+                          + (time.perf_counter() - t0) * 1e3)
+
     def before_step(self, step: int) -> None:
         if self._active:
             return
@@ -185,7 +200,7 @@ class TraceCapture:
             self.start_step = step
         if not self._done and step >= self.start_step:
             Path(self.dir).mkdir(parents=True, exist_ok=True)
-            jax.profiler.start_trace(self.dir)
+            self._timed(jax.profiler.start_trace, self.dir)
             self._active = True
             self._until = step + self.num_steps
 
@@ -195,8 +210,8 @@ class TraceCapture:
         close while the captured steps still run on device."""
         if self._active and step + 1 >= self._until:
             if sync is not None:
-                jax.block_until_ready(sync)
-            jax.profiler.stop_trace()
+                self._timed(jax.block_until_ready, sync)
+            self._timed(jax.profiler.stop_trace)
             self._active = False
             self._done = True
             self._note_capture(step)

@@ -12,12 +12,14 @@ dashboards) reads back without scraping logs.
 Record schema (all optional except ``v``/``step``/``t``):
 
     {"v": 1, "step": 0, "t": <unix seconds>,
-     "wall_ms": ..., "data_wait_ms": ...,
+     "wall_ms": ..., "data_wait_ms": ..., "dispatch_ms": ...,
+     "unattributed_ms": ..., "record_ms": ..., "stall": {...},
      "loss": ..., "grad_norm": ..., "lr": ...,
      "examples": ..., "tokens": ...,
      "steps_per_sec": ..., "examples_per_sec": ..., "tokens_per_sec": ...,
      "mfu": ...,
-     "compile_events": [{"event": ..., "dur_ms": ...}, ...],
+     "compile_events": [{"event": ..., "fun_name": ..., "dur_ms": ...},
+                        ...],
      "host_rss_mb": ..., "devices": {"0": {"bytes_in_use": ...,
                                            "peak_bytes_in_use": ...}}}
 
@@ -27,14 +29,20 @@ but not per-step cheap on big slices). Compile events come from a
 ``jax.monitoring`` duration listener installed once per process: any
 jit/pjit compilation that happened since the previous record rides
 along on the next one, so recompilation storms are visible in the
-timeline instead of silently halving throughput.
+timeline instead of silently halving throughput, each under the name
+of the function that compiled. ``IterationAccount`` closes a training
+iteration's account (``unattributed_ms``) and names a stalled one
+(``stall``).
 """
 from __future__ import annotations
 
 import atexit
 import collections
+import gc
 import json
 import os
+import resource
+import statistics
 import threading
 import time
 import weakref
@@ -86,7 +94,6 @@ def host_rss_bytes() -> Optional[int]:
     except OSError:
         pass
     try:
-        import resource
         import sys
 
         # ru_maxrss is KB on linux, bytes on macOS; prefer /proc above,
@@ -138,11 +145,18 @@ _compile_listener_installed = False
 _cache_counters = {"hits": 0, "misses": 0, "requests": 0}
 
 
+# jaxpr_trace_duration fires for every traced sub-jaxpr, nested inside
+# the function that called it: depth by thread tells the top-level one,
+# whose duration holds theirs. One entry a function between two drains.
+_trace_depth: dict = {}          # thread id -> open trace events
+_trace_entries: dict = {}        # fun_name -> its entry in _compile_events
+
+
 def _install_compile_listener() -> None:
     """Register ``jax.monitoring`` listeners recording every compilation
-    event (durations) and every persistent-cache hit/miss (plain
-    events). Idempotent; silently absent on jax builds without the
-    monitoring API."""
+    event (durations, each with the ``fun_name`` jax passes) and every
+    persistent-cache hit/miss (plain events). Idempotent; silently
+    absent on jax builds without the monitoring API."""
     global _compile_listener_installed
     with _compile_lock:
         if _compile_listener_installed:
@@ -151,17 +165,50 @@ def _install_compile_listener() -> None:
     try:
         from jax import monitoring
 
-        def _listen(event: str, duration: float, **kw) -> None:
-            # real compilation work only (XLA backend compile + MLIR
-            # lowering); the /jax/core/compile/jaxpr_trace_duration
-            # events fire per traced sub-jaxpr and spam hundreds of
-            # sub-ms entries on the first step
-            if "compil" in event and "trace_duration" not in event:
+        def _listen_start(event: str, value: float, **kw) -> None:
+            # jax records a scalar (the start time) as each timed stage
+            # begins; only tracing nests
+            if event.endswith("jaxpr_trace_duration"):
+                tid = threading.get_ident()
                 with _compile_lock:
-                    _compile_events.append(
-                        {"event": event,
-                         "dur_ms": round(duration * 1e3, 3)}
-                    )
+                    _trace_depth[tid] = _trace_depth.get(tid, 0) + 1
+
+        def _listen(event: str, duration: float, **kw) -> None:
+            # the stages of a compilation: tracing (top-level functions
+            # only, summed by name: the sub-jaxprs' own events are
+            # hundreds of sub-ms entries on a first step, and their
+            # time is in their caller's), MLIR lowering, the backend's
+            # compile or cache read, the cache's disk read. Dropped:
+            # compile_time_saved_sec, an estimate and not a duration of
+            # anything this process did
+            if "compil" not in event or event.endswith("time_saved_sec"):
+                return
+            dur_ms = round(duration * 1e3, 3)
+            # tracing says "train_step", the later stages the module's
+            # name, "jit(train_step)": one name a function
+            fun_name = kw.get("fun_name")
+            if fun_name is not None:
+                fun_name = str(fun_name)
+                if fun_name.startswith("jit(") and fun_name.endswith(")"):
+                    fun_name = fun_name[4:-1]
+            is_trace = event.endswith("jaxpr_trace_duration")
+            with _compile_lock:
+                if is_trace:
+                    tid = threading.get_ident()
+                    depth = max(_trace_depth.pop(tid, 0) - 1, 0)
+                    if depth:
+                        _trace_depth[tid] = depth
+                        return
+                    entry = _trace_entries.get(fun_name)
+                    if entry is not None:
+                        entry["dur_ms"] = round(entry["dur_ms"] + dur_ms, 3)
+                        return
+                entry = {"event": event, "dur_ms": dur_ms}
+                if fun_name is not None:
+                    entry["fun_name"] = fun_name
+                if is_trace:
+                    _trace_entries[fun_name] = entry
+                _compile_events.append(entry)
 
         def _listen_plain(event: str, **kw) -> None:
             # cache hit/miss ride the per-step records too (a miss next
@@ -183,6 +230,7 @@ def _install_compile_listener() -> None:
                 elif key == "compile_requests_use_cache":
                     _cache_counters["requests"] += 1
 
+        monitoring.register_scalar_listener(_listen_start)
         monitoring.register_event_duration_secs_listener(_listen)
         monitoring.register_event_listener(_listen_plain)
     except Exception:
@@ -194,6 +242,7 @@ def drain_compile_events() -> list:
     with _compile_lock:
         out = list(_compile_events)
         _compile_events.clear()
+        _trace_entries.clear()
     return out
 
 
@@ -218,6 +267,127 @@ def compile_cache_stats() -> dict:
         "dir": cache_dir,
         **counters,
     }
+
+
+# ---------------------------------------------------------------------------
+# what the host was doing: garbage collections and the kernel's counts
+# ---------------------------------------------------------------------------
+
+_gc_lock = threading.Lock()
+_gc_installed = False
+_gc_totals = {"ms": 0.0, "gen2": 0, "t0": None}
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    # runs under the interpreter lock, on the collecting thread, and
+    # only when a collection does; every Python thread waits meanwhile
+    if phase == "start":
+        _gc_totals["t0"] = time.perf_counter()
+    elif _gc_totals["t0"] is not None:
+        _gc_totals["ms"] += (time.perf_counter() - _gc_totals["t0"]) * 1e3
+        _gc_totals["t0"] = None
+        if info.get("generation") == 2:
+            _gc_totals["gen2"] += 1
+
+
+def _install_gc_callback() -> None:
+    global _gc_installed
+    with _gc_lock:
+        if not _gc_installed:
+            _gc_installed = True
+            gc.callbacks.append(_on_gc)
+
+
+def host_counters() -> tuple:
+    """``(gc_ms, gc_gen2, nvcsw, nivcsw, majflt)`` of this process so
+    far: milliseconds inside garbage collections and how many were of
+    generation 2 (counted from the first ``FlightRecorder`` on), and
+    ``getrusage``'s voluntary and involuntary context switches and
+    major page faults. Their difference over an iteration tells a late
+    host from a late device."""
+    ru = resource.getrusage(resource.RUSAGE_SELF)
+    return (_gc_totals["ms"], _gc_totals["gen2"],
+            ru.ru_nvcsw, ru.ru_nivcsw, ru.ru_majflt)
+
+
+# the parts of an iteration that have a field of their own; what
+# wall_ms holds beyond them is unattributed_ms
+ITERATION_PARTS = ("data_wait_ms", "dispatch_ms", "health_fetch_ms",
+                   "log_flush_ms", "profile_ms")
+
+
+class IterationAccount:
+    """Closes each training iteration's account and names a stalled one.
+
+    ``settle(rec)`` adds ``unattributed_ms`` = ``wall_ms`` less the
+    ``ITERATION_PARTS`` the record carries, so the parts sum to the
+    whole on every record. A record whose ``wall_ms`` exceeds twice the
+    median of the up to 32 before it (at least 8) also gains
+    ``stall``::
+
+        {"over_ms": wall_ms - that median,
+         "in": the part that grew most beyond its own trailing median,
+               or "unattributed",
+         "gc_ms", "gc_gen2", "nvcsw", "nivcsw", "majflt":
+               ``host_counters()`` over the iteration,
+         "threads": the other threads' open spans, by name}
+
+    A late device shows in ``dispatch_ms``, ``health_fetch_ms`` or
+    ``log_flush_ms`` (the host blocked on the chip); a profiler
+    capture's start or stop in ``profile_ms``; a late host in none of
+    the parts, with a collection, involuntary switches or page faults
+    beside it (where the kernel counts them: a sandboxed one may say 0
+    throughout). The rule has no knob. Call ``settle`` once an
+    iteration, where its clock is read: the counters' difference runs
+    from one call to the next.
+    """
+
+    WINDOW, AT_LEAST, TIMES = 32, 8, 2.0
+
+    def __init__(self, spans=None):
+        self.spans = spans          # a SpanRecorder, for ``threads``
+        self._recent: "collections.deque" = collections.deque(
+            maxlen=self.WINDOW)
+        self._counters = host_counters()
+
+    def settle(self, rec: dict, first: bool = False) -> Optional[dict]:
+        """Complete ``rec`` in place; the ``stall`` it gained, or None.
+        ``first``: the run's first iteration, which waits for the
+        compile: never a stall, and kept out of the trailing window."""
+        parts = {k: rec.get(k, 0.0) for k in ITERATION_PARTS}
+        rec["unattributed_ms"] = round(
+            rec["wall_ms"] - sum(parts.values()), 3)
+        parts["unattributed_ms"] = rec["unattributed_ms"]
+        counters, before = host_counters(), self._counters
+        self._counters = counters
+        if first:
+            return None
+        recent = list(self._recent)
+        self._recent.append((rec["wall_ms"], parts))
+        if len(recent) < self.AT_LEAST:
+            return None
+        typical = statistics.median(w for w, _ in recent)
+        if rec["wall_ms"] <= self.TIMES * typical:
+            return None
+        grew = {k: v - statistics.median(p[k] for _, p in recent)
+                for k, v in parts.items()}
+        worst = max(grew, key=grew.get)
+        me = threading.get_ident()
+        stall = {
+            "over_ms": round(rec["wall_ms"] - typical, 3),
+            "in": "unattributed" if worst == "unattributed_ms" else worst,
+            "gc_ms": round(counters[0] - before[0], 3),
+            "gc_gen2": counters[1] - before[1],
+            "nvcsw": counters[2] - before[2],
+            "nivcsw": counters[3] - before[3],
+            "majflt": counters[4] - before[4],
+            "threads": sorted({s["name"]
+                               for s in self.spans.active_spans()
+                               if s["tid"] != me})
+            if self.spans is not None else [],
+        }
+        rec["stall"] = stall
+        return stall
 
 
 # ---------------------------------------------------------------------------
@@ -263,6 +433,7 @@ class FlightRecorder:
             self._file = open(self.path, "a", buffering=1)  # line-buffered
             _register_for_atexit(self)
         _install_compile_listener()
+        _install_gc_callback()
 
     # -- write ---------------------------------------------------------------
 

@@ -20,6 +20,9 @@ Consumers beyond the viewers:
 - the watchdog (utils/watchdog.py) snapshots ``active_spans()`` when a
   step stalls, so the dump says WHICH call never returned ("stuck 214 s
   inside checkpoint/save") next to the faulthandler stacks;
+- the trainer reads its own set-up's phases back (``since()``) into the
+  first flight record's ``setup``, and names the other threads' open
+  spans on a stalled iteration's ``stall``;
 - tests assert nesting and exception safety on the recorded events.
 
 The module-level ``span()`` uses one process-wide recorder
@@ -116,16 +119,19 @@ class SpanRecorder:
                     })
         return out
 
-    def drain(self) -> list:
-        """Finished events so far; clears the ring."""
-        with self._lock:
-            out = list(self.events)
-            self.events.clear()
-        return out
-
     def snapshot(self) -> list:
         with self._lock:
             return list(self.events)
+
+    def since(self, t0: float) -> list:
+        """Finished events that began at or after ``t0``, a
+        ``perf_counter`` reading (a span's ``frame["t0"]``): what a
+        trainer reads for its own set-up's phases, where the ring holds
+        other trainers' too. Their ``ts`` is on the ring's clock;
+        ``(e["ts"] - first["ts"]) / 1e6`` is seconds after the first."""
+        ts = round((t0 - self._t0) * 1e6, 1)
+        with self._lock:
+            return [e for e in self.events if e["ts"] >= ts]
 
     # -- output --------------------------------------------------------------
 

@@ -17,6 +17,9 @@ Report fields (JSON with ``--json``, markdown otherwise):
 - data-wait fraction (summed ``data_wait_ms`` / summed ``wall_ms``) —
   the "is this run input-bound?" number;
 - compile-cache hit rate from the per-record cache hit/miss events;
+- set-up by phase (the first record's ``setup``: seconds inside each
+  of the trainer's set-up spans) and stalled iterations (records with
+  ``stall``: count, the worst, and the tally of where they went);
 - anomaly count + straggler windows + per-host wall spread (from the
   health layer's recorder events and ``hosts{}`` aggregates);
 - supervisor restart counters (``--supervisor supervisor.jsonl`` or a
@@ -144,6 +147,25 @@ def analyze_telemetry(records: list) -> dict:
             hbm_peak = max(hbm_peak, int(stats.get("peak_bytes_in_use", 0)))
     if hbm_peak:
         out["hbm_peak_mb"] = round(hbm_peak / 2**20, 1)
+    # set-up by phase: the trainer's first record says where its own
+    # start went (seconds inside each span; the warm-up's stages ran on
+    # their own thread, beside the rest)
+    setup = next((r["setup"] for r in records
+                  if isinstance(r.get("setup"), dict)), None)
+    if setup:
+        out["setup"] = dict(setup)
+    # stalled iterations: how many, the worst, and where they went
+    stalled = [r for r in records if isinstance(r.get("stall"), dict)]
+    if stalled:
+        worst = max(stalled, key=lambda r: r["stall"].get("over_ms", 0))
+        stalls = {"count": len(stalled),
+                  "worst_over_ms": worst["stall"].get("over_ms"),
+                  "worst_step": worst.get("step"),
+                  "worst_gc_ms": worst["stall"].get("gc_ms")}
+        for r in stalled:
+            key = f"in {r['stall'].get('in')}"
+            stalls[key] = stalls.get(key, 0) + 1
+        out["stalls"] = stalls
     return out
 
 
@@ -554,6 +576,10 @@ def to_markdown(report: dict) -> str:
         lines.append("")
 
     table("Flight recorder", report.get("telemetry", {}))
+    table("Set-up by phase (seconds)",
+          report.get("telemetry", {}).get("setup"))
+    table("Stalled iterations",
+          report.get("telemetry", {}).get("stalls"))
     table("Prefix cache (serving)", report.get("prefix_cache", {}))
     table("Tensor parallel (serving)", report.get("tensor_parallel", {}))
     table("Supervisor", report.get("supervisor", {}))
