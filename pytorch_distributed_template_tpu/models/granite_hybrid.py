@@ -97,7 +97,7 @@ class GraniteLayer(nn.Module):
             y = Mamba2Mixer(
                 c.d_model, c.ssm_n_head, c.ssm_head_dim, c.ssm_n_group,
                 c.ssm_state, c.ssm_conv, c.ssm_chunk, c.rms_eps, c.dtype,
-                name="mixer")(h)
+                c.mesh, name="mixer")(h)
         else:
             y = LlamaAttention(
                 c.d_model, c.n_head, c.n_kv_head, c.dtype, c.attn_impl,

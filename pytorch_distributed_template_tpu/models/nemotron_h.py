@@ -46,7 +46,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..config.registry import MODELS
 from ..observability.trace import say_once
-from ..ops.ssm import ssd_scan
+from ..ops.ssm import sharded_conv_silu, ssd_scan
 from .llama import LlamaAttention, RMSNorm, _HeadKernel, _dense_init
 from .moe import ExpertLayer
 from .remat_policy import BlockKind, block_policy
@@ -92,6 +92,7 @@ class Mamba2Mixer(nn.Module):
     chunk: int
     rms_eps: float
     dtype: Any
+    mesh: Optional[Any] = None
 
     @nn.compact
     def __call__(self, u):
@@ -113,19 +114,14 @@ class Mamba2Mixer(nn.Module):
         with jax.named_scope("ssm_proj"):
             zxd = checkpoint_name(
                 dense(2 * d_in + d_bc + h, "in_proj")(u), "ssm_in_proj")
-        z, xbc, dt = jnp.split(zxd, [d_in, 2 * d_in + d_bc], axis=-1)
+        z, _, dt = jnp.split(zxd, [d_in, 2 * d_in + d_bc], axis=-1)
+        taps = self.param("conv_kernel", _dense_init(),
+                          (self.conv, d_in + d_bc), f32)
+        bias = self.param("conv_bias", nn.initializers.zeros,
+                          (d_in + d_bc,), f32)
+        # scoped as ssm_conv, and read where it lies in the kept projection
+        xbc = sharded_conv_silu(zxd, taps, bias, d_in, self.mesh)
         with jax.named_scope("ssm_scan"):
-            with jax.named_scope("ssm_conv"):
-                taps = self.param("conv_kernel", _dense_init(),
-                                  (self.conv, d_in + d_bc), f32)
-                bias = self.param("conv_bias", nn.initializers.zeros,
-                                  (d_in + d_bc,), f32)
-                # tap k multiplies position t - (conv - 1) + k
-                padded = jnp.pad(xbc.astype(f32),
-                                 ((0, 0), (self.conv - 1, 0), (0, 0)))
-                xbc = nn.silu(bias + sum(
-                    taps[k] * padded[:, k:k + t] for k in range(self.conv))
-                ).astype(self.dtype)
             x, bm, cm = jnp.split(xbc, [d_in, d_in + g * n], axis=-1)
             dt = jax.nn.softplus(dt.astype(f32) + self.param(
                 "dt_bias", _step_bias_init(), (h,), f32))
@@ -197,7 +193,7 @@ class HybridLayer(nn.Module):
             y = Mamba2Mixer(
                 c.d_model, c.ssm_n_head, c.ssm_head_dim, c.ssm_n_group,
                 c.ssm_state, c.ssm_conv, c.ssm_chunk, c.rms_eps, c.dtype,
-                name="mixer")(h)
+                c.mesh, name="mixer")(h)
         elif self.kind == "E":
             y = ExpertLayer(
                 d_model=c.d_model, d_ff=c.moe_d_ff, n_routed=c.moe_n_routed,

@@ -703,6 +703,14 @@ def test_hybrid_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     dispatch = [d for d in _said("moe/dispatch") if d["tokens"] == 16384]
     assert dispatch and all(d["rows"] == 131072 and d["expected"] == 5632
                             for d in dispatch)
+    # five mixers' convolutions: a backward kernel each, over two rows
+    conv = [c for c in _said("ssm/conv") if c["positions"] == 16384]
+    assert conv == [dict(
+        taps=4, channels=1280, positions=16384, block_channels=128,
+        block_positions=2048, backward="kernel",
+        forward_bytes=2 * 16384 * 1280 * 2,
+        backward_bytes=3 * 16384 * 1280 * 2)]
+    assert len(re.findall(r"%ssm_conv_bwd(\.\d+)? = ", text)) == 5
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
@@ -751,11 +759,92 @@ def test_granite_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     assert (pattern["rows"], pattern["of_rows"]) == (12544, 100352)
     (said,) = _said("head_loss/slice")
     assert said["gradients"] == "forward" and said["rows_per_device"] == 2048
+    # the convolution (ISSUE 39): said once for nine layers of one shape;
+    # one backward kernel a layer; and under its scope no float32 array
+    # of the size of its input, padded or not, in any phase: the
+    # forward's and the recomputation's fusions take the bfloat16 slice
+    # and give bfloat16, the kernel keeps float32 in its block
+    (conv,) = _said("ssm/conv")
+    assert conv == dict(
+        taps=4, channels=4352, positions=8192, block_channels=128,
+        block_positions=2048, backward="kernel",
+        forward_bytes=2 * 8192 * 4352 * 2, backward_bytes=3 * 8192 * 4352 * 2)
+    assert len(re.findall(r"%ssm_conv_bwd(\.\d+)? = ", text)) == 9
+    made = [(name, array) for name, _, arrays, _, _ in _scope_instructions(
+        text, "ssm_conv") for array in arrays]
+    assert len(made) > 9 * 4
+    wide = [(name, dims) for name, (dtype, dims, _, _) in made
+            if dtype == "f32" and sorted(dims)[-2:] in ([4352, 8192],
+                                                        [4352, 8195])]
+    assert not wide, wide
     m = compiled.memory_analysis()
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     print(f"granite step: {total} bytes compiled, policy {policy}")
     assert total < V5E_BYTES_LIMIT - (1 << 30)
+
+
+# [batch, positions, the projection's width], where the convolution's
+# channels start and how many they are, the type: the two hybrid cells'
+# layers read where they lie; float32 (fewer positions a block); a debug
+# config's widths, which no block divides, cut out and padded
+CONVOLUTIONS = {
+    "granite4_h_micro_l10.seq8k": ((1, 8192, 8512), 4096, 4352, jnp.bfloat16),
+    "nemotron3_super_l11.seq8k": ((2, 8192, 2320), 1024, 1280, jnp.bfloat16),
+    "float32": ((1, 8192, 8512), 4096, 4352, jnp.float32),
+    "debug-widths": ((2, 200, 232), 64, 96, jnp.bfloat16),
+}
+
+
+def _convolution_step(start, mesh):
+    from pytorch_distributed_template_tpu.ops.ssm import sharded_conv_silu
+
+    def loss(zxd, taps, bias):
+        out = sharded_conv_silu(zxd, taps, bias, start, mesh)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))
+
+
+@pytest.mark.parametrize("shape,start,channels,dtype", CONVOLUTIONS.values(),
+                         ids=CONVOLUTIONS.keys())
+def test_convolutions_backward_kernel_compiles_for_v5e(
+        one_chip, monkeypatch, shape, start, channels, dtype):
+    """ops/ssm.causal_conv_silu's backward with the blocks `conv_blocks`
+    gives the shape: Mosaic takes the lane rotations, the block's fast
+    memory and the accumulated tile of sums."""
+    from pytorch_distributed_template_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    text = _convolution_step(start, None).lower(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((4, channels), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((channels,), jnp.float32, sharding=one_chip),
+    ).compile().as_text()
+    # the forward is the compiler's own fusion; one kernel, the backward
+    assert len(re.findall(r"%ssm_conv_bwd(\.\d+)? = ", text)) == 1
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_convolutions_backward_kernel_is_partitioned_over_the_batch_for_v5e(
+        four_chips, monkeypatch):
+    """Four chips, data parallel: inside `shard_map` each chip's kernel
+    takes its row of the batch, and the parameters' gradients cross."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_template_tpu.ops import flash
+
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+    rows, whole = (NamedSharding(four_chips, P("data")),
+                   NamedSharding(four_chips, P()))
+    text = _convolution_step(1024, four_chips).lower(
+        jax.ShapeDtypeStruct((4, 8192, 2320), jnp.bfloat16, sharding=rows),
+        jax.ShapeDtypeStruct((4, 1280), jnp.float32, sharding=whole),
+        jax.ShapeDtypeStruct((1280,), jnp.float32, sharding=whole),
+    ).compile().as_text()
+    (kernel,) = re.findall(r"%ssm_conv_bwd(?:\.\d+)? = \((\S+), ", text)
+    assert kernel.startswith("bf16[1,1280,8192]")
+    assert re.search(r"all-reduce", text)
 
 
 @pytest.mark.xfail(strict=True, raises=ValueError,

@@ -160,6 +160,43 @@ def test_the_model_says_its_pattern_and_the_scans_mask_once(caplog):
     assert f"{2 * 3 * 4 * 16 * 16 * 4} bytes" in chunks
 
 
+def test_the_convolution_says_its_form_once_a_process_and_shape(caplog):
+    """`ssm/conv`: one line and one zero-length span for the two mixer
+    layers' convolutions of one shape however often they are traced,
+    another for another length; it is the mechanism's counter, so a
+    model without a mixer says nothing."""
+    import logging
+
+    from pytorch_distributed_template_tpu.observability import trace
+
+    trace._said.clear()
+    trace.get_recorder().clear()
+    model = _tiny()
+    with caplog.at_level(logging.INFO):
+        params = model.init(jax.random.key(0), jnp.zeros((2, 40), jnp.int32))
+        for t in (40, 40, 24):
+            model.apply(params, jnp.zeros((2, t), jnp.int32))
+        _tiny(layer_types=("attention",)).init(
+            jax.random.key(0), jnp.zeros((2, 56), jnp.int32))
+    lines = [r.getMessage() for r in caplog.records
+             if r.getMessage().startswith("ssm/conv")]
+    spans = [e["args"] for e in trace.get_recorder().snapshot()
+             if e["name"] == "ssm/conv"]
+    assert len(lines) == len(spans) == 2
+    # 64 inner channels and 2 x 1 x 16 of B and C; 2 rows of 40, then 24
+    assert [(s["taps"], s["channels"], s["positions"]) for s in spans] == [
+        (4, 96, 80), (4, 96, 48)]
+    # off the TPU the backward is plain jax.numpy over the whole array,
+    # its float32 pre-activation gradient out and back in
+    assert spans[0]["backward"] == "xla fusions"
+    assert (spans[0]["block_channels"], spans[0]["block_positions"]) == (
+        96, 80)
+    assert spans[0]["forward_bytes"] == 2 * 80 * 96 * 4
+    assert spans[0]["backward_bytes"] == 80 * 96 * (3 * 4 + 8)
+    assert "4 taps over 96 channels at 80 positions" in lines[0]
+    assert "the backward as xla fusions" in lines[0]
+
+
 def test_unknown_layer_types_and_decode_are_refused():
     tokens = jnp.zeros((1, 8), jnp.int32)
     with pytest.raises(ValueError, match="each one of"):

@@ -94,3 +94,249 @@ def test_bfloat16_operands_keep_their_type_and_stay_close():
     want = ssd_recurrence(*args)
     err = jnp.abs(got.astype(jnp.float32) - want)
     assert float(jnp.max(err)) < 0.05 * float(jnp.max(jnp.abs(want)))
+
+
+# -- the depthwise causal convolution with its bias and silu ----------------
+
+
+def four_slices(xbc, taps, bias):
+    """The convolution as `Mamba2Mixer` wrote it out before ISSUE 39: the
+    input widened and padded, one shifted slice a tap, jax's own
+    backward."""
+    k, t = taps.shape[0], xbc.shape[1]
+    padded = jnp.pad(xbc.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
+    return jax.nn.silu(bias + sum(
+        taps[i] * padded[:, i:i + t] for i in range(k))).astype(xbc.dtype)
+
+
+def conv_operands(b, t, c, k, dtype, seed=0):
+    keys = jax.random.split(jax.random.key(seed), 4)
+    return (jax.random.normal(keys[0], (b, t, c), dtype),
+            0.5 * jax.random.normal(keys[1], (k, c)),
+            0.1 * jax.random.normal(keys[2], (c,)),
+            jax.random.normal(keys[3], (b, t, c), dtype))
+
+
+def conv_loss(conv, weight):
+    return lambda *a: jnp.sum(conv(*a).astype(jnp.float32)
+                              * weight.astype(jnp.float32))
+
+
+def assert_conv_gradients_close(got, want, positions, dtype):
+    """(input's, taps', bias's) gradients: the input's to one unit in the
+    last place of the type it is rounded to, the sums to float32's over
+    `positions` products of size one."""
+    ulp = float(jnp.finfo(dtype).eps)
+    for name, g, w, tol in zip(("input", "taps", "bias"), got, want,
+                               (max(ulp, 2e-6), 2e-6, 2e-6)):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert g.shape == w.shape, name
+        np.testing.assert_allclose(
+            g, w, rtol=0, err_msg=name,
+            atol=tol * max(1.0, float(jnp.max(jnp.abs(w))))
+            * (1 if name == "input" else positions ** 0.5))
+
+
+# the two cells' widths at a short length, an odd handful of channels;
+# lengths no block of rows divides, one shorter than the taps, one row
+CONV_CASES = {
+    "granite-width": (1, 24, 4352, 4), "hybrid-width": (2, 40, 1280, 4),
+    "odd-handful": (2, 37, 5, 4), "no-block-divides": (1, 300, 24, 4),
+    "shorter-than-the-taps": (2, 3, 7, 4), "two-taps": (2, 37, 24, 2),
+    "one-position-two-taps": (1, 1, 3, 2), "whole-blocks": (2, 512, 128, 4),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("b,t,c,k", CONV_CASES.values(),
+                         ids=CONV_CASES.keys())
+def test_convolution_is_the_four_slice_form(b, t, c, k, dtype):
+    from pytorch_distributed_template_tpu.ops.ssm import causal_conv_silu
+
+    xbc, taps, bias, weight = conv_operands(b, t, c, k, dtype)
+    got = causal_conv_silu(xbc, taps, bias)
+    want = four_slices(xbc, taps, bias)
+    assert got.dtype == want.dtype == dtype and got.shape == (b, t, c)
+    # one unit in the last place of the type it is rounded to
+    ulp = float(jnp.finfo(dtype).eps)
+    np.testing.assert_allclose(
+        got.astype(jnp.float32), want.astype(jnp.float32), rtol=ulp,
+        atol=1e-6)
+    got = jax.grad(conv_loss(causal_conv_silu, weight), (0, 1, 2))(
+        xbc, taps, bias)
+    want = jax.grad(conv_loss(four_slices, weight), (0, 1, 2))(
+        xbc, taps, bias)
+    assert got[0].dtype == dtype and got[1].dtype == got[2].dtype \
+        == jnp.float32
+    assert_conv_gradients_close(got, want, b * t, dtype)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_nothing_crosses_from_one_row_of_the_batch_to_the_next(dtype):
+    from pytorch_distributed_template_tpu.ops.ssm import causal_conv_silu
+
+    xbc, taps, bias, weight = conv_operands(2, 21, 12, 4, dtype, seed=3)
+    other = xbc.at[0].set(conv_operands(1, 21, 12, 4, dtype, seed=4)[0][0])
+
+    def both(x):
+        return (causal_conv_silu(x, taps, bias),
+                jax.grad(conv_loss(causal_conv_silu, weight))(x, taps, bias))
+
+    (y, dx), (y_other, dx_other) = both(xbc), both(other)
+    # row 0 changed: row 1's output and input gradient are bit for bit
+    # what they were, and row 0's are not
+    for a, a_other in ((y, y_other), (dx, dx_other)):
+        np.testing.assert_array_equal(np.asarray(a[1], np.float32),
+                                      np.asarray(a_other[1], np.float32))
+        assert np.any(np.asarray(a[0], np.float32)
+                      != np.asarray(a_other[0], np.float32))
+    # and each row starts from zeros: position 0 sees the last tap alone
+    np.testing.assert_allclose(
+        np.asarray(y[:, 0], np.float32),
+        np.asarray(jax.nn.silu(bias + taps[-1] * xbc[:, 0].astype(
+            jnp.float32)).astype(dtype), np.float32), rtol=0, atol=0)
+
+
+def test_convolutions_gradient_is_the_same_under_the_mixers_checkpoint():
+    from jax.ad_checkpoint import checkpoint_name
+
+    from pytorch_distributed_template_tpu.ops.ssm import causal_conv_silu
+
+    xbc, taps, bias, weight = conv_operands(2, 37, 24, 4, jnp.bfloat16,
+                                            seed=5)
+
+    def block(x, taps, bias):
+        # as `Mamba2Mixer`: the convolution reads a slice of the kept
+        # projection, and its own output is recomputed
+        zxd = checkpoint_name(jnp.concatenate([x, x * 0.5, x], -1),
+                              "ssm_in_proj")
+        y = causal_conv_silu(zxd[..., 24:48] * 2.0, taps, bias)
+        return jnp.sum(jnp.tanh(y.astype(jnp.float32))
+                       * weight.astype(jnp.float32))
+
+    kept = jax.checkpoint(
+        block, policy=jax.checkpoint_policies.save_only_these_names(
+            "ssm_in_proj"))
+    want = jax.grad(block, (0, 1, 2))(xbc, taps, bias)
+    got = jax.grad(kept, (0, 1, 2))(xbc, taps, bias)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+    # what the rule keeps from forward to backward is what it was given:
+    # the input as it came, the taps, the bias; no pre-activation
+    from jax._src.ad_checkpoint import saved_residuals
+
+    kept = saved_residuals(lambda *a: jnp.sum(causal_conv_silu(*a)),
+                           xbc, taps, bias)
+    assert [(r.dtype, r.shape) for r, _ in kept] == [
+        (jnp.bfloat16, (2, 37, 24)), (jnp.float32, (4, 24)),
+        (jnp.float32, (24,))]
+    assert all("from the argument" in why for _, why in kept)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_convolution_reads_its_channels_where_they_lie(dtype):
+    """`start`: the convolution of the channels `[start, start + C)` of a
+    wider array is the convolution of that slice, and the wider array's
+    gradient is the slice's between zeros."""
+    from pytorch_distributed_template_tpu.ops.ssm import causal_conv_silu
+
+    zxd, taps, bias, weight = conv_operands(2, 37, 40, 4, dtype, seed=7)
+    taps, bias, weight = taps[:, :24], bias[:24], weight[..., :24]
+    got = causal_conv_silu(zxd, taps, bias, 8)
+    want = causal_conv_silu(zxd[..., 8:32], taps, bias)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    got = jax.grad(conv_loss(lambda *a: causal_conv_silu(*a, 8), weight),
+                   (0, 1, 2))(zxd, taps, bias)
+    want = jax.grad(conv_loss(causal_conv_silu, weight), (0, 1, 2))(
+        zxd[..., 8:32], taps, bias)
+    assert got[0].shape == zxd.shape and got[0].dtype == dtype
+    np.testing.assert_array_equal(np.asarray(got[0][..., 8:32], np.float32),
+                                  np.asarray(want[0], np.float32))
+    assert not np.any(np.asarray(got[0][..., :8], np.float32))
+    assert not np.any(np.asarray(got[0][..., 32:], np.float32))
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(g, w)
+
+
+# the backward kernel, interpreted: blocks smaller than the shapes, so
+# that taps reach across the blocks' edges on both sides; the channels
+# read where they lie (`start` a whole block) and cut out (not one);
+# lengths and widths no block divides
+KERNEL_CASES = {
+    "three-blocks-of-positions": ((128, 128), 2, 384, 128, 4, 0, 128),
+    "where-they-lie": ((128, 128), 1, 256, 256, 4, 128, 520),
+    "cut-out-and-padded": ((128, 256), 2, 300, 72, 4, 24, 100),
+    "two-taps": ((128, 128), 1, 260, 128, 2, 0, 128),
+    "by-the-shapes-own-blocks": (None, 2, 37, 24, 4, 0, 24),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("blocks,b,t,c,k,start,width", KERNEL_CASES.values(),
+                         ids=KERNEL_CASES.keys())
+def test_backward_kernel_is_the_four_slice_forms_gradient(
+        blocks, b, t, c, k, start, width, dtype, monkeypatch):
+    from pytorch_distributed_template_tpu.ops import ssm
+
+    if blocks:
+        monkeypatch.setattr(ssm, "conv_blocks", lambda t, c, size: blocks)
+    zxd, taps, bias, dy = conv_operands(b, t, width, k, dtype, seed=9)
+    taps, bias, dy = taps[:, :c], bias[:c], dy[..., :c]
+    got = ssm._conv_bwd_pallas(zxd, start, taps, bias, dy, interpret=True)
+    want = jax.grad(conv_loss(four_slices, dy), (0, 1, 2))(
+        zxd[..., start:start + c], taps, bias)
+    assert_conv_gradients_close(got, want, b * t, dtype)
+
+
+@pytest.mark.parametrize("t,c,size,want", [
+    (8192, 4352, 2, (128, 2048)),      # granite's layer
+    (8192, 1280, 2, (128, 2048)),      # the hybrid's
+    (8192, 4352, 4, (128, 1536)),      # float32: fewer positions
+    (40, 96, 4, (96, 128)),            # a debug config: one block
+    (300, 384, 2, (128, 384)),         # three blocks of 128 channels
+    (3, 5, 2, (16, 128)),              # an odd handful: one sublane tile
+])
+def test_convolutions_blocks_follow_the_shapes(t, c, size, want):
+    from pytorch_distributed_template_tpu.ops.ssm import conv_blocks
+
+    assert conv_blocks(t, c, size) == want
+
+
+@pytest.mark.parametrize("axes,batch", [({"data": 4, "tensor": 2}, 4),
+                                        ({"data": 2, "fsdp": 4}, 8),
+                                        ({"data": 8}, 2)],
+                         ids=["data-and-tensor", "data-and-fsdp",
+                              "batch-the-axes-do-not-divide"])
+def test_convolution_under_a_mesh_is_the_one_devices(axes, batch):
+    """`sharded_conv_silu`: the batch over the data axes inside
+    `shard_map` (whole where they do not divide it), the parameters whole
+    on every device, their gradients summed over the devices."""
+    from pytorch_distributed_template_tpu.ops.ssm import (
+        causal_conv_silu, sharded_conv_silu,
+    )
+    from pytorch_distributed_template_tpu.parallel import build_mesh
+
+    mesh = build_mesh(axes)
+    zxd, taps, bias, weight = conv_operands(batch, 21, 40, 4, jnp.float32,
+                                            seed=11)
+    taps, bias, weight = taps[:, :24], bias[:24], weight[..., :24]
+
+    def on_mesh(z, w, b):
+        return sharded_conv_silu(z, w, b, 8, mesh)
+
+    got = jax.jit(jax.value_and_grad(conv_loss(on_mesh, weight), (0, 1, 2)))(
+        zxd, taps, bias)
+    want = jax.value_and_grad(
+        conv_loss(lambda *a: causal_conv_silu(*a, 8), weight), (0, 1, 2))(
+        zxd, taps, bias)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, rtol=0, atol=2e-6 * max(
+            1.0, float(jnp.max(jnp.abs(w)))))
+    assert sharded_conv_silu(zxd, taps, bias, 8, None).shape == (
+        batch, 21, 24)

@@ -296,6 +296,40 @@ def test_hybrid_step_carries_its_scopes():
     assert not some(r"ssm_scan/.*in_proj") and not some(r"ssm_scan/.*out_proj")
 
 
+def test_every_operation_of_the_convolutions_rule_lies_under_its_scope():
+    """ops/ssm.causal_conv_silu's forward and backward are its own: both
+    carry `ssm_scan/ssm_conv` themselves, so in every phase of a
+    checkpointed block everything they compute is the scope's."""
+    from pytorch_distributed_template_tpu.ops.ssm import causal_conv_silu
+
+    def block(x, taps, bias):
+        with jax.named_scope("layers_0"):
+            return jnp.sum(jnp.sin(causal_conv_silu(x * 2.0, taps, bias)))
+
+    x = jnp.ones((2, 37, 24), jnp.bfloat16)
+    taps, bias = jnp.ones((4, 24)), jnp.ones((24,))
+    names = set(re.findall(r'op_name="([^"]*)"', jax.jit(jax.grad(
+        jax.checkpoint(block), (0, 1, 2))).lower(x, taps, bias).compile(
+        ).as_text()))
+    # outside it: the arguments and the block's own doubling, sine and
+    # sum; nothing the rule's bodies are made of (shifts, widening, silu)
+    inside = {n for n in names if "ssm_" in n}
+    assert {n.rsplit("/", 1)[-1] for n in names - inside} <= {
+        "x", "taps", "bias", "mul", "broadcast_in_dim", "reduce_sum",
+        "remat2", "sin", "cos"}, names - inside
+    assert {"pad", "slice", "convert_element_type", "exp"} <= {
+        n.rsplit("/", 1)[-1] for n in inside}
+    assert inside and all("/layers_0/ssm_scan/ssm_conv" in n for n in inside)
+    for phase in (r"checkpoint/layers_0/ssm_scan/ssm_conv",
+                  r"rematted_computation/layers_0/ssm_scan/ssm_conv"):
+        assert any(re.search(phase, n) for n in inside), phase
+    # not under differentiation: the same scope, once
+    plain = set(re.findall(r'op_name="([^"]*)"', jax.jit(block).lower(
+        x, taps, bias).compile().as_text()))
+    assert any("/layers_0/ssm_scan/ssm_conv" in n for n in plain)
+    assert not any("ssm_conv/ssm_scan" in n for n in names | plain)
+
+
 def test_a_choice_is_said_once_a_process_and_distinct_record(caplog):
     import logging
 
