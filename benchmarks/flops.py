@@ -46,26 +46,69 @@ def model_flops_per_token(arch, s: dict, seq_len: int) -> float:
 
 
 # -- the flash attention kernels (ops/flash.py: forward, dkv, dq) ----------
-# Per visible (query, key) pair and head: a product with the head size
-# costs 2 x head size. Forward: scores and context (2 products). dkv:
-# scores again, dP, dV, dK (4). dq: scores again, dP, dQ (3).
-_PRODUCTS = {"fwd": 2, "dkv": 4, "dq": 3}
+# A call of the family has two head sizes: the query-key one, which the
+# scores are made over, and the value one, which the context is (the same
+# in every kernel the program has today; a latent-attention call has 192
+# and 128). Per visible (query, key) pair and head a product costs twice
+# the head size it runs over. (Products over the query-key size, over the
+# value size). Forward: scores; context. dkv: scores again, dK; dP, dV.
+# dq: scores again, dQ; dP.
+_PRODUCTS = {"fwd": (1, 1), "dkv": (2, 2), "dq": (2, 1)}
 # Whole [B, T, H, D] tensors each call has to move once, in the compute
-# type: fwd reads q, k, v and writes o; dkv reads q, k, v, do and writes
-# dk, dv; dq reads q, k, v, do and writes dq (lse and delta are 1/D of
-# a tensor and left out).
-_TENSORS = {"fwd": 4, "dkv": 6, "dq": 5}
+# type, (at the query-key size, at the value size): fwd reads q, k; v and
+# writes o. dkv reads q, k and writes dk; reads v, do and writes dv. dq
+# reads q, k and writes dq; reads v, do (lse and delta are 1/D of a
+# tensor and left out).
+_TENSORS = {"fwd": (2, 2), "dkv": (3, 3), "dq": (3, 2)}
+
+
+def _widths(table: dict, kind: str, head_dim: int, v_head_dim) -> int:
+    at_qk, at_v = table[kind]
+    return at_qk * head_dim + at_v * (
+        head_dim if v_head_dim is None else v_head_dim)
 
 
 def flash_call_flops(kind: str, batch: int, seq_len: int, n_head: int,
-                     head_dim: int, window: int = 0) -> float:
+                     head_dim: int, window: int = 0,
+                     v_head_dim: int | None = None) -> float:
+    """`head_dim` is the query-key head size; `v_head_dim` the value
+    one, where it differs."""
     pairs = batch * n_head * seq_len * mean_visible_keys(seq_len, window)
-    return _PRODUCTS[kind] * 2 * head_dim * pairs
+    return 2 * _widths(_PRODUCTS, kind, head_dim, v_head_dim) * pairs
 
 
 def flash_call_bytes(kind: str, batch: int, seq_len: int, n_head: int,
-                     head_dim: int, itemsize: int = 2) -> float:
-    return _TENSORS[kind] * batch * seq_len * n_head * head_dim * itemsize
+                     head_dim: int, itemsize: int = 2,
+                     v_head_dim: int | None = None) -> float:
+    return (batch * seq_len * n_head
+            * _widths(_TENSORS, kind, head_dim, v_head_dim) * itemsize)
+
+
+# -- the state-space convolution's backward (ops/ssm.py: ssm_conv_bwd) -----
+# One call over `[batch, channels, positions]` with `taps` taps a channel
+# remakes the pre-activation, and gives the input's gradient, the taps'
+# and the bias's. An entry of the input: `taps` multiply-adds for the
+# pre-activation (2 taps), silu' as sigmoid (negate, exp, add, reciprocal:
+# 4) and sig * (1 + pre * (1 - sig)) (4), its product with the cotangent
+# (1), `taps` multiply-adds for the input's gradient (2 taps - 1), `taps`
+# for the taps' sums (2 taps), one add for the bias's: 6 taps + 9. None of
+# it is a matmul, so against `bf16_flops_per_s` the bytes bound it always.
+
+
+def ssm_conv_bwd_flops(batch: int, channels: int, positions: int,
+                       taps: int) -> float:
+    return (6 * taps + 9) * batch * channels * positions
+
+
+def ssm_conv_bwd_bytes(batch: int, channels: int, positions: int, taps: int,
+                       itemsize: int = 2) -> float:
+    """The input and the cotangent read once and the input's gradient
+    written once, in the compute type; taps and bias read, their
+    gradients written, in float32. The kernel moves more: 128 positions
+    beside each block of 2048, three times, and a tile of 128 float32 a
+    channel for its five sums."""
+    return (3 * batch * channels * positions * itemsize
+            + (2 * taps + 2) * channels * 4)
 
 
 def roofline_seconds(flops: float, nbytes: float, peak: dict) -> tuple:

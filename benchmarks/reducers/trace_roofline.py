@@ -6,40 +6,54 @@ shapes by benchmarks/flops.py) over the time they took in the trace.
 `kernels` maps a kind flops.py knows (`fwd`, `dkv`, `dq`) to a regex
 that picks that kernel's events by the instruction's own name; `operand`
 is a regex whose three groups read batch x heads, sequence length and
-head size from the event's first operand. An event a kernel's regex
-picks and `operand` cannot read is an error that names the event: the
-kernel's call shape changed, or the regex picks what it should not. The
-band comes from the configuration's `window`."""
+head size from the event's first operand. Where the family's value head
+size is another than its query-key one, `value_operand` is a regex whose
+one group reads it from the value's operand; without it both are
+`operand`'s. An event a kernel's regex picks and a given regex cannot
+read is an error that names the event: the kernel's call shape changed,
+or the regex picks what it should not. The band comes from the
+configuration's `window`."""
 import re
 
 from .. import flops
-from ..trace_reduce import clipped
+from ..trace_reduce import whole_events
 
 
-def reduce(facts, kernels: dict, operand: str):
+def read(regex: str, what: str, pattern: str, kind: str, event) -> tuple:
+    found = re.search(regex, event[0])
+    if found is None:
+        raise ValueError(
+            f"trace_roofline: {pattern!r} picks this event as a {kind!r} "
+            f"kernel, but {regex!r} finds no {what} in it: {event[0][:300]}")
+    return tuple(map(int, found.groups()))
+
+
+def share(facts, counted):
+    """`counted` yields (event, operations, bytes) of each call."""
+    least = took = 0.0
+    for event, operations, nbytes in counted:
+        least += flops.roofline_seconds(operations, nbytes, facts.peak)[0]
+        took += event[2] / 1e9
+    return 100.0 * least / took if took > 0 else None
+
+
+def reduce(facts, kernels: dict, operand: str, value_operand: str = None):
     trace = facts.trace
     if trace is None:
         return None
-    least = took = 0.0
-    for kind, pattern in kernels.items():
-        for event in trace.ops:
-            if not re.search(pattern, event[0]):
-                continue
-            inside = clipped([event], trace.lo, trace.hi)
-            if not inside or inside[0][1] - inside[0][0] < event[2]:
-                continue            # cut by the window's edge
-            found = re.search(operand, event[0])
-            if found is None:
-                raise ValueError(
-                    f"trace_roofline: {pattern!r} picks this event as a "
-                    f"{kind!r} kernel, but {operand!r} finds no batch x "
-                    f"heads, length and head size in it: {event[0][:300]}")
-            bh, t, d = map(int, found.groups())
-            shape = dict(batch=1, seq_len=t, n_head=bh, head_dim=d)
-            seconds, _ = flops.roofline_seconds(
-                flops.flash_call_flops(kind, window=facts.sizes.get(
-                    "window", 0), **shape),
-                flops.flash_call_bytes(kind, **shape), facts.peak)
-            least += seconds
-            took += event[2] / 1e9
-    return 100.0 * least / took if took > 0 else None
+
+    def counted():
+        window = facts.sizes.get("window", 0)
+        for kind, pattern in kernels.items():
+            for event in whole_events(trace.ops, pattern, trace.lo, trace.hi):
+                bh, t, d = read(operand, "batch x heads, length and head "
+                                "size", pattern, kind, event)
+                shape = dict(batch=1, seq_len=t, n_head=bh, head_dim=d)
+                if value_operand is not None:
+                    shape["v_head_dim"], = read(
+                        value_operand, "value head size", pattern, kind, event)
+                yield (event,
+                       flops.flash_call_flops(kind, window=window, **shape),
+                       flops.flash_call_bytes(kind, **shape))
+
+    return share(facts, counted())

@@ -75,6 +75,18 @@ def clipped(events, lo, hi) -> list:
             if s < hi and s + d > lo]
 
 
+def whole_events(events, pattern: str, lo, hi) -> list:
+    """The events whose name matches `pattern` and that [lo, hi] holds
+    whole: a call cut by the window's edge is no whole call."""
+    out = []
+    for event in events:
+        if re.search(pattern, event[0]):
+            inside = clipped([event], lo, hi)
+            if inside and inside[0][1] - inside[0][0] >= event[2]:
+                out.append(event)
+    return out
+
+
 def union_ns(events, lo, hi) -> float:
     return sum(b - a for a, b in merged(clipped(events, lo, hi)))
 

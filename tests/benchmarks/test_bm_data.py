@@ -38,15 +38,17 @@ def test_top_level_keys_and_limits():
     assert runs * (SPEC["run_seconds"] + 60) + 24 * 180 + 1200 <= 43200
 
 
-def test_configs_name_files_that_hold_what_is_run():
-    assert {c["name"] for c in SPEC["configs"]} == \
-        {w["config"] for w in SPEC["workloads"]}
-    for c in SPEC["configs"]:
+def check_configs(spec, root):
+    """Every configuration is used by a cell, and its file under `root`
+    says what its entry says."""
+    assert {c["name"] for c in spec["configs"]} == \
+        {w["config"] for w in spec["workloads"]}
+    for c in spec["configs"]:
         assert set(c) == {"name", "source", "file", "reduced", "why"}
         assert NAME.match(c["name"]) and one_line(c["source"])
         assert one_line(c["why"])
         assert c["file"] == f"benchmarks/configs/{c['name']}.json"
-        held = load(ROOT / c["file"])
+        held = load(Path(root) / c["file"])
         assert held["source"] == c["source"]
         assert held["reduced"] == c["reduced"] and len(c["reduced"]) <= 16
         assert set(held["reduced_why"]) == set(c["reduced"])
@@ -61,19 +63,33 @@ def test_configs_name_files_that_hold_what_is_run():
                 assert args[key] == value, (c["name"], key)
 
 
-def test_every_workload_file_matches_its_entry():
-    files = {p.stem for p in (BENCH / "workloads").glob("*.json")}
-    assert files == {w["name"] for w in SPEC["workloads"]}
+def test_configs_name_files_that_hold_what_is_run():
+    check_configs(SPEC, ROOT)
+
+
+def four_chip_cells_fit(spec) -> bool:
+    """At most a quarter of the cells, rounded down, may ask for four
+    chips, and one always may."""
+    four = sum(w["chips"] == 4 for w in spec["workloads"])
+    return four <= max(1, len(spec["workloads"]) // 4)
+
+
+def check_workloads(spec, bench_dir):
+    """Every cell has its file under `bench_dir`, which says what its
+    entry says; how many cells there are is no rule."""
+    bench_dir = Path(bench_dir)
+    files = {p.stem for p in (bench_dir / "workloads").glob("*.json")}
+    assert files == {w["name"] for w in spec["workloads"]}
     pairs = set()
-    for w in SPEC["workloads"]:
+    for w in spec["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
         for key in ("name", "config", "traffic"):
             assert NAME.match(w[key]), w[key]
         assert one_line(w["why"]) and w["chips"] in (1, 4)
-        held = load(BENCH / "workloads" / f"{w['name']}.json")
+        held = load(bench_dir / "workloads" / f"{w['name']}.json")
         for key in ("name", "config", "traffic", "chips", "why"):
             assert held[key] == w[key], (w["name"], key)
-        assert (BENCH / "configs" / f"{w['config']}.json").is_file()
+        assert (bench_dir / "configs" / f"{w['config']}.json").is_file()
         assert (w["config"], w["traffic"]) not in pairs
         pairs.add((w["config"], w["traffic"]))
         limits = held["check"]["limits"]
@@ -83,8 +99,11 @@ def test_every_workload_file_matches_its_entry():
         assert 0 < limits["update_norm_gap"] < 1
         assert held["check"]["limits_from"]
         assert held["warmup_iterations"] >= 5 and held["trace_steps"] >= 4
-    four = sum(w["chips"] == 4 for w in SPEC["workloads"])
-    assert four <= max(1, len(SPEC["workloads"]) // 4)
+    assert four_chip_cells_fit(spec)
+
+
+def test_every_workload_file_matches_its_entry():
+    check_workloads(SPEC, BENCH)
 
 
 def test_end_to_end_metrics():
@@ -134,6 +153,68 @@ def check_per_layer(spec, metric_dir):
 
 def test_per_layer_metrics_have_a_reader_and_an_arrow():
     check_per_layer(SPEC, BENCH / "layer_metrics")
+
+
+def named_once_in_order(spec, names) -> list:
+    """The `per_layer` entries of `names`, looked up by name: each there
+    once, and in `names`' order among themselves. Where in the list they
+    stand is no rule: a later PR puts its entries at the end."""
+    names = list(names)
+    found = [m for m in spec["per_layer"] if m["name"] in names]
+    assert [m["name"] for m in found] == names
+    return found
+
+
+def _strings(value):
+    if isinstance(value, str):
+        yield value
+    elif isinstance(value, dict):
+        for v in value.values():
+            yield from _strings(v)
+    elif isinstance(value, list):
+        for v in value:
+            yield from _strings(v)
+
+
+def reads_a_kernels_own_events(held: dict) -> bool:
+    """Whether a metric's file reads the events of a kernel by its own
+    name: its layer is `kernels`, or its reducer is `trace_ops` or a
+    `trace_roofline*` over a pattern anchored at `%name`, the form in
+    which only the chip's traces print an instruction (the all-reduce
+    readings' `^%?all-reduce` finds the CPU's too)."""
+    if held["layer"] == "kernels":
+        return True
+    return (held["reducer"] == "trace_ops"
+            or held["reducer"].startswith("trace_roofline")) and any(
+        re.match(r"\^%\w", text) for text in _strings(held["args"]))
+
+
+def may_lack_on_the_cpu(names, metric_dir=BENCH / "layer_metrics") -> set:
+    """Those of the metrics `names` that a CPU rehearsal's line may lack:
+    a rehearsal runs no TPU kernel, so what reads a kernel's own events
+    finds nothing there. Every other metric a cell expects has to be in
+    its rehearsal's line: the rest of the per-layer ones, and the
+    end-to-end ones, which have no reader's file."""
+    files = {n: Path(metric_dir) / f"{n}.json" for n in names}
+    return {n for n, path in files.items()
+            if path.is_file() and reads_a_kernels_own_events(load(path))}
+
+
+def test_only_what_reads_a_kernel_may_be_absent_from_a_rehearsal():
+    may = may_lack_on_the_cpu(m["name"] for m in SPEC["per_layer"])
+    assert {"flash_ms_per_step", "flash_roofline_pct",
+            "ssm_conv_bwd_roofline"} <= may
+    assert not {"ssm_conv_ms_per_step", "allreduce_ms_per_step",
+                "step_busy_ms", "iter_ms_max"} & may
+    # by what the file reads, not by its name
+    scope = load(BENCH / "layer_metrics" / "ssm_conv_ms_per_step.json")
+    assert not reads_a_kernels_own_events(scope)
+    assert reads_a_kernels_own_events({**scope, "layer": "kernels"})
+    assert reads_a_kernels_own_events({
+        **scope, "reducer": "trace_ops",
+        "args": {"pattern": "^%ssd_scan(\\.\\d+)? = "}})
+    assert not reads_a_kernels_own_events({
+        **scope, "reducer": "trace_ops", "args": {"pattern": "^%?all-gather"}})
 
 
 def test_a_later_metric_with_a_list_of_its_own_needs_no_edit(tmp_path):

@@ -73,6 +73,68 @@ def test_flash_kernel_counts():
     assert flops.roofline_seconds(1.0, 819e9, peak) == (1.0, "bytes")
 
 
+@pytest.mark.parametrize("kind,products,tensors", [
+    # products over the query-key size and over the value size; tensors
+    # moved at either: worked out in flops.py's comments, again here
+    ("fwd", 192 + 128, 2 * 192 + 2 * 128),          # QK', PV; q k, v o
+    ("dkv", 2 * 192 + 2 * 128, 3 * 192 + 3 * 128),  # QK' dK, dP dV
+    ("dq", 2 * 192 + 128, 3 * 192 + 2 * 128)])      # QK' dQ, dP
+def test_flash_counts_with_two_head_sizes_by_hand(kind, products, tensors):
+    """A latent-attention call at [1, 8192, 32, 192 / 128], full causal:
+    whatever kernel runs it, this is the work."""
+    kw = dict(batch=1, seq_len=8192, n_head=32, head_dim=192, v_head_dim=128)
+    pairs = 32 * 8192 * 4096.5
+    assert flops.flash_call_flops(kind, **kw) == 2 * products * pairs
+    assert flops.flash_call_bytes(kind, **kw) == tensors * 8192 * 32 * 2
+    # read with the query-key size for both, the same call counts
+    # 12.5-20% more operations than it has
+    one = dict(kw, v_head_dim=None)
+    over = flops.flash_call_flops(kind, **one) / flops.flash_call_flops(
+        kind, **kw)
+    assert over == {"fwd": 1.2, "dkv": 1.2, "dq": 1.125}[kind]
+
+
+@pytest.mark.parametrize("kind", ["fwd", "dkv", "dq"])
+@pytest.mark.parametrize("head_dim,window", [(128, 4096), (64, 0)])
+def test_equal_head_sizes_count_as_before_to_the_last_bit(kind, head_dim,
+                                                          window):
+    """The parent's expressions, written out: `flash_roofline_pct` in the
+    six cells holds only while these do not move."""
+    kw = dict(batch=1, seq_len=8192, n_head=32, head_dim=head_dim)
+    products = {"fwd": 2, "dkv": 4, "dq": 3}[kind]
+    tensors = {"fwd": 4, "dkv": 6, "dq": 5}[kind]
+    pairs = 1 * 32 * 8192 * flops.mean_visible_keys(8192, window)
+    for v in (None, head_dim):
+        assert flops.flash_call_flops(kind, window=window, v_head_dim=v,
+                                      **kw) == products * 2 * head_dim * pairs
+        assert flops.flash_call_bytes(kind, v_head_dim=v, **kw) == \
+            tensors * 1 * 8192 * 32 * head_dim * 2
+
+
+def test_ssm_conv_bwd_counts_by_hand():
+    """Granite's call, [1, 4352, 8192] in bfloat16 with four taps: the
+    input and the cotangent in, the input's gradient out (213.9 MB,
+    PERF.md's figure), taps and bias in and their gradients out in
+    float32 (0.17 MB); 33 operations an entry. The bytes bound it."""
+    b, c, t, k = 1, 4352, 8192, 4
+    assert flops.ssm_conv_bwd_bytes(b, c, t, k) == \
+        3 * 4352 * 8192 * 2 + (4 + 1 + 4 + 1) * 4352 * 4 == 214_083_584
+    assert 3 * 4352 * 8192 * 2 == 213_909_504
+    # pre-activation 2k, silu' 8, times dy 1, dx 2k - 1, dtaps 2k, dbias 1
+    assert flops.ssm_conv_bwd_flops(b, c, t, k) == \
+        (8 + 8 + 1 + 7 + 8 + 1) * 4352 * 8192 == 33 * 4352 * 8192
+    peak = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    seconds, bound = flops.roofline_seconds(
+        flops.ssm_conv_bwd_flops(b, c, t, k),
+        flops.ssm_conv_bwd_bytes(b, c, t, k), peak)
+    assert bound == "bytes" and seconds == pytest.approx(0.2614e-3, rel=1e-3)
+    # the hybrid's call, two rows of 1280 channels
+    assert flops.ssm_conv_bwd_bytes(2, 1280, 8192, 4) == \
+        3 * 2 * 1280 * 8192 * 2 + 10 * 1280 * 4
+    assert flops.ssm_conv_bwd_bytes(1, 8, 16, 4, itemsize=4) == \
+        3 * 8 * 16 * 4 + 10 * 8 * 4
+
+
 class Weights100:
     @staticmethod
     def matmul_weights(s):

@@ -12,6 +12,8 @@ from benchmarks.reducers import (
     compile_events_window, flight_once, flight_worst,
 )
 
+from test_bm_data import named_once_in_order
+
 SPEC = json.loads((run.ROOT / "BENCHMARK.json").read_text())
 HOST_METRICS = {
     "setup_trainer_init_s": "s", "setup_state_init_s": "s",
@@ -130,10 +132,8 @@ def test_the_loop_metrics_read_their_flight_fields():
 
 
 def test_the_ten_come_to_every_cell_and_move_what_the_issue_says():
-    by_name = {m["name"]: m for m in SPEC["per_layer"]}
-    assert [m["name"] for m in SPEC["per_layer"][-10:]] == list(HOST_METRICS)
-    for name, unit in HOST_METRICS.items():
-        m = by_name[name]
+    ten = named_once_in_order(SPEC, HOST_METRICS)
+    for m, (name, unit) in zip(ten, HOST_METRICS.items()):
         assert m["unit"] == unit and "workloads" not in m
         assert m["better"] == "lower"
         assert m["moves"] == ("setup_s" if name.startswith("setup_")

@@ -20,6 +20,7 @@ from pytorch_distributed_template_tpu import models  # noqa: F401
 from pytorch_distributed_template_tpu.config import MODELS
 from pytorch_distributed_template_tpu.engine.losses import lm_cross_entropy
 
+from test_bm_data import may_lack_on_the_cpu
 from test_bm_reference import flat_of, nested
 from test_bm_run import SPEC, rehearse
 
@@ -105,7 +106,9 @@ def test_init_rules_give_the_scan_a_carry_that_matters():
 NEW_SCOPES = {"ssm_scan_ms_per_step", "moe_route_ms_per_step",
               "moe_experts_ms_per_step", "moe_shared_ms_per_step"}
 NEW_COUNTERS = {"moe_pairs_per_step", "moe_load_max_over_mean"}
-ONLY_ON_THE_CHIP = {"flash_ms_per_step", "flash_roofline_pct"}
+# PR 40: the convolution's scope, in both hybrid cells' lines and in no
+# dense cell's; its backward kernel's roofline share, on the chip alone
+CONV = {"ssm_conv_ms_per_step", "ssm_conv_bwd_roofline"}
 
 
 @pytest.mark.parametrize("workload,trace", [
@@ -118,7 +121,7 @@ def test_a_rehearsal_of_a_new_cell_ends_in_a_valid_line(workload, trace):
     assert code == run.EXIT_REHEARSED != 0
     expected = run.expected_metrics(SPEC, workload, bool(trace))
     absent = {n for n in expected if n not in line["metrics"]}
-    assert absent <= ONLY_ON_THE_CHIP
+    assert absent <= may_lack_on_the_cpu(expected)
     lastline.validate(line, {n: u for n, u in expected.items()
                              if n not in absent}, bool(trace))
     assert line["correct"] is True and line["failed"] == 0
@@ -132,3 +135,7 @@ def test_a_rehearsal_of_a_new_cell_ends_in_a_valid_line(workload, trace):
         assert line["metrics"]["moe_load_max_over_mean"]["value"] >= 1.0
     else:
         assert not new
+    assert (CONV <= set(expected)) == bool(trace and workload == CELL)
+    assert ("ssm_conv_ms_per_step" in line["metrics"]) == bool(
+        trace and workload == CELL)
+    assert "ssm_conv_bwd_roofline" not in line["metrics"]    # no TPU here

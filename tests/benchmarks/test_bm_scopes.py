@@ -209,6 +209,57 @@ def test_cutting_keeps_whole_steps_and_their_metadata(capture, tmp_path):
                          "--steps", "2"])
 
 
+GRANITE = (BENCH / "fixtures"
+           / "trace_chip_pr40_granite4_h_micro_l10_seq8k.xplane.pb.gz")
+# what the traced run printed over its eight steps (my chip run, PR 40:
+# `granite4_h_micro_l10.seq8k`, seed 2147496001, this PR's tree)
+GRANITE_LINE = {
+    "flash_ms_per_step": 18.89671025, "flash_roofline_pct": 33.23172842980275,
+    "step_busy_ms": 438.220851625, "ssm_scan_ms_per_step": 80.932042875,
+    "ssm_intra_ms_per_step": 39.298901875,
+    "ssm_state_ms_per_step": 24.846685375,
+    "ssm_conv_ms_per_step": 14.4888355,
+    "ssm_conv_bwd_roofline": 42.238451449386005}
+
+
+def test_the_convolutions_readings_on_a_hybrid_steps_chip_trace(
+        tmp_path_factory):
+    """One step of the eight traced, cut by `cut_xplane.py --first 2
+    --steps 1`: the convolution's scope, its backward kernel's share of
+    the bytes roofline, and the scan's whole scope above its parts."""
+    path = tmp_path_factory.mktemp("granite") / "chip.xplane.pb"
+    path.write_bytes(gzip.decompress(GRANITE.read_bytes()))
+    trace = tr.Trace(tr.load_xplane(path), "train_step")
+    assert (trace.device, trace.steps) == ("/device:TPU:0", 1)
+    peaks = json.loads((BENCH / "peaks.json").read_text())
+    sizes = json.loads((BENCH / "configs" / "granite4_h_micro_l10.json")
+                       .read_text())["sizes"]
+    facts = run.Facts(sizes=sizes, peak=peaks["TPU v5 lite"],
+                      setup_records=[], window_records=[], compile_events=[],
+                      trace=trace, capture=path)
+    got = {m: read(facts, m) for m in GRANITE_LINE}
+    for m, printed in GRANITE_LINE.items():
+        assert got[m] == pytest.approx(printed, rel=5e-4), m
+    # nine layers' kernels, each [1, 4352, 8192] with four taps: 0.2614 ms
+    # of bytes against 0.619 ms a call
+    kernels = [e for e in trace.ops if e[0].startswith("%ssm_conv_bwd")]
+    assert len(kernels) == 9
+    assert all(" = (bf16[1,4352,8192]" in n and " f32[4352,4]{" in n
+               for n, _, _ in kernels)
+    assert sum(d for _, _, d in kernels) / 9e6 == pytest.approx(0.619, rel=2e-3)
+    assert got["ssm_conv_bwd_roofline"] == pytest.approx(
+        100 * 0.2614 / 0.619, rel=2e-3) and got["ssm_conv_bwd_roofline"] < 105
+    # the kernel is 5.6 of the scope's 14.5 ms; the scope lies in the scan's
+    assert 9 * 0.619 < got["ssm_conv_ms_per_step"] < 15
+    parts = sum(got[m] for m in ("ssm_intra_ms_per_step",
+                                 "ssm_state_ms_per_step",
+                                 "ssm_conv_ms_per_step"))
+    assert parts < got["ssm_scan_ms_per_step"] < parts + 5
+    # the flash kernels beside it, head 64 at 8192 positions without a band
+    assert "window" not in facts.sizes
+    assert 33 < got["flash_roofline_pct"] < 33.5
+
+
 @pytest.mark.parametrize("workload", [w["name"] for w in SPEC["workloads"]])
 def test_a_traced_rehearsal_reports_every_new_reading(workload):
     args = argparse.Namespace(workload=workload, seed=2**31 + 25,

@@ -12,6 +12,7 @@ import pytest
 from benchmarks import trace_reduce as tr
 
 FIXTURES = Path(__file__).resolve().parents[2] / "benchmarks" / "fixtures"
+SPEC = json.loads((FIXTURES.parents[1] / "BENCHMARK.json").read_text())
 
 
 def planes_of(raw: dict) -> dict:
@@ -249,6 +250,10 @@ def test_chip_trace_kernels_and_gap_names(chip):
     assert read_metric(facts, "flash_roofline_pct") == pytest.approx(
         RUN_LINE["flash_roofline_pct"], rel=1e-4)
     assert 0 < read_metric(facts, "flash_roofline_pct") <= 100
+    # to the last bit what the parent's reader (one head size, PR 39) made
+    # of these three steps
+    assert read_metric(facts, "flash_ms_per_step") == 30.378231
+    assert read_metric(facts, "flash_roofline_pct") == 62.01264262406572
     # the readings PR 31's ledger line holds, made by what a call returns
     assert read_metric(
         facts, "flash_ms_per_step",
@@ -302,26 +307,42 @@ def test_another_kernel_in_the_trace_changes_neither_flash_reading():
 
 
 def test_a_step_without_flash_kernels_reports_what_it_has_and_no_more():
-    """A later cell whose step calls no flash kernel (a stack of scans):
-    the metrics have no `workloads` list, their readers find nothing, and
-    the harness leaves them out of the line on the chip as in a rehearsal:
-    no failed run, never a 0."""
+    """The flash metrics carry a `workloads` list (PR 40). A later cell
+    whose step calls no flash kernel (a stack of scans, or attention
+    kernels under names of their own) is not on it: it neither expects
+    nor reports them, whatever its trace holds. A listed cell reports
+    both; and a listed cell whose trace holds no `%flash_*` event still
+    finds nothing to read and leaves them out of the line: no failed run,
+    never a 0."""
     from benchmarks import lastline, run
 
-    spec = json.loads((FIXTURES.parents[1] / "BENCHMARK.json").read_text())
     kept = ("flash_ms_per_step", "flash_roofline_pct", "step_busy_ms")
+    spec = json.loads(json.dumps(SPEC))
     spec["per_layer"] = [m for m in spec["per_layer"] if m["name"] in kept]
-    assert not any("workloads" in m for m in spec["per_layer"])
+    by_name = {m["name"]: m for m in spec["per_layer"]}
+    assert "workloads" not in by_name["step_busy_ms"]
+    on_the_list = by_name["flash_ms_per_step"]["workloads"]
+    assert on_the_list == by_name["flash_roofline_pct"]["workloads"]
+    # the six cells PR 39's ledger lines carry both readings in
+    assert {"mistral7b_l2.seq8k", "gpt2_large.seq1k", "mistral7b_l2.seq8k_dp4",
+            "nemotron3_super_l11.seq8k", "mistral7b_l2.seq4k",
+            "granite4_h_micro_l10.seq8k"} <= set(on_the_list)
+    listed = on_the_list[0]
     facts, said = made_up_facts(OTHERS), []
+    assert set(run.expected_metrics(spec, "scans_l8.seq8k", True)) == {
+        "step_busy_ms"}
     values = run.layer_values(spec, "scans_l8.seq8k", facts, said.append)
+    assert set(values) == {"step_busy_ms"} and not said
+    assert set(run.expected_metrics(spec, listed, True)) == set(kept)
+    values = run.layer_values(spec, listed, facts, said.append)
     assert set(values) == set(kept) and not said
     facts.trace.ops = [e for e in facts.trace.ops if "%flash_" not in e[0]]
-    values = run.layer_values(spec, "scans_l8.seq8k", facts, said.append)
+    values = run.layer_values(spec, listed, facts, said.append)
     assert set(values) == {"step_busy_ms"} and values["step_busy_ms"] > 0
     assert said == [f"nothing to read for {n}: left out of the line"
                     for n in kept[:2]]
     expected = {n: u for n, u in run.expected_metrics(
-        spec, "scans_l8.seq8k", True).items() if n in values}
+        spec, listed, True).items() if n in values}
     line = json.loads(lastline.build(
         correct=True, attempted=3, failed=0, values=values,
         expected=expected, trace=True, device={
@@ -329,6 +350,77 @@ def test_a_step_without_flash_kernels_reports_what_it_has_and_no_more():
             "memory_peak_bytes": 1, "busy_s": facts.trace.busy_s,
             "window_s": facts.trace.window_s}))
     assert set(line["metrics"]) == {"step_busy_ms"}
+
+
+# a flash-family call whose value head size is another than its query-key
+# one, under a name of its own: q and k at 192, v and what it returns at 128
+TWO_SIZES = (7e6, '%mla_fwd.3 = (bf16[32,8192,128]{2,1,0:T(8,128)(2,1)}, '
+             'f32[32,8192,1]{2,1,0:T(8,128)}) custom-call(bf16[32,8192,192]'
+             '{2,1,0:T(8,128)(2,1)S(1)} %fusion.172, bf16[32,8192,192]{2,1,0} '
+             '%bitcast.639, bf16[32,8192,128]{2,1,0:T(8,128)(2,1)} '
+             '%bitcast.640), custom_call_target="tpu_custom_call"')
+VALUE_OPERAND = (r"custom-call\((?:bf16\[[\d,]+\]\S* %\S+, ){2}"
+                 r"bf16\[\d+,\d+,(\d+)\]")
+
+
+def test_the_roofline_reads_a_second_head_size_where_it_is_told_to():
+    from benchmarks import flops
+
+    facts = made_up_facts([TWO_SIZES])
+    facts.sizes = {}                    # full causal
+    mla = {"kernels": {"fwd": r"^%mla_fwd(\.\d+)? = "}}
+    shape = dict(batch=1, seq_len=8192, n_head=32, head_dim=192)
+    least = {v: flops.roofline_seconds(
+        flops.flash_call_flops("fwd", v_head_dim=v, **shape),
+        flops.flash_call_bytes("fwd", v_head_dim=v, **shape),
+        facts.peak)[0] for v in (None, 128)}
+    two = read_metric(facts, "flash_roofline_pct", **mla,
+                      value_operand=VALUE_OPERAND)
+    assert two == pytest.approx(100 * least[128] / 7e-3, rel=1e-12)
+    assert 45 < two < 55
+    # with the first operand's size for both, a fifth over the true share
+    one = read_metric(facts, "flash_roofline_pct", **mla)
+    assert one == pytest.approx(100 * least[None] / 7e-3, rel=1e-12)
+    assert one / two == pytest.approx(1.2, rel=1e-9)
+    # the three flash kernels beside it read as without it
+    flash = read_metric(made_up_facts(), "flash_roofline_pct")
+    assert read_metric(made_up_facts([TWO_SIZES]),
+                       "flash_roofline_pct") == flash
+    with pytest.raises(ValueError, match="finds no value head size"):
+        read_metric(made_up_facts(), "flash_roofline_pct",
+                    value_operand=VALUE_OPERAND)    # two operands printed
+
+
+# the convolution's backward as the chip's traces print it (my chip run,
+# PR 40, granite's step): the projection three times, the cotangent twice,
+# taps and bias
+CONV_BWD = (0.619e6, '%ssm_conv_bwd.9 = (bf16[1,4352,8192]{2,1,0:T(8,128)'
+            '(2,1)}, f32[1,4352,128]{2,1,0:T(8,128)}) custom-call(bf16[1,8512,'
+            '8192]{2,1,0:T(8,128)(2,1)} %bitcast.5323, bf16[1,8512,8192]{2,1,0'
+            ':T(8,128)(2,1)} %bitcast.5324, bf16[1,8512,8192]{2,1,0:T(8,128)'
+            '(2,1)} %bitcast.5325, bf16[1,4352,8192]{2,1,0:T(8,128)(2,1)} '
+            '%maximum_bitcast_fusion, bf16[1,4352,8192]{2,1,0:T(8,128)(2,1)} '
+            '%maximum_bitcast_fusion, f32[4352,4]{1,0:T(8,128)S(1)} '
+            '%bitcast.5686, f32[4352,1]{1,0:T(8,128)S(1)} '
+            '%broadcast_in_dim.165), custom_call_target="tpu_custom_call"')
+
+
+def test_the_convolutions_kernel_reads_its_share_of_the_bytes_roofline():
+    facts = made_up_facts([CONV_BWD] * 9)
+    share = read_metric(facts, "ssm_conv_bwd_roofline")
+    # 214.08 MB at 819 GB/s is 0.2614 ms, of 0.619: PERF.md's 42%
+    assert share == pytest.approx(100 * 214_083_584 / 819e9 / 0.619e-3,
+                                  rel=1e-12)
+    assert 42 < share < 42.5
+    # another kernel's events change nothing; none of its own, nothing
+    assert read_metric(made_up_facts([CONV_BWD] * 9 + OTHERS),
+                       "ssm_conv_bwd_roofline") == share
+    assert read_metric(made_up_facts(OTHERS), "ssm_conv_bwd_roofline") is None
+    assert read_metric(facts, "flash_roofline_pct") == \
+        read_metric(made_up_facts(), "flash_roofline_pct")
+    with pytest.raises(ValueError, match="finds no taps a channel") as err:
+        read_metric(facts, "ssm_conv_bwd_roofline", taps=r"f32\[(\d+)\]\{")
+    assert "%ssm_conv_bwd.9 = (bf16[1,4352,8192]" in str(err.value)
 
 
 def test_an_event_the_roofline_cannot_read_is_named_in_the_error():
@@ -413,6 +505,8 @@ def test_four_chip_trace_reads_the_bare_all_reduces_only(dp4):
         30.64, rel=1e-3)
     assert read_metric(facts, "flash_roofline_pct") == pytest.approx(
         61.48, rel=1e-3)
+    assert read_metric(facts, "flash_ms_per_step") == 30.641886
+    assert read_metric(facts, "flash_roofline_pct") == 61.47906113071222
 
 
 def test_cutting_keeps_one_device_of_a_multi_chip_capture(dp4, tmp_path):
