@@ -9,5 +9,6 @@ from .nemotron_h import NemotronHLM, nemotron_h, tiny_nemotron_h
 from .granite_hybrid import (
     GraniteHybridLM, granite_hybrid, tiny_granite_hybrid,
 )
+from .solar_open2 import SolarOpen2LM, solar_open2, tiny_solar_open2
 from .transformer import TransformerLM, gpt2, tiny_lm
 from .vit import ViT, vit

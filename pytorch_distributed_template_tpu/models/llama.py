@@ -133,6 +133,9 @@ class LlamaAttention(nn.Module):
     # path and kernel fixes head_dim ** -0.5, so q is scaled by what is
     # left, multiplier * sqrt(head_dim), in q_proj's epilogue
     attention_multiplier: float = 0.0
+    # the context times sigmoid(g_proj(x)), a gate per output channel,
+    # before o_proj (gated attention, Qiu et al. arXiv:2505.06708)
+    out_gate: bool = False
 
     @nn.compact
     def __call__(self, x, positions, train: bool, decode: bool = False,
@@ -211,6 +214,11 @@ class LlamaAttention(nn.Module):
                 ctx = multihead_attention(q, k, v, causal=True,
                                           window=self.window)
         ctx = ctx.reshape(b, t, self.n_head * hd)
+        if self.out_gate:
+            gate = checkpoint_name(
+                dense(self.n_head * hd, "g_proj")(x), "attn_gate")
+            ctx = (ctx.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(ctx.dtype)
         return checkpoint_name(dense(self.d_model, "o_proj")(ctx),
                                "attn_proj")
 

@@ -42,6 +42,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config.registry import MODELS
 from ..observability.trace import say_once
+from .llama import SwiGLU
 
 logger = logging.getLogger(__name__)
 
@@ -302,6 +303,30 @@ def held_experts(x, weight, up, down):
                    * out.astype(jnp.float32), axis=0)
 
 
+def sow_counter(module, name: str, value) -> None:
+    """One of a model's ``step_counters``, summed over the layers that sow
+    it (engine/steps.py carries the sums in the step's metrics)."""
+    module.sow("counters", name, jnp.asarray(value, jnp.float32),
+               reduce_fn=lambda a, b: a + b,
+               init_fn=lambda: jnp.zeros((), jnp.float32))
+
+
+def held_gated_experts(x, weight, gate, up, down):
+    """``held_experts`` for experts of three matrices: ``gate``,
+    ``up [E, D, F]`` and ``down [E, F, D]``. Returns ``[S, D]`` in float32:
+    ``sum_e weight[s, e] * (silu(x[s] @ gate[e]) * (x[s] @ up[e])) @
+    down[e]``, every held expert over every token. A token's weight is
+    one scalar a row of the expert's activation, so it is laid on the
+    activation and the third product contracts over experts and
+    features at once, summing in float32 as it goes: no ``[E, S, D]``
+    result is made (537 MB at 8 experts, 8192 tokens, 4096 wide)."""
+    act = jax.nn.silu(jnp.einsum("sd,edf->esf", x, gate.astype(x.dtype))) \
+        * jnp.einsum("sd,edf->esf", x, up.astype(x.dtype))
+    act = act * weight.T[:, :, None].astype(x.dtype)
+    return jnp.einsum("esf,efd->sd", act, down.astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+
+
 class ExpertLayer(nn.Module):
     """Routed experts as a chip holds them, with what today's sparse
     models put around them. Each part is there or not by its argument.
@@ -336,6 +361,11 @@ class ExpertLayer(nn.Module):
     uniform routing and 42.2 ms when three held experts take every
     token, and three times the memory. PERF.md, PR 33.)
 
+    ``gated`` makes every expert three matrices,
+    ``(silu(l @ gate_e) * (l @ up_e)) @ down_e`` (``held_gated_experts``),
+    and the shared expert a ``SwiGLU``; the routing, the mask and the
+    counters are the same.
+
     ``shared_d_ff`` adds one expert every token takes. Counters of the
     step, sown under ``counters`` (engine/steps.py carries them):
     ``moe_pairs_here``, ``moe_load_max_over_mean`` over the experts held
@@ -352,6 +382,7 @@ class ExpertLayer(nn.Module):
     router: str = "sigmoid"
     selection_bias: bool = False
     scale: float = 1.0
+    gated: bool = False             # three matrices an expert, SiLU gate
     n_layers: int = 1               # expert layers in the model (counters)
     dtype: Any = jnp.float32
 
@@ -418,6 +449,9 @@ class ExpertLayer(nn.Module):
                     dense(self.latent, name="latent_down")(tokens),
                     "moe_latent")
         width = self.latent or d
+        if self.gated:
+            gate = self.param("experts_gate", _init(0.02),
+                              (n_held, width, self.d_ff), jnp.float32)
         up = self.param("experts_up", _init(0.02),
                         (n_held, width, self.d_ff), jnp.float32)
         down = self.param("experts_down", _init(0.02),
@@ -427,19 +461,27 @@ class ExpertLayer(nn.Module):
             logger, "moe/dispatch",
             dict(tokens=s, held=n_held, routed=self.n_routed, top_k=k,
                  expected=s * k * n_held / self.n_routed,
-                 rows=s * min(k, n_held)),
+                 rows=s * min(k, n_held),
+                 **({"experts": "gated"} if self.gated else {})),
             "moe/dispatch: %(tokens)d tokens, %(held)d of %(routed)d experts "
             "held, %(top_k)d a token: %(expected).0f pairs a layer a step at "
             "uniform routing, room for %(rows)d, which no routing passes: "
-            "every held expert over every token")
+            "every held expert over every token"
+            + (", three matrices an expert" if self.gated else ""))
         with jax.named_scope("moe_experts"):
-            routed = held_experts(tokens, weight, up, down)
+            if self.gated:
+                routed = held_gated_experts(tokens, weight, gate, up, down)
+            else:
+                routed = held_experts(tokens, weight, up, down)
 
         with jax.named_scope("moe_shared"):
             out = routed.astype(self.dtype)
             if self.latent:
                 out = dense(d, name="latent_up")(out)
-            if self.shared_d_ff:
+            if self.shared_d_ff and self.gated:
+                out = out + SwiGLU(d, self.shared_d_ff, self.dtype,
+                                   name="shared")(xc)
+            elif self.shared_d_ff:
                 mid = checkpoint_name(
                     dense(self.shared_d_ff, name="shared_up")(xc),
                     "moe_shared_up")
@@ -448,9 +490,7 @@ class ExpertLayer(nn.Module):
         return out.reshape(b, t, d)
 
     def _count(self, name, value):
-        self.sow("counters", name, jnp.asarray(value, jnp.float32),
-                 reduce_fn=lambda a, b: a + b,
-                 init_fn=lambda: jnp.zeros((), jnp.float32))
+        sow_counter(self, name, value)
 
 
 @MODELS.register("MoeLM")

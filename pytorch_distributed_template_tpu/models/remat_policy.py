@@ -48,6 +48,13 @@ mixer a layer, by a pattern) names besides, each where its mixer makes it:
   shared expert's first product. Each contracts over ``d_model`` like
   ``qkv_proj``, and they stand with the projections, the widest last.
 
+A stack of delta-rule and gated-attention layers, each with gated experts
+(models/solar_open2.py), names three more, each beside its like:
+``attn_gate`` (the output gate's projection, behind ``qkv_proj``),
+``kda_in_proj`` (a KDA mixer's three projections in front of their
+convolutions) and ``kda_out_proj`` (its ``o_proj``), behind ``attn_proj``;
+its shared expert is a ``SwiGLU`` and makes ``mlp_gate`` and ``mlp_up``.
+
 Neither the scan's output nor the routed experts' has a name: their
 backward needs what lies inside them, so keeping the result would spare
 next to nothing. The bytes are reckoned by kind of block and summed over
@@ -83,7 +90,10 @@ PREFERENCE: Tuple[Tuple[str, ...], ...] = (
     ("attn_out", "attn_lse"),
     ("moe_router",),
     ("qkv_proj",),
+    ("attn_gate",),
     ("attn_proj",),
+    ("kda_in_proj",),
+    ("kda_out_proj",),
     ("ssm_in_proj",),
     ("moe_latent",),
     ("mlp_gate",),

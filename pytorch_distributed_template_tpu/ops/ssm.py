@@ -93,8 +93,8 @@ def _conv_pre(xbc, taps, bias):
     float32 pre-activation: widened inside the one fusion a compiler
     makes of it."""
     xs = _shifted(xbc, 1 - taps.shape[0], 1)
-    return xs, bias + sum(taps[i] * x.astype(jnp.float32)
-                          for i, x in enumerate(xs))
+    pre = sum(taps[i] * x.astype(jnp.float32) for i, x in enumerate(xs))
+    return xs, pre if bias is None else bias + pre
 
 
 def _conv_bwd_xla(xbc, taps, bias, dy):
@@ -105,7 +105,8 @@ def _conv_bwd_xla(xbc, taps, bias, dy):
     dx = sum(taps[i] * d for i, d in enumerate(reversed(_shifted(dpre, 0, k))))
     dtaps = jnp.stack([(dpre * x.astype(jnp.float32)).sum(axis=(0, 1))
                        for x in xs])
-    return dx.astype(xbc.dtype), dtaps, dpre.sum(axis=(0, 1))
+    return dx.astype(xbc.dtype), dtaps, (
+        None if bias is None else dpre.sum(axis=(0, 1)))
 
 
 # -- the backward as a kernel: one pass over the input and the cotangent ----
@@ -277,8 +278,9 @@ def causal_conv_silu(zxd, taps, bias, start: int = 0):
     """``silu(bias + sum_i taps[i] * x[t - (k - 1) + i])`` a channel, with
     ``x`` the channels ``[start, start + C)`` of ``zxd [B, T, W]``: the
     depthwise causal convolution with ``taps [k, C]`` and ``bias [C]``
-    (both float32), each row of the batch from zeros, in ``zxd``'s type
-    with float32 inside.
+    (both float32; ``bias`` ``None``: a convolution without one, which
+    then has no gradient for it either), each row of the batch from
+    zeros, in ``zxd``'s type with float32 inside.
 
     The forward is one fusion over the input as it lies (the shifts are
     taken on its own type). The backward rule keeps ``zxd``, ``taps`` and
@@ -302,7 +304,11 @@ def _conv_silu_bwd(start, kept, dy):
     zxd, taps, bias = kept
     c = taps.shape[1]
     if flash._on_tpu():
-        dx, dtaps, dbias = _conv_bwd_pallas(zxd, start, taps, bias, dy)
+        # the kernel adds a bias: zeros made here are no leaf of a model
+        dx, dtaps, dbias = _conv_bwd_pallas(
+            zxd, start, taps, jnp.zeros((c,), jnp.float32)
+            if bias is None else bias, dy)
+        dbias = None if bias is None else dbias
     else:
         dx, dtaps, dbias = _conv_bwd_xla(zxd[..., start:start + c], taps,
                                          bias, dy)
@@ -333,10 +339,12 @@ def sharded_conv_silu(zxd, taps, bias, start: int, mesh):
     if zxd.shape[0] % math.prod(mesh.shape[a] for a in over):
         over = ()
     rows = P(over or None, None, None)
+    whole = (taps,) if bias is None else (taps, bias)
     return shard_map(
-        lambda z, w, b: causal_conv_silu(z, w, b, start), mesh=mesh,
-        in_specs=(rows, P(), P()), out_specs=rows, check_vma=False,
-    )(zxd, taps, bias)
+        lambda z, w, b=None: causal_conv_silu(z, w, b, start), mesh=mesh,
+        in_specs=(rows,) + (P(),) * len(whole), out_specs=rows,
+        check_vma=False,
+    )(zxd, *whole)
 
 
 def _segment_decays(a):

@@ -698,7 +698,10 @@ def test_hybrid_step_lowers_and_fits_for_v5e(topo, monkeypatch,
         assert re.search(rf"%{kernel}(\.\d+)? = ", text)
     (policy,) = _said("remat/policy")
     assert policy["blocks"] == 11
-    assert policy["names"].startswith("attn_out,attn_lse,moe_router")
+    # the names it was told before the delta-rule stack brought three more
+    assert policy["names"] == ("attn_out,attn_lse,moe_router,qkv_proj,"
+                               "attn_proj,ssm_in_proj,moe_latent,"
+                               "moe_shared_up,attn_qkv")
     # the state's init traces one sequence, the step two
     dispatch = [d for d in _said("moe/dispatch") if d["tokens"] == 16384]
     assert dispatch and all(d["rows"] == 131072 and d["expected"] == 5632
@@ -747,7 +750,8 @@ def test_granite_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     # parameters and both moments; the gradient is the backward's own
     assert abs(policy["held_bytes"] - 772_160_448 * 12) < 64
     kept = policy["names"].split(",")
-    assert kept[:2] == ["attn_out", "attn_lse"]
+    assert kept == ["attn_out", "attn_lse", "qkv_proj", "attn_proj",
+                    "ssm_in_proj", "mlp_gate"]
     assert set(kept) <= {"attn_out", "attn_lse", "qkv_proj", "attn_proj",
                          "ssm_in_proj", "mlp_gate", "mlp_up", "attn_qkv"}
     chunk = arch["args"]["ssm_chunk"]
@@ -781,6 +785,62 @@ def test_granite_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     total = (m.argument_size_in_bytes + m.output_size_in_bytes
              + m.temp_size_in_bytes - m.alias_size_in_bytes)
     print(f"granite step: {total} bytes compiled, policy {policy}")
+    assert total < V5E_BYTES_LIMIT - (1 << 30)
+
+
+def test_delta_rule_step_lowers_and_fits_for_v5e(topo, monkeypatch,
+                                                 fresh_records):
+    """`solar_open2_l4.seq8k`'s step (1 x 8192 on one chip): three KDA
+    blocks and a gated attention block, each with 8 held of 320 gated
+    experts over every token, compile for the v5e with plain XLA for the
+    scan (its triangular system, its loop over 128 chunks), the three
+    flash kernels at 8 heads on one key-value head and nine convolution
+    kernels without a bias; the checkpoint policy reckons both kinds of
+    block and keeps every name they make; the step stays 1 GiB under the
+    chip's `bytes_limit` with 840.9 M parameters held."""
+    import json
+    from pathlib import Path
+
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.parallel import build_mesh
+
+    arch = json.loads((
+        Path(__file__).resolve().parent.parent / "benchmarks" / "configs"
+        / "solar_open2_l4.json").read_text())["experiment"]["arch"]
+    mesh = build_mesh({"data": 1}, devices=topo.devices[:1])
+    _, compiled = _compiled_train_step(
+        MODELS.get(arch["type"])(**arch["args"]), mesh, 1, 8192, monkeypatch)
+    text = compiled.as_text()
+    assert "ragged-dot" not in text and "triangular-solve" not in text
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert len(re.findall(rf"%{kernel}(\.\d+)? = ", text)) == 1
+    assert len(re.findall(r"%ssm_conv_bwd(\.\d+)? = ", text)) == 9
+    assert text.count("tpu_custom_call") == 12
+    (policy,) = _said("remat/policy")
+    assert policy["blocks"] == 4
+    assert abs(policy["held_bytes"] - 840_875_672 * 12) < 64
+    assert policy["names"] == (
+        "attn_out,attn_lse,moe_router,qkv_proj,attn_gate,attn_proj,"
+        "kda_in_proj,kda_out_proj,mlp_gate,mlp_up,attn_qkv")
+    assert policy["kept_bytes"] == 734_265_344 <= policy["budget_bytes"]
+    (chunks,) = _said("kda/chunks")
+    assert chunks == dict(chunk=64, sub_chunk=16, chunks=128, heads=8,
+                          pair_bytes=8192 * 8 * 16 * 128 * 4)
+    (pattern,) = _said("model/pattern")
+    assert pattern["pattern"] == "*KKK" and pattern["held"] == 8
+    dispatch = [d for d in _said("moe/dispatch") if d["tokens"] == 8192]
+    assert dispatch == [dict(tokens=8192, held=8, routed=320, top_k=8,
+                             expected=1638.4, rows=65536, experts="gated")]
+    (conv,) = _said("ssm/conv")
+    assert (conv["channels"], conv["positions"], conv["backward"]) == (
+        1024, 8192, "kernel")
+    (said,) = _said("head_loss/slice")
+    assert said["gradients"] == "forward"
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    print(f"delta-rule step: {total} bytes compiled, policy {policy}")
     assert total < V5E_BYTES_LIMIT - (1 << 30)
 
 
@@ -822,6 +882,27 @@ def test_convolutions_backward_kernel_compiles_for_v5e(
         jax.ShapeDtypeStruct((channels,), jnp.float32, sharding=one_chip),
     ).compile().as_text()
     # the forward is the compiler's own fusion; one kernel, the backward
+    assert len(re.findall(r"%ssm_conv_bwd(\.\d+)? = ", text)) == 1
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_a_convolution_without_a_bias_compiles_for_v5e(one_chip, monkeypatch):
+    """The KDA mixer's three: 1024 channels read from their own
+    projection, no bias leaf; the kernel is the same one."""
+    from pytorch_distributed_template_tpu.ops import flash
+    from pytorch_distributed_template_tpu.ops.ssm import causal_conv_silu
+
+    monkeypatch.setattr(flash, "_on_tpu", lambda: True)
+
+    def loss(zxd, taps):
+        out = causal_conv_silu(zxd, taps, None)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        jax.ShapeDtypeStruct((1, 8192, 1024), jnp.bfloat16,
+                             sharding=one_chip),
+        jax.ShapeDtypeStruct((4, 1024), jnp.float32, sharding=one_chip),
+    ).compile().as_text()
     assert len(re.findall(r"%ssm_conv_bwd(\.\d+)? = ", text)) == 1
     assert text.count("tpu_custom_call") == 1
 

@@ -15,7 +15,7 @@ from pytorch_distributed_template_tpu.engine.steps import (
     COUNTER_PREFIX, finalize_metrics, make_train_step, selection_bias_step,
 )
 from pytorch_distributed_template_tpu.models.moe import (
-    ExpertLayer, held_experts,
+    ExpertLayer, held_experts, held_gated_experts,
 )
 
 D, F, LATENT = 16, 24, 12
@@ -246,3 +246,110 @@ def test_without_a_rate_the_biases_stay():
     assert not np.any(new.params["layers_0"]["mixer"]["selection_bias"])
     assert np.any(new.params["layers_0"]["mixer"]["router"]
                   != state.params["layers_0"]["mixer"]["router"])
+
+
+# -- gated experts: three matrices an expert, a SwiGLU shared expert ---------
+
+
+def gated(**kw):
+    return layer(**{**dict(gated=True, latent=0, scale=1.0), **kw})
+
+
+def silu(z):
+    return z / (1 + np.exp(-z))
+
+
+def plain_gated(params, x, lo, n_held, top_k, scale):
+    """Token by token, expert by expert, in numpy."""
+    p = jax.tree.map(np.asarray, params)
+    out = np.zeros_like(x)
+    for i, tok in enumerate(x):
+        s = 1 / (1 + np.exp(-(tok @ p["router"])))
+        chosen = np.argsort(-(s + p.get("selection_bias", 0.0)),
+                            kind="stable")[:top_k]
+        w = scale * s[chosen] / (s[chosen].sum() + 1e-20)
+        for e, w_e in zip(chosen, w):
+            if lo <= e < lo + n_held:
+                h = silu(tok @ p["experts_gate"][e - lo]) \
+                    * (tok @ p["experts_up"][e - lo])
+                out[i] += w_e * (h @ p["experts_down"][e - lo])
+        if "shared" in p:
+            sh = p["shared"]
+            out[i] += (silu(tok @ sh["gate_proj"]["kernel"])
+                       * (tok @ sh["up_proj"]["kernel"])
+                       ) @ sh["down_proj"]["kernel"]
+    return out
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"held": (0, 0)}, {"shared_d_ff": 0}, {"top_k": 5, "held": (9, 7)},
+], ids=["share", "all-held", "no-shared", "held-more-than-chosen"])
+def test_gated_layer_is_the_plain_sum_over_the_experts_held(kw):
+    module = gated(**kw)
+    x = jax.random.normal(jax.random.key(2), (3, 20, D))
+    params = init(module, x)
+    assert params["experts_gate"].shape == params["experts_up"].shape
+    assert ("shared" in params) == bool(module.shared_d_ff)
+    assert "shared_up" not in params and "latent_down" not in params
+    with jax.default_matmul_precision("highest"):
+        got = module.apply({"params": params}, x)
+    lo, n_held = module.held[0], module.held[1] or module.n_routed
+    want = plain_gated(params, np.asarray(x).reshape(-1, D), lo, n_held,
+                       module.top_k, module.scale)
+    np.testing.assert_allclose(np.asarray(got).reshape(-1, D), want,
+                               rtol=0, atol=2e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("expert", [4, 5])
+def test_gated_no_token_is_dropped_when_all_pick_one_expert(expert):
+    module = gated(shared_d_ff=0)
+    x = jax.random.normal(jax.random.key(3), (3, 20, D))
+    params = skewed(module, x, expert)
+    with jax.default_matmul_precision("highest"):
+        got, sown = module.apply({"params": params}, x, mutable=["counters"])
+    counters = sown["counters"]
+    assert float(counters["moe_pairs_here"]) >= 60
+    assert float(counters["moe_tokens_unserved"]) == 0
+    want = plain_gated(params, np.asarray(x).reshape(-1, D), 4, 2, 2, 1.0)
+    np.testing.assert_allclose(np.asarray(got).reshape(-1, D), want,
+                               rtol=0, atol=2e-5 * np.abs(want).max())
+    assert np.all(np.abs(want).sum(axis=1) > 0)
+
+
+def test_held_gated_experts_is_the_sum_expert_by_expert_with_its_gradient():
+    k = jax.random.split(jax.random.key(4), 6)
+    s, e = 40, 3
+    x = jax.random.normal(k[0], (s, LATENT))
+    hit = jax.random.bernoulli(k[1], 0.4, (s, e))
+    weight = jnp.where(hit, jax.random.uniform(k[2], (s, e)), 0.0)
+    gate, up = (0.3 * jax.random.normal(key, (e, LATENT, F))
+                for key in k[3:5])
+    down = 0.3 * jax.random.normal(k[5], (e, F, LATENT))
+
+    def one_by_one(x, w, gate, up, down):
+        return sum(w[:, i:i + 1] * ((jax.nn.silu(x @ gate[i]) * (x @ up[i]))
+                                    @ down[i]) for i in range(e))
+
+    def through(f):
+        return lambda *a: jnp.sum(jnp.sin(f(*a)))
+
+    with jax.default_matmul_precision("highest"):
+        got = held_gated_experts(x, weight, gate, up, down)
+        assert got.dtype == jnp.float32
+        np.testing.assert_allclose(
+            got, one_by_one(x, weight, gate, up, down), atol=2e-5)
+        got = jax.grad(through(held_gated_experts), argnums=range(5))(
+            x, weight, gate, up, down)
+        want = jax.grad(through(one_by_one), argnums=range(5))(
+            x, weight, gate, up, down)
+    for name, g, w in zip(("x", "weight", "gate", "up", "down"), got, want):
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()),
+                                   err_msg=name)
+
+
+def test_the_relu_squared_layer_has_no_third_matrix():
+    x = jax.random.normal(jax.random.key(2), (1, 8, D))
+    params = init(layer(), x)
+    assert "experts_gate" not in params and "shared" not in params
+    assert {"experts_up", "experts_down", "shared_up", "shared_down",
+            "latent_down", "latent_up"} <= set(params)

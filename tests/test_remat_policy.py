@@ -512,14 +512,17 @@ def test_a_kinds_scratch_is_part_of_its_backwards_room():
 
 
 def test_new_names_leave_the_old_names_order_as_it_was():
+    later = ("moe_", "ssm_", "kda_", "attn_gate")
     old = [g for g in rp.PREFERENCE
-           if not any(n.startswith(("moe_", "ssm_")) for n in g)]
+           if not any(n.startswith(later) for n in g)]
     assert old == [("attn_out", "attn_lse"), ("qkv_proj",), ("attn_proj",),
                    ("mlp_gate",), ("mlp_up",), ("attn_qkv",)]
-    new = [n for g in rp.PREFERENCE for n in g
-           if n.startswith(("moe_", "ssm_"))]
-    assert new == ["moe_router", "ssm_in_proj", "moe_latent",
-                   "moe_shared_up"]
+    new = [n for g in rp.PREFERENCE for n in g if n.startswith(later)]
+    # PR 33's four in their order, PR 42's three between them
+    assert [n for n in new if n.startswith(("moe_", "ssm_"))] == [
+        "moe_router", "ssm_in_proj", "moe_latent", "moe_shared_up"]
+    assert new == ["moe_router", "attn_gate", "kda_in_proj", "kda_out_proj",
+                   "ssm_in_proj", "moe_latent", "moe_shared_up"]
 
 
 def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
@@ -559,6 +562,112 @@ def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
         + (heads + 2 * 2) * 16 + 64                 # qkv_proj, attn_proj
         + 2 * (2 * d_in + heads + 2 * 2 * 16))      # two in_proj outputs
     assert "remat/policy: keeping [attn_out,moe_router" in caplog.text
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+
+
+# -- solar_open2_l4.seq8k's two kinds: a KDA block and a gated-attention
+# block, each with the gated experts, at 1 x 8192 tokens in bfloat16 --------
+
+SOLAR_TOK = 1 * 8192 * 2
+SOLAR_EXPERTS = {"moe_router": SOLAR_TOK * 640, "mlp_gate": SOLAR_TOK * 1280,
+                 "mlp_up": SOLAR_TOK * 1280}
+SOLAR_KDA = {"kda_in_proj": SOLAR_TOK * 3072, "kda_out_proj": SOLAR_TOK * 4096,
+             **SOLAR_EXPERTS}
+SOLAR_ATTN = {"attn_out": 16777216, "attn_lse": 262144,
+              "qkv_proj": SOLAR_TOK * 1280, "attn_gate": SOLAR_TOK * 1024,
+              "attn_proj": SOLAR_TOK * 4096, "attn_qkv": 3 * 16777216,
+              **SOLAR_EXPERTS}
+SOLAR = [(SOLAR_KDA, 3), (SOLAR_ATTN, 1)]
+SOLAR_ORDER = ("attn_out", "attn_lse", "moe_router", "qkv_proj", "attn_gate",
+               "attn_proj", "kda_in_proj", "kda_out_proj", "mlp_gate",
+               "mlp_up", "attn_qkv")
+
+
+@pytest.mark.parametrize("budget,n_kept", [
+    (0, 0), (17039360, 2), (17039360 + 4 * SOLAR_TOK * 640, 3),
+    (GIB // 4, 6), (GIB // 2, 8), (2_380_000_000, 11)],
+    ids=["empty", "attention", "router", "attention-block", "kda",
+         "the-cells-2.38-GB"])
+def test_the_two_new_kinds_keep_a_prefix_that_fits(budget, n_kept):
+    got = rp.choose_names(SOLAR, budget)
+    assert got == SOLAR_ORDER[:n_kept]
+    assert kept_bytes(SOLAR, got) <= budget
+    if n_kept < len(SOLAR_ORDER):
+        step = 2 if n_kept == 0 else 1
+        assert kept_bytes(SOLAR, SOLAR_ORDER[:n_kept + step]) > budget
+    # every name the two kinds make is 0.734 GB: inside the 2.38 GB that
+    # ISSUE 42 reckoned and the 3.12 GB the policy reckons on the chip
+    assert kept_bytes(SOLAR, SOLAR_ORDER) == 734_265_344
+
+
+def test_the_solar_model_states_its_two_kinds():
+    from pytorch_distributed_template_tpu.ops.linear_attention import (
+        SUB_CHUNK,
+    )
+
+    model = MODELS.get("SolarOpen2")(
+        pattern="*KKK", vocab_size=24576, n_head=8, n_kv_head=1,
+        kda_n_head=8, moe_held=(0, 8))
+    kda, attn = model._block_kinds()
+    assert (kda.count, attn.count) == (3, 1)
+    assert {n: SOLAR_TOK * w for n, w in kda.widths.items()} == SOLAR_KDA
+    assert attn.widths == {"qkv_proj": 1280, "attn_gate": 1024,
+                           "attn_proj": 4096, "moe_router": 640,
+                           "mlp_gate": 1280, "mlp_up": 1280}
+    assert (attn.attn_heads, attn.head_dim, attn.scratch) == (8, 128, 0)
+    # the scan's pairwise decays: 8 heads x 16 positions x 128 channels of
+    # float32 a token, 537 MB a layer at 8192 tokens
+    assert kda.scratch == 8 * SUB_CHUNK * 128 * 2
+    assert 8192 * 2 * kda.scratch == 536870912
+    args = dict(held_bytes=10_090_508_064, outside_param_bytes=805_322_752,
+                block_input_bytes=SOLAR_TOK * 4096,
+                head_bytes=2 * SOLAR_TOK * 4096)
+    budget = rp.budget_bytes(16_909_336_064, blocks=SOLAR,
+                             scratch=[SOLAR_TOK * kda.scratch, 0], **args)
+    assert budget == (16_909_336_064 - 10_090_508_064 - 805_322_752
+                      - 6 * SOLAR_TOK * 4096
+                      - 2 * (sum(SOLAR_KDA.values()) + 536870912)
+                      - rp.HEADROOM_BYTES)
+    assert rp.choose_names(SOLAR, budget) == SOLAR_ORDER
+
+
+def test_solar_model_reckons_two_kinds_and_says_so(monkeypatch, caplog):
+    """The stack under a training step on a device of known capacity: one
+    `remat/policy` record for its 3 blocks of two kinds, names from both,
+    and the same loss and gradient as with nothing kept."""
+    from pytorch_distributed_template_tpu.engine.losses import (
+        lm_cross_entropy,
+    )
+
+    trace._said.clear()
+    get_recorder().clear()
+    model = MODELS.get("TinySolarOpen2")(pattern="*KK", remat=True)
+    tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, 256)
+    params = model.init(jax.random.key(1), tokens)["params"]
+
+    def loss(p):
+        logits = model.apply({"params": p}, tokens, train=True)
+        return jnp.mean(lm_cross_entropy(logits, tokens))
+
+    want = jax.value_and_grad(loss)(params)     # outside a step: nothing
+    monkeypatch.setattr(rp, "device_capacity_bytes",
+                        lambda mesh=None: 2 * GIB)
+    with caplog.at_level(logging.INFO), rp.step_holds(1 << 20):
+        got = jax.value_and_grad(loss)(params)
+    (said,) = [e["args"] for e in get_recorder().snapshot()
+               if e["name"] == "remat/policy"]
+    assert said["blocks"] == 3
+    assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_gate,"
+                             "attn_proj,kda_in_proj,kda_out_proj,mlp_gate,"
+                             "mlp_up")
+    tok = 2 * 32 * 4
+    assert said["kept_bytes"] == tok * (
+        4 * 16                                      # attn_out, one block
+        + 3 * (8 + 48 + 48)                         # router, shared gate, up
+        + (4 + 2 * 2) * 16 + 4 * 16 + 64            # qkv, gate, attn_proj
+        + 2 * (3 * 4 * 16 + 64))                    # two KDA blocks
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)

@@ -214,7 +214,7 @@ def test_compiled_step_carries_the_scopes(grad_accum_steps):
 # -- the hybrid stack's scopes, lines and counters (PR 33) -----------------
 
 
-def _hybrid_trainer(tmp_path, steps=6):
+def _hybrid_trainer(tmp_path, steps=6, config="nemotron_h_debug.json"):
     import pytorch_distributed_template_tpu.data  # noqa: F401
     import pytorch_distributed_template_tpu.engine  # noqa: F401
     import pytorch_distributed_template_tpu.models  # noqa: F401
@@ -224,7 +224,7 @@ def _hybrid_trainer(tmp_path, steps=6):
     from pytorch_distributed_template_tpu.engine import Trainer
     from pytorch_distributed_template_tpu.parallel import mesh_from_config
 
-    cfg = json.loads((REPO / "configs" / "nemotron_h_debug.json").read_text())
+    cfg = json.loads((REPO / "configs" / config).read_text())
     cfg["trainer"].update(save_dir=str(tmp_path), epochs=1, save_period=100,
                           tensorboard=False, monitor="off")
     cfg["arch"]["args"]["moe_held"] = [2, 4]
@@ -352,3 +352,89 @@ def test_a_choice_is_said_once_a_process_and_distinct_record(caplog):
                                           {"rows": 256, "held": 8}]
     assert [r.getMessage() for r in caplog.records] == [
         "buffer of 64 rows", "buffer of 128 rows", "buffer of 0.3 k rows"]
+
+
+# -- the delta-rule stack (models/solar_open2.py, ops/linear_attention.py) ---
+
+
+def test_kda_counters_reach_the_flight_record(tmp_path):
+    """The mixer's two counters ride beside the expert layers' three."""
+    trainer = _hybrid_trainer(tmp_path, config="solar_open2_debug.json")
+    trainer._train_epoch(1)
+    logged = [r for r in trainer.recorder.last() if "loss" in r]
+    assert logged
+    for r in logged:
+        # the program's own init draws the rate in 1..16 and the step in
+        # 0.001..0.1 a channel: a chunk of 16 sums to between the two ends
+        assert -16 * 16 * 0.1 < r["kda_chunk_log_decay_mean"] < -16 * 0.001
+        assert 0.8 < r["kda_beta_mean"] < 1.2
+        # 16 x 32 tokens, 2 of 8 experts a token, 4 held, 4 layers
+        assert 0.5 * 2048 < r["moe_pairs_here"] < 1.5 * 2048
+        assert r["moe_load_max_over_mean"] >= 1.0
+    assert all("kda_beta_mean" not in r
+               for r in trainer.recorder.last() if "loss" not in r)
+    # the configuration states a selection_bias_rate: every layer's router
+    # has had its biases moved by whole rates
+    for name in ("layers_0", "layers_3"):
+        bias = np.asarray(
+            trainer.state.params[name]["experts"]["selection_bias"]) / 1e-3
+        assert np.any(bias) and np.abs(bias).max() <= 6.001
+        np.testing.assert_allclose(bias, np.round(bias), atol=1e-3)
+
+
+def test_delta_rule_step_carries_its_scopes(caplog):
+    import logging
+
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    from pytorch_distributed_template_tpu.engine.losses import (
+        lm_cross_entropy,
+    )
+    from pytorch_distributed_template_tpu.engine.state import (
+        create_train_state,
+    )
+    from pytorch_distributed_template_tpu.engine.steps import make_train_step
+    from pytorch_distributed_template_tpu.observability import trace
+
+    trace._said.clear()
+    trace.get_recorder().clear()
+    model = MODELS.get("TinySolarOpen2")(pattern="*K", remat=True)
+    tx = optax.adamw(1e-3)
+    with caplog.at_level(logging.INFO):
+        state = create_train_state(model, tx, np.zeros((1, 40), np.int32),
+                                   seed=0)
+        step = make_train_step(model, tx, lm_cross_entropy, [],
+                               input_key="tokens", target_key="tokens")
+        batch = {"tokens": jnp.zeros((2, 40), jnp.int32),
+                 "mask": jnp.ones((2,), jnp.float32)}
+        names = set(re.findall(
+            r'op_name="([^"]*)"',
+            jax.jit(step).lower(state, batch).compile().as_text()))
+
+    def some(pattern):
+        return any(re.search(pattern, n) for n in names)
+
+    for scope in ("kda_scan", "kda_scan/kda_intra", "kda_scan/kda_state",
+                  "kda_proj", "gated_attn", "ssm_scan/ssm_conv",
+                  "moe_route", "moe_experts", "moe_shared"):
+        assert some(rf"jvp\(.*/{scope}/"), scope
+        assert some(rf"transpose\(jvp\(.*/{scope}/"), scope
+    assert some(r"layers_0/gated_attn/mixer/g_proj")
+    assert some(r"layers_1/mixer/kda_proj/q_proj")
+    assert some(r"layers_1/experts/moe_experts")
+    # the scan is outside the projections' scope and the other way round
+    assert not some(r"kda_proj/.*kda_scan") and not some(r"kda_scan/.*_proj")
+    assert not some(r"kda_(proj|scan)/.*ssm_conv")
+    # what the stack and the scan chose from shapes, once each
+    for name in ("model/pattern", "kda/chunks", "moe/dispatch", "ssm/conv"):
+        assert [e for e in trace.get_recorder().snapshot()
+                if e["name"] == name], name
+    # the init probe's one row and the step's two: distinct records
+    said = [e["args"] for e in trace.get_recorder().snapshot()
+            if e["name"] == "kda/chunks"]
+    assert [(c["chunks"], c["chunk"], c["sub_chunk"], c["heads"])
+            for c in said] == [(3, 16, 16, 4)] * 2
+    assert [c["pair_bytes"] for c in said] == [
+        b * 3 * 4 * 16 * 16 * 16 * 4 for b in (1, 2)]
+    assert "model/pattern: *K (2 layers" in caplog.text
+    assert "three matrices an expert" in caplog.text
