@@ -294,9 +294,12 @@ def held_experts(x, weight, up, down):
     the weight of token s at held expert e (0 where it did not choose
     it), ``up [E, D, F]`` and ``down [E, F, D]``. Returns ``[S, D]`` in
     float32: ``sum_e weight[s, e] * relu(x[s] @ up[e])**2 @ down[e]``,
-    every held expert over every token, two batched products."""
-    act = jnp.square(jax.nn.relu(
-        jnp.einsum("sd,edf->esf", x, up.astype(x.dtype))))
+    every held expert over every token, two batched products. The first
+    is named ``moe_experts_up`` for the checkpoint policy: the backward
+    reads it, the second's output it does not."""
+    act = jnp.square(jax.nn.relu(checkpoint_name(
+        jnp.einsum("sd,edf->esf", x, up.astype(x.dtype)),
+        "moe_experts_up")))
     out = jnp.einsum("esf,efd->esd", act, down.astype(x.dtype))
     # a product and a sum the compiler fuses: no float32 copy of `out`
     return jnp.sum(weight.astype(jnp.float32).T[:, :, None]
@@ -319,10 +322,16 @@ def held_gated_experts(x, weight, gate, up, down):
     one scalar a row of the expert's activation, so it is laid on the
     activation and the third product contracts over experts and
     features at once, summing in float32 as it goes: no ``[E, S, D]``
-    result is made (537 MB at 8 experts, 8192 tokens, 4096 wide)."""
-    act = jax.nn.silu(jnp.einsum("sd,edf->esf", x, gate.astype(x.dtype))) \
-        * jnp.einsum("sd,edf->esf", x, up.astype(x.dtype))
-    act = act * weight.T[:, :, None].astype(x.dtype)
+    result is made (537 MB at 8 experts, 8192 tokens, 4096 wide). The
+    first two products are named ``moe_experts_gate`` and
+    ``moe_experts_up`` as the einsums make them: kept, the backward runs
+    neither a second time."""
+    pre = checkpoint_name(
+        jnp.einsum("sd,edf->esf", x, gate.astype(x.dtype)),
+        "moe_experts_gate")
+    lin = checkpoint_name(
+        jnp.einsum("sd,edf->esf", x, up.astype(x.dtype)), "moe_experts_up")
+    act = jax.nn.silu(pre) * lin * weight.T[:, :, None].astype(x.dtype)
     return jnp.einsum("esf,efd->sd", act, down.astype(x.dtype),
                       preferred_element_type=jnp.float32)
 

@@ -313,7 +313,9 @@ class NemotronHLM(nn.Module):
 
     def _block_kinds(self):
         """The names each kind of layer makes, in features a token (the
-        float32 router logits count twice a 16-bit model's item)."""
+        float32 router logits count twice a 16-bit model's item; the
+        routed experts' first product is as wide as the experts held
+        times their ``moe_d_ff``)."""
         item = jnp.dtype(self.dtype).itemsize
         ssm, scratch = mamba_block_sizes(
             self.ssm_n_head, self.ssm_head_dim, self.ssm_n_group,
@@ -322,7 +324,10 @@ class NemotronHLM(nn.Module):
             "M": BlockKind(ssm, 0, scratch=scratch),
             "E": BlockKind({"moe_router": self.moe_n_routed * 4 // item,
                             "moe_latent": self.moe_latent,
-                            "moe_shared_up": self.moe_shared_d_ff}, 0),
+                            "moe_shared_up": self.moe_shared_d_ff,
+                            "moe_experts_up": (self.moe_held[1]
+                                               or self.moe_n_routed)
+                            * self.moe_d_ff}, 0),
             "*": BlockKind({"qkv_proj": (self.n_head + 2 * self.n_kv_head)
                             * self.head_dim, "attn_proj": self.d_model},
                            0, self.n_head, self.head_dim),

@@ -57,7 +57,18 @@ its shared expert is a ``SwiGLU`` and makes ``mlp_gate`` and ``mlp_up``.
 
 Neither the scan's output nor the routed experts' has a name: their
 backward needs what lies inside them, so keeping the result would spare
-next to nothing. The bytes are reckoned by kind of block and summed over
+next to nothing. What lies inside the routed experts has two
+(models/moe.py): ``moe_experts_gate`` and ``moe_experts_up``, every held
+expert's first products over every token as the einsums make them (one,
+``moe_experts_up``, where an expert has two matrices). By recomputation
+spared for a byte they would stand with ``mlp_gate`` where they contract
+over ``d_model`` and at a quarter of that over a 1024-wide latent, but
+they are ``held`` times as wide as any other name, one order serves
+every model and the choice is a prefix, so a name that does not fit drops
+all behind it: they go last, each a group of its own, and no model loses
+a name to them; a budget with room for one keeps one. Kept or not they
+are alive in the block's backward, so they count in its margin
+(``budget_bytes``). The bytes are reckoned by kind of block and summed over
 the kinds' counts; a name a kind lacks costs it nothing. What a scan makes
 inside itself (its float32 decay masks and their product with ``C . B``)
 is the block's ``scratch``: kept by no name, alive in that block's
@@ -100,6 +111,8 @@ PREFERENCE: Tuple[Tuple[str, ...], ...] = (
     ("mlp_up",),
     ("moe_shared_up",),
     ("attn_qkv",),
+    ("moe_experts_gate",),
+    ("moe_experts_up",),
 )
 
 

@@ -304,13 +304,17 @@ class SolarOpen2LM(nn.Module):
 
     def _block_kinds(self):
         """The names each kind of layer makes, in features a token (the
-        float32 router logits count twice a 16-bit model's item), and the
-        KDA scan's scratch: the float32 pairwise decays of its sub-chunks,
-        ``heads x sub-chunk x head size`` entries a token."""
+        float32 router logits count twice a 16-bit model's item; the
+        routed experts' first two products are as wide as the experts
+        held times their ``moe_d_ff``), and the KDA scan's scratch: the
+        float32 pairwise decays of its sub-chunks, ``heads x sub-chunk x
+        head size`` entries a token."""
         item = jnp.dtype(self.dtype).itemsize
+        held = (self.moe_held[1] or self.moe_n_routed) * self.moe_d_ff
         experts = {"moe_router": self.moe_n_routed * 4 // item,
                    "mlp_gate": self.moe_shared_d_ff,
-                   "mlp_up": self.moe_shared_d_ff}
+                   "mlp_up": self.moe_shared_d_ff,
+                   "moe_experts_gate": held, "moe_experts_up": held}
         width = self.kda_n_head * self.kda_head_dim
         table = {
             "K": BlockKind({"kda_in_proj": 3 * width,

@@ -702,6 +702,9 @@ def test_hybrid_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     assert policy["names"] == ("attn_out,attn_lse,moe_router,qkv_proj,"
                                "attn_proj,ssm_in_proj,moe_latent,"
                                "moe_shared_up,attn_qkv")
+    # the routed experts' first product (3.52 GB over five layers) is in
+    # the E kind's margin and outside what is kept
+    assert policy["budget_bytes"] >= policy["kept_bytes"]
     # the state's init traces one sequence, the step two
     dispatch = [d for d in _said("moe/dispatch") if d["tokens"] == 16384]
     assert dispatch and all(d["rows"] == 131072 and d["expected"] == 5632
@@ -796,8 +799,9 @@ def test_delta_rule_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     scan (its triangular system, its loop over 128 chunks), the three
     flash kernels at 8 heads on one key-value head and nine convolution
     kernels without a bias; the checkpoint policy reckons both kinds of
-    block and keeps every name they make; the step stays 1 GiB under the
-    chip's `bytes_limit` with 840.9 M parameters held."""
+    block and keeps every name they make, the routed experts' first two
+    products last (1.342 GB of the 2.076 kept); the step stays 1 GiB under
+    the chip's `bytes_limit` with 840.9 M parameters held."""
     import json
     from pathlib import Path
 
@@ -822,8 +826,10 @@ def test_delta_rule_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     assert abs(policy["held_bytes"] - 840_875_672 * 12) < 64
     assert policy["names"] == (
         "attn_out,attn_lse,moe_router,qkv_proj,attn_gate,attn_proj,"
-        "kda_in_proj,kda_out_proj,mlp_gate,mlp_up,attn_qkv")
-    assert policy["kept_bytes"] == 734_265_344 <= policy["budget_bytes"]
+        "kda_in_proj,kda_out_proj,mlp_gate,mlp_up,attn_qkv,"
+        "moe_experts_gate,moe_experts_up")
+    assert policy["kept_bytes"] == 2_076_442_624 <= policy["budget_bytes"]
+    assert abs(policy["budget_bytes"] - 2_452_541_152) < 64
     (chunks,) = _said("kda/chunks")
     assert chunks == dict(chunk=64, sub_chunk=16, chunks=128, heads=8,
                           pair_bytes=8192 * 8 * 16 * 128 * 4)

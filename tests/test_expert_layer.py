@@ -347,6 +347,64 @@ def test_held_gated_experts_is_the_sum_expert_by_expert_with_its_gradient():
                                    err_msg=name)
 
 
+def _first_products(jaxpr, x_shape, w_shape):
+    """The `sd,edf->esf` products in a jaxpr and all it calls: the
+    `dot_general`s of a `[S, D]` by an `[E, D, F]` operand."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general" and sorted(
+                v.aval.shape for v in eqn.invars) == sorted(
+                [x_shape, w_shape]):
+            found += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found += _first_products(sub, x_shape, w_shape)
+    return found
+
+
+@pytest.mark.parametrize("fn,names", [
+    (held_gated_experts, ("moe_experts_gate", "moe_experts_up")),
+    (held_experts, ("moe_experts_up",))], ids=["gated", "relu-squared"])
+def test_kept_first_products_are_not_run_a_second_time(fn, names):
+    """Under `jax.checkpoint` the policy can keep the experts' first
+    products by their names: the same output and the same gradient of
+    every operand as with nothing kept, and the backward holds the
+    products once (the forward's) where nothing kept holds them twice."""
+    k = jax.random.split(jax.random.key(7), 6)
+    s, e = 40, 3
+    x = jax.random.normal(k[0], (s, LATENT))
+    hit = jax.random.bernoulli(k[1], 0.4, (s, e))
+    weight = jnp.where(hit, jax.random.uniform(k[2], (s, e)), 0.0)
+    firsts = [0.3 * jax.random.normal(key, (e, LATENT, F))
+              for key in k[3:3 + len(names)]]
+    down = 0.3 * jax.random.normal(k[5], (e, F, LATENT))
+    operands = (x, weight, *firsts, down)
+
+    def loss(policy):
+        inner = jax.checkpoint(fn, policy=policy)
+        return lambda *a: jnp.sum(jnp.sin(inner(*a)))
+
+    policies = jax.checkpoint_policies
+    keeping = loss(policies.save_only_these_names(*names))
+    nothing = loss(policies.nothing_saveable)
+    argnums = range(len(operands))
+    got = jax.value_and_grad(keeping, argnums=argnums)(*operands)
+    want = jax.value_and_grad(nothing, argnums=argnums)(*operands)
+    np.testing.assert_array_equal(got[0], want[0])
+    for g, w in zip(got[1], want[1]):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-7)
+
+    def products(f):
+        return _first_products(
+            jax.make_jaxpr(jax.grad(f, argnums=argnums))(*operands).jaxpr,
+            x.shape, firsts[0].shape)
+
+    assert products(keeping) == len(names)
+    assert products(nothing) == 2 * len(names)
+    # a name short, that product alone is run again
+    if len(names) == 2:
+        assert products(loss(policies.save_only_these_names(names[0]))) == 3
+
+
 def test_the_relu_squared_layer_has_no_third_matrix():
     x = jax.random.normal(jax.random.key(2), (1, 8, D))
     params = init(layer(), x)

@@ -414,13 +414,16 @@ def test_the_step_says_what_it_holds(capacity, family, setting, tx, extra):
 TOK = 2 * 8192 * 2
 SSM = {"ssm_in_proj": TOK * 2320}
 EXPERTS = {"moe_router": TOK * 1024, "moe_latent": TOK * 1024,
-           "moe_shared_up": TOK * 5376}
+           "moe_shared_up": TOK * 5376, "moe_experts_up": TOK * 21504}
 ATTENDS = {"attn_out": 16777216, "attn_lse": 262144, "qkv_proj": TOK * 768,
            "attn_proj": TOK * 4096, "attn_qkv": 3 * 16777216}
 HYBRID = [(SSM, 5), (EXPERTS, 5), (ATTENDS, 1)]
 HYBRID_ORDER = ("attn_out", "attn_lse", "moe_router", "qkv_proj",
                 "attn_proj", "ssm_in_proj", "moe_latent", "moe_shared_up",
-                "attn_qkv")
+                "attn_qkv", "moe_experts_up")
+# what the policy reckons on the chip with the routed experts' first
+# product in the E kind's margin (4 588 793 516 without it)
+HYBRID_BUDGET = 3_247_664_812
 
 
 def kept_bytes(blocks, names):
@@ -430,9 +433,10 @@ def kept_bytes(blocks, names):
 
 @pytest.mark.parametrize("budget,n_kept", [
     (0, 0), (17039360 - 1, 0), (17039360, 2),
-    (17039360 + 5 * TOK * 1024, 3), (GIB // 2, 5), (GIB, 7), (4 * GIB, 9)],
+    (17039360 + 5 * TOK * 1024, 3), (GIB // 2, 5), (GIB, 7), (4 * GIB, 9),
+    (HYBRID_BUDGET, 9), (6 * GIB, 10)],
     ids=["empty", "one-byte-short", "attention", "router", "projections",
-         "latent", "all"])
+         "latent", "all", "the-cells-3.25-GB", "the-first-product-too"])
 def test_unequal_blocks_are_summed_by_kind_and_count(budget, n_kept):
     got = rp.choose_names(HYBRID, budget)
     assert got == HYBRID_ORDER[:n_kept]
@@ -491,19 +495,28 @@ def test_a_kinds_scratch_is_part_of_its_backwards_room():
 
     args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
                 block_input_bytes=TOK * 4096, head_bytes=2 * TOK * 4096)
-    plain = rp.budget_bytes(16_909_336_064, blocks=HYBRID, **args)
-    assert rp.budget_bytes(16_909_336_064, blocks=HYBRID,
+    # the expert layers without their routed experts' first product, as
+    # they were reckoned before it had a name: 7424 features a token
+    blocks = [(SSM, 5), ({n: b for n, b in EXPERTS.items()
+                          if n != "moe_experts_up"}, 5), (ATTENDS, 1)]
+    plain = rp.budget_bytes(16_909_336_064, blocks=blocks, **args)
+    assert rp.budget_bytes(16_909_336_064, blocks=blocks,
                            scratch=[0, 0, 0], **args) == plain
     # the hybrid cell's scan: 16 heads, chunk 128: 2320 + 6144 features a
-    # token, over the expert layers' 7424
+    # token, over those 7424
     widths, scratch = mamba_block_sizes(16, 64, 1, 128, 128, 2)
     assert widths == {"ssm_in_proj": 2320} and scratch == 16 * 128 * 3
-    with_masks = rp.budget_bytes(16_909_336_064, blocks=HYBRID,
+    with_masks = rp.budget_bytes(16_909_336_064, blocks=blocks,
                                  scratch=[TOK * scratch, 0, 0], **args)
     assert plain - with_masks == 2 * TOK * (2320 + 6144 - 7424)
-    small = rp.budget_bytes(16_909_336_064, blocks=HYBRID,
+    small = rp.budget_bytes(16_909_336_064, blocks=blocks,
                             scratch=[TOK * 1024, 0, 0], **args)
     assert small == plain
+    # with the first product (21504 features) the expert layers are the
+    # largest kind by far, and the scan's masks change nothing
+    assert rp.budget_bytes(
+        16_909_336_064, blocks=HYBRID, scratch=[TOK * scratch, 0, 0],
+        **args) == rp.budget_bytes(16_909_336_064, blocks=HYBRID, **args)
     # granite-4.0-h-micro's: 64 heads, the published chunk of 256: the
     # float32 mask 537 MB and its bfloat16 product 268 MB at 8192 tokens
     widths, scratch = mamba_block_sizes(64, 64, 1, 128, 256, 2)
@@ -519,10 +532,67 @@ def test_new_names_leave_the_old_names_order_as_it_was():
                    ("mlp_gate",), ("mlp_up",), ("attn_qkv",)]
     new = [n for g in rp.PREFERENCE for n in g if n.startswith(later)]
     # PR 33's four in their order, PR 42's three between them
-    assert [n for n in new if n.startswith(("moe_", "ssm_"))] == [
+    assert [n for n in new if n.startswith(("moe_", "ssm_"))][:4] == [
         "moe_router", "ssm_in_proj", "moe_latent", "moe_shared_up"]
-    assert new == ["moe_router", "attn_gate", "kda_in_proj", "kda_out_proj",
-                   "ssm_in_proj", "moe_latent", "moe_shared_up"]
+    assert new[:7] == ["moe_router", "attn_gate", "kda_in_proj",
+                       "kda_out_proj", "ssm_in_proj", "moe_latent",
+                       "moe_shared_up"]
+    # PR 43's two behind every other name, each a group of its own, so
+    # that no model loses a name to them and room for one keeps one
+    assert new[7:] == ["moe_experts_gate", "moe_experts_up"]
+    assert rp.PREFERENCE[-2:] == (("moe_experts_gate",),
+                                  ("moe_experts_up",))
+    assert list(rp.PREFERENCE[:-2]) == [
+        ("attn_out", "attn_lse"), ("moe_router",), ("qkv_proj",),
+        ("attn_gate",), ("attn_proj",), ("kda_in_proj",), ("kda_out_proj",),
+        ("ssm_in_proj",), ("moe_latent",), ("mlp_gate",), ("mlp_up",),
+        ("moe_shared_up",), ("attn_qkv",)]
+
+
+NEMOTRON_CELL = dict(
+    vocab_size=16384, pattern="EMEMEMEMEM*", n_head=4, n_kv_head=1,
+    ssm_n_head=16, ssm_n_group=1, moe_held=(0, 8))
+
+
+def test_the_hybrid_model_states_its_three_kinds():
+    model = MODELS.get("NemotronH")(**NEMOTRON_CELL)
+    ssm, experts, attends = model._block_kinds()
+    assert (ssm.count, experts.count, attends.count) == (5, 5, 1)
+    # the routed experts' first product: 8 held experts of 2688 features
+    assert experts.widths == {"moe_router": 1024, "moe_latent": 1024,
+                              "moe_shared_up": 5376,
+                              "moe_experts_up": 8 * 2688}
+    assert {n: TOK * w for n, w in experts.widths.items()} == EXPERTS
+    assert {n: TOK * w for n, w in ssm.widths.items()} == SSM
+    assert (attends.attn_heads, attends.head_dim) == (4, 128)
+    # none said held: all 512 are
+    whole = MODELS.get("NemotronH")(pattern="E")._block_kinds()[0]
+    assert whole.widths["moe_experts_up"] == 512 * 2688
+    # the E kind, 243 -> 948 MB, is the largest with the product in it:
+    # the margin grows by twice 705 MB
+    args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
+                block_input_bytes=TOK * 4096, head_bytes=2 * TOK * 4096)
+    budget = rp.budget_bytes(16_909_336_064, blocks=HYBRID,
+                             scratch=[TOK * ssm.scratch, 0, 0], **args)
+    assert budget == HYBRID_BUDGET == (
+        16_909_336_064 - 8410386260 - 536887296 - 13 * TOK * 4096
+        - 2 * TOK * (1024 + 1024 + 5376 + 21504) - rp.HEADROOM_BYTES)
+    # (before, the scan's kind with its masks was: 2320 + 6144 features)
+    assert budget == 4_588_793_516 - 2 * TOK * (28928 - 2320 - 6144)
+
+
+def test_the_hybrid_cell_keeps_its_nine_names_and_not_the_first_product():
+    """`nemotron3_super_l11.seq8k` at its chip's budget: the first product
+    is 705 MB a layer, 3.52 GB over five, more than the whole budget; the
+    nine names kept before it had a name (1.823 GB) still fit, so the cell
+    keeps what it kept, to the letter."""
+    got = rp.choose_names(HYBRID, HYBRID_BUDGET)
+    assert got == HYBRID_ORDER[:9]
+    assert kept_bytes(HYBRID, got) == 1_823_211_520 <= HYBRID_BUDGET
+    assert kept_bytes(HYBRID, ("moe_experts_up",)) == 5 * TOK * 21504 \
+        == 3_523_215_360 > HYBRID_BUDGET
+    # nor would the budget it had before the margin held the product
+    assert rp.choose_names(HYBRID, 4_588_793_516) == got
 
 
 def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
@@ -553,12 +623,13 @@ def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
                if e["name"] == "remat/policy"]
     assert said["blocks"] == 5
     assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_proj,"
-                             "ssm_in_proj,moe_latent,moe_shared_up")
+                             "ssm_in_proj,moe_latent,moe_shared_up,"
+                             "moe_experts_up")
     tok = 2 * 32 * 4
     d_in, heads = 4 * 16, 4
     assert said["kept_bytes"] == tok * (
         heads * 16                                  # attn_out, one block
-        + 2 * (8 + 32 + 96)                         # two expert layers
+        + 2 * (8 + 32 + 96 + 8 * 48)                # two expert layers
         + (heads + 2 * 2) * 16 + 64                 # qkv_proj, attn_proj
         + 2 * (2 * d_in + heads + 2 * 2 * 16))      # two in_proj outputs
     assert "remat/policy: keeping [attn_out,moe_router" in caplog.text
@@ -572,7 +643,9 @@ def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
 
 SOLAR_TOK = 1 * 8192 * 2
 SOLAR_EXPERTS = {"moe_router": SOLAR_TOK * 640, "mlp_gate": SOLAR_TOK * 1280,
-                 "mlp_up": SOLAR_TOK * 1280}
+                 "mlp_up": SOLAR_TOK * 1280,
+                 "moe_experts_gate": SOLAR_TOK * 10240,
+                 "moe_experts_up": SOLAR_TOK * 10240}
 SOLAR_KDA = {"kda_in_proj": SOLAR_TOK * 3072, "kda_out_proj": SOLAR_TOK * 4096,
              **SOLAR_EXPERTS}
 SOLAR_ATTN = {"attn_out": 16777216, "attn_lse": 262144,
@@ -582,14 +655,21 @@ SOLAR_ATTN = {"attn_out": 16777216, "attn_lse": 262144,
 SOLAR = [(SOLAR_KDA, 3), (SOLAR_ATTN, 1)]
 SOLAR_ORDER = ("attn_out", "attn_lse", "moe_router", "qkv_proj", "attn_gate",
                "attn_proj", "kda_in_proj", "kda_out_proj", "mlp_gate",
-               "mlp_up", "attn_qkv")
+               "mlp_up", "attn_qkv", "moe_experts_gate", "moe_experts_up")
+# what the policy reckons on the chip with the experts' two products in
+# the KDA kind's margin (3 123 629 792 without them)
+SOLAR_BUDGET = 2_452_541_152
 
 
 @pytest.mark.parametrize("budget,n_kept", [
     (0, 0), (17039360, 2), (17039360 + 4 * SOLAR_TOK * 640, 3),
-    (GIB // 4, 6), (GIB // 2, 8), (2_380_000_000, 11)],
+    (GIB // 4, 6), (GIB // 2, 8), (734_265_344, 11), (1_400_000_000, 11),
+    (734_265_344 + 4 * SOLAR_TOK * 10240, 12), (2_000_000_000, 12),
+    (SOLAR_BUDGET, 13)],
     ids=["empty", "attention", "router", "attention-block", "kda",
-         "the-cells-2.38-GB"])
+         "neither-product", "neither-product-with-room-to-spare",
+         "the-gate-product-alone", "the-gate-product-and-0.6-GB",
+         "the-cells-2.45-GB"])
 def test_the_two_new_kinds_keep_a_prefix_that_fits(budget, n_kept):
     got = rp.choose_names(SOLAR, budget)
     assert got == SOLAR_ORDER[:n_kept]
@@ -597,9 +677,11 @@ def test_the_two_new_kinds_keep_a_prefix_that_fits(budget, n_kept):
     if n_kept < len(SOLAR_ORDER):
         step = 2 if n_kept == 0 else 1
         assert kept_bytes(SOLAR, SOLAR_ORDER[:n_kept + step]) > budget
-    # every name the two kinds make is 0.734 GB: inside the 2.38 GB that
-    # ISSUE 42 reckoned and the 3.12 GB the policy reckons on the chip
-    assert kept_bytes(SOLAR, SOLAR_ORDER) == 734_265_344
+    # every older name the two kinds make is 0.734 GB, each of the routed
+    # experts' products 0.671 GB over the four layers: 2.076 GB, inside
+    # the 2.45 GB the policy reckons on the chip
+    assert kept_bytes(SOLAR, SOLAR_ORDER[:11]) == 734_265_344
+    assert kept_bytes(SOLAR, SOLAR_ORDER) == 2_076_442_624 <= SOLAR_BUDGET
 
 
 def test_the_solar_model_states_its_two_kinds():
@@ -615,7 +697,13 @@ def test_the_solar_model_states_its_two_kinds():
     assert {n: SOLAR_TOK * w for n, w in kda.widths.items()} == SOLAR_KDA
     assert attn.widths == {"qkv_proj": 1280, "attn_gate": 1024,
                            "attn_proj": 4096, "moe_router": 640,
-                           "mlp_gate": 1280, "mlp_up": 1280}
+                           "mlp_gate": 1280, "mlp_up": 1280,
+                           "moe_experts_gate": 10240,
+                           "moe_experts_up": 10240}
+    # 8 held experts of 1280 features; none said held: all 320 are
+    assert kda.widths["moe_experts_gate"] == 8 * 1280
+    whole = MODELS.get("SolarOpen2")(pattern="K")._block_kinds()[0]
+    assert whole.widths["moe_experts_up"] == 320 * 1280
     assert (attn.attn_heads, attn.head_dim, attn.scratch) == (8, 128, 0)
     # the scan's pairwise decays: 8 heads x 16 positions x 128 channels of
     # float32 a token, 537 MB a layer at 8192 tokens
@@ -630,6 +718,9 @@ def test_the_solar_model_states_its_two_kinds():
                       - 6 * SOLAR_TOK * 4096
                       - 2 * (sum(SOLAR_KDA.values()) + 536870912)
                       - rp.HEADROOM_BYTES)
+    # the KDA kind's backward holds both products (335.5 MB) and their
+    # cotangents: 671 MB less than before they had names
+    assert budget == SOLAR_BUDGET == 3_123_629_792 - 4 * SOLAR_TOK * 10240
     assert rp.choose_names(SOLAR, budget) == SOLAR_ORDER
 
 
@@ -661,11 +752,12 @@ def test_solar_model_reckons_two_kinds_and_says_so(monkeypatch, caplog):
     assert said["blocks"] == 3
     assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_gate,"
                              "attn_proj,kda_in_proj,kda_out_proj,mlp_gate,"
-                             "mlp_up")
+                             "mlp_up,moe_experts_gate,moe_experts_up")
     tok = 2 * 32 * 4
     assert said["kept_bytes"] == tok * (
         4 * 16                                      # attn_out, one block
         + 3 * (8 + 48 + 48)                         # router, shared gate, up
+        + 3 * 2 * 8 * 48                            # 8 experts' two products
         + (4 + 2 * 2) * 16 + 4 * 16 + 64            # qkv, gate, attn_proj
         + 2 * (3 * 4 * 16 + 64))                    # two KDA blocks
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
