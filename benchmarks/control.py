@@ -11,7 +11,9 @@ computed in the next-lower precision (fp8 weight matmuls, see
 benchmarks/reference/common.py) against the float32 reference: the
 smallest of these is what the control gives. A limit has to stand
 between the two with room on both sides. One JSON line per reading; the
-trainer is built once and reseeded, so only the first seed pays set-up.
+trainer is built once and reseeded, so only the first seed pays set-up;
+a configuration with experts has its selection biases solved anew for
+every seed (benchmarks/balance.py), and says so on standard error.
 """
 from __future__ import annotations
 
@@ -53,7 +55,8 @@ def main() -> None:
     run_dir = ROOT / ".cache" / "bench" / (cell["name"] + ".control")
     shutil.rmtree(run_dir, ignore_errors=True)
     run_dir.mkdir(parents=True)
-    b = run.Bench(cell, config, seeds[0], run_dir, args.rehearse)
+    b = run.Bench(cell, config, seeds[0], run_dir, args.rehearse,
+                  say=lambda text: print(text, file=sys.stderr, flush=True))
     for seed in seeds:
         t0 = time.perf_counter()
         if seed != b.seed:

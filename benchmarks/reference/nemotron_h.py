@@ -145,7 +145,8 @@ def matmul_weights(a) -> int:
     convolution's 4 taps a channel are no matrix). `*`: Q, K, V, O. E: the
     router, both latent projections and the shared expert whole; a routed
     expert held here is met by `moe_top_k / moe_n_routed` of the tokens
-    (uniform routing over the published experts). The untied head."""
+    (uniform routing over the published experts, which is how the cells
+    route since benchmarks/balance.py). The untied head."""
     d = a["d_model"]
     d_in, d_bc = _ssm_widths(a)
     hd = a["head_dim"]
@@ -189,16 +190,18 @@ def init_rules(a) -> list:
     """The published `initializer_range` 0.02 throughout; norms and the
     skip `D` at identity; `A_log` 0 (A = -1) and `dt_bias` the inverse
     softplus of 0.01, so that a chunk of 128 positions decays a state to
-    about 0.3 and what chunks hand on matters; the selection bias 0.
+    about 0.3 and what chunks hand on matters; the selection bias 0,
+    which is where benchmarks/balance.py starts its solve from.
 
     What these weights do to routing (PERF.md, PR 33): behind the first
     mixer the stream is all mixer output, and `relu(.)^2` is never
     negative, so every token carries one common vector about half as
-    large as its own part and each router prefers the same few experts
-    for every token (on the chip: the busiest of the 8 held at 2.8-3.6
-    times their mean at the first step). A deployment's routers are
+    large as its own part and, at a bias of 0, each router prefers the
+    same few experts for every token (on the chip: the busiest of the 8
+    held at 2.4-4.4 times their mean). A deployment's routers are
     balanced by a selection bias that hundreds of steps have trained;
-    weights from a seed have none."""
+    the benchmark solves that bias for the seed's weights and gives it to
+    both sides (`Weights.give`, PR 44)."""
     return [
             (r"norm/weight$|norm_weight$", "ones", 0.0),
             (r"/D$", "ones", 0.0),
@@ -278,11 +281,25 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
-def _experts(a, p, h, dot):
+def router_scores(a, p, h, dot):
+    """Every published expert's score of every token of `h`, the normed
+    input of an expert layer, `[..., moe_n_routed]`: what `experts`
+    routes by, and what benchmarks/balance.py solves the selection bias
+    on."""
+    return jax.nn.sigmoid(dot(h, p["mixer/router"]))
+
+
+def expert_input(a, p, x, dot):
+    """`(stream, h)` of an expert layer, whose output is
+    `stream + experts(a, p, h, dot)`."""
+    return x, _rms_norm(x, p["norm/weight"], a["rms_eps"])
+
+
+def experts(a, p, h, dot):
     lo, n_held = _held(a)
 
     def tokens(hb):
-        scores = jax.nn.sigmoid(dot(hb, p["mixer/router"]))
+        scores = router_scores(a, p, hb, dot)
         _, chosen = jax.lax.top_k(jax.lax.stop_gradient(
             scores + p["mixer/selection_bias"]), a["moe_top_k"])
         took = jnp.sum(jax.nn.one_hot(chosen, a["moe_n_routed"],
@@ -306,11 +323,12 @@ def _experts(a, p, h, dot):
 
 def layer(a, p, x, dot):
     """One layer; its kind is told from the leaves it is given."""
+    if "mixer/router" in p:
+        stream, h = expert_input(a, p, x, dot)
+        return stream + experts(a, p, h, dot)
     h = _rms_norm(x, p["norm/weight"], a["rms_eps"])
     if "mixer/in_proj/kernel" in p:
         return x + _mamba(a, p, h, dot)
-    if "mixer/router" in p:
-        return x + _experts(a, p, h, dot)
     return x + _attend(a, p, h, dot)
 
 

@@ -156,7 +156,8 @@ def matmul_weights(a) -> int:
     channel are no matrix). `*`: Q, K, V, the gate, O. Every layer: the
     router and the shared expert whole; a routed expert held here is met
     by `moe_top_k / moe_n_routed` of the tokens (uniform routing over the
-    published experts). The untied head."""
+    published experts, which is how the cell routes since
+    benchmarks/balance.py). The untied head."""
     d = a["d_model"]
     hp, r = a["kda_n_head"] * a["kda_head_dim"], a["kda_rank"]
     hd = a["head_dim"]
@@ -190,7 +191,8 @@ def mixer_flops_per_token(a, seq_len: int) -> float:
 
 def init_rules(a) -> list:
     """Normal 0.02 for every matrix and the convolutions' taps; norms at
-    identity; `b_g` zeros; the selection bias 0; `A_log` 0 (a rate of 1)
+    identity; `b_g` zeros; the selection bias 0 (where
+    benchmarks/balance.py starts its solve from); `A_log` 0 (a rate of 1)
     and `dt_bias` the inverse softplus of 0.01, so that a chunk of 64
     positions decays a state to about 0.53 and what chunks hand on
     matters. The family draws the rate in 1..16 and the step in
@@ -283,11 +285,19 @@ def _swiglu(x, gate, up, down, dot):
     return dot(jax.nn.silu(dot(x, gate)) * dot(x, up), down)
 
 
-def _experts(a, p, u, dot):
+def router_scores(a, p, u, dot):
+    """Every published expert's score of every token of `u`, the normed
+    input of a layer's experts, `[..., moe_n_routed]`: what `experts`
+    routes by, and what benchmarks/balance.py solves the selection bias
+    on."""
+    return jax.nn.sigmoid(dot(u, p["experts/router"]))
+
+
+def experts(a, p, u, dot):
     lo, n_held = _held(a)
 
     def tokens(ub):
-        scores = jax.nn.sigmoid(dot(ub, p["experts/router"]))
+        scores = router_scores(a, p, ub, dot)
         _, chosen = jax.lax.top_k(jax.lax.stop_gradient(
             scores + p["experts/selection_bias"]), a["moe_top_k"])
         took = jnp.sum(jax.nn.one_hot(chosen, a["moe_n_routed"],
@@ -309,13 +319,20 @@ def _experts(a, p, u, dot):
                         ).reshape(u.shape)
 
 
-def layer(a, p, x, dot):
-    """One layer; its mixer's kind is told from the leaves it is given."""
+def expert_input(a, p, x, dot):
+    """`(stream, u)` of a layer, whose output is
+    `stream + experts(a, p, u, dot)`: the stream behind the mixer, its
+    kind told from the leaves given, and the experts' normed input."""
     u = _rms_norm(x, p["input_layernorm/weight"], a["rms_eps"])
     mixer = _kda if "mixer/A_log" in p else _attend
     h = x + mixer(a, p, u, dot)
-    u = _rms_norm(h, p["post_attention_layernorm/weight"], a["rms_eps"])
-    return h + _experts(a, p, u, dot)
+    return h, _rms_norm(h, p["post_attention_layernorm/weight"],
+                        a["rms_eps"])
+
+
+def layer(a, p, x, dot):
+    stream, u = expert_input(a, p, x, dot)
+    return stream + experts(a, p, u, dot)
 
 
 def head_loss(a, p, x, tok, dot):

@@ -9,7 +9,10 @@ goes through `ConfigParser`, `mesh_from_config`, `MODELS`/`LOADERS`,
 trainer's own epoch method (`Trainer._train_epoch`, iteration mode):
 
 set-up   weights made on the device from `--seed` (benchmarks/weights.py)
-         and put in the trainer's state; tokens from `--seed`
+         and put in the trainer's state, the routers' selection biases
+         among them solved first for the seed's own weights and first
+         batch (benchmarks/balance.py; only a configuration with
+         experts has any); tokens from `--seed`
          (benchmarks/data.py) read back through the program's loader;
          the first steps one at a time, as many as the cell's file
          says under `check.steps` (their losses, the first
@@ -181,7 +184,8 @@ class Bench:
     """One trainer, built once, driven through set-up and the window."""
 
     def __init__(self, cell: dict, config: dict, seed: int, run_dir: Path,
-                 rehearse: bool, phase=lambda name: None):
+                 rehearse: bool, phase=lambda name: None,
+                 say=lambda text: None):
         import jax
         import numpy as np
 
@@ -199,6 +203,7 @@ class Bench:
         )
 
         self.cell, self.config, self.seed = cell, config, int(seed)
+        self.phase, self.say = phase, say
         self.sizes = config["sizes"]
         devices = jax.devices()
         if not rehearse and (devices[0].platform != "tpu"
@@ -241,6 +246,7 @@ class Bench:
                                ref.init_rules(self.sizes), self.seed)
         self._epoch = 0
         self.fed = 0            # batches the loader has handed over
+        self._balancer = None
         self.install_weights()
         phase("weights from the seed into the trainer's state")
 
@@ -280,18 +286,49 @@ class Bench:
         template = trainer.state.replace(params=None, opt_state=None)
         del flat
         self.free()
+        self.balance()
 
-        def fresh(template, root):
-            made = weights.all(root)
+        def fresh(template, root, given):
+            made = weights.all(root, given)
             params = jax.tree.unflatten(treedef, [made[p] for p in paths])
             return template.replace(step=jnp.zeros((), jnp.int32),
                                     params=params,
                                     opt_state=trainer.tx.init(params))
 
         trainer.state = jax.jit(
-            fresh, out_shardings=trainer.state_sharding)(template,
-                                                         weights.root())
+            fresh, out_shardings=trainer.state_sharding)(
+                template, weights.root(), weights.given)
         self._paths = paths
+
+    def balance(self) -> None:
+        """The routers' selection biases, solved for this seed's weights
+        on the batch the next step is fed (benchmarks/balance.py) and
+        given to `self.weights`. It runs on an empty chip: the program's
+        initial state is gone, the benchmark's not yet made. A
+        configuration without such a leaf is passed over."""
+        from benchmarks import balance
+        from benchmarks.data import batch_rows
+
+        if not balance.bias_paths(self.weights.shapes):
+            return
+        t0 = time.perf_counter()
+        everywhere, place_rows = reference_placement(self.devices)
+        if self._balancer is None:
+            self._balancer = balance.Balancer(
+                reference_module(self.config), self.sizes,
+                place_rows=place_rows)
+        params = self.weights.make(everywhere)
+        solved, report = self._balancer.solve(
+            params, batch_rows(self.tokens, self.fed, self.batch_size))
+        for leaf in params.values():
+            leaf.delete()
+        self.weights.give(solved)
+        self.say("balance: busiest published expert over the mean, before "
+                 "-> after the solve: " + ", ".join(
+                     f"{name} {before:.3f} -> {after:.3f}"
+                     for name, before, after in report)
+                 + f"; {time.perf_counter() - t0:.2f} s")
+        self.phase("selection biases solved on the first batch")
 
     def _params(self) -> dict:
         import jax
@@ -377,6 +414,19 @@ class Bench:
         return int(max(peaks))
 
 
+def reference_placement(devices) -> tuple:
+    """Where the benchmark's own programs keep their arrays: `(the
+    sharding of a leaf, on every chip; what puts a block of token rows,
+    spread over the chips)`."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(devices), ("rows",))
+    return NamedSharding(mesh, P()), lambda x: jax.device_put(
+        x, NamedSharding(mesh, P("rows")))
+
+
 def reference_steps(cell: dict, config: dict, exp: dict, weights, tokens,
                     batch_size: int, devices, mode: str = "f32",
                     first_batch: int = 0) -> dict:
@@ -385,8 +435,6 @@ def reference_steps(cell: dict, config: dict, exp: dict, weights, tokens,
     gradients), so a four-chip cell's reference takes no longer than a
     one-chip cell's."""
     import jax
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from benchmarks.data import batch_rows
     from benchmarks.reference import common
@@ -394,15 +442,12 @@ def reference_steps(cell: dict, config: dict, exp: dict, weights, tokens,
     check = cell["check"]
     batches = [batch_rows(tokens, first_batch + k, batch_size)
                for k in range(check["steps"])]
-    mesh = Mesh(np.asarray(devices), ("rows",))
-    everywhere = NamedSharding(mesh, P())
+    everywhere, place_rows = reference_placement(devices)
     rows = check["rows_per_block"] * len(devices)
     return common.follow_steps(
         reference_module(config), config["sizes"], optimizer_settings(exp),
         weights.make(everywhere), batches, weights,
-        mode=mode, rows_per_block=rows,
-        place_rows=lambda x: jax.device_put(
-            x, NamedSharding(mesh, P("rows"))),
+        mode=mode, rows_per_block=rows, place_rows=place_rows,
         place_leaf=lambda x: jax.device_put(x, everywhere))
 
 
@@ -472,7 +517,7 @@ def run_in(run_dir: Path, args, say, bench: dict, cell: dict,
             f"(at {t_phase[-1] - _T0:.1f} s)")
 
     phase("imports and the cell's files")
-    b = Bench(cell, config, args.seed, run_dir, args.rehearse, phase)
+    b = Bench(cell, config, args.seed, run_dir, args.rehearse, phase, say)
     kind = b.devices[0].device_kind
     if kind in peaks:
         peak = peaks[kind]

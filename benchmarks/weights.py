@@ -10,6 +10,13 @@ the value (a state-space layer's step bias, a router's selection bias:
 leaves that mean something only away from 0 and 1). A leaf's key is the
 seed folded with the leaf's rank among the sorted paths, so one leaf can
 be made again alone.
+
+A leaf can also be given from outside (`give`): benchmarks/balance.py
+solves the routers' selection biases from the seed's other leaves, and
+`leaf`, `all` and `make` then return the solved arrays for those paths,
+so that the program's state, the reference's parameters and both sides'
+`update_norms` start from the same numbers. The rule of such a leaf
+stays what the solve starts from.
 """
 from __future__ import annotations
 
@@ -46,26 +53,40 @@ class Weights:
                     break
             else:
                 raise ValueError(f"no init rule matches {path!r}")
+        self.given = {}
 
-    def _make(self, path, root):
+    def give(self, leaves: dict) -> None:
+        """`{path: array}` that takes the place of those paths' rules."""
+        for path, leaf in leaves.items():
+            if self.shapes.get(path) != tuple(leaf.shape):
+                raise ValueError(f"the leaf given for {path!r} has shape "
+                                 f"{tuple(leaf.shape)}, not "
+                                 f"{self.shapes.get(path)}")
+        self.given = dict(leaves)
+
+    def _make(self, path, root, given):
+        if path in given:
+            return given[path]
         kind, std = self._rule[path]
         return _leaf(jax.random.fold_in(root, self._rank[path]),
                      self.shapes[path], kind, std)
 
     def leaf(self, path: str):
-        return self._make(path, self.root())
+        return self._make(path, self.root(), self.given)
 
     def root(self):
         # the seed is folded in two 31-bit halves: it may pass 2**31
         key = jax.random.key(self.seed & 0x7FFFFFFF)
         return jax.random.fold_in(key, self.seed >> 31)
 
-    def all(self, root) -> dict:
-        """Every leaf from `self.root()`. Jit this with the root as an
-        argument, as `make` does: a seed traced into the program as a
-        constant would compile anew for every seed."""
-        return {path: self._make(path, root) for path in self.shapes}
+    def all(self, root, given) -> dict:
+        """Every leaf from `self.root()` and `self.given`. Jit this with
+        both as arguments, as `make` does: a seed or a solved leaf traced
+        into the program as a constant would compile anew for every
+        seed."""
+        return {path: self._make(path, root, given) for path in self.shapes}
 
     def make(self, out_shardings=None) -> dict:
         """Every leaf, in one jitted program that any seed reuses."""
-        return jax.jit(self.all, out_shardings=out_shardings)(self.root())
+        return jax.jit(self.all, out_shardings=out_shardings)(
+            self.root(), self.given)

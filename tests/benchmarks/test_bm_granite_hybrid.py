@@ -317,15 +317,19 @@ def test_the_new_metrics_belong_to_the_new_cell_alone():
     assert cell["chips"] == 1
 
 
-@pytest.mark.parametrize("name,reducer,layer", [
-    ("ssm_scan_ms_per_step", "trace_scopes", "compiled step"),
-    ("ssm_conv_ms_per_step", "trace_scopes", "compiled step"),
-    ("ssm_conv_bwd_roofline", "trace_roofline_conv", "kernels")])
-def test_the_readings_both_hybrid_cells_share(name, reducer, layer):
+@pytest.mark.parametrize("name,reducer,layer,later", [
+    ("ssm_scan_ms_per_step", "trace_scopes", "compiled step", []),
+    ("ssm_conv_ms_per_step", "trace_scopes", "compiled step",
+     ["solar_open2_l4.seq8k"]),
+    ("ssm_conv_bwd_roofline", "trace_roofline_conv", "kernels",
+     ["solar_open2_l4.seq8k"])])
+def test_the_readings_both_hybrid_cells_share(name, reducer, layer, later):
     """The scan's scope, the convolution's inside it and the convolution's
-    backward kernel are in both hybrid steps and in no dense one."""
+    backward kernel are in both hybrid steps and in no dense one; the
+    convolution's two since PR 44 in the delta-rule cell too, whose nine
+    convolutions are the same function."""
     (entry,) = [m for m in SPEC["per_layer"] if m["name"] == name]
-    assert entry["workloads"] == ["nemotron3_super_l11.seq8k", CELL]
+    assert entry["workloads"] == ["nemotron3_super_l11.seq8k", CELL] + later
     assert (entry["layer"], entry["moves"]) == (layer, "mfu_pct")
     spec = json.loads(
         (run.BENCH / "layer_metrics" / f"{name}.json").read_text())

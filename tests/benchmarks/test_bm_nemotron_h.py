@@ -129,12 +129,19 @@ def test_a_rehearsal_of_a_new_cell_ends_in_a_valid_line(workload, trace):
     new = (NEW_SCOPES | NEW_COUNTERS) & set(line["metrics"])
     if trace and workload == CELL:
         assert new == NEW_SCOPES | NEW_COUNTERS
-        # 128 tokens x 2 rows, 6 of 16 experts a token, 4 held, 2 layers
+        # 128 tokens x 2 rows, 6 of 16 experts a token, 4 held, 2 layers,
+        # the biases solved on the first batch (PR 44): 96 a held expert,
+        # give or take a batch's sampling noise
         pairs = line["metrics"]["moe_pairs_per_step"]["value"]
-        assert 0.5 * 768 < pairs < 1.5 * 768
-        assert line["metrics"]["moe_load_max_over_mean"]["value"] >= 1.0
+        assert 0.85 * 768 < pairs < 1.15 * 768
+        assert 1.0 <= line["metrics"]["moe_load_max_over_mean"]["value"] < 1.4
+        (solved,) = [s for s in said if s.startswith("balance: ")]
+        assert "layers_0 " in solved and "layers_2 " in solved
     else:
         assert not new
+        # a cell without experts says nothing of a solve
+        assert (workload == CELL) == any(
+            s.startswith("balance: ") for s in said)
     assert (CONV <= set(expected)) == bool(trace and workload == CELL)
     assert ("ssm_conv_ms_per_step" in line["metrics"]) == bool(
         trace and workload == CELL)
