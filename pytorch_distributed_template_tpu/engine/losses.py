@@ -330,7 +330,7 @@ def fused_lm_cross_entropy(chunk: int = 256):
             # fuses the add into the matmul. Summed over the whole batch:
             # where the batch is spread over chips the partitioner keeps
             # each chip's partial sum through the loop and crosses once
-            # behind it (tests/test_chip_compile.py reads that it does)
+            # behind it (tests/test_chip_compile_head_loss.py reads that)
             dw = (dw + jnp.einsum(
                 "rd,rv->dv", hc, dlogits,
                 preferred_element_type=jnp.float32)).astype(dw.dtype)

@@ -231,7 +231,7 @@ def make_train_step(model, tx, criterion: Callable,
     (the norm is homogeneous). Nothing else lies between the summed
     gradients and the new state but scalars, so the compiler makes one
     fusion a leaf and the leaf's state crosses memory once
-    (``tests/test_chip_compile.py`` holds the v5e compile to it; the
+    (``tests/test_chip_compile_dense.py`` holds the v5e compile to it; the
     ``optimizer/pass`` line says what was built). The division sits on
     the scalar: exact where the count is a power of two, else a leaf
     differs from ``g / count`` in the last place.

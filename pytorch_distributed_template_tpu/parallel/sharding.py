@@ -49,7 +49,7 @@ def train_step_compile_options(mesh: Mesh,
     synchronous instruction that stops the core for its whole length.
     Each option below changes the scheduled program, and taking any one
     away gives back more synchronous all-reduces (v5e:2x2 compiles of the
-    data-parallel step; tests/test_chip_compile.py holds them to it):
+    data-parallel step; tests/test_chip_compile_dense.py holds them to it):
 
     - ``xla_enable_async_all_reduce``: an all-reduce may be a start and a
       done with other instructions between them at all;

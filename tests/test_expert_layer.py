@@ -1,6 +1,11 @@
 """models/moe.ExpertLayer: experts held here, routed over all published,
 no token dropped; its counters and its biases' rule through the training
-step."""
+step.
+
+The layer, its initialiser and the training state's run jitted, one
+program each, as a training run has them: operation by operation a case
+was a compile a primitive. The token-by-token numpy references are as
+they were."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +24,13 @@ from pytorch_distributed_template_tpu.models.moe import (
 )
 
 D, F, LATENT = 16, 24, 12
+
+
+def train_state(model, tx, length):
+    """`create_train_state` as one jitted program (what the init's forward
+    pass computes is dead code there; run eagerly it was most of a case)."""
+    return jax.jit(lambda: create_train_state(
+        model, tx, np.zeros((1, length), np.int32), seed=0))()
 
 
 def layer(**kw):
@@ -54,7 +66,7 @@ def plain(params, x, lo, n_held, top_k, scale, softmax=False):
 
 
 def init(module, x, seed=0, bias=0.3):
-    params = module.init(jax.random.key(seed), x)["params"]
+    params = jax.jit(module.init)(jax.random.key(seed), x)["params"]
     if "selection_bias" in params:     # a bias that changes the choice
         params = dict(params, selection_bias=bias * jax.random.normal(
             jax.random.key(seed + 1), params["selection_bias"].shape))
@@ -72,7 +84,7 @@ def test_layer_is_the_plain_sum_over_the_experts_held(kw):
     x = jax.random.normal(jax.random.key(2), (3, 20, D))
     params = init(module, x)
     with jax.default_matmul_precision("highest"):
-        got = module.apply({"params": params}, x)
+        got = jax.jit(module.apply)({"params": params}, x)
     lo, n_held = module.held[0], module.held[1] or module.n_routed
     want = plain(params, np.asarray(x).reshape(-1, D), lo, n_held,
                  module.top_k, module.scale, module.router == "softmax")
@@ -96,7 +108,8 @@ def test_no_token_is_dropped_when_every_token_picks_the_same_expert(expert):
     x = jax.random.normal(jax.random.key(3), (3, 20, D))
     params = skewed(module, x, expert)
     with jax.default_matmul_precision("highest"):
-        got, sown = module.apply({"params": params}, x, mutable=["counters"])
+        got, sown = jax.jit(lambda p: module.apply(
+            {"params": p}, x, mutable=["counters"]))(params)
     counters = sown["counters"]
     assert float(counters["moe_pairs_here"]) >= 60
     assert float(counters["moe_tokens_unserved"]) == 0
@@ -143,12 +156,13 @@ def test_the_bias_steers_the_choice_and_gets_no_gradient():
     def loss(p):
         return jnp.sum(module.apply({"params": p}, x) ** 2)
 
-    grads = jax.grad(loss)(params)
+    grads = jax.jit(jax.grad(loss))(params)
     assert not np.any(np.asarray(grads["selection_bias"]))
     assert np.any(np.asarray(grads["router"]))      # by the weights
     moved = dict(params, selection_bias=-params["selection_bias"])
-    was = module.apply({"params": params}, x)
-    assert float(jnp.abs(module.apply({"params": moved}, x) - was).max()) \
+    apply = jax.jit(module.apply)
+    was = apply({"params": params}, x)
+    assert float(jnp.abs(apply({"params": moved}, x) - was).max()) \
         > 0.1 * float(jnp.abs(was).max())
 
 
@@ -168,7 +182,7 @@ def test_the_steps_metrics_carry_the_models_counters(accum):
     model = MODELS.get("TinyNemotronH")(pattern="EME*", moe_held=(2, 4))
     tokens = jax.random.randint(jax.random.key(6), (4, 32), 0, 256)
     tx = optax.adamw(1e-3)
-    state = create_train_state(model, tx, np.zeros((1, 32), np.int32), seed=0)
+    state = train_state(model, tx, 32)
     step = jax.jit(make_train_step(
         model, tx, lm_cross_entropy, [], input_key="tokens",
         target_key="tokens", grad_accum_steps=accum))
@@ -188,7 +202,7 @@ def test_the_steps_metrics_carry_the_models_counters(accum):
 def test_a_model_without_counters_gets_none():
     model = MODELS.get("TinyLlama")()
     tx = optax.adamw(1e-3)
-    state = create_train_state(model, tx, np.zeros((1, 16), np.int32), seed=0)
+    state = train_state(model, tx, 16)
     step = jax.jit(make_train_step(model, tx, lm_cross_entropy, [],
                                    input_key="tokens", target_key="tokens"))
     _, metrics = step(state, {"tokens": jnp.zeros((2, 16), jnp.int32),
@@ -210,7 +224,7 @@ def test_the_biases_rule_by_hand():
 
 def step_of(model, accum=1):
     tx = optax.adamw(1e-3)
-    state = create_train_state(model, tx, np.zeros((1, 32), np.int32), seed=0)
+    state = train_state(model, tx, 32)
     return state, jax.jit(make_train_step(
         model, tx, lm_cross_entropy, [], input_key="tokens",
         target_key="tokens", grad_accum_steps=accum))
@@ -228,8 +242,9 @@ def test_a_step_moves_each_bias_against_its_experts_load(accum):
     new, metrics = step(state, {"tokens": tokens,
                                 "mask": jnp.ones((4,), bool)})
     assert "router_load" not in metrics
-    _, sown = model.apply({"params": state.params}, tokens, train=True,
-                          mutable=["router_load"])
+    _, sown = jax.jit(lambda p: model.apply(
+        {"params": p}, tokens, train=True, mutable=["router_load"]))(
+        state.params)
     for name in ("layers_0", "layers_2"):
         load = np.asarray(sown["router_load"][name]["mixer"]["selection_bias"])
         assert load.shape == (8,) and load.sum() == 128 * 2
@@ -292,7 +307,7 @@ def test_gated_layer_is_the_plain_sum_over_the_experts_held(kw):
     assert ("shared" in params) == bool(module.shared_d_ff)
     assert "shared_up" not in params and "latent_down" not in params
     with jax.default_matmul_precision("highest"):
-        got = module.apply({"params": params}, x)
+        got = jax.jit(module.apply)({"params": params}, x)
     lo, n_held = module.held[0], module.held[1] or module.n_routed
     want = plain_gated(params, np.asarray(x).reshape(-1, D), lo, n_held,
                        module.top_k, module.scale)
@@ -306,7 +321,8 @@ def test_gated_no_token_is_dropped_when_all_pick_one_expert(expert):
     x = jax.random.normal(jax.random.key(3), (3, 20, D))
     params = skewed(module, x, expert)
     with jax.default_matmul_precision("highest"):
-        got, sown = module.apply({"params": params}, x, mutable=["counters"])
+        got, sown = jax.jit(lambda p: module.apply(
+            {"params": p}, x, mutable=["counters"]))(params)
     counters = sown["counters"]
     assert float(counters["moe_pairs_here"]) >= 60
     assert float(counters["moe_tokens_unserved"]) == 0

@@ -1214,7 +1214,14 @@ def test_router_deadline_forwarded_and_expiry_is_504(tmp_path):
         assert fakes[0].requests
         fwd = int(fakes[0].requests[0]["deadline_ms"])
         assert 0 < fwd <= 300
-        m = _get_json(url, "/metrics?format=json")
+        # the handler counts after it has answered: on a loaded machine
+        # the scrape can come first (PR 45's whole run read 0 once)
+        until = time.monotonic() + 5.0
+        while True:
+            m = _get_json(url, "/metrics?format=json")
+            if m["deadline_expired_total"] or time.monotonic() > until:
+                break
+            time.sleep(0.05)
         assert m["deadline_expired_total"] == 1
         assert m["router_e2e_seconds"]["count"] == 0   # out of SLO
         # malformed header is the client's error

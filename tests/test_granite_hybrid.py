@@ -77,10 +77,11 @@ def test_fused_tied_head_is_the_scaled_logits_loss():
     """`fused_head` hands the loss the normed hidden state over
     `logits_scaling` and the embedding's transpose: the fused loss over
     them is the plain loss over the logits, and so is the gradient, the
-    embedding's from both its uses."""
+    embedding's from both its uses. (Each side is one jitted program:
+    run operation by operation the two stacks were most of a minute.)"""
     tokens = jax.random.randint(jax.random.key(0), (3, 24), 0, 256)
     plain, fused = _tiny(), _tiny(fused_head=True)
-    params = plain.init(jax.random.key(1), tokens)
+    params = jax.jit(plain.init)(jax.random.key(1), tokens)
     assert "lm_head" not in params["params"]
     loss = fused_lm_cross_entropy(chunk=8)
 
@@ -91,10 +92,10 @@ def test_fused_tied_head_is_the_scaled_logits_loss():
     def fused_loss(p):
         return jnp.mean(loss(fused.apply(p, tokens, train=True), tokens))
 
-    hidden, w = fused.apply(params, tokens)
-    logits = plain.apply(params, tokens)
+    hidden, w = jax.jit(fused.apply)(params, tokens)
+    logits = jax.jit(plain.apply)(params, tokens)
     np.testing.assert_allclose(hidden @ w, logits, rtol=0, atol=1e-5)
-    (lp, gp), (lf, gf) = (jax.value_and_grad(f)(params)
+    (lp, gp), (lf, gf) = (jax.jit(jax.value_and_grad(f))(params)
                           for f in (plain_loss, fused_loss))
     assert float(lf) == pytest.approx(float(lp), rel=1e-6)
     for (path, a), c in zip(jax.tree_util.tree_leaves_with_path(gp),
@@ -112,7 +113,8 @@ def test_granite_step_carries_its_scopes():
 
     model = _tiny(remat=True)
     tx = optax.adamw(1e-3)
-    state = create_train_state(model, tx, np.zeros((1, 32), np.int32), seed=0)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, tx, np.zeros((1, 32), np.int32), seed=0))     # shapes alone
     step = make_train_step(model, tx, lm_cross_entropy, [],
                            input_key="tokens", target_key="tokens")
     batch = {"tokens": jnp.zeros((2, 32), jnp.int32),

@@ -1,6 +1,11 @@
 """ops/linear_attention.py: the chunked gated delta rule against the
 recurrence it stands for, one position at a time; and ops/ssm's
-convolution without a bias, which the KDA mixer calls three times."""
+convolution without a bias, which the KDA mixer calls three times.
+
+The chunked form runs jitted, as the mixer runs it: a case is one compile
+at its shapes, where operation by operation it was a compile a primitive
+and up to forty seconds. `kda_recurrence` is called as it is written; the
+gradients of both go through one jitted helper."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,9 +42,12 @@ def close(got, want, tol, name=""):
         atol=tol * float(jnp.max(jnp.abs(want))), err_msg=name)
 
 
+kda = jax.jit(kda_chunked, static_argnums=(5, 6))
+
+
 def grads(scan, args):
-    return jax.grad(lambda *a: jnp.sum(jnp.sin(scan(*a))),
-                    argnums=range(5))(*args)
+    return jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(scan(*a))),
+                            argnums=range(5)))(*args)
 
 
 # 37 and 21: lengths the chunk of 16 does not divide; 16 and 48: whole
@@ -51,7 +59,7 @@ def grads(scan, args):
 def test_chunked_rule_is_the_recurrence(t, chunk, sub):
     args = operands(t)
     with jax.default_matmul_precision("highest"):
-        got = kda_chunked(*args, chunk, sub)
+        got = kda(*args, chunk, sub)
         want = kda_recurrence(*args)
     assert got.shape == want.shape == (2, t, 3, 12)
     close(got, want, 2e-5)
@@ -84,7 +92,7 @@ def test_where_the_factorised_form_overflows_the_sub_chunks_are_exact():
     assert float(jnp.min(summed)) < -100 and float(jnp.max(summed)) < -88
     assert not bool(jnp.all(jnp.isfinite(naive(*args))))
     with jax.default_matmul_precision("highest"):
-        got, want = kda_chunked(*args), kda_recurrence(*args)
+        got, want = kda(*args), kda_recurrence(*args)
         assert bool(jnp.all(jnp.isfinite(got)))
         close(got, want, 2e-5)
         for name, g, w in zip("q k v g beta".split(),
@@ -103,7 +111,7 @@ def test_beta_near_two_and_keys_that_repeat():
     k = k.at[:, 4::4].set(k[:, :1])
     args = (q, k, v, g, beta)
     with jax.default_matmul_precision("highest"):
-        close(kda_chunked(*args), kda_recurrence(*args), 5e-5)
+        close(kda(*args), kda_recurrence(*args), 5e-5)
         for name, got, want in zip("q k v g beta".split(),
                                    grads(kda_chunked, args),
                                    grads(kda_recurrence, args)):
@@ -116,7 +124,7 @@ def test_bfloat16_operands_stay_near_the_float32_recurrence():
     through five chunks; g, beta and the state float32: within 3% of the
     largest output. The float32 path stays at 2e-5 (above)."""
     args = operands(300, dtype=jnp.bfloat16, seed=7)
-    got = kda_chunked(*args)
+    got = kda(*args)
     assert got.dtype == jnp.bfloat16
     close(got, kda_recurrence(*args), 3e-2)
 
@@ -125,11 +133,11 @@ def test_padding_passes_the_state_unchanged():
     """Positions of g = 0 and beta = 0 neither decay nor write."""
     args = operands(40, seed=9)
     with jax.default_matmul_precision("highest"):
-        whole = kda_chunked(*args, 16)
+        whole = kda(*args, 16)
         # the same 40 positions with 8 idle ones in front of the last 8
         idle = [jnp.concatenate([z[:, :32], jnp.zeros_like(z[:, :8]),
                                  z[:, 32:]], axis=1) for z in args]
-        spread = kda_chunked(*idle, 16)
+        spread = kda(*idle, 16)
     close(spread[:, :32], whole[:, :32], 2e-5)
     close(spread[:, 40:], whole[:, 32:], 2e-5)
 
@@ -160,7 +168,9 @@ def test_the_line_says_chunks_and_the_largest_intermediate(caplog):
     from pytorch_distributed_template_tpu.observability import trace
     trace._said.clear()
     with caplog.at_level(logging.INFO, logger=linear_attention.__name__):
-        kda_chunked(*operands(150, b=1, h=2, dk=8, dv=8), 64, 16)
+        # traced alone: the line is said where the shapes are read
+        jax.eval_shape(lambda: kda_chunked(
+            *operands(150, b=1, h=2, dk=8, dv=8), 64, 16))
     said = [r for r in caplog.records if r.msg.startswith("kda/chunks")]
     assert len(said) == 1
     record = said[0].args

@@ -92,3 +92,31 @@ def test_maybe_tqdm_gating():
     forced = maybe_tqdm(iter(data), total=3, desc="t", enable=True)
     assert type(forced).__name__ == "tqdm"
     assert list(forced) == data
+
+
+def test_slowest_modules_report_sees_xdists_workers(tmp_path):
+    """tests/conftest.py sums the modules' times from the test reports,
+    which reach xdist's controller from every worker: under the tier-1
+    command's `-p xdist -n .. --dist loadfile` the table lists every
+    file, with the sum and the sum over the workers beside it."""
+    import shutil
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    shutil.copy(Path(__file__).with_name("conftest.py"), tmp_path)
+    for name in ("one", "two"):
+        (tmp_path / f"test_{name}.py").write_text(
+            f"def test_{name}():\n    pass\n")
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "xdist", "-n", "2", "--dist", "loadfile", "-p", "no:randomly",
+         # plugins the toy suite has no use for: half of its start-up
+         "-p", "no:hypothesispytest", "-p", "no:jaxtyping",
+         "-p", "no:typeguard", str(tmp_path)],
+        cwd=tmp_path, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stdout + out.stderr
+    assert "slowest test modules" in out.stdout, out.stdout
+    report = out.stdout.split("slowest test modules", 1)[1]
+    assert "test_one.py" in report and "test_two.py" in report
+    assert "all 2 modules" in report and "over 2 worker(s)" in report

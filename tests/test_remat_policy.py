@@ -183,7 +183,7 @@ def test_kept_values_change_no_arithmetic(capacity, family, matmuls,
     tokens = _tokens()
     plain = MODELS.get(family)(remat=False, attn_impl=attn_impl)
     remat = MODELS.get(family)(remat=True, attn_impl=attn_impl)
-    params = plain.init(jax.random.key(1), tokens)["params"]
+    params = jax.jit(plain.init)(jax.random.key(1), tokens)["params"]
 
     def run(model):
         fn = jax.value_and_grad(_loss_fn(model, tokens))
@@ -219,7 +219,7 @@ def test_unknown_capacity_is_todays_policy(capacity, family, caplog):
     assert rp.device_capacity_bytes() is None
     tokens = _tokens()
     model = MODELS.get(family)(remat=True)
-    params = model.init(jax.random.key(1), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.key(1), tokens)["params"]
     with caplog.at_level(logging.INFO, logger=rp.logger.name):
         text = str(jax.make_jaxpr(jax.grad(_loss_fn(model, tokens)))(params))
     assert "policy=None" in text or "nothing_saveable" in text
@@ -244,7 +244,7 @@ def test_policy_record_and_determinism(capacity, family, caplog):
     with caplog.at_level(logging.INFO, logger=rp.logger.name):
         for _ in range(2):
             model = MODELS.get(family)(remat=True, attn_impl="flash")
-            params = model.init(jax.random.key(1), tokens)["params"]
+            params = jax.jit(model.init)(jax.random.key(1), tokens)["params"]
             jax.make_jaxpr(jax.grad(_loss_fn(model, tokens)))(params)
     (rec,) = _record()
     assert rec["names"].split(",")[:2] == ["attn_out", "attn_lse"]
@@ -269,7 +269,7 @@ def test_tight_capacity_keeps_a_prefix(capacity, family):
     tokens = _tokens()
     model = MODELS.get(family)(remat=True)
     plain = MODELS.get(family)(remat=False)
-    params = plain.init(jax.random.key(1), tokens)["params"]
+    params = jax.jit(plain.init)(jax.random.key(1), tokens)["params"]
     capacity(64 * GIB)
     jax.make_jaxpr(jax.grad(_loss_fn(model, tokens)))(params)
     (full,) = _record()
@@ -289,7 +289,7 @@ def test_data_parallel_mesh_picks_what_one_device_picks(capacity, family):
     four-chip cell against its one-chip twin)."""
     tokens1, tokens4 = _tokens(b=1), _tokens(b=4)
     one = MODELS.get(family)(remat=True, attn_impl="flash")
-    params = one.init(jax.random.key(1), tokens1)["params"]
+    params = jax.jit(one.init)(jax.random.key(1), tokens1)["params"]
     # room for the attention output, its log-sum-exp and the projections
     # of one row, not of four
     capacity(64 * GIB)
@@ -332,7 +332,7 @@ def test_sequence_parallel_model_keeps_what_its_mesh_leaves(capacity,
     sp = MODELS.get("TinyLlama")(remat=True, attn_impl=attn_impl, mesh=mesh)
     plain = MODELS.get("TinyLlama")(remat=False, attn_impl=attn_impl,
                                     mesh=mesh)
-    params = plain.init(jax.random.key(1), tokens)["params"]
+    params = jax.jit(plain.init)(jax.random.key(1), tokens)["params"]
     capacity(64 * GIB)
     got = jax.jit(jax.grad(_loss_fn(sp, tokens)))(params)
     (rec,) = _record()
@@ -353,7 +353,7 @@ def test_gradient_outside_a_training_step_keeps_nothing(capacity, family):
     capacity(64 * GIB)
     tokens = _tokens()
     model = MODELS.get(family)(remat=True)
-    params = model.init(jax.random.key(1), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.key(1), tokens)["params"]
     text = str(jax.make_jaxpr(
         jax.grad(_loss_fn(model, tokens, held=None)))(params))
     assert "policy=None" in text or "nothing_saveable" in text
@@ -608,17 +608,19 @@ def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
     get_recorder().clear()
     model = MODELS.get("TinyNemotronH")(pattern="EMEM*", remat=True)
     tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, 256)
-    params = model.init(jax.random.key(1), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.key(1), tokens)["params"]
 
     def loss(p):
         logits = model.apply({"params": p}, tokens, train=True)
         return jnp.mean(lm_cross_entropy(logits, tokens))
 
-    want = jax.value_and_grad(loss)(params)     # outside a step: nothing
+    # each side one jitted program, traced where it stands (a new
+    # function a side, so the second is no cache hit of the first)
+    want = jax.jit(jax.value_and_grad(loss))(params)    # no step: nothing
     monkeypatch.setattr(rp, "device_capacity_bytes",
                         lambda mesh=None: 2 * GIB)
     with caplog.at_level(logging.INFO), rp.step_holds(1 << 20):
-        got = jax.value_and_grad(loss)(params)
+        got = jax.jit(jax.value_and_grad(loss))(params)
     (said,) = [e["args"] for e in get_recorder().snapshot()
                if e["name"] == "remat/policy"]
     assert said["blocks"] == 5
@@ -736,17 +738,19 @@ def test_solar_model_reckons_two_kinds_and_says_so(monkeypatch, caplog):
     get_recorder().clear()
     model = MODELS.get("TinySolarOpen2")(pattern="*KK", remat=True)
     tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, 256)
-    params = model.init(jax.random.key(1), tokens)["params"]
+    params = jax.jit(model.init)(jax.random.key(1), tokens)["params"]
 
     def loss(p):
         logits = model.apply({"params": p}, tokens, train=True)
         return jnp.mean(lm_cross_entropy(logits, tokens))
 
-    want = jax.value_and_grad(loss)(params)     # outside a step: nothing
+    # each side one jitted program, traced where it stands (a new
+    # function a side, so the second is no cache hit of the first)
+    want = jax.jit(jax.value_and_grad(loss))(params)    # no step: nothing
     monkeypatch.setattr(rp, "device_capacity_bytes",
                         lambda mesh=None: 2 * GIB)
     with caplog.at_level(logging.INFO), rp.step_holds(1 << 20):
-        got = jax.value_and_grad(loss)(params)
+        got = jax.jit(jax.value_and_grad(loss))(params)
     (said,) = [e["args"] for e in get_recorder().snapshot()
                if e["name"] == "remat/policy"]
     assert said["blocks"] == 3

@@ -1,5 +1,14 @@
 """ops/ssm.py: the chunked Mamba-2 scan against the recurrence it stands
-for, one position at a time."""
+for, one position at a time.
+
+The forms under test run jitted, as the models run them: a case is then
+one compile at its shapes, where operation by operation it was a compile
+a primitive and three to thirteen seconds. The references (the
+recurrence, the four-slice convolution) are called as they are written;
+their gradients are jitted too, which changes no line of them. The cases
+that compare bit for bit stay operation by operation on both sides."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,6 +27,15 @@ def operands(t, b=2, h=4, p=8, g=2, n=16, dtype=jnp.float32, seed=0):
             jax.random.normal(k[5], (h,)))
 
 
+scan = jax.jit(ssd_scan, static_argnums=6)
+
+
+def grads_through(form):
+    """The jitted gradient, by all six operands, of `sum(sin(form))`."""
+    return jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(form(*a))),
+                            argnums=range(6)))
+
+
 # 37 and 21: lengths the chunk of 16 does not divide (two chunks and a
 # part, one and a part); 16 and 48: whole chunks; 5: less than one
 @pytest.mark.parametrize("t,chunk", [(37, 16), (21, 16), (16, 16), (48, 16),
@@ -25,7 +43,7 @@ def operands(t, b=2, h=4, p=8, g=2, n=16, dtype=jnp.float32, seed=0):
 def test_chunked_scan_is_the_recurrence(t, chunk):
     args = operands(t)
     with jax.default_matmul_precision("highest"):
-        got = ssd_scan(*args, chunk)
+        got = scan(*args, chunk)
         want = ssd_recurrence(*args)
     assert got.shape == want.shape == (2, t, 4, 8)
     np.testing.assert_allclose(got, want, rtol=0,
@@ -39,7 +57,7 @@ def test_chunked_scan_is_the_recurrence(t, chunk):
 def test_sixty_four_heads_in_one_group_at_chunk_256(t):
     args = operands(t, b=1, h=64, p=16, g=1, n=32, seed=11)
     with jax.default_matmul_precision("highest"):
-        got = ssd_scan(*args, 256)
+        got = scan(*args, 256)
         want = ssd_recurrence(*args)
     assert got.shape == want.shape == (1, t, 64, 16)
     np.testing.assert_allclose(got, want, rtol=0,
@@ -48,11 +66,9 @@ def test_sixty_four_heads_in_one_group_at_chunk_256(t):
 
 def test_sixty_four_heads_in_one_group_have_the_recurrences_gradient():
     args = operands(300, b=1, h=64, p=8, g=1, n=16, seed=13)
-    loss = lambda scan: lambda *a: jnp.sum(jnp.sin(scan(*a)))  # noqa: E731
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(loss(lambda *a: ssd_scan(*a, 256)),
-                       argnums=range(6))(*args)
-        want = jax.grad(loss(ssd_recurrence), argnums=range(6))(*args)
+        got = grads_through(lambda *a: ssd_scan(*a, 256))(*args)
+        want = grads_through(ssd_recurrence)(*args)
     for name, g, w in zip("x dt a b c d".split(), got, want):
         np.testing.assert_allclose(
             g, w, rtol=0, atol=2e-5 * float(jnp.max(jnp.abs(w))),
@@ -62,14 +78,9 @@ def test_sixty_four_heads_in_one_group_have_the_recurrences_gradient():
 @pytest.mark.parametrize("t,chunk", [(37, 16), (16, 16)])
 def test_chunked_scan_has_the_recurrences_gradient(t, chunk):
     args = operands(t, seed=3)
-
-    def through(scan):
-        return lambda *a: jnp.sum(jnp.sin(scan(*a)))
-
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(through(lambda *a: ssd_scan(*a, chunk)),
-                       argnums=range(6))(*args)
-        want = jax.grad(through(ssd_recurrence), argnums=range(6))(*args)
+        got = grads_through(lambda *a: ssd_scan(*a, chunk))(*args)
+        want = grads_through(ssd_recurrence)(*args)
     for name, g, w in zip("x dt a b c d".split(), got, want):
         np.testing.assert_allclose(
             g, w, rtol=0, atol=2e-5 * float(jnp.max(jnp.abs(w))),
@@ -82,14 +93,14 @@ def test_the_state_is_carried_from_chunk_to_chunk():
     x, dt, a, b, c, d = operands(48, seed=5)
     a = jnp.full_like(a, -0.01)
     with jax.default_matmul_precision("highest"):
-        whole = ssd_scan(x, dt, a, b, c, d, 16)
-        cut = ssd_scan(x.at[:, :16].set(0.0), dt, a, b, c, d, 16)
+        whole = scan(x, dt, a, b, c, d, 16)
+        cut = scan(x.at[:, :16].set(0.0), dt, a, b, c, d, 16)
     assert float(jnp.max(jnp.abs(whole[:, 32:] - cut[:, 32:]))) > 1e-2
 
 
 def test_bfloat16_operands_keep_their_type_and_stay_close():
     args = operands(64, dtype=jnp.bfloat16, seed=7)
-    got = ssd_scan(*args, 16)
+    got = scan(*args, 16)
     assert got.dtype == jnp.bfloat16
     want = ssd_recurrence(*args)
     err = jnp.abs(got.astype(jnp.float32) - want)
@@ -155,7 +166,7 @@ def test_convolution_is_the_four_slice_form(b, t, c, k, dtype):
     from pytorch_distributed_template_tpu.ops.ssm import causal_conv_silu
 
     xbc, taps, bias, weight = conv_operands(b, t, c, k, dtype)
-    got = causal_conv_silu(xbc, taps, bias)
+    got = jax.jit(causal_conv_silu)(xbc, taps, bias)
     want = four_slices(xbc, taps, bias)
     assert got.dtype == want.dtype == dtype and got.shape == (b, t, c)
     # one unit in the last place of the type it is rounded to
@@ -163,9 +174,9 @@ def test_convolution_is_the_four_slice_form(b, t, c, k, dtype):
     np.testing.assert_allclose(
         got.astype(jnp.float32), want.astype(jnp.float32), rtol=ulp,
         atol=1e-6)
-    got = jax.grad(conv_loss(causal_conv_silu, weight), (0, 1, 2))(
+    got = jax.jit(jax.grad(conv_loss(causal_conv_silu, weight), (0, 1, 2)))(
         xbc, taps, bias)
-    want = jax.grad(conv_loss(four_slices, weight), (0, 1, 2))(
+    want = jax.jit(jax.grad(conv_loss(four_slices, weight), (0, 1, 2)))(
         xbc, taps, bias)
     assert got[0].dtype == dtype and got[1].dtype == got[2].dtype \
         == jnp.float32
@@ -288,8 +299,10 @@ def test_backward_kernel_is_the_four_slice_forms_gradient(
         monkeypatch.setattr(ssm, "conv_blocks", lambda t, c, size: blocks)
     zxd, taps, bias, dy = conv_operands(b, t, width, k, dtype, seed=9)
     taps, bias, dy = taps[:, :c], bias[:c], dy[..., :c]
-    got = ssm._conv_bwd_pallas(zxd, start, taps, bias, dy, interpret=True)
-    want = jax.grad(conv_loss(four_slices, dy), (0, 1, 2))(
+    # a new jitted function a case: the blocks are read while it is traced
+    got = jax.jit(functools.partial(ssm._conv_bwd_pallas, interpret=True),
+                  static_argnums=1)(zxd, start, taps, bias, dy)
+    want = jax.jit(jax.grad(conv_loss(four_slices, dy), (0, 1, 2)))(
         zxd[..., start:start + c], taps, bias)
     assert_conv_gradients_close(got, want, b * t, dtype)
 

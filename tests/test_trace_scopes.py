@@ -168,7 +168,9 @@ def _tiny_step_text(grad_accum_steps: int) -> str:
         remat=True, fused_head=True)
     tx = optax.adamw(1e-3)
     tokens = np.zeros((4, 64), np.int32)
-    state = create_train_state(model, tx, tokens)
+    # shapes alone: lowering reads no value, and the init's forward pass,
+    # run eagerly, is most of what this test took
+    state = jax.eval_shape(lambda: create_train_state(model, tx, tokens))
     step = make_train_step(
         model, tx, resolve_loss({"type": "fused_lm_cross_entropy",
                                  "args": {"chunk": 32}}),
@@ -275,7 +277,8 @@ def test_hybrid_step_carries_its_scopes():
 
     model = MODELS.get("TinyNemotronH")(pattern="EM*", remat=True)
     tx = optax.adamw(1e-3)
-    state = create_train_state(model, tx, np.zeros((1, 32), np.int32), seed=0)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, tx, np.zeros((1, 32), np.int32), seed=0))     # shapes alone
     step = make_train_step(model, tx, lm_cross_entropy, [],
                            input_key="tokens", target_key="tokens")
     batch = {"tokens": jnp.zeros((2, 32), jnp.int32),
@@ -401,8 +404,10 @@ def test_delta_rule_step_carries_its_scopes(caplog):
     model = MODELS.get("TinySolarOpen2")(pattern="*K", remat=True)
     tx = optax.adamw(1e-3)
     with caplog.at_level(logging.INFO):
-        state = create_train_state(model, tx, np.zeros((1, 40), np.int32),
-                                   seed=0)
+        # shapes alone; the init's probe is traced all the same, and says
+        # its one row
+        state = jax.eval_shape(lambda: create_train_state(
+            model, tx, np.zeros((1, 40), np.int32), seed=0))
         step = make_train_step(model, tx, lm_cross_entropy, [],
                                input_key="tokens", target_key="tokens")
         batch = {"tokens": jnp.zeros((2, 40), jnp.int32),

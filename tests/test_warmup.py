@@ -242,20 +242,9 @@ def test_abstract_batch_matches_loader_and_transform():
 
 @pytest.fixture
 def cache_config(monkeypatch):
-    """Restore jax's cache settings after a test that moved them, and
-    start from the rule's case (c): no env var."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    names = ("jax_compilation_cache_dir",
-             "jax_persistent_cache_min_compile_time_secs",
-             "jax_persistent_cache_min_entry_size_bytes",
-             "jax_compilation_cache_max_size")
-    old = {n: getattr(jax.config, n) for n in names}
+    """Start from the rule's case (c): no env var. (tests/conftest.py puts
+    jax's cache settings back after every test, these too.)"""
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
-    yield
-    for n, v in old.items():
-        jax.config.update(n, v)
-    compilation_cache.reset_cache()   # detach from the test's dir
 
 
 def test_persistent_cache_roundtrip(tmp_path, cache_config):
