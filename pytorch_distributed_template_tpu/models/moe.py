@@ -38,6 +38,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config.registry import MODELS
@@ -289,6 +290,24 @@ class MoeMlp(nn.Module):
 # The dropless layer: experts held here, routed over all published
 # ---------------------------------------------------------------------------
 
+@jax.custom_vjp
+def gradient_as_stored(leaf):
+    """``leaf`` itself; its gradient leaves in row-major order, the order
+    the leaf is stored in. The optimizer's fusion runs in its gradient's
+    order, and the jit's arguments and donated results cannot follow it:
+    a gradient in another order costs six copies of the leaf a step, the
+    parameter and both moments in and the three results out."""
+    return leaf
+
+
+def _gradient_as_stored_bwd(_, g):
+    row_major = Layout(major_to_minor=tuple(range(g.ndim)))
+    return (with_layout_constraint(g, row_major),)
+
+
+gradient_as_stored.defvjp(lambda leaf: (leaf, None), _gradient_as_stored_bwd)
+
+
 def held_experts(x, weight, up, down):
     """What the experts held give: ``x [S, D]`` tokens, ``weight [S, E]``
     the weight of token s at held expert e (0 where it did not choose
@@ -296,9 +315,10 @@ def held_experts(x, weight, up, down):
     float32: ``sum_e weight[s, e] * relu(x[s] @ up[e])**2 @ down[e]``,
     every held expert over every token, two batched products. The first
     is named ``moe_experts_up`` for the checkpoint policy: the backward
-    reads it, the second's output it does not."""
+    reads it, the second's output it does not. ``up``'s gradient leaves
+    as ``up`` is stored (``gradient_as_stored``)."""
     act = jnp.square(jax.nn.relu(checkpoint_name(
-        jnp.einsum("sd,edf->esf", x, up.astype(x.dtype)),
+        jnp.einsum("sd,edf->esf", x, gradient_as_stored(up.astype(x.dtype))),
         "moe_experts_up")))
     out = jnp.einsum("esf,efd->esd", act, down.astype(x.dtype))
     # a product and a sum the compiler fuses: no float32 copy of `out`
@@ -325,12 +345,15 @@ def held_gated_experts(x, weight, gate, up, down):
     result is made (537 MB at 8 experts, 8192 tokens, 4096 wide). The
     first two products are named ``moe_experts_gate`` and
     ``moe_experts_up`` as the einsums make them: kept, the backward runs
-    neither a second time."""
+    neither a second time. The gradients of ``gate`` and ``up`` leave as
+    the two are stored (``gradient_as_stored``)."""
     pre = checkpoint_name(
-        jnp.einsum("sd,edf->esf", x, gate.astype(x.dtype)),
+        jnp.einsum("sd,edf->esf", x,
+                   gradient_as_stored(gate.astype(x.dtype))),
         "moe_experts_gate")
     lin = checkpoint_name(
-        jnp.einsum("sd,edf->esf", x, up.astype(x.dtype)), "moe_experts_up")
+        jnp.einsum("sd,edf->esf", x, gradient_as_stored(up.astype(x.dtype))),
+        "moe_experts_up")
     act = jax.nn.silu(pre) * lin * weight.T[:, :, None].astype(x.dtype)
     return jnp.einsum("esf,efd->sd", act, down.astype(x.dtype),
                       preferred_element_type=jnp.float32)
@@ -373,7 +396,10 @@ class ExpertLayer(nn.Module):
     ``gated`` makes every expert three matrices,
     ``(silu(l @ gate_e) * (l @ up_e)) @ down_e`` (``held_gated_experts``),
     and the shared expert a ``SwiGLU``; the routing, the mask and the
-    counters are the same.
+    counters are the same. Either way the gradient of a matrix that a
+    first product reads leaves the layer ``[E][D][F]`` in memory, as the
+    leaf lies, so that the optimizer updates it where it lies
+    (``gradient_as_stored``).
 
     ``shared_d_ff`` adds one expert every token takes. Counters of the
     step, sown under ``counters`` (engine/steps.py carries them):

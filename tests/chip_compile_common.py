@@ -149,6 +149,36 @@ def _compiled_train_step(model, mesh, batch, seq, monkeypatch, without=None):
     return options, compiled
 
 
+def _step_compiled_once(model, topo, batch, seq):
+    """What a module-scoped fixture hands the tests that read one cell's
+    step on one chip, so that the step is compiled once for all of them:
+    the scheduled `text`, the compile's `total_bytes`, and `said`, what
+    the program said while the step was traced with the v5e's capacity
+    supplied to the checkpoint policy (`_said(name, step.said)`)."""
+    from types import SimpleNamespace
+
+    from pytorch_distributed_template_tpu.observability.trace import (
+        get_recorder,
+    )
+    from pytorch_distributed_template_tpu.parallel import build_mesh
+
+    mesh = build_mesh({"data": 1}, devices=topo.devices[:1])
+    with pytest.MonkeyPatch.context() as patch:
+        _v5e_capacity_and_nothing_said(patch)
+        _, compiled = _compiled_train_step(model, mesh, batch, seq, patch)
+        return SimpleNamespace(
+            text=compiled.as_text(), said=get_recorder().snapshot(),
+            total_bytes=_compiled_bytes(compiled))
+
+
+def _compiled_bytes(compiled):
+    """What a compiled program takes of the device: arguments, results
+    and temporaries, a donated argument and its result counted once."""
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
 def _entry_lines(text):
     """The entry computation's instructions, in scheduled order."""
     return re.search(r"^ENTRY [^\n]*\{\n(.*?)^\}", text,
@@ -173,18 +203,23 @@ def _computation(text, name):
 V5E_BYTES_LIMIT = 16_909_336_064    # `bytes_limit` as the chip reports it
 
 
-def _said(name):
+def _said(name, events=None):
+    """What the program said under `name`: in `events`, or so far."""
     from pytorch_distributed_template_tpu.observability.trace import (
         get_recorder,
     )
-    return [e["args"] for e in get_recorder().snapshot()
-            if e["name"] == name]
+    return [e["args"] for e in (get_recorder().snapshot() if events is None
+                                else events) if e["name"] == name]
 
 
 @pytest.fixture
 def fresh_records(monkeypatch):
     """The v5e's capacity supplied to the checkpoint policy, and nothing
     said yet by it or by the fused loss."""
+    _v5e_capacity_and_nothing_said(monkeypatch)
+
+
+def _v5e_capacity_and_nothing_said(monkeypatch):
     from pytorch_distributed_template_tpu.models import remat_policy
     from pytorch_distributed_template_tpu.observability import trace
     from pytorch_distributed_template_tpu.observability.trace import (
@@ -235,6 +270,52 @@ def _scope_instructions(text, scope):
                [(o, *made.get(o, ("", [])))
                 for o in re.findall(r"%([\w\.\-]+)", m.group(4))],
                _computation(text, calls.group(1)) if calls else "")
+
+
+def _copies_of(text, *dims):
+    """The result shapes of the entry computation's `copy` instructions
+    that write an array of one of the shapes `dims`, whatever its dtype
+    and layout: a leaf, a moment or a result moved from one order to
+    another."""
+    return [shape for op, shape, _ in _entry_instructions(text)
+            if op == "copy" and any(a[1] == list(d) for a in _arrays(shape)
+                                    for d in dims)]
+
+
+_PREFETCH = ("copy-done", "copy-start", "slice-done", "slice-start",
+             "ConcatBitcast")
+
+
+def _optimizer_reads(text, leaf):
+    """How the optimizer's pass reads the leaves whose name `leaf` finds:
+    {the jit's parameter: the result shapes of the instruction under the
+    scope `optimizer` that takes it as an operand}, itself or the
+    compiler's prefetch of it into fast memory (whole, `copy-start` and
+    `copy-done`, or in slices joined by a `ConcatBitcast`) in the order it
+    is held in, and no `copy` into another order. The state's parameters,
+    `mu` and `nu` are the jit's arguments, so a leaf the pass updates
+    where it lies has three entries here."""
+    made = {}
+    for ln in _entry_lines(text):
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) "
+                     r"([\w\-]+)\(%?([\w\.\-]*)", ln)
+        if m:
+            joins = 'custom_call_target="ConcatBitcast"' in ln
+            made[m.group(1)] = ("ConcatBitcast" if joins else m.group(3),
+                                m.group(4),
+                                re.findall(r"\{([\d,]*)", m.group(2))[:1])
+
+    def held(name):
+        op, source, order = made.get(name, ("", "", None))
+        while op in _PREFETCH and made.get(source, ("", "", None))[2] == order:
+            name, (op, source, order) = source, made[source]
+        return name
+
+    return {source: [a[1] for a in arrays]
+            for _, _, arrays, operands, _ in _scope_instructions(
+                text, "optimizer")
+            for source in (held(o) for o, _, _ in operands)
+            if re.search(leaf, source)}
 
 
 def _crossings(text):

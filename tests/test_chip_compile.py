@@ -7,8 +7,11 @@ convolutions and the checkpoint policy in test_chip_compile_kernels.py.
 """
 import re
 
+import pytest
+
 from chip_compile_common import (  # noqa: F401  (fixtures by name)
-    V5E_BYTES_LIMIT, _compiled_train_step, _said, _scope_instructions,
+    V5E_BYTES_LIMIT, _compiled_bytes, _compiled_train_step, _copies_of,
+    _optimizer_reads, _said, _scope_instructions, _step_compiled_once,
     fresh_records, topo,
 )
 
@@ -22,26 +25,28 @@ NEMOTRON = dict(
     attn_impl="flash", remat=True, fused_head=True)
 
 
-def test_hybrid_step_lowers_and_fits_for_v5e(topo, monkeypatch,
-                                             fresh_records):
+@pytest.fixture(scope="module")
+def hybrid_step(topo):
+    """The hybrid cell's step, compiled once for the tests that read it."""
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+
+    return _step_compiled_once(MODELS.get("NemotronH")(**NEMOTRON), topo, 2,
+                               8192)
+
+
+def test_hybrid_step_lowers_and_fits_for_v5e(hybrid_step):
     """`nemotron3_super_l11.seq8k`'s step (2 x 8192 on one chip): the
     pattern-built stack with its scan, its dropless expert layers (every
     held expert over every token: no branch, no sorted buffer) and the
     flash kernels compiles for the v5e, the checkpoint policy reckons
     three kinds of block, and the step stays under the chip's
     `bytes_limit`."""
-    from pytorch_distributed_template_tpu.config.registry import MODELS
-    import pytorch_distributed_template_tpu.models  # noqa: F401
-    from pytorch_distributed_template_tpu.parallel import build_mesh
-
-    mesh = build_mesh({"data": 1}, devices=topo.devices[:1])
-    _, compiled = _compiled_train_step(
-        MODELS.get("NemotronH")(**NEMOTRON), mesh, 2, 8192, monkeypatch)
-    text = compiled.as_text()
+    text, said = hybrid_step.text, hybrid_step.said
     assert "ragged-dot" not in text
     for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
         assert re.search(rf"%{kernel}(\.\d+)? = ", text)
-    (policy,) = _said("remat/policy")
+    (policy,) = _said("remat/policy", said)
     assert policy["blocks"] == 11
     # the names it was told before the delta-rule stack brought three more
     assert policy["names"] == ("attn_out,attn_lse,moe_router,qkv_proj,"
@@ -51,21 +56,36 @@ def test_hybrid_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     # the E kind's margin and outside what is kept
     assert policy["budget_bytes"] >= policy["kept_bytes"]
     # the state's init traces one sequence, the step two
-    dispatch = [d for d in _said("moe/dispatch") if d["tokens"] == 16384]
+    dispatch = [d for d in _said("moe/dispatch", said) if d["tokens"] == 16384]
     assert dispatch and all(d["rows"] == 131072 and d["expected"] == 5632
                             for d in dispatch)
     # five mixers' convolutions: a backward kernel each, over two rows
-    conv = [c for c in _said("ssm/conv") if c["positions"] == 16384]
+    conv = [c for c in _said("ssm/conv", said) if c["positions"] == 16384]
     assert conv == [dict(
         taps=4, channels=1280, positions=16384, block_channels=128,
         block_positions=2048, backward="kernel",
         forward_bytes=2 * 16384 * 1280 * 2,
         backward_bytes=3 * 16384 * 1280 * 2)]
     assert len(re.findall(r"%ssm_conv_bwd(\.\d+)? = ", text)) == 5
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
-    assert total < V5E_BYTES_LIMIT - (1 << 30)
+    assert hybrid_step.total_bytes < V5E_BYTES_LIMIT - (1 << 30)
+
+
+def test_the_optimizer_reads_the_hybrids_expert_matrices_where_they_lie(
+        hybrid_step):
+    """As `test_chip_compile_delta_rule.py` has it for three matrices an
+    expert: `held_experts` has one first product, `experts_up`, whose
+    parameter, `mu` and `nu` were copied into the gradient's order and
+    the three results back in each of the five expert layers, 30 copies
+    of 88 MB a step. With `models/moe.gradient_as_stored` none is, and
+    the pass over each of the ten matrices takes the jit's own three
+    arguments (or the compiler's prefetch of one, in the same order)."""
+    assert not _copies_of(hybrid_step.text, (8, 1024, 2688), (8, 2688, 1024))
+    reads = _optimizer_reads(hybrid_step.text, r"experts_(up|down)__")
+    assert len(reads) == 5 * 2 * 3              # layers, matrices, holders
+    for parameter, results in reads.items():
+        wide = (8, 2688, 1024) if "experts_down" in parameter else (
+            8, 1024, 2688)
+        assert results.count(list(wide)) == 3, (parameter, results)
 
 
 def test_granite_step_lowers_and_fits_for_v5e(topo, monkeypatch,
@@ -129,8 +149,6 @@ def test_granite_step_lowers_and_fits_for_v5e(topo, monkeypatch,
             if dtype == "f32" and sorted(dims)[-2:] in ([4352, 8192],
                                                         [4352, 8195])]
     assert not wide, wide
-    m = compiled.memory_analysis()
-    total = (m.argument_size_in_bytes + m.output_size_in_bytes
-             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    total = _compiled_bytes(compiled)
     print(f"granite step: {total} bytes compiled, policy {policy}")
     assert total < V5E_BYTES_LIMIT - (1 << 30)

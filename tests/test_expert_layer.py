@@ -427,3 +427,51 @@ def test_the_relu_squared_layer_has_no_third_matrix():
     assert "experts_gate" not in params and "shared" not in params
     assert {"experts_up", "experts_down", "shared_up", "shared_down",
             "latent_down", "latent_up"} <= set(params)
+
+
+@pytest.mark.parametrize("make,keep,devices", [
+    (gated, False, 1), (gated, True, 1), (layer, False, 1), (layer, True, 1),
+    (gated, True, 8),
+], ids=["gated-nothing-kept", "gated-names-kept", "relu-squared-nothing-kept",
+        "relu-squared-names-kept", "gated-names-kept-eight-devices"])
+def test_a_gradient_as_stored_is_the_same_gradient(make, keep, devices,
+                                                   monkeypatch):
+    """`gradient_as_stored` pins the order a first product's weight
+    gradient leaves in and no value: jitted under `jax.checkpoint`, in
+    bfloat16 over float32 leaves as the cells run it, the layer's output
+    and every gradient are bit for bit those of the same einsums without
+    the rule, with nothing kept and with the products' names kept, and
+    with the tokens' rows spread over the tests' eight devices."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from pytorch_distributed_template_tpu.models import moe
+
+    module = make(dtype=jnp.bfloat16)
+    x = jax.random.normal(jax.random.key(5), (8, 12, D))
+    params = init(module, x)
+    if devices > 1:
+        mesh = Mesh(np.asarray(jax.devices()[:devices]), ("data",))
+        x = jax.device_put(x, NamedSharding(mesh, P("data")))
+        params = jax.device_put(params, NamedSharding(mesh, P()))
+    policies = jax.checkpoint_policies
+    policy = (policies.save_only_these_names("moe_experts_gate",
+                                             "moe_experts_up")
+              if keep else policies.nothing_saveable)
+
+    def value_and_gradients():
+        def loss(p, x):
+            out = jax.checkpoint(
+                lambda p, x: module.apply({"params": p}, x),
+                policy=policy)(p, x)
+            return jnp.sum(jnp.sin(out.astype(jnp.float32))), out
+        f = jax.value_and_grad(loss, argnums=(0, 1), has_aux=True)
+        pinned = str(jax.make_jaxpr(f)(params, x)).count("layout_constraint")
+        return pinned, jax.jit(f)(params, x)
+
+    pinned, got = value_and_gradients()
+    assert pinned == (2 if module.gated else 1)       # gate and up; up
+    monkeypatch.setattr(moe, "gradient_as_stored", lambda leaf: leaf)
+    pinned, want = value_and_gradients()
+    assert pinned == 0
+    assert got[1][0]["experts_up"].dtype == jnp.float32
+    jax.tree.map(np.testing.assert_array_equal, got, want)
