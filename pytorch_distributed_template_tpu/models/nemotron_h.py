@@ -324,6 +324,7 @@ class NemotronHLM(nn.Module):
             "M": BlockKind(ssm, 0, scratch=scratch),
             "E": BlockKind({"moe_router": self.moe_n_routed * 4 // item,
                             "moe_latent": self.moe_latent,
+                            "moe_experts_out": self.moe_latent,
                             "moe_shared_up": self.moe_shared_d_ff,
                             "moe_experts_up": (self.moe_held[1]
                                                or self.moe_n_routed)

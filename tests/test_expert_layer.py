@@ -363,17 +363,17 @@ def test_held_gated_experts_is_the_sum_expert_by_expert_with_its_gradient():
                                    err_msg=name)
 
 
-def _first_products(jaxpr, x_shape, w_shape):
-    """The `sd,edf->esf` products in a jaxpr and all it calls: the
-    `dot_general`s of a `[S, D]` by an `[E, D, F]` operand."""
+def _products(jaxpr, *operand_shapes):
+    """How many `dot_general`s a jaxpr and all it calls hold over
+    operands of these two shapes: `[S, D]` by `[E, D, F]` finds the
+    `sd,edf->esf` products, `[E, S, F]` by `[E, F, D]` the second ones."""
     found = 0
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "dot_general" and sorted(
-                v.aval.shape for v in eqn.invars) == sorted(
-                [x_shape, w_shape]):
+                v.aval.shape for v in eqn.invars) == sorted(operand_shapes):
             found += 1
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            found += _first_products(sub, x_shape, w_shape)
+            found += _products(sub, *operand_shapes)
     return found
 
 
@@ -410,7 +410,7 @@ def test_kept_first_products_are_not_run_a_second_time(fn, names):
         np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-7)
 
     def products(f):
-        return _first_products(
+        return _products(
             jax.make_jaxpr(jax.grad(f, argnums=argnums))(*operands).jaxpr,
             x.shape, firsts[0].shape)
 
@@ -419,6 +419,148 @@ def test_kept_first_products_are_not_run_a_second_time(fn, names):
     # a name short, that product alone is run again
     if len(names) == 2:
         assert products(loss(policies.save_only_these_names(names[0]))) == 3
+
+
+def _made(jaxpr):
+    """The shape of every value a jaxpr and all it calls make."""
+    for eqn in jaxpr.eqns:
+        for v in eqn.outvars:
+            yield tuple(getattr(v.aval, "shape", ()))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _made(sub)
+
+
+@pytest.mark.parametrize("fn,firsts", [
+    (held_gated_experts, 2), (held_experts, 1)],
+    ids=["gated", "relu-squared"])
+def test_no_result_a_held_expert_wide_is_made(fn, firsts):
+    """The token's weight lies on the activation and the second product
+    sums over experts and features at once: neither the forward nor the
+    gradient of any operand makes an `[E, S, D]` value (268 MB a layer
+    at the hybrid cell's shapes), which the weight's gradient read when
+    the weight was laid on the output."""
+    s, e = 40, 3
+    operands = (jnp.ones((s, LATENT)), jnp.ones((s, e)),
+                *[jnp.ones((e, LATENT, F))] * firsts, jnp.ones((e, F, LATENT)))
+
+    def loss(*a):
+        return jnp.sum(jnp.sin(fn(*a)))
+
+    made = set(_made(jax.make_jaxpr(jax.value_and_grad(
+        loss, argnums=range(len(operands))))(*operands).jaxpr))
+    assert (e, s, F) in made and (s, LATENT) in made
+    assert not made & {(e, s, LATENT), (s, e, LATENT), (e, LATENT, s)}
+
+
+@pytest.mark.parametrize("latent", [LATENT, 0], ids=["latent", "no-latent"])
+def test_the_layers_sum_has_a_name_where_a_projection_reads_it(latent):
+    """`latent_up`'s weight gradient reads the routed experts' sum over
+    the experts held, so the sum is named `moe_experts_out` there: kept
+    under `jax.checkpoint`, with the first product not kept, the backward
+    holds the second product once, the forward's, where nothing kept
+    holds it twice; the output and every gradient are the same either
+    way. A layer without `latent` adds the sum to the shared expert's
+    output, no gradient reads it, it makes no such name, and its backward
+    holds the second product once under any policy."""
+    module = layer(latent=latent)
+    x = jax.random.normal(jax.random.key(8), (2, 20, D))
+    params = init(module, x)
+    named = str(jax.make_jaxpr(module.apply)({"params": params}, x)).count(
+        "name=moe_experts_out")
+    assert named == (1 if latent else 0)
+    width, s, e = latent or D, 2 * 20, module.held[1]
+
+    def loss(policy):
+        def f(p, x):
+            out = jax.checkpoint(
+                lambda p, x: module.apply({"params": p}, x),
+                policy=policy)(p, x)
+            return jnp.sum(jnp.sin(out))
+        return jax.value_and_grad(f, argnums=(0, 1))
+
+    def seconds(f):
+        return _products(jax.make_jaxpr(f)(params, x).jaxpr,
+                         (e, s, F), (e, F, width))
+
+    policies = jax.checkpoint_policies
+    keeping = loss(policies.save_only_these_names("moe_experts_out"))
+    nothing = loss(policies.nothing_saveable)
+    # without a latent nothing reads the sum, and jax drops the second
+    # product from the recomputation whatever the policy
+    assert seconds(nothing) == (2 if latent else 1)
+    assert seconds(keeping) == 1
+    got, want = jax.jit(keeping)(params, x), jax.jit(nothing)(params, x)
+    np.testing.assert_array_equal(got[0], want[0])
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(g, w, rtol=1e-6, atol=1e-7)
+
+
+def held_experts_weight_on_the_output(x, weight, up, down):
+    """`held_experts` as it was until PR 47: the `[E, S, D]` result of
+    the second product made, the weight laid on it in float32."""
+    act = jnp.square(jax.nn.relu(jnp.einsum("sd,edf->esf", x,
+                                            up.astype(x.dtype))))
+    out = jnp.einsum("esf,efd->esd", act, down.astype(x.dtype))
+    return jnp.sum(weight.astype(jnp.float32).T[:, :, None]
+                   * out.astype(jnp.float32), axis=0)
+
+
+def held_experts_weight_rounded_first(x, weight, up, down):
+    """The laying with the weight itself rounded to the operands' type
+    before it meets the activation: what `held_experts` must not be."""
+    act = jnp.square(jax.nn.relu(jnp.einsum("sd,edf->esf", x,
+                                            up.astype(x.dtype))))
+    act = act * weight.T[:, :, None].astype(x.dtype)
+    return jnp.einsum("esf,efd->sd", act, down.astype(x.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_bfloat16_operands_keep_the_precision_the_layer_had(seed):
+    """bfloat16 operands, float32 sums, a float32 weight, as the hybrid
+    cell runs it: the output and all four gradients lie no further from
+    the float32 sum expert by expert than those of the form with the
+    weight on the `[E, S, D]` output did (within 3%: the two round in
+    different places), because the weight is laid on in float32 and the
+    product rounded once. Rounding the weight first adds a tenth to the
+    output's rounding error and 40% and more to that of the weight's,
+    the router's, gradient (twice it at the cell's widths)."""
+    k = jax.random.split(jax.random.key(seed), 5)
+    s, e, d, f = 512, 4, 64, 96
+    x = jax.random.normal(k[0], (s, d)).astype(jnp.bfloat16)
+    hit = jax.random.bernoulli(k[1], 0.25, (s, e))
+    weight = jnp.where(hit, jax.random.uniform(k[2], (s, e), minval=0.2), 0.0)
+    up = jax.random.normal(k[3], (e, d, f)) / np.sqrt(d)
+    down = jax.random.normal(k[4], (e, f, d)) / np.sqrt(f)
+
+    def one_by_one(x, w, up, down):
+        x = x.astype(jnp.float32)
+        return sum(w[:, i:i + 1] * (jnp.maximum(x @ up[i], 0) ** 2 @ down[i])
+                   for i in range(e))
+
+    def all_of(fn):
+        def f(x, w, up, down):
+            out = fn(x, w, up, down)
+            return jnp.sum(jnp.sin(out)), out
+        (_, out), grads = jax.jit(jax.value_and_grad(
+            f, argnums=range(4), has_aux=True))(x, weight, up, down)
+        return (out, *grads)
+
+    with jax.default_matmul_precision("highest"):
+        want = all_of(one_by_one)
+
+    def gaps(fn):
+        return [float(jnp.linalg.norm((g - w).astype(jnp.float32))
+                      / jnp.linalg.norm(w.astype(jnp.float32)))
+                for g, w in zip(all_of(fn), want)]
+
+    names = ("output", "x", "weight", "up", "down")
+    was = gaps(held_experts_weight_on_the_output)
+    now = gaps(held_experts)
+    for name, a, b in zip(names, now, was):
+        assert 1e-3 < b < 1e-2 and a <= 1.03 * b, (name, a, b)
+    rounded = gaps(held_experts_weight_rounded_first)
+    assert rounded[2] > 1.3 * was[2] and rounded[0] > 1.08 * was[0]
 
 
 def test_the_relu_squared_layer_has_no_third_matrix():

@@ -414,16 +414,18 @@ def test_the_step_says_what_it_holds(capacity, family, setting, tx, extra):
 TOK = 2 * 8192 * 2
 SSM = {"ssm_in_proj": TOK * 2320}
 EXPERTS = {"moe_router": TOK * 1024, "moe_latent": TOK * 1024,
-           "moe_shared_up": TOK * 5376, "moe_experts_up": TOK * 21504}
+           "moe_experts_out": TOK * 1024, "moe_shared_up": TOK * 5376,
+           "moe_experts_up": TOK * 21504}
 ATTENDS = {"attn_out": 16777216, "attn_lse": 262144, "qkv_proj": TOK * 768,
            "attn_proj": TOK * 4096, "attn_qkv": 3 * 16777216}
 HYBRID = [(SSM, 5), (EXPERTS, 5), (ATTENDS, 1)]
 HYBRID_ORDER = ("attn_out", "attn_lse", "moe_router", "qkv_proj",
-                "attn_proj", "ssm_in_proj", "moe_latent", "moe_shared_up",
-                "attn_qkv", "moe_experts_up")
+                "attn_proj", "ssm_in_proj", "moe_experts_out", "moe_latent",
+                "moe_shared_up", "attn_qkv", "moe_experts_up")
 # what the policy reckons on the chip with the routed experts' first
-# product in the E kind's margin (4 588 793 516 without it)
-HYBRID_BUDGET = 3_247_664_812
+# product in the E kind's margin (4 588 793 516 without it) and, since
+# PR 47, the layer's sum over the experts held (3 247 664 812 without it)
+HYBRID_BUDGET = 3_180_555_948
 
 
 def kept_bytes(blocks, names):
@@ -433,10 +435,11 @@ def kept_bytes(blocks, names):
 
 @pytest.mark.parametrize("budget,n_kept", [
     (0, 0), (17039360 - 1, 0), (17039360, 2),
-    (17039360 + 5 * TOK * 1024, 3), (GIB // 2, 5), (GIB, 7), (4 * GIB, 9),
-    (HYBRID_BUDGET, 9), (6 * GIB, 10)],
+    (17039360 + 5 * TOK * 1024, 3), (GIB // 2, 5), (892076032, 7), (GIB, 8),
+    (4 * GIB, 10), (HYBRID_BUDGET, 10), (6 * GIB, 11)],
     ids=["empty", "one-byte-short", "attention", "router", "projections",
-         "latent", "all", "the-cells-3.25-GB", "the-first-product-too"])
+         "the-layers-sum-and-no-latent", "latent", "all", "the-cells-3.18-GB",
+         "the-first-product-too"])
 def test_unequal_blocks_are_summed_by_kind_and_count(budget, n_kept):
     got = rp.choose_names(HYBRID, budget)
     assert got == HYBRID_ORDER[:n_kept]
@@ -453,8 +456,9 @@ def test_a_name_costs_only_the_kinds_that_make_it():
     a kind without the name is passed over, not counted at another's."""
     assert kept_bytes(HYBRID, ("moe_router",)) == 5 * TOK * 1024
     assert kept_bytes(HYBRID, ("qkv_proj",)) == TOK * 768
+    assert kept_bytes(HYBRID, ("moe_experts_out",)) == 5 * TOK * 1024
     one_kind = rp.choose_names([(EXPERTS, 5)], GIB)
-    assert one_kind == ("moe_router", "moe_latent")
+    assert one_kind == ("moe_router", "moe_experts_out", "moe_latent")
     assert rp.choose_names([(EXPERTS, 5), ({}, 6)], GIB) == one_kind
 
 
@@ -495,10 +499,12 @@ def test_a_kinds_scratch_is_part_of_its_backwards_room():
 
     args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
                 block_input_bytes=TOK * 4096, head_bytes=2 * TOK * 4096)
-    # the expert layers without their routed experts' first product, as
-    # they were reckoned before it had a name: 7424 features a token
+    # the expert layers without their routed experts' first product and
+    # their sum, as they were reckoned before those had names: 7424
+    # features a token
     blocks = [(SSM, 5), ({n: b for n, b in EXPERTS.items()
-                          if n != "moe_experts_up"}, 5), (ATTENDS, 1)]
+                          if not n.startswith("moe_experts_")}, 5),
+              (ATTENDS, 1)]
     plain = rp.budget_bytes(16_909_336_064, blocks=blocks, **args)
     assert rp.budget_bytes(16_909_336_064, blocks=blocks,
                            scratch=[0, 0, 0], **args) == plain
@@ -532,6 +538,11 @@ def test_new_names_leave_the_old_names_order_as_it_was():
                    ("mlp_gate",), ("mlp_up",), ("attn_qkv",)]
     new = [n for g in rp.PREFERENCE for n in g if n.startswith(later)]
     # PR 33's four in their order, PR 42's three between them
+    # PR 47's one among them: the layer's sum over the experts held, as
+    # wide as `moe_latent` and a product over every held expert's features
+    # spared where that spares one over `d_model`, so in front of it
+    assert new.index("moe_experts_out") == new.index("moe_latent") - 1
+    new.remove("moe_experts_out")
     assert [n for n in new if n.startswith(("moe_", "ssm_"))][:4] == [
         "moe_router", "ssm_in_proj", "moe_latent", "moe_shared_up"]
     assert new[:7] == ["moe_router", "attn_gate", "kda_in_proj",
@@ -545,8 +556,8 @@ def test_new_names_leave_the_old_names_order_as_it_was():
     assert list(rp.PREFERENCE[:-2]) == [
         ("attn_out", "attn_lse"), ("moe_router",), ("qkv_proj",),
         ("attn_gate",), ("attn_proj",), ("kda_in_proj",), ("kda_out_proj",),
-        ("ssm_in_proj",), ("moe_latent",), ("mlp_gate",), ("mlp_up",),
-        ("moe_shared_up",), ("attn_qkv",)]
+        ("ssm_in_proj",), ("moe_experts_out",), ("moe_latent",),
+        ("mlp_gate",), ("mlp_up",), ("moe_shared_up",), ("attn_qkv",)]
 
 
 NEMOTRON_CELL = dict(
@@ -559,8 +570,9 @@ def test_the_hybrid_model_states_its_three_kinds():
     ssm, experts, attends = model._block_kinds()
     assert (ssm.count, experts.count, attends.count) == (5, 5, 1)
     # the routed experts' first product: 8 held experts of 2688 features
+    # and their sum over the experts held, where `latent_up` reads it
     assert experts.widths == {"moe_router": 1024, "moe_latent": 1024,
-                              "moe_shared_up": 5376,
+                              "moe_experts_out": 1024, "moe_shared_up": 5376,
                               "moe_experts_up": 8 * 2688}
     assert {n: TOK * w for n, w in experts.widths.items()} == EXPERTS
     assert {n: TOK * w for n, w in ssm.widths.items()} == SSM
@@ -569,26 +581,29 @@ def test_the_hybrid_model_states_its_three_kinds():
     whole = MODELS.get("NemotronH")(pattern="E")._block_kinds()[0]
     assert whole.widths["moe_experts_up"] == 512 * 2688
     # the E kind, 243 -> 948 MB, is the largest with the product in it:
-    # the margin grows by twice 705 MB
+    # the margin grows by twice 705 MB, and by twice 33.5 MB with the sum
     args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
                 block_input_bytes=TOK * 4096, head_bytes=2 * TOK * 4096)
     budget = rp.budget_bytes(16_909_336_064, blocks=HYBRID,
                              scratch=[TOK * ssm.scratch, 0, 0], **args)
     assert budget == HYBRID_BUDGET == (
         16_909_336_064 - 8410386260 - 536887296 - 13 * TOK * 4096
-        - 2 * TOK * (1024 + 1024 + 5376 + 21504) - rp.HEADROOM_BYTES)
+        - 2 * TOK * (1024 + 1024 + 1024 + 5376 + 21504)
+        - rp.HEADROOM_BYTES)
     # (before, the scan's kind with its masks was: 2320 + 6144 features)
-    assert budget == 4_588_793_516 - 2 * TOK * (28928 - 2320 - 6144)
+    assert budget == 4_588_793_516 - 2 * TOK * (29952 - 2320 - 6144)
+    assert budget == 3_247_664_812 - 2 * TOK * 1024
 
 
-def test_the_hybrid_cell_keeps_its_nine_names_and_not_the_first_product():
+def test_the_hybrid_cell_keeps_its_ten_names_and_not_the_first_product():
     """`nemotron3_super_l11.seq8k` at its chip's budget: the first product
     is 705 MB a layer, 3.52 GB over five, more than the whole budget; the
-    nine names kept before it had a name (1.823 GB) still fit, so the cell
-    keeps what it kept, to the letter."""
+    nine names kept before it had a name (1.823 GB) still fit, and so
+    does the layers' sum beside them (33.5 MB a layer, 168 MB over five)."""
     got = rp.choose_names(HYBRID, HYBRID_BUDGET)
-    assert got == HYBRID_ORDER[:9]
-    assert kept_bytes(HYBRID, got) == 1_823_211_520 <= HYBRID_BUDGET
+    assert got == HYBRID_ORDER[:10]
+    assert kept_bytes(HYBRID, got) == 1_823_211_520 + 5 * TOK * 1024 \
+        == 1_990_983_680 <= HYBRID_BUDGET
     assert kept_bytes(HYBRID, ("moe_experts_up",)) == 5 * TOK * 21504 \
         == 3_523_215_360 > HYBRID_BUDGET
     # nor would the budget it had before the margin held the product
@@ -625,13 +640,13 @@ def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
                if e["name"] == "remat/policy"]
     assert said["blocks"] == 5
     assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_proj,"
-                             "ssm_in_proj,moe_latent,moe_shared_up,"
-                             "moe_experts_up")
+                             "ssm_in_proj,moe_experts_out,moe_latent,"
+                             "moe_shared_up,moe_experts_up")
     tok = 2 * 32 * 4
     d_in, heads = 4 * 16, 4
     assert said["kept_bytes"] == tok * (
         heads * 16                                  # attn_out, one block
-        + 2 * (8 + 32 + 96 + 8 * 48)                # two expert layers
+        + 2 * (8 + 32 + 32 + 96 + 8 * 48)           # two expert layers
         + (heads + 2 * 2) * 16 + 64                 # qkv_proj, attn_proj
         + 2 * (2 * d_in + heads + 2 * 2 * 16))      # two in_proj outputs
     assert "remat/policy: keeping [attn_out,moe_router" in caplog.text
@@ -702,6 +717,9 @@ def test_the_solar_model_states_its_two_kinds():
                            "mlp_gate": 1280, "mlp_up": 1280,
                            "moe_experts_gate": 10240,
                            "moe_experts_up": 10240}
+    # no latent, so no projection reads the routed experts' sum and
+    # neither kind states `moe_experts_out`: to the byte what it was
+    assert "moe_experts_out" not in {**kda.widths, **attn.widths}
     # 8 held experts of 1280 features; none said held: all 320 are
     assert kda.widths["moe_experts_gate"] == 8 * 1280
     whole = MODELS.get("SolarOpen2")(pattern="K")._block_kinds()[0]
