@@ -583,3 +583,25 @@ def tiny_moe_lm(vocab_size: int = 256, n_layer: int = 2, n_head: int = 4,
         capacity_factor=capacity_factor, aux_loss_weight=aux_loss_weight,
         bfloat16=bfloat16, attn_impl=attn_impl, remat=remat, mesh=mesh,
     )
+
+
+def expert_block_sizes(d_ff: int, n_routed: int, held=(0, 0), latent: int = 0,
+                       shared_d_ff: int = 0, gated: bool = False,
+                       dtype: Any = jnp.float32, **_) -> dict:
+    """What a block with an ``ExpertLayer`` of these fields (the others
+    set no width) tells models/remat_policy.py: every name the layer
+    makes with its width in features a token of ``dtype``. The float32
+    router logits count twice a 16-bit model's item; the routed experts'
+    first products are as wide as the experts held times their ``d_ff``;
+    the layer's sum has a name only where ``latent_up`` reads it; a gated
+    layer's shared expert is a ``SwiGLU`` and makes its two names."""
+    first = (held[1] or n_routed) * d_ff
+    widths = {"moe_router": n_routed * 4 // jnp.dtype(dtype).itemsize}
+    if latent:
+        widths.update({"moe_latent": latent, "moe_experts_out": latent})
+    if shared_d_ff:
+        widths.update({"mlp_gate": shared_d_ff, "mlp_up": shared_d_ff}
+                      if gated else {"moe_shared_up": shared_d_ff})
+    if gated:
+        widths["moe_experts_gate"] = first
+    return {**widths, "moe_experts_up": first}

@@ -37,8 +37,9 @@ which is the order of recomputation spared for a byte kept:
    repeats and folds again (2.2 ms for 201 MB there) and needs
    ``qkv_proj`` no more, which jax then drops from what is kept.
 
-A stack whose layers are of several kinds (models/nemotron_h.py: one
-mixer a layer, by a pattern) names besides, each where its mixer makes it:
+A stack whose layers are of several kinds (models/hybrid.py: a mixer a
+layer, by a pattern) names besides, each where its mixer makes it, its
+width stated beside that mixer (models/mixers.py, models/moe.py):
 
 - ``moe_router``: an expert layer's router logits, float32 and as wide as
   the experts published; they come from a float32 product of six passes,
@@ -59,7 +60,7 @@ mixer a layer, by a pattern) names besides, each where its mixer makes it:
   gradient reads it, and it makes no such name.
 
 A stack of delta-rule and gated-attention layers, each with gated experts
-(models/solar_open2.py), names three more, each beside its like:
+(models/hybrid.py's ``SOLAR_OPEN2``), names three more, each beside its like:
 ``attn_gate`` (the output gate's projection, behind ``qkv_proj``),
 ``kda_in_proj`` (a KDA mixer's three projections in front of their
 convolutions) and ``kda_out_proj`` (its ``o_proj``), behind ``attn_proj``;
@@ -265,7 +266,7 @@ def budget_bytes(capacity: int, held_bytes: int, outside_param_bytes: int,
 def block_policy(model, training: bool, kinds: Sequence[BlockKind],
                  batch: int, seq_len: int, block_key: str):
     """The checkpoint policy for the blocks of ``model`` (a bound
-    ``TransformerLM``, ``LlamaLM`` or ``NemotronHLM`` inside its call,
+    ``TransformerLM``, ``LlamaLM`` or ``HybridLM`` inside its call,
     whose blocks' parameters are under keys that start with ``block_key``).
 
     Everything comes from the shapes traced there (``batch``, ``seq_len``,

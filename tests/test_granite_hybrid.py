@@ -1,5 +1,5 @@
-"""models/granite_hybrid.py: the stack of a mixer and a gated MLP a layer
-with the family's four scalars. The program against its plain reference
+"""models/hybrid.py's `GRANITE_HYBRID`: the stack of a mixer and a gated MLP
+a layer with the family's four scalars. The program against its plain reference
 is tests/benchmarks/test_bm_granite_hybrid.py; here: the attention's scale
 on every path the model can take, the tied scaled head through the fused
 loss, the scopes the trace reads, what the model says of itself, and a

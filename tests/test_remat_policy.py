@@ -493,7 +493,7 @@ def test_a_kinds_scratch_is_part_of_its_backwards_room():
     the kind of block says it as `scratch`, and the room left for one
     block's backward is the largest kind's names AND scratch, twice. A
     kind that is not the largest with its scratch changes nothing."""
-    from pytorch_distributed_template_tpu.models.nemotron_h import (
+    from pytorch_distributed_template_tpu.models.mixers import (
         mamba_block_sizes,
     )
 

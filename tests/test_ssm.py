@@ -111,9 +111,9 @@ def test_bfloat16_operands_keep_their_type_and_stay_close():
 
 
 def four_slices(xbc, taps, bias):
-    """The convolution as `Mamba2Mixer` wrote it out before ISSUE 39: the
-    input widened and padded, one shifted slice a tap, jax's own
-    backward."""
+    """The convolution as models/mixers.py's `Mamba2Mixer` wrote it out
+    before ISSUE 39: the input widened and padded, one shifted slice a
+    tap, jax's own backward."""
     k, t = taps.shape[0], xbc.shape[1]
     padded = jnp.pad(xbc.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
     return jax.nn.silu(bias + sum(
@@ -219,7 +219,7 @@ def test_convolutions_gradient_is_the_same_under_the_mixers_checkpoint():
                                             seed=5)
 
     def block(x, taps, bias):
-        # as `Mamba2Mixer`: the convolution reads a slice of the kept
+        # as models/mixers.py's `Mamba2Mixer`: it reads a slice of the kept
         # projection, and its own output is recomputed
         zxd = checkpoint_name(jnp.concatenate([x, x * 0.5, x], -1),
                               "ssm_in_proj")

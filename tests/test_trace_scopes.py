@@ -357,7 +357,8 @@ def test_a_choice_is_said_once_a_process_and_distinct_record(caplog):
         "buffer of 64 rows", "buffer of 128 rows", "buffer of 0.3 k rows"]
 
 
-# -- the delta-rule stack (models/solar_open2.py, ops/linear_attention.py) ---
+# -- the delta-rule stack (models/hybrid.py's SOLAR_OPEN2, models/mixers.py,
+# ops/linear_attention.py) ---
 
 
 def test_kda_counters_reach_the_flight_record(tmp_path):
