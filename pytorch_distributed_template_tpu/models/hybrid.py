@@ -13,12 +13,16 @@ A symbol names the layer's mixer (``MIXERS``):
   scan is ops/ssm.py, in chunks of ``ssm_chunk``);
 - ``K``: a gated delta-rule mixer, Kimi Delta Attention (models/mixers.py;
   the scan is ops/linear_attention.py, in chunks of ``kda_chunk``);
-- ``*`` or ``attention``: grouped-query attention without rotation
-  (``LlamaAttention`` with ``rope_base`` 0: these families state no
-  position embedding, order is carried by the recurrent layers), causal
-  over all earlier keys; scores ``q . k * attention_multiplier`` (0 there
-  means ``head_dim ** -0.5``); with ``out_gate`` the context is gated per
-  output channel before ``o_proj``, under the scope ``gated_attn``;
+- ``*``, ``attention`` or ``full_attention``: grouped-query attention
+  (``LlamaAttention``), causal over all earlier keys; rotated by the
+  family's ``rope_base`` (0: no rotation: three families state no
+  position embedding, order is carried by the recurrent layers); scores
+  ``q . k * attention_multiplier`` (0 there means ``head_dim ** -0.5``);
+  with ``out_gate`` the context is gated per output channel before
+  ``o_proj``, under the scope ``gated_attn``; with ``qk_norm`` each head
+  of q and of k is normed before the rotation, under ``qknorm_attn``;
+- ``conv``: a gated short convolution (models/mixers.ShortConvMixer),
+  ``conv_taps`` taps, linear between two multiplicative gates;
 - ``E``: routed experts as the mixer (models/moe.ExpertLayer: sigmoid
   router with a selection bias, ``moe_top_k`` of ``moe_n_routed`` experts
   a token, in a ``moe_latent``-wide latent where there is one, one shared
@@ -26,10 +30,12 @@ A symbol names the layer's mixer (``MIXERS``):
 
 The second sublayer is none, a gated MLP (``SwiGLU``, named ``mlp``,
 under the scope ``dense_mlp`` with its norm and its sum) or an
-``ExpertLayer`` (named ``experts``). What a family IS is a ``Family``:
-its symbols, the norms' names, the second sublayer, the four scalars,
-the head, and what is the family's in its experts. The three families
-here are records, each with two registered factories that hand the one
+``ExpertLayer`` (named ``experts``); where it is experts, the first
+``n_dense_layers`` layers take the gated MLP instead. What a family IS
+is a ``Family``: its symbols, the norms' names, the second sublayer,
+the four scalars, the attention's rotation and norms, the head, and
+what is the family's in its experts. The four families here are
+records, each with two registered factories that hand the one
 ``HybridLM`` the record and the published sizes:
 
 - ``NEMOTRON_H`` (Nemotron-H, arXiv:2504.03624; NVIDIA-Nemotron-3-Super-
@@ -40,7 +46,11 @@ here are records, each with two registered factories that hand the one
   gated MLP, the family's four scalars, the head the embedding, tied;
 - ``SOLAR_OPEN2`` (upstage/Solar-Open2-250B ``config.json``): ``K`` or a
   gated ``*`` and gated experts (three matrices an expert, a shared
-  ``SwiGLU``), untied head.
+  ``SwiGLU``), untied head;
+- ``LFM2_MOE`` (``lfm2_moe``: LiquidAI LFM2-24B-A2B ``config.json``):
+  ``conv`` or a rotated ``full_attention`` with q/k norms, then a gated
+  MLP in the leading dense layers and gated experts without a shared
+  one in the rest, the head the embedding, tied.
 
 A family added later is a record, its factories, and a mixer only if it
 brings a new one. The block branches on what a record holds, never on
@@ -64,8 +74,9 @@ tied matrix is a smaller vocabulary: ids, logits and loss are over the
 slice). The layer runs without its exchange; nothing here stands in for
 absent chips.
 
-Training only: a decode path needs the recurrent mixers' state beside
-the attention layers' pages (ROADMAP R5), and there is none yet.
+Training only: a decode path needs the recurrent mixers' state (a
+convolution mixer's last ``conv_taps - 1`` positions too) beside the
+attention layers' pages (ROADMAP R5), and there is none yet.
 """
 from __future__ import annotations
 
@@ -84,7 +95,8 @@ from ..config.registry import MODELS
 from ..observability.trace import say_once
 from .llama import LlamaAttention, RMSNorm, SwiGLU, _HeadKernel, _dense_init
 from .mixers import (
-    KdaMixer, Mamba2Mixer, kda_block_sizes, mamba_block_sizes,
+    KdaMixer, Mamba2Mixer, ShortConvMixer, kda_block_sizes,
+    mamba_block_sizes, short_conv_block_sizes,
 )
 from .moe import ExpertLayer, expert_block_sizes
 from .remat_policy import BlockKind, block_policy
@@ -103,6 +115,8 @@ class Family(NamedTuple):
     second: str = ""                        # "" | "mlp" | "experts"
     second_norm: str = ""                   # the second sublayer's pre-norm
     out_gate: bool = False                  # the attention's output gate
+    rope_base: float = 0.0                  # the attention's; 0: no rotation
+    qk_norm: bool = False                   # a norm a head of q and of k
     attention_multiplier: float = 0.0       # 0: head_dim ** -0.5
     residual_multiplier: float = 1.0
     embedding_multiplier: float = 1.0
@@ -126,6 +140,10 @@ SOLAR_OPEN2 = Family(
     "post_attention_layernorm", out_gate=True, gated_experts=True,
     step_counters=MOE_COUNTERS + ("kda_chunk_log_decay_mean",
                                   "kda_beta_mean"))
+LFM2_MOE = Family(
+    "Lfm2Moe", ("conv", "full_attention"), "operator_norm", "experts",
+    "ffn_norm", rope_base=1e6, qk_norm=True, tied_head=True,
+    gated_experts=True, step_counters=MOE_COUNTERS)
 
 
 def _experts(c) -> dict:
@@ -179,12 +197,20 @@ MIXERS = {
         lambda c: BlockKind(expert_block_sizes(**_experts(c)), 0),
         "%(held)d of %(moe_n_routed)d experts held from %(first)d, "
         "%(moe_top_k)d a token, latent %(moe_latent)d"),
+    "C": Mixer(
+        lambda c: ShortConvMixer(c.d_model, c.conv_taps, c.dtype,
+                                 name="mixer"),
+        lambda c: _scan_block(short_conv_block_sizes(
+            c.d_model, jnp.dtype(c.dtype).itemsize)),
+        "a gated convolution of %(conv_taps)d taps over %(d_model)d "
+        "channels"),
     "*": Mixer(
         lambda c: LlamaAttention(
             c.d_model, c.n_head, c.n_kv_head, c.dtype, c.attn_impl, c.mesh,
-            rope_base=0.0, head_dim=c.head_dim,
+            rope_base=c.family.rope_base, head_dim=c.head_dim,
             attention_multiplier=c.family.attention_multiplier,
-            out_gate=c.family.out_gate, name="mixer"),
+            out_gate=c.family.out_gate, qk_norm=c.family.qk_norm,
+            qk_norm_eps=c.rms_eps, name="mixer"),
         # the names ``LlamaAttention``'s projections make; its kernel's own
         # names are the policy's to reckon, from the head count
         lambda c: BlockKind(
@@ -192,19 +218,20 @@ MIXERS = {
              **({"attn_gate": c.n_head * c.head_dim} if c.family.out_gate
                 else {}), "attn_proj": c.d_model},
             0, c.n_head, c.head_dim),
-        "%(n_head)d query heads on %(n_kv_head)d of %(head_dim)d, no "
-        "rotation%(gated_output)s"),
+        "%(n_head)d query heads on %(n_kv_head)d of %(head_dim)d, "
+        "%(rotation)s%(gated_output)s"),
 }
 KIND = {"M": "M", "mamba": "M", "K": "K", "E": "E", "*": "*",
-        "attention": "*"}
+        "attention": "*", "full_attention": "*", "conv": "C"}
 
 
 class HybridLayer(nn.Module):
     """The mixer that ``symbol`` names behind its pre-norm, added to the
-    residual stream; then the family's second sublayer, if it has one,
+    residual stream; then the layer's second sublayer, if it has one,
     behind its own."""
     symbol: str
     cfg: Any                        # the model's ``Sizes``
+    second: str = ""                # "" | "mlp" | "experts", at this depth
 
     @nn.compact
     def __call__(self, x, positions, train: bool):
@@ -222,17 +249,19 @@ class HybridLayer(nn.Module):
         h = RMSNorm(c.rms_eps, name=f.mixer_norm)(x)
         mixer = MIXERS[kind].build(c)
         if kind == "*":
-            with (jax.named_scope("gated_attn") if f.out_gate
+            scope = ("gated_attn" if f.out_gate
+                     else "qknorm_attn" if f.qk_norm else "")
+            with (jax.named_scope(scope) if scope
                   else contextlib.nullcontext()):
                 y = mixer(h, positions, train)
         else:
             y = mixer(h)
         x = add(x, y)
-        if f.second == "mlp":
+        if self.second == "mlp":
             with jax.named_scope("dense_mlp"):
                 h = RMSNorm(c.rms_eps, name=f.second_norm)(x)
                 x = add(x, SwiGLU(c.d_model, c.d_ff, c.dtype, name="mlp")(h))
-        elif f.second == "experts":
+        elif self.second == "experts":
             h = RMSNorm(c.rms_eps, name=f.second_norm)(x)
             x = add(x, ExpertLayer(**_experts(c), name="experts")(h))
         return x
@@ -249,6 +278,9 @@ class HybridLM(nn.Module):
     max_len: int
     # the sizes of what the family has; 0: it has no such part
     d_ff: int = 0                   # the gated MLP's
+    # where the second sublayer is experts: the leading layers that take
+    # the gated MLP of ``d_ff`` instead
+    n_dense_layers: int = 0
     n_head: int = 0                 # attention
     n_kv_head: int = 0
     head_dim: int = 0
@@ -263,6 +295,7 @@ class HybridLM(nn.Module):
     kda_conv: int = 0
     kda_chunk: int = 0
     kda_rank: int = 0               # of the decay gate and the output gate
+    conv_taps: int = 0              # the gated short convolution's
     moe_n_routed: int = 0           # experts, as mixer or second sublayer
     moe_held: Tuple[int, int] = (0, 0)      # (offset, count); count 0: all
     moe_top_k: int = 0
@@ -324,8 +357,9 @@ class HybridLM(nn.Module):
             # static_argnums count self as 0: train (3) is a Python bool
             layer_cls = nn.remat(HybridLayer, static_argnums=(3,),
                                  policy=policy)
-        for i, symbol in enumerate(self.pattern):
-            x = layer_cls(symbol, sizes, name=f"layers_{i}")(
+        for i, (symbol, second) in enumerate(zip(self.pattern,
+                                                 self._seconds())):
+            x = layer_cls(symbol, sizes, second, name=f"layers_{i}")(
                 x, positions, train)
         x = RMSNorm(self.rms_eps, name="norm")(x)
         if f.logits_scaling != 1:
@@ -339,9 +373,17 @@ class HybridLM(nn.Module):
             return x, w
         return jnp.matmul(x, w).astype(jnp.float32)
 
+    def _seconds(self) -> Tuple[str, ...]:
+        """Each layer's second sublayer: the family's, but the gated MLP
+        in the leading dense layers of a family whose second is experts."""
+        second = self.family.second
+        return tuple(
+            "mlp" if second == "experts" and i < self.n_dense_layers
+            else second for i in range(len(self.pattern)))
+
     def _sizes(self):
-        experts = (len(self.pattern) if self.family.second == "experts"
-                   else self.pattern.count("E"))
+        experts = (self._seconds().count("experts")
+                   or self.pattern.count("E"))
         return Sizes(*(getattr(self, name) for name in Sizes._fields[:-2]),
                      n_expert_layers=experts,
                      n_kda_layers=max(self.pattern.count("K"), 1))
@@ -352,7 +394,9 @@ class HybridLM(nn.Module):
             "": "",
             "mlp": ", each a mixer and a gated MLP of %(d_ff)d",
             "experts": ", each a mixer and "
-            + ("gated " if f.gated_experts else "") + "experts",
+            + ("gated " if f.gated_experts else "") + "experts"
+            + (", the first %(n_dense_layers)d a gated MLP of %(d_ff)d"
+               if self.n_dense_layers else ""),
         }[f.second]
         clauses = ["model/pattern: %(pattern)s (%(layers)d layers" + second
                    + ")"]
@@ -378,6 +422,9 @@ class HybridLM(nn.Module):
             c._asdict(), pattern="".join(s[0] for s in self.pattern),
             layers=len(self.pattern), first=c.moe_held[0],
             gated_output=", gated output" if f.out_gate else "",
+            rotation=(f"rotation of base {f.rope_base:g}" if f.rope_base
+                      else "no rotation")
+            + (", a norm a head of q and of k" if f.qk_norm else ""),
             held=c.moe_held[1] or c.moe_n_routed,
             embedding=f.embedding_multiplier, residual=f.residual_multiplier,
             attention=f.attention_multiplier, logits=f.logits_scaling,
@@ -390,20 +437,21 @@ class HybridLM(nn.Module):
     def _block_kinds(self):
         """What each kind of layer the pattern has tells the checkpoint
         policy: its mixer's names and scratch and its second sublayer's
-        names, in features a token, and how many such layers there are."""
+        names, in features a token, and how many such layers there are.
+        A kind is a mixer AND a second sublayer; in the family's order
+        of symbols, a symbol's kinds as they come in the pattern."""
         c, f = self._sizes(), self.family
-        second = {}
-        if f.second == "mlp":
-            second = {"mlp_gate": c.d_ff, "mlp_up": c.d_ff}     # SwiGLU's
-        elif f.second == "experts":
-            second = expert_block_sizes(**_experts(c))
+        seconds = {
+            "": {}, "mlp": {"mlp_gate": c.d_ff, "mlp_up": c.d_ff},  # SwiGLU
+            "experts": (expert_block_sizes(**_experts(c))
+                        if f.second == "experts" else {})}
+        layers = collections.Counter(zip(self.pattern, self._seconds()))
         kinds = []
-        for symbol in f.symbols:
-            if symbol in self.pattern:
-                kind = MIXERS[KIND[symbol]].block(c)
-                kinds.append(kind._replace(
-                    widths={**kind.widths, **second},
-                    count=self.pattern.count(symbol)))
+        for (symbol, second), count in sorted(
+                layers.items(), key=lambda kind: f.symbols.index(kind[0][0])):
+            kind = MIXERS[KIND[symbol]].block(c)
+            kinds.append(kind._replace(
+                widths={**kind.widths, **seconds[second]}, count=count))
         return kinds
 
     def batch_template(self, batch_size: int = 1):
@@ -478,6 +526,14 @@ SOLAR_OPEN2_250B = dict(
     kda_rank=128, moe_n_routed=320, moe_held=(0, 0), moe_top_k=8,
     moe_d_ff=1280, moe_shared_d_ff=1280, moe_scale=1.0,
     selection_bias_rate=0.0, rms_eps=1e-5, max_len=1048576, mesh=None)
+LFM2_24B_A2B = dict(
+    vocab_size=65536,
+    layer_types=("conv", "conv") + ("full_attention", "conv", "conv",
+                                    "conv") * 9 + ("full_attention", "conv"),
+    d_model=2048, d_ff=11776, n_dense_layers=2, n_head=32, n_kv_head=8,
+    head_dim=64, conv_taps=3, moe_n_routed=64, moe_held=(0, 0), moe_top_k=4,
+    moe_d_ff=1536, moe_scale=1.0, selection_bias_rate=0.0,
+    vocab_published=0, rms_eps=1e-5, max_len=128000, mesh=None)
 # every kind of layer at a size for tests and dry runs
 TINY = dict(d_model=64, n_head=4, n_kv_head=2, head_dim=16, max_len=128,
             vocab_size=256, mesh=None)
@@ -527,3 +583,17 @@ tiny_solar_open2 = _register(
     "Both kinds of layer at a size for tests and dry runs.",
     ("vocab_size", "pattern", "mesh", "moe_held", "selection_bias_rate"),
     **TINY_FLAGS)
+lfm2_moe = _register(
+    "Lfm2Moe", LFM2_MOE, LFM2_24B_A2B,
+    """LFM2-24B-A2B's sizes (``LFM2_24B_A2B``) unless the call says
+    otherwise. A chip's share of a deployment is the same call with
+    ``moe_held`` and the rows of the tied matrix that chip holds as
+    ``vocab_size``. Training only.""", pattern_key="layer_types")
+tiny_lfm2_moe = _register(
+    "TinyLfm2Moe", LFM2_MOE,
+    dict(TINY, **TINY_MOE, layer_types=("conv", "full_attention", "conv"),
+         d_ff=96, n_dense_layers=1, conv_taps=3, moe_scale=1.0),
+    "Both kinds of layer and both second sublayers at a size for tests "
+    "and dry runs.",
+    ("vocab_size", "layer_types", "n_dense_layers", "mesh", "moe_held",
+     "selection_bias_rate"), "layer_types", **TINY_FLAGS)

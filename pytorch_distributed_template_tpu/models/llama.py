@@ -136,6 +136,10 @@ class LlamaAttention(nn.Module):
     # the context times sigmoid(g_proj(x)), a gate per output channel,
     # before o_proj (gated attention, Qiu et al. arXiv:2505.06708)
     out_gate: bool = False
+    # an RMSNorm over each head of q and of k (one weight [head_dim] each,
+    # ``q_layernorm``, ``k_layernorm``) between projection and rotation
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-5
 
     @nn.compact
     def __call__(self, x, positions, train: bool, decode: bool = False,
@@ -152,11 +156,15 @@ class LlamaAttention(nn.Module):
             y = checkpoint_name(dense(heads * hd, name)(x), "qkv_proj")
             return y.reshape(b, t, heads, hd)
 
-        q = proj("q_proj", self.n_head)
+        def normed(y, name):
+            return (RMSNorm(self.qk_norm_eps, name=name)(y) if self.qk_norm
+                    else y)
+
+        q = normed(proj("q_proj", self.n_head), "q_layernorm")
         if self.attention_multiplier:
             q = q * jnp.asarray(self.attention_multiplier * hd ** 0.5,
                                 q.dtype)
-        k = proj("k_proj", self.n_kv_head)
+        k = normed(proj("k_proj", self.n_kv_head), "k_layernorm")
         v = proj("v_proj", self.n_kv_head)
 
         if decode:

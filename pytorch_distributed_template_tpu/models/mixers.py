@@ -1,8 +1,9 @@
 """What a layer of a pattern-built stack (models/hybrid.py) can mix with
 and no older module holds: the Mamba-2 state-space mixer (Nemotron-H,
-arXiv:2504.03624; ``granitemoehybrid``) and the gated delta rule (Kimi
-Delta Attention, arXiv:2510.26692). Attention is models/llama.py's, the
-experts models/moe.py's.
+arXiv:2504.03624; ``granitemoehybrid``), the gated delta rule (Kimi
+Delta Attention, arXiv:2510.26692) and the gated short convolution
+(LFM2, ``lfm2_moe``). Attention is models/llama.py's, the experts
+models/moe.py's.
 
 Beside each mixer stands the function that says what a block with it
 tells the checkpoint policy (models/remat_policy.py): the names the
@@ -29,7 +30,7 @@ from jax.ad_checkpoint import checkpoint_name
 
 from ..observability.trace import say_once
 from ..ops.linear_attention import SUB_CHUNK, kda_chunked
-from ..ops.ssm import sharded_conv_silu, ssd_scan
+from ..ops.ssm import causal_conv, sharded_conv_silu, ssd_scan
 from .llama import _dense_init
 from .moe import sow_counter
 
@@ -234,3 +235,61 @@ def kda_block_sizes(d_model: int, n_head: int, head_dim: int,
     width = n_head * head_dim
     widths = {"kda_in_proj": 3 * width, "kda_out_proj": d_model}
     return widths, SUB_CHUNK * width * 4 // itemsize
+
+
+class ShortConvMixer(nn.Module):
+    """The gated short convolution of LFM2 (``Lfm2ShortConv``) on
+    ``h [B, T, d_model]``: ``[B, C, z] = split3(in_proj(h))``, each
+    ``d_model`` wide and in that order; ``u = B * z``; ``c = conv(u)``,
+    depthwise, causal, ``taps`` taps (tap i multiplies position
+    ``t - (taps - 1) + i``), from zeros, no bias and NO activation (the
+    convolution is linear between its two gates); ``out_proj(C * c)``.
+    Both gates and the convolution are float32 values of one bfloat16
+    projection, rounded once in front of ``out_proj``.
+
+    Every channel goes its own way between the two projections, so a
+    chip's share by channels would be exact; the cells hold it whole.
+
+    For the trace: ``short_conv_proj`` holds ``in_proj`` and
+    ``out_proj``, ``short_conv`` both gates and the convolution;
+    ``conv/short`` is its line and span."""
+    d_model: int
+    taps: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        b, t, d = h.shape
+        f32 = jnp.float32
+        size = jnp.dtype(self.dtype).itemsize
+        dense = lambda width, name: nn.Dense(            # noqa: E731
+            width, use_bias=False, dtype=self.dtype,
+            kernel_init=_dense_init(), name=name)
+        say_once(
+            logger, "conv/short",
+            dict(taps=self.taps, channels=d, positions=b * t,
+                 read_bytes=3 * b * t * d * size,
+                 written_bytes=b * t * d * size),
+            "conv/short: %(taps)d taps over %(channels)d channels at "
+            "%(positions)d positions, linear between two gates: it reads "
+            "the projection's %(read_bytes)d bytes and writes "
+            "%(written_bytes)d")
+        with jax.named_scope("short_conv_proj"):
+            bcz = checkpoint_name(dense(3 * d, "in_proj")(h), "conv_in_proj")
+        taps = self.param("conv_kernel", _dense_init(), (self.taps, d), f32)
+        with jax.named_scope("short_conv"):
+            gate_in, gate_out, z = (m.astype(f32)
+                                    for m in jnp.split(bcz, 3, axis=-1))
+            y = (gate_out * causal_conv(gate_in * z, taps)).astype(self.dtype)
+        with jax.named_scope("short_conv_proj"):
+            return checkpoint_name(dense(d, "out_proj")(y), "conv_out_proj")
+
+
+def short_conv_block_sizes(d_model: int, itemsize: int) -> Tuple[dict, int]:
+    """What a block with a ``ShortConvMixer`` tells models/remat_policy.py,
+    in features a token of the compute type: the names the mixer makes
+    (``conv_in_proj``, three gates wide, and ``out_proj``'s result,
+    ``conv_out_proj``), and its scratch, the gated input and the
+    convolution's result in float32."""
+    widths = {"conv_in_proj": 3 * d_model, "conv_out_proj": d_model}
+    return widths, 2 * d_model * 4 // itemsize

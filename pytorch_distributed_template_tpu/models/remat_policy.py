@@ -65,6 +65,8 @@ A stack of delta-rule and gated-attention layers, each with gated experts
 ``kda_in_proj`` (a KDA mixer's three projections in front of their
 convolutions) and ``kda_out_proj`` (its ``o_proj``), behind ``attn_proj``;
 its shared expert is a ``SwiGLU`` and makes ``mlp_gate`` and ``mlp_up``.
+A gated short convolution (models/mixers.ShortConvMixer) names its two
+projections ``conv_in_proj`` and ``conv_out_proj``, beside the KDA's.
 
 The scan's output has no name: its backward needs what lies inside it,
 so keeping the result would spare next to nothing. So it is with the
@@ -118,6 +120,8 @@ PREFERENCE: Tuple[Tuple[str, ...], ...] = (
     ("attn_proj",),
     ("kda_in_proj",),
     ("kda_out_proj",),
+    ("conv_in_proj",),
+    ("conv_out_proj",),
     ("ssm_in_proj",),
     ("moe_experts_out",),
     ("moe_latent",),

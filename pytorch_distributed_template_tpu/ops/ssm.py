@@ -12,6 +12,8 @@ the TPU is one kernel, positions on the lanes (the order both hybrid
 steps keep the projection in): the input and the cotangent read once,
 the input's gradient written once, float32 only in the block. Elsewhere
 the backward is ``jax.numpy`` too. ``ssm/conv`` is its line and span.
+``causal_conv`` is the same sum without bias or activation, for a mixer
+whose convolution is linear between two gates; no rule of its own.
 
 **The scan.** A head ``h`` with a scalar decay carries a state ``S [P, N]``:
 
@@ -318,6 +320,16 @@ def _conv_silu_bwd(start, kept, dy):
 
 
 causal_conv_silu.defvjp(_conv_silu_fwd, _conv_silu_bwd)
+
+
+def causal_conv(x, taps):
+    """``sum_i taps[i] * x[t - (k - 1) + i]`` a channel of ``x [B, T, C]``:
+    the depthwise causal convolution with ``taps [k, C]`` (float32) alone,
+    no bias and no activation (a gated short convolution's, linear
+    between its two gates: models/mixers.ShortConvMixer), each row of
+    the batch from zeros, in ``x``'s type with float32 inside. Plain
+    ``jax.numpy``, jax's own backward, no kernel; the caller scopes it."""
+    return _conv_pre(x, taps, None)[1].astype(x.dtype)
 
 
 def sharded_conv_silu(zxd, taps, bias, start: int, mesh):

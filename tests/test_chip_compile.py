@@ -183,3 +183,61 @@ def test_granite_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     total = _compiled_bytes(compiled)
     print(f"granite step: {total} bytes compiled, policy {policy}")
     assert total < V5E_BYTES_LIMIT - (1 << 30)
+
+
+def test_lfm2_step_lowers_and_fits_for_v5e(topo, monkeypatch, fresh_records):
+    """`lfm2_24b_a2b_l5.seq8k`'s step (1 x 8192 on one chip): four gated
+    short convolutions (plain `jax.numpy`, no kernel) and one rotated,
+    q/k-normed attention at head 64 through the three flash kernels, the
+    leading layer's 11776-wide gated MLP, 16 held of 64 gated experts
+    over every token in the four layers behind it, and the tied head
+    over a quarter of the vocabulary through the fused loss compile for
+    the v5e; the checkpoint policy reckons three kinds of block and
+    keeps the names it was told; the step stays under the chip's
+    `bytes_limit`."""
+    import json
+    from pathlib import Path
+
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.parallel import build_mesh
+
+    arch = json.loads((
+        Path(__file__).resolve().parent.parent / "benchmarks" / "configs"
+        / "lfm2_24b_a2b_l5.json").read_text())["experiment"]["arch"]
+    mesh = build_mesh({"data": 1}, devices=topo.devices[:1])
+    _, compiled = _compiled_train_step(
+        MODELS.get(arch["type"])(**arch["args"]), mesh, 1, 8192, monkeypatch)
+    text = compiled.as_text()
+    for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
+        assert len(re.findall(rf"%{kernel}(\.\d+)? = ", text)) == 1
+    assert "ssm_conv_bwd" not in text and "ragged-dot" not in text
+    (policy,) = _said("remat/policy")
+    print(f"lfm2 step: {_compiled_bytes(compiled)} bytes compiled, "
+          f"policy {policy}")
+    assert policy["blocks"] == 5
+    # parameters and both moments; the gradient is the backward's own
+    assert abs(policy["held_bytes"] - 788_052_352 * 12) < 64
+    (pattern,) = _said("model/pattern")
+    assert pattern["pattern"] == "cfccc" and pattern["n_dense_layers"] == 1
+    assert (pattern["rows"], pattern["of_rows"]) == (16384, 65536)
+    assert (pattern["held"], pattern["moe_n_routed"], pattern["moe_top_k"],
+            pattern["conv_taps"]) == (16, 64, 4, 3)
+    (conv,) = [c for c in _said("conv/short") if c["positions"] == 8192]
+    assert conv == dict(taps=3, channels=2048, positions=8192,
+                        read_bytes=3 * 8192 * 2048 * 2,
+                        written_bytes=8192 * 2048 * 2)
+    (dispatch,) = [d for d in _said("moe/dispatch") if d["tokens"] == 8192]
+    assert dispatch["rows"] == 32768 and dispatch["expected"] == 8192
+    assert dispatch["experts"] == "gated"
+    (said,) = _said("head_loss/slice")
+    assert said["gradients"] == "forward"
+    # every name its three kinds make but the last: the 16 held experts'
+    # gate products fit (403 MB a layer, 1.61 GB over four), their up
+    # products beside them do not (2.895 GB kept of a budget of 3.688)
+    assert policy["names"].split(",") == [
+        "attn_out", "attn_lse", "moe_router", "qkv_proj", "attn_proj",
+        "conv_in_proj", "conv_out_proj", "mlp_gate", "mlp_up", "attn_qkv",
+        "moe_experts_gate"]
+    assert policy["budget_bytes"] >= policy["kept_bytes"] == 2_895_118_336
+    assert _compiled_bytes(compiled) < V5E_BYTES_LIMIT - (1 << 30)

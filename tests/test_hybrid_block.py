@@ -1,5 +1,5 @@
 """models/hybrid.py: one block and one LM assembled from a family's data.
-The six registered factories build the parameter trees they built when
+The six older registered factories build the parameter trees they built when
 each family had a stack of its own (paths, shapes, dtypes and, at the
 `Tiny*` sizes, values under one key: every checkpoint, `hf_import` and the
 benchmark's `Weights.give` find leaves by path); a record no family uses
@@ -63,6 +63,13 @@ GATED_EXPERTS = {
     "experts/shared/up_proj/kernel": (D, 48)}
 MLP = {"mlp/down_proj/kernel": (96, D), "mlp/gate_proj/kernel": (D, 96),
        "mlp/up_proj/kernel": (D, 96)}
+SHORT_CONV = {"mixer/conv_kernel": (3, D), "mixer/in_proj/kernel": (D, 3 * D),
+              "mixer/out_proj/kernel": (D, D)}
+QK_NORMS = {"mixer/q_layernorm/weight": (16,),
+            "mixer/k_layernorm/weight": (16,)}
+LFM2_NORMS = {"operator_norm/weight": (D,), "ffn_norm/weight": (D,)}
+LFM2_EXPERTS = {k: v for k, v in GATED_EXPERTS.items()
+                if "/shared/" not in k}
 ONE_NORM = {"norm/weight": (D,)}
 TWO_NORMS = {"input_layernorm/weight": (D,),
              "post_attention_layernorm/weight": (D,)}
@@ -114,13 +121,35 @@ TINY = {
          "layers_2/experts/shared/gate_proj/kernel": -2.3518905639648438,
          "layers_2/mixer/k_conv": 0.02845807373523712,
          "lm_head/kernel": 1.392583966255188}),
+    # PR 49's family: the leading layer takes the gated MLP, the others
+    # experts without a shared one; tied head
+    "TinyLfm2Moe": (
+        _tree([{**LFM2_NORMS, **SHORT_CONV, **MLP},
+               {**LFM2_NORMS, **ATTENTION, **QK_NORMS, **LFM2_EXPERTS},
+               {**LFM2_NORMS, **SHORT_CONV, **LFM2_EXPERTS}], EMBED),
+        {"embed_tokens/embedding": -4.3325700759887695,
+         "layers_0/mixer/conv_kernel": -0.21451514959335327,
+         "layers_0/mixer/in_proj/kernel": -0.16797837615013123,
+         "layers_0/mlp/up_proj/kernel": -1.6237635612487793,
+         "layers_1/mixer/q_proj/kernel": 1.2203103303909302,
+         "layers_1/mixer/q_layernorm/weight": 16.0,
+         "layers_1/experts/experts_gate": 4.088201522827148,
+         "layers_2/experts/router": 0.15178877115249634,
+         "layers_2/mixer/out_proj/kernel": -1.4241102933883667}),
 }
 # name -> (leaves, parameters at the factory's defaults; its cell's file,
-# parameters at the cell's arguments)
+# parameters and leaves at the cell's arguments)
 FULL = {
-    "NemotronH": (98, 16_023_116_160, "nemotron3_super_l11", 700_865_520),
-    "GraniteHybrid": (128, 951_991_232, "granite4_h_micro_l10", 772_160_448),
-    "SolarOpen2": (96, 22_333_740_864, "solar_open2_l4", 840_875_672),
+    "NemotronH": (98, 16_023_116_160,
+                  "nemotron3_super_l11", 700_865_520, 98),
+    "GraniteHybrid": (128, 951_991_232,
+                      "granite4_h_micro_l10", 772_160_448, 128),
+    "SolarOpen2": (96, 22_333_740_864,
+                   "solar_open2_l4", 840_875_672, 96),
+    # the cell holds five of the forty layers: embedding, final norm, a
+    # dense conv layer's 8, an attention expert layer's 13, 3 x 10
+    "Lfm2Moe": (428, 23_843_661_440,
+                "lfm2_24b_a2b_l5", 788_052_352, 53),
 }
 
 
@@ -148,23 +177,26 @@ def test_the_six_factories_build_the_trees_they_built(name):
             np.testing.assert_allclose(float(jnp.sum(leaf)), total,
                                        rtol=1e-5, err_msg=path)
         return
-    leaves, count, cell, cell_count = FULL[name]
+    leaves, count, cell, cell_count, cell_leaves = FULL[name]
     arch = json.loads((REPO / "benchmarks" / "configs" / f"{cell}.json"
                        ).read_text())["experiment"]["arch"]
     assert arch["type"] == name
-    for model, n in ((MODELS.get(name)(), count),
-                     (MODELS.get(name)(**arch["args"]), cell_count)):
+    for model, n, want_leaves in (
+            (MODELS.get(name)(), count, leaves),
+            (MODELS.get(name)(**arch["args"]), cell_count, cell_leaves)):
         got = _abstract(model)
-        assert len(got) == leaves
+        assert len(got) == want_leaves
         assert sum(int(np.prod(v.shape)) for v in got.values()) == n
         assert {v.dtype for v in got.values()} == {jnp.dtype("float32")}
 
 
-@pytest.mark.parametrize("name", ["GraniteHybrid", "SolarOpen2"])
+@pytest.mark.parametrize("name", ["GraniteHybrid", "SolarOpen2", "Lfm2Moe"])
 def test_a_field_the_family_does_not_have_is_refused(name):
     with pytest.raises(TypeError, match="unexpected keyword argument 'n'"):
         MODELS.get(name)(n=1)
-    other = {"GraniteHybrid": "kda_chunk", "SolarOpen2": "ssm_chunk"}[name]
+    # `Lfm2Moe`: the rotation's base is the record's, no size of a call
+    other = {"GraniteHybrid": "kda_chunk", "SolarOpen2": "ssm_chunk",
+             "Lfm2Moe": "rope_base"}[name]
     with pytest.raises(TypeError, match=other):
         MODELS.get(name)(**{other: 16})
 
@@ -253,3 +285,58 @@ def test_the_block_is_assembled_from_a_record(monkeypatch, caplog):
 ], ids=["hybrid-E", "solar-experts", "bare"])
 def test_an_expert_layers_names_and_widths(fields, want):
     assert expert_block_sizes(**fields) == want
+
+
+# -- what PR 49's family asked of the attention --------------------------------
+
+def test_attention_without_its_new_option_is_what_it_was():
+    """`qk_norm` off (the default): `LlamaAttention` has the four leaves
+    it had and gives the output it gave at the parent commit (e5a4fb7,
+    the sums read there); on, it gains one weight `[head_dim]` for q and
+    one for k, and with both at identity it norms each head before the
+    rotation."""
+    from pytorch_distributed_template_tpu.models.llama import (
+        LlamaAttention, apply_rope, rope_tables,
+    )
+
+    x = jax.random.normal(jax.random.key(2), (2, 24, 48), jnp.float32)
+    pos = jnp.arange(24, dtype=jnp.int32)
+    fields = dict(d_model=48, n_head=4, n_kv_head=2, dtype=jnp.float32,
+                  head_dim=8, rope_base=1e6)
+    off = LlamaAttention(**fields)
+    held = jax.jit(lambda k: off.init(k, x, pos, True))(jax.random.key(1))
+    assert set(held["params"]) == {"q_proj", "k_proj", "v_proj", "o_proj"}
+    y = jax.jit(lambda v: off.apply(v, x, pos, True))(held)
+    np.testing.assert_allclose(float(jnp.sum(y)), -1.4076359272003174,
+                               rtol=1e-5)
+    np.testing.assert_allclose(float(jnp.sum(jnp.abs(y))), 12.19357681274414,
+                               rtol=1e-5)
+    on = LlamaAttention(**fields, qk_norm=True, qk_norm_eps=1e-5)
+    shapes = jax.eval_shape(lambda k: on.init(k, x, pos, True),
+                            jax.random.key(1))["params"]
+    assert set(shapes) == set(held["params"]) | {"q_layernorm", "k_layernorm"}
+    assert shapes["q_layernorm"]["weight"].shape == (8,)
+    assert shapes["k_layernorm"]["weight"].shape == (8,)
+    weights = {**held["params"],
+               "q_layernorm": {"weight": jnp.full((8,), 1.5)},
+               "k_layernorm": {"weight": jnp.full((8,), 0.5)}}
+    got = jax.jit(lambda v: on.apply({"params": v}, x, pos, True))(weights)
+
+    def by_hand(p):
+        def normed(z, w):
+            return w * z * jax.lax.rsqrt(
+                jnp.mean(z * z, axis=-1, keepdims=True) + 1e-5)
+
+        q = normed((x @ p["q_proj"]["kernel"]).reshape(2, 24, 4, 8), 1.5)
+        k = normed((x @ p["k_proj"]["kernel"]).reshape(2, 24, 2, 8), 0.5)
+        v = (x @ p["v_proj"]["kernel"]).reshape(2, 24, 2, 8)
+        cos, sin = rope_tables(pos, 8, 1e6)
+        q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+        k, v = (jnp.repeat(m, 2, axis=2) for m in (k, v))
+        scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) / 8 ** 0.5
+        scores = jnp.where(pos[None, :] <= pos[:, None], scores, -jnp.inf)
+        ctx = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(scores, -1), v)
+        return ctx.reshape(2, 24, 32) @ p["o_proj"]["kernel"]
+
+    np.testing.assert_allclose(got, jax.jit(by_hand)(held["params"]),
+                               rtol=2e-5, atol=2e-6)

@@ -531,7 +531,7 @@ def test_a_kinds_scratch_is_part_of_its_backwards_room():
 
 
 def test_new_names_leave_the_old_names_order_as_it_was():
-    later = ("moe_", "ssm_", "kda_", "attn_gate")
+    later = ("moe_", "ssm_", "kda_", "conv_", "attn_gate")
     old = [g for g in rp.PREFERENCE
            if not any(n.startswith(later) for n in g)]
     assert old == [("attn_out", "attn_lse"), ("qkv_proj",), ("attn_proj",),
@@ -545,6 +545,10 @@ def test_new_names_leave_the_old_names_order_as_it_was():
     new.remove("moe_experts_out")
     assert [n for n in new if n.startswith(("moe_", "ssm_"))][:4] == [
         "moe_router", "ssm_in_proj", "moe_latent", "moe_shared_up"]
+    # PR 49's two, a short convolution mixer's projections, behind
+    # `attn_proj` and beside the KDA's
+    assert new[4:6] == ["conv_in_proj", "conv_out_proj"]
+    del new[4:6]
     assert new[:7] == ["moe_router", "attn_gate", "kda_in_proj",
                        "kda_out_proj", "ssm_in_proj", "moe_latent",
                        "moe_shared_up"]
@@ -556,6 +560,7 @@ def test_new_names_leave_the_old_names_order_as_it_was():
     assert list(rp.PREFERENCE[:-2]) == [
         ("attn_out", "attn_lse"), ("moe_router",), ("qkv_proj",),
         ("attn_gate",), ("attn_proj",), ("kda_in_proj",), ("kda_out_proj",),
+        ("conv_in_proj",), ("conv_out_proj",),
         ("ssm_in_proj",), ("moe_experts_out",), ("moe_latent",),
         ("mlp_gate",), ("mlp_up",), ("moe_shared_up",), ("attn_qkv",)]
 
@@ -782,6 +787,85 @@ def test_solar_model_reckons_two_kinds_and_says_so(monkeypatch, caplog):
         + 3 * 2 * 8 * 48                            # 8 experts' two products
         + (4 + 2 * 2) * 16 + 4 * 16 + 64            # qkv, gate, attn_proj
         + 2 * (3 * 4 * 16 + 64))                    # two KDA blocks
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)
+
+
+# -- a convolution/attention stack whose second sublayer changes with depth
+# (models/hybrid.py's LFM2_MOE): a kind is a mixer AND a second sublayer ----
+
+LFM2_CELL = dict(
+    vocab_size=16384, n_dense_layers=1, moe_held=(0, 16),
+    layer_types=("conv", "full_attention", "conv", "conv", "conv"))
+
+
+def test_the_lfm2_model_states_a_kind_a_mixer_and_second_sublayer():
+    from pytorch_distributed_template_tpu.models.mixers import (
+        short_conv_block_sizes,
+    )
+
+    model = MODELS.get("Lfm2Moe")(**LFM2_CELL)
+    dense, conv, attn = model._block_kinds()
+    assert (dense.count, conv.count, attn.count) == (1, 3, 1)
+    mixer = {"conv_in_proj": 3 * 2048, "conv_out_proj": 2048}
+    experts = {"moe_router": 128, "moe_experts_gate": 16 * 1536,
+               "moe_experts_up": 16 * 1536}
+    assert dense.widths == {**mixer, "mlp_gate": 11776, "mlp_up": 11776}
+    assert conv.widths == {**mixer, **experts}
+    assert attn.widths == {"qkv_proj": (32 + 2 * 8) * 64, "attn_proj": 2048,
+                           **experts}
+    assert (attn.attn_heads, attn.head_dim, attn.scratch) == (32, 64, 0)
+    # the gated input and the convolution's result in float32
+    assert short_conv_block_sizes(2048, 2) == (mixer, 2 * 2048 * 2)
+    assert dense.scratch == conv.scratch == 8192
+    # no leading dense layer: two kinds; the published stack: its two
+    # leading layers are one kind, its 28 other convolution layers another
+    two = MODELS.get("Lfm2Moe")(**{**LFM2_CELL, "n_dense_layers": 0})
+    assert [k.count for k in two._block_kinds()] == [4, 1]
+    whole = MODELS.get("Lfm2Moe")()._block_kinds()
+    assert [k.count for k in whole] == [2, 28, 10]
+    assert whole[1].widths["moe_experts_up"] == 64 * 1536
+    # the families that came before state a kind a symbol, as they did
+    granite = MODELS.get("GraniteHybrid")()._block_kinds()
+    assert [k.count for k in granite] == [9, 1]      # one period
+
+
+def test_lfm2_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
+    """The stack under a training step on a device of known capacity: one
+    `remat/policy` record for its 3 blocks of three kinds, both new names
+    among those kept, and the loss and gradient of nothing kept."""
+    from pytorch_distributed_template_tpu.engine.losses import (
+        lm_cross_entropy,
+    )
+
+    trace._said.clear()
+    get_recorder().clear()
+    model = MODELS.get("TinyLfm2Moe")(remat=True)
+    tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, 256)
+    params = jax.jit(model.init)(jax.random.key(1), tokens)["params"]
+
+    def loss(p):
+        logits = model.apply({"params": p}, tokens, train=True)
+        return jnp.mean(lm_cross_entropy(logits, tokens))
+
+    want = jax.jit(jax.value_and_grad(loss))(params)    # no step: nothing
+    monkeypatch.setattr(rp, "device_capacity_bytes",
+                        lambda mesh=None: 2 * GIB)
+    with caplog.at_level(logging.INFO), rp.step_holds(1 << 20):
+        got = jax.jit(jax.value_and_grad(loss))(params)
+    (said,) = [e["args"] for e in get_recorder().snapshot()
+               if e["name"] == "remat/policy"]
+    assert said["blocks"] == 3
+    assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_proj,"
+                             "conv_in_proj,conv_out_proj,mlp_gate,mlp_up,"
+                             "moe_experts_gate,moe_experts_up")
+    tok = 2 * 32 * 4
+    assert said["kept_bytes"] == tok * (
+        4 * 16 + (4 + 2 * 2) * 16 + 64              # attn_out, qkv, attn_proj
+        + 2 * (3 * 64 + 64)                         # two convolution mixers
+        + 2 * 96                                    # the leading layer's MLP
+        + 2 * (8 + 2 * 8 * 48))                     # two expert layers
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)

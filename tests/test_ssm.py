@@ -353,3 +353,63 @@ def test_convolution_under_a_mesh_is_the_one_devices(axes, batch):
             1.0, float(jnp.max(jnp.abs(w)))))
     assert sharded_conv_silu(zxd, taps, bias, 8, None).shape == (
         batch, 21, 24)
+
+
+# -- the convolution without bias or activation (a gated short convolution's)
+
+
+def _conv_by_positions(x, taps):
+    """`sum_i taps[i] * x[t - (k - 1) + i]`, one position at a time."""
+    k, t = taps.shape[0], x.shape[1]
+    rows = []
+    for at in range(t):
+        total = jnp.zeros_like(x[:, 0])
+        for i in range(k):
+            if at - (k - 1) + i >= 0:
+                total = total + taps[i] * x[:, at - (k - 1) + i]
+        rows.append(total)
+    return jnp.stack(rows, axis=1)
+
+
+@pytest.mark.parametrize("t", [1, 2, 3, 7, 33])
+@pytest.mark.parametrize("k", [3, 4])
+def test_the_plain_convolution_is_the_loop_over_positions(t, k):
+    """Outputs and both gradients, at lengths under, at and over the
+    taps' reach and at an odd one; float32 inside, the input's type
+    outside; no bias, no activation."""
+    from pytorch_distributed_template_tpu.ops.ssm import causal_conv
+
+    key = jax.random.key(t * 10 + k)
+    x = jax.random.normal(jax.random.fold_in(key, 0), (2, t, 5), jnp.float32)
+    taps = jax.random.normal(jax.random.fold_in(key, 1), (k, 5), jnp.float32)
+    ct = jax.random.normal(jax.random.fold_in(key, 2), (2, t, 5), jnp.float32)
+    got, pull = jax.vjp(jax.jit(causal_conv), x, taps)
+    want, pull_want = jax.vjp(jax.jit(_conv_by_positions), x, taps)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    for g, w in zip(pull(ct), pull_want(ct)):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-6)
+    # linear: twice the input is twice the output, and zeros give zeros
+    np.testing.assert_allclose(causal_conv(2 * x, taps), 2 * got, rtol=1e-5,
+                               atol=1e-6)
+    assert not np.any(np.asarray(causal_conv(jnp.zeros_like(x), taps)))
+    low = causal_conv(x.astype(jnp.bfloat16), taps)
+    assert low.dtype == jnp.bfloat16
+    np.testing.assert_allclose(low.astype(jnp.float32), want, rtol=0.02,
+                               atol=0.05)
+
+
+def test_the_gated_mixer_is_its_three_lines():
+    """`ShortConvMixer`: `[B, C, z] = split3(in_proj(h))`,
+    `out_proj(C * conv(B * z))`, against the loop."""
+    from pytorch_distributed_template_tpu.models.mixers import ShortConvMixer
+
+    mixer = ShortConvMixer(6, 3, jnp.float32)
+    h = jax.random.normal(jax.random.key(4), (2, 9, 6), jnp.float32)
+    held = jax.jit(mixer.init)(jax.random.key(5), h)["params"]
+    assert {k: jax.tree.leaves(v)[0].shape for k, v in held.items()} == {
+        "in_proj": (6, 18), "conv_kernel": (3, 6), "out_proj": (6, 6)}
+    gate_in, gate_out, z = jnp.split(h @ held["in_proj"]["kernel"], 3, -1)
+    want = (gate_out * _conv_by_positions(gate_in * z, held["conv_kernel"])
+            ) @ held["out_proj"]["kernel"]
+    got = jax.jit(lambda p: mixer.apply({"params": p}, h))(held)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)

@@ -444,3 +444,85 @@ def test_delta_rule_step_carries_its_scopes(caplog):
         b * 3 * 4 * 16 * 16 * 16 * 4 for b in (1, 2)]
     assert "model/pattern: *K (2 layers" in caplog.text
     assert "three matrices an expert" in caplog.text
+
+
+# -- the convolution/attention stack (models/hybrid.py's LFM2_MOE,
+# models/mixers.ShortConvMixer) ---
+
+
+def test_lfm2_counters_reach_the_flight_record(tmp_path):
+    """The expert layers' three counters through the Trainer, from the
+    three layers behind the leading dense one."""
+    trainer = _hybrid_trainer(tmp_path, config="lfm2_moe_debug.json")
+    assert trainer.model.step_counters == (
+        "moe_pairs_here", "moe_load_max_over_mean", "moe_tokens_unserved")
+    trainer._train_epoch(1)
+    logged = [r for r in trainer.recorder.last() if "loss" in r]
+    assert logged
+    for r in logged:
+        # 16 x 32 tokens, 2 of 8 experts a token, 4 held, 3 expert layers
+        assert 0.5 * 1536 < r["moe_pairs_here"] < 1.5 * 1536
+        assert r["moe_load_max_over_mean"] >= 1.0
+        assert 0 <= r["moe_tokens_unserved"] <= 3 * 512
+    params = trainer.state.params
+    assert "experts" not in params["layers_0"] and "mlp" in params["layers_0"]
+    bias = np.asarray(params["layers_3"]["experts"]["selection_bias"]) / 1e-3
+    assert np.any(bias) and np.abs(bias).max() <= 6.001
+
+
+def test_short_conv_step_carries_its_scopes(caplog):
+    import logging
+
+    import pytorch_distributed_template_tpu.models  # noqa: F401
+    from pytorch_distributed_template_tpu.config.registry import MODELS
+    from pytorch_distributed_template_tpu.engine.losses import (
+        lm_cross_entropy,
+    )
+    from pytorch_distributed_template_tpu.engine.state import (
+        create_train_state,
+    )
+    from pytorch_distributed_template_tpu.engine.steps import make_train_step
+    from pytorch_distributed_template_tpu.observability import trace
+
+    trace._said.clear()
+    trace.get_recorder().clear()
+    model = MODELS.get("TinyLfm2Moe")(remat=True)
+    tx = optax.adamw(1e-3)
+    with caplog.at_level(logging.INFO):
+        state = jax.eval_shape(lambda: create_train_state(
+            model, tx, np.zeros((1, 40), np.int32), seed=0))
+        step = make_train_step(model, tx, lm_cross_entropy, [],
+                               input_key="tokens", target_key="tokens")
+        batch = {"tokens": jnp.zeros((2, 40), jnp.int32),
+                 "mask": jnp.ones((2,), jnp.float32)}
+        names = set(re.findall(
+            r'op_name="([^"]*)"',
+            jax.jit(step).lower(state, batch).compile().as_text()))
+
+    def some(pattern):
+        return any(re.search(pattern, n) for n in names)
+
+    for scope in ("short_conv", "short_conv_proj", "qknorm_attn", "dense_mlp",
+                  "moe_route", "moe_experts"):
+        assert some(rf"jvp\(.*/{scope}/"), scope
+        assert some(rf"transpose\(jvp\(.*/{scope}/"), scope
+    assert some(r"layers_0/mixer/short_conv_proj/in_proj")
+    assert some(r"layers_2/mixer/short_conv_proj/out_proj")
+    assert some(r"layers_1/qknorm_attn/mixer/q_layernorm")
+    assert some(r"layers_0/dense_mlp/mlp") and some(r"layers_1/experts/moe_")
+    # the leading layer has no experts, the others no dense MLP
+    assert not some(r"layers_0/experts") and not some(r"layers_[12]/dense_mlp")
+    # the gates and the convolution are outside the projections' scope
+    assert not some(r"short_conv/.*_proj") and not some(r"short_conv_proj/.*"
+                                                        r"short_conv/")
+    assert not some(r"ssm_conv|gated_attn")
+    said = [e["args"] for e in trace.get_recorder().snapshot()
+            if e["name"] == "conv/short"]
+    # the init probe's one row and the step's two: distinct records
+    assert said == [dict(taps=3, channels=64, positions=b * 40,
+                         read_bytes=3 * b * 40 * 64 * 4,
+                         written_bytes=b * 40 * 64 * 4) for b in (1, 2)]
+    assert "model/pattern: cfc (3 layers, each a mixer and gated experts, " \
+        "the first 1 a gated MLP of 96); c: a gated convolution of 3 taps" \
+        in caplog.text
+    assert "rotation of base 1e+06, a norm a head of q and of k" in caplog.text
