@@ -143,7 +143,7 @@ SOLAR_OPEN2 = Family(
 LFM2_MOE = Family(
     "Lfm2Moe", ("conv", "full_attention"), "operator_norm", "experts",
     "ffn_norm", rope_base=1e6, qk_norm=True, tied_head=True,
-    gated_experts=True, step_counters=MOE_COUNTERS)
+    gated_experts=True, step_counters=MOE_COUNTERS + ("moe_rows_run",))
 
 
 def _experts(c) -> dict:

@@ -22,7 +22,10 @@ the XLA SPMD partitioner understands natively):
 ``ExpertLayer`` is the other expert layer, for the models whose experts
 outnumber the chips: it is told which experts it holds, routes over all
 the published ones, drops no token, and computes every expert it holds
-over every token, so that a step's time does not follow its routing.
+over every token, so that a step's time does not follow its routing, or,
+where a token can take fewer experts than are held, over the pairs of
+token and held expert alone (``routed_over_pairs``, at the end of this
+file).
 ``MoeMlp`` stays for the GShard form: capacity slots, the einsum dispatch
 the SPMD partitioner turns into an all-to-all over an ``expert`` mesh
 axis, the load-balancing loss and padded-example masks, none of which the
@@ -32,7 +35,7 @@ from __future__ import annotations
 
 import functools
 import logging
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -43,6 +46,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config.registry import MODELS
 from ..observability.trace import say_once
+from ..ops.grouped import grouped_matmul, grouped_matmul_gradients
 from .llama import SwiGLU
 
 logger = logging.getLogger(__name__)
@@ -385,20 +389,43 @@ class ExpertLayer(nn.Module):
     layer runs without its exchange, and the sum over all shares of
     ``held`` (the shared expert counted once) is the whole layer.
 
-    No token is dropped whatever the imbalance, and the step's time does
-    not follow it. The room for pairs of token and held expert is sized
-    from shapes alone at its bound, ``tokens x min(top_k, held)``, which
-    no routing can pass; with that much room every held expert has a
-    place for every token, so nothing is sorted or gathered: each held
-    expert's two products run over all the tokens, weighed 0 where a
-    token did not choose it (``held_experts``). That is ``held`` dense
-    products where routing needs ``top_k / n_routed`` of each: right for
-    a chip's share of a few experts, not for hundreds held at once. (On
-    a v5e at 16384 tokens, 8 held of 512, 22 a token, forward and
-    gradient: 27.1 ms a layer whatever the routing; a buffer of that
-    size sorted by expert under ``jax.lax.ragged_dot`` takes 24.8 ms at
-    uniform routing and 42.2 ms when three held experts take every
-    token, and three times the memory. PERF.md, PR 33.)
+    No token is dropped whatever the imbalance. The room for pairs of
+    token and held expert is sized from shapes alone, ``tokens x
+    min(top_k, held)`` rows, and which of two forms the routed experts'
+    products take is read from the same two numbers:
+
+    - ``top_k >= held`` (a chip's share of a few experts of many: 8 held,
+      22 or 8 a token): the room is ``tokens x held``, which no routing
+      can pass; with that much room every held expert has a place for
+      every token, so nothing is sorted or gathered: each held expert's
+      products run over all the tokens, weighed 0 where a token did not
+      choose it (``held_experts``), and the step's time does not follow
+      the routing. That is ``held`` dense products where routing needs
+      ``top_k / n_routed`` of each. (On a v5e at 16384 tokens, 8 held of
+      512, 22 a token, forward and gradient: 27.1 ms a layer whatever
+      the routing; a buffer of that size sorted by expert under
+      ``jax.lax.ragged_dot`` took 24.8 ms at uniform routing and 42.2 ms
+      when three held experts take every token, and three times the
+      memory. PERF.md, PR 33: the reason this form stays here, beside
+      routing that drifts inside the benchmark's window.)
+    - ``top_k < held`` (many small experts held, or all of them: 16 held,
+      4 a token): every held expert over every token would be ``held /
+      top_k`` times the room, four times what the worst routing fills
+      and sixteen times what balanced routing does. The products run
+      over the pairs (``routed_over_pairs``): the tokens' rows gathered
+      expert by expert into the room (``hit.T`` read row-major is in
+      expert order, so a pair's row is a running count and nothing is
+      sorted by expert), grouped products with one group a held expert
+      (ops/grouped.py: ``jax.lax.ragged_dot``, which the TPU's compiler
+      makes kernels that visit only the row tiles a group fills, so the
+      time follows the rows filled and not the room), a token's rows
+      summed back in float32. The step's time
+      follows ``moe_pairs_here``. The room is a bound only while no
+      expert ties exactly at a token's bar (the choice is a mask, so a
+      tie gives the token more than ``top_k`` experts: a router of zeros
+      gives it all of them): in a step whose pairs pass the room, a
+      ``cond`` on their count takes every held expert over every token
+      instead, and ``moe_rows_run`` says so. Nothing is clipped.
 
     ``gated`` makes every expert three matrices,
     ``(silu(l @ gate_e) * (l @ up_e)) @ down_e`` (``held_gated_experts``),
@@ -419,8 +446,13 @@ class ExpertLayer(nn.Module):
     ``shared_d_ff`` adds one expert every token takes. Counters of the
     step, sown under ``counters`` (engine/steps.py carries them):
     ``moe_pairs_here``, ``moe_load_max_over_mean`` over the experts held
-    (``1 / n_layers`` of it, so that the layers' sum is their mean) and
-    ``moe_tokens_unserved``, the tokens none of whose experts is held.
+    (``1 / n_layers`` of it, so that the layers' sum is their mean),
+    ``moe_tokens_unserved``, the tokens none of whose experts is held,
+    and ``moe_rows_run``, the rows the routed experts' products ran over
+    (``tokens x held``; over the pairs the rows the groups hold, which
+    are the pairs: the tiles ``ragged_dot`` rounds a group up to are the
+    compiler's; ``tokens x held`` again in a step whose ties pass the
+    room).
     """
     d_model: int
     d_ff: int
@@ -507,22 +539,47 @@ class ExpertLayer(nn.Module):
         down = self.param("experts_down", _init(0.02),
                           (n_held, self.d_ff, width), jnp.float32)
 
-        say_once(
-            logger, "moe/dispatch",
-            dict(tokens=s, held=n_held, routed=self.n_routed, top_k=k,
-                 expected=s * k * n_held / self.n_routed,
-                 rows=s * min(k, n_held),
-                 **({"experts": "gated"} if self.gated else {})),
-            "moe/dispatch: %(tokens)d tokens, %(held)d of %(routed)d experts "
-            "held, %(top_k)d a token: %(expected).0f pairs a layer a step at "
-            "uniform routing, room for %(rows)d, which no routing passes: "
-            "every held expert over every token"
-            + (", three matrices an expert" if self.gated else ""))
+        # the room for pairs at its bound; fewer rows than every held
+        # expert over every token only where a token can take fewer
+        # experts than are held
+        room = s * min(k, n_held)
+        over_pairs = min(k, n_held) < n_held
+        said = dict(tokens=s, held=n_held, routed=self.n_routed, top_k=k,
+                    expected=s * k * n_held / self.n_routed, rows=room,
+                    **({"experts": "gated"} if self.gated else {}))
+        text = ("moe/dispatch: %(tokens)d tokens, %(held)d of %(routed)d "
+                "experts held, %(top_k)d a token: %(expected).0f pairs a "
+                "layer a step at uniform routing, room for %(rows)d, ")
+        if over_pairs:
+            text += ("under every held expert over every token "
+                     "(%(dense_rows)d rows): the products run over the "
+                     "pairs, one group a held expert; every "
+                     "held expert over every token only when ties fill "
+                     "more than the room")
+            said["dense_rows"] = s * n_held
+        else:
+            text += ("which no routing passes: every held expert over every "
+                     "token")
+        say_once(logger, "moe/dispatch", said,
+                 text + (", three matrices an expert" if self.gated else ""))
         with jax.named_scope("moe_experts"):
-            if self.gated:
-                routed = held_gated_experts(tokens, weight, gate, up, down)
+            if over_pairs:
+                mats = tuple(gradient_as_stored(m.astype(self.dtype))
+                             for m in ((gate, up) if self.gated else (up,)))
+                mats += (down.astype(self.dtype),)
+                fits = pairs <= room
+                routed = routed_over_pairs(
+                    tokens, weight, hit, counts.astype(jnp.int32), mats,
+                    room)
+                self._count("moe_rows_run",
+                            jnp.where(fits, pairs, s * n_held))
             else:
-                routed = held_experts(tokens, weight, up, down)
+                self._count("moe_rows_run", s * n_held)
+                if self.gated:
+                    routed = held_gated_experts(tokens, weight, gate, up,
+                                                down)
+                else:
+                    routed = held_experts(tokens, weight, up, down)
 
         with jax.named_scope("moe_shared"):
             out = routed.astype(self.dtype)
@@ -585,18 +642,28 @@ def tiny_moe_lm(vocab_size: int = 256, n_layer: int = 2, n_head: int = 4,
     )
 
 
-def expert_block_sizes(d_ff: int, n_routed: int, held=(0, 0), latent: int = 0,
-                       shared_d_ff: int = 0, gated: bool = False,
-                       dtype: Any = jnp.float32, **_) -> dict:
+def expert_block_sizes(d_ff: int, n_routed: int, top_k: int = 0, held=(0, 0),
+                       latent: int = 0, shared_d_ff: int = 0,
+                       gated: bool = False, dtype: Any = jnp.float32,
+                       **_) -> dict:
     """What a block with an ``ExpertLayer`` of these fields (the others
     set no width) tells models/remat_policy.py: every name the layer
     makes with its width in features a token of ``dtype``. The float32
     router logits count twice a 16-bit model's item; the routed experts'
-    first products are as wide as the experts held times their ``d_ff``;
+    first products are as wide as the rows they run over a token times
+    their ``d_ff``: the experts held or, where a token can take fewer
+    (``top_k``; 0: not said, every held expert), the room for its pairs,
+    whose layout is then ``moe_pairs``;
     the layer's sum has a name only where ``latent_up`` reads it; a gated
     layer's shared expert is a ``SwiGLU`` and makes its two names."""
-    first = (held[1] or n_routed) * d_ff
-    widths = {"moe_router": n_routed * 4 // jnp.dtype(dtype).itemsize}
+    n_held = held[1] or n_routed
+    places = min(top_k or n_held, n_held)
+    first, item = places * d_ff, jnp.dtype(dtype).itemsize
+    widths = {"moe_router": n_routed * 4 // item}
+    if places < n_held:
+        # ``PairLayout`` and the rows' weights: a key, a flag and a weight
+        # a row; a row and a place a held expert, a count, a token
+        widths["moe_pairs"] = -(-(places * 9 + n_held * 8 + 4) // item)
     if latent:
         widths.update({"moe_latent": latent, "moe_experts_out": latent})
     if shared_d_ff:
@@ -605,3 +672,317 @@ def expert_block_sizes(d_ff: int, n_routed: int, held=(0, 0), latent: int = 0,
     if gated:
         widths["moe_experts_gate"] = first
     return {**widths, "moe_experts_up": first}
+
+
+
+# ---------------------------------------------------------------------------
+# The routed experts over the pairs, where a token can take fewer experts
+# than are held (``ExpertLayer`` says when)
+# ---------------------------------------------------------------------------
+
+class PairLayout(NamedTuple):
+    """Where each pair of token and held expert lies in a buffer of
+    ``room`` rows laid expert by expert, tokens in their order inside an
+    expert. Integers all: no gradient reaches a layout.
+
+    ``key [room]`` is ``expert * S + token`` of the pair a row holds
+    (``E * S`` where none does), rising; ``live [room]`` whether a pair
+    fills the row; ``pos [S, E]`` a pair's row and ``order [S, E]`` its
+    place among its token's pairs (-1: no pair); ``taken [S]`` a token's
+    pairs."""
+    key: Any
+    live: Any
+    pos: Any
+    order: Any
+    taken: Any
+
+    @property
+    def token(self):
+        return self.key % self.taken.shape[0]
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def lay_pairs(weight, hit, counts, room: int):
+    """The layout of the pairs ``hit [S, E]`` marks in ``room`` rows, and
+    each row's weight ``[room]`` of ``weight [S, E]`` (0 where no pair
+    fills it). ``hit.T`` read row-major is in expert order already, so
+    nothing is sorted by expert: the marked entries' flat indices are
+    moved to the front by one sort of ``E * S`` integers that carries the
+    weights along, and a pair's row is a running count. Pairs past the
+    room have no row (the caller takes the other way then)."""
+    s, e = hit.shape
+    flat = hit.T.reshape(-1)
+    key, of_row = jax.lax.sort(
+        (jnp.where(flat, jnp.arange(e * s, dtype=jnp.int32), e * s),
+         weight.T.reshape(-1)), num_keys=1)
+    starts = jnp.cumsum(counts) - counts
+    pos = starts[None, :] + jnp.cumsum(hit, axis=0, dtype=jnp.int32) - 1
+    order = jnp.where(hit, jnp.cumsum(hit, axis=1, dtype=jnp.int32) - 1, -1)
+    live = jnp.arange(room, dtype=jnp.int32) < jnp.sum(counts)
+    lay = PairLayout(key[:room], live, pos, order,
+                     jnp.sum(hit, axis=1, dtype=jnp.int32))
+    return lay, jnp.where(live, of_row[:room], 0.0)
+
+
+def _lay_pairs_fwd(weight, hit, counts, room):
+    lay, of_row = lay_pairs(weight, hit, counts, room)
+    return (lay, of_row), lay
+
+
+def _weights_of_rows_back(lay, g):
+    """Each live row's cotangent ``g [room]`` back to its pair's place in
+    ``[S, E]``: the keys rise and no two are alike; a row no pair fills
+    holds a key past the end."""
+    s, e = lay.order.shape
+    back = jnp.zeros((e * s,), g.dtype).at[lay.key].set(
+        g, mode="drop", indices_are_sorted=True, unique_indices=True)
+    return back.reshape(e, s).T
+
+
+lay_pairs.defvjp(
+    _lay_pairs_fwd,
+    lambda room, lay, g: (_weights_of_rows_back(lay, g[1]), None, None))
+
+
+@jax.custom_vjp
+def rows_of_tokens(x, lay: PairLayout):
+    """``x [S, D]`` -> ``[room, D]``: each pair's row is its token's row
+    of ``x``. A row no pair fills holds some token's row, which nothing
+    may read: the grouped products do not, and ``tokens_of_rows``, its
+    transpose over the rows that pairs fill, does not. Each is the
+    other's backward: rows are gathered both ways and never scattered."""
+    return x[lay.token]
+
+
+@jax.custom_vjp
+def tokens_of_rows(y, lay: PairLayout):
+    """``y [room, D]`` -> ``[S, D]`` in float32: each token's row is the
+    float32 sum of its pairs' rows of ``y``, read where a pair is and
+    nowhere else. A token's first ``room / S`` pairs are gathered at
+    once, every token's; a token with more (experts tied exactly at its
+    bar) makes a loop go on behind them, one place a turn, for as long
+    as the busiest token needs."""
+    s = lay.taken.shape[0]
+    places = lay.live.shape[0] // s
+
+    def row(i):
+        return jnp.sum(jnp.where(lay.order == i, lay.pos, 0), axis=1)
+
+    # place by place, so that the rows gathered split into places on
+    # their major dimension and are summed as they lie
+    def add(i, out, got):
+        return out + jnp.where((i < lay.taken)[:, None],
+                               got.astype(jnp.float32), 0.0)
+
+    rows = jnp.concatenate([row(i) for i in range(places)])
+    got = y[rows].reshape(places, s, -1)
+    out = jnp.zeros((s, y.shape[1]), jnp.float32)
+    for i in range(places):         # one pass over what was gathered
+        out = add(i, out, got[i])
+    return jax.lax.fori_loop(places, jnp.max(lay.taken),
+                             lambda i, out: add(i, out, y[row(i)]), out)
+
+
+rows_of_tokens.defvjp(
+    lambda x, lay: (rows_of_tokens(x, lay), (lay, x[:0])),
+    lambda kept, g: (tokens_of_rows(g, kept[0]).astype(kept[1].dtype), None))
+tokens_of_rows.defvjp(
+    lambda y, lay: (tokens_of_rows(y, lay), (lay, y[:0])),
+    lambda kept, g: (rows_of_tokens(g, kept[0]).astype(kept[1].dtype), None))
+
+
+def _activation(pre):
+    """An expert's activation of its first products, in float32:
+    ``silu(gate) * up`` of two, ``relu(up)**2`` of one."""
+    pre = [p.astype(jnp.float32) for p in pre]
+    return (jax.nn.silu(pre[0]) * pre[1] if len(pre) == 2
+            else jnp.square(jax.nn.relu(pre[0])))
+
+
+def _laid_on(pre, of_row, live, dtype):
+    """The experts' activation with the token's weight on it: jax says
+    nothing of what ``ragged_dot`` leaves in the rows no group holds, so
+    each first product passes a select on ``live`` before it meets
+    arithmetic; the weight is laid on in float32 and the product rounded
+    once, to ``dtype``."""
+    act = _activation([jnp.where(live[:, None], p, 0) for p in pre])
+    return (act * of_row[:, None]).astype(dtype)
+
+
+def experts_over_pairs(x, weight, hit, counts, *mats, room: int):
+    """What ``held_experts`` and ``held_gated_experts`` give, computed
+    over the pairs alone: ``x [S, D]``, ``weight [S, E]``, ``hit [S, E]``
+    where a token chose a held expert, ``counts [E]`` their sums, and
+    ``mats`` the experts' matrices in the compute type, ``(up, down)``
+    or ``(gate, up, down)``. Returns ``[S, D]`` in float32. The pairs
+    must fit the ``room``.
+
+    The tokens' rows are gathered into ``room`` rows expert by expert
+    (scope ``moe_dispatch``), the products are grouped products with one
+    group a held expert (ops/grouped.py); the
+    token's weight is laid on the activation in float32 and the product
+    rounded once to the operands' type; the last product gives a float32
+    row a pair, and a token's rows are summed in float32 (scope
+    ``moe_combine``)."""
+    with jax.named_scope("moe_dispatch"):
+        lay, of_row = lay_pairs(weight.astype(jnp.float32), hit, counts, room)
+    return _over_pairs(x, lay, of_row, counts, *mats)[0]
+
+
+def _over_pairs(x, lay, of_row, counts, *mats):
+    """(``experts_over_pairs``'s value in a layout given, the first
+    products as the grouped products make them, ``[room, F]`` each)."""
+    *first, down = mats
+    with jax.named_scope("moe_dispatch"):
+        rows = rows_of_tokens(x, lay)
+    pre = tuple(grouped_matmul(rows, m, counts) for m in first)
+    out = grouped_matmul(_laid_on(pre, of_row, lay.live, x.dtype), down,
+                         counts, jnp.float32)
+    with jax.named_scope("moe_combine"):
+        return tokens_of_rows(out, lay), pre
+
+
+def every_expert_over_every_token(x, weight, *mats):
+    """``experts_over_pairs``'s values by every held expert over every
+    token, the weight laid on in float32 as there: for the step whose
+    pairs do not fit the room."""
+    *first, down = mats
+    # one product for the first matrices side by side: this way runs in a
+    # step in a thousand runs, and what it costs every run is its code
+    pre = jnp.einsum("sd,edf->esf", x, jnp.concatenate(first, axis=2))
+    act = _activation(jnp.split(pre, len(first), axis=2))
+    act = (act * weight.astype(jnp.float32).T[:, :, None]).astype(x.dtype)
+    return jnp.einsum("esf,efd->sd", act, down,
+                      preferred_element_type=jnp.float32)
+
+
+def _of_expert(e, weight, *mats):
+    """Held expert ``e``'s column of ``weight [S, E]`` and its matrices,
+    each with the expert's dimension kept, one long."""
+    return (jax.lax.dynamic_slice_in_dim(weight, e, 1, axis=1),
+            *(jax.lax.dynamic_slice_in_dim(m, e, 1, axis=0) for m in mats))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def routed_over_pairs(x, weight, hit, counts, mats, room: int):
+    """``experts_over_pairs`` where the pairs fit the ``room``, and
+    ``every_expert_over_every_token`` in the step where they do not
+    (exact ties at a token's bar give it more than ``top_k`` experts, so
+    ``tokens x top_k`` is no bound then): no token is dropped either way.
+    One ``cond`` on the pairs' count forward and one backward, under a
+    backward rule of this function's own, so that neither way costs the
+    other anything: differentiated, a ``cond`` hands out what EACH branch
+    would keep for its backward, the untaken one's as zeros, and the
+    compiler cannot fuse across it (1.8 GB a layer of float32
+    activations and masks at 32768 rows of 1536, PR 50). Here the
+    forward keeps the pairs' layout (``moe_pairs``) and the first
+    products under their checkpoint names and what came in, whichever
+    way was taken; the backward remakes the activation on the way it
+    takes, and no product. The other way keeps nothing: its backward
+    runs its first products again, an expert at a time.
+
+    Both rules' bodies are jitted functions of this module, so that a
+    model's expert layers of one shape are traced and lowered once, not
+    once a layer and again in each layer's recomputation (the step's
+    trace 3.2 -> 7.6 s on the chip's host without, PR 50)."""
+    return _forward(x, weight, hit, counts, *mats, room=room)[0]
+
+
+@functools.partial(jax.jit, static_argnames=("room",))
+def _forward(x, weight, hit, counts, *mats, room):
+    """(the layer's float32 sum, the pairs' layout, each row's weight,
+    the first products: zeros from the step whose pairs pass the room)."""
+    with jax.named_scope("moe_dispatch"):
+        lay, of_row = lay_pairs(weight.astype(jnp.float32), hit, counts, room)
+
+    def pairs(x, weight, *mats):
+        out, pre = _over_pairs(x, lay, of_row, counts, *mats)
+        return (out, *pre)
+
+    def every(x, weight, *mats):
+        def one(e, out):
+            return out + every_expert_over_every_token(
+                x, *_of_expert(e, weight, *mats))
+
+        nothing = jnp.zeros((room, mats[0].shape[2]), x.dtype)
+        return (jax.lax.fori_loop(0, weight.shape[1], one, jnp.zeros(
+            x.shape[:1] + mats[-1].shape[2:], jnp.float32)),
+            *(nothing for _ in mats[:-1]))
+
+    out, *pre = jax.lax.cond(jnp.sum(counts) <= room, pairs, every,
+                             x, weight, *mats)
+    return out, lay, of_row, tuple(pre)
+
+
+def _routed_over_pairs_fwd(x, weight, hit, counts, mats, room):
+    out, lay, of_row, pre = _forward(x, weight, hit, counts, *mats,
+                                     room=room)
+    lay, of_row = jax.tree.map(
+        lambda a: checkpoint_name(a, "moe_pairs"), (lay, of_row))
+    names = ("moe_experts_gate", "moe_experts_up")[-len(pre):]
+    pre = tuple(checkpoint_name(p, name) for p, name in zip(pre, names))
+    return out, (x, weight, counts, mats, lay, of_row, pre)
+
+
+@functools.partial(jax.jit, static_argnames=("room",))
+def _backward(x, weight, counts, lay, of_row, pre, g, *mats, room):
+    def pairs(x, weight, pre, g, *mats):
+        *first, down = mats
+        # the first products and the layout are the forward's; the rows
+        # they read and the activation are made again, no product is
+        with jax.named_scope("moe_dispatch"):
+            rows = rows_of_tokens(x, lay)
+        act, to_first = jax.vjp(functools.partial(
+            _laid_on, live=lay.live, dtype=x.dtype), pre, of_row)
+        with jax.named_scope("moe_combine"):
+            # the cotangent as it stands in memory: fused into the gather,
+            # what makes it would be made again for every row
+            d_out = rows_of_tokens(
+                jax.lax.optimization_barrier(g.astype(x.dtype)), lay)
+        d_act, d_down = grouped_matmul_gradients(act, down, counts, d_out)
+        d_pre, d_of_row = to_first(d_act)
+        d_rows, d_first = zip(*(
+            grouped_matmul_gradients(rows, m, counts, d)
+            for m, d in zip(first, d_pre)))
+        with jax.named_scope("moe_dispatch"):
+            # the products' cotangents are added over the room, in the
+            # rows' type as the dense form adds them, and summed by token
+            # once
+            dx = tokens_of_rows(sum(d_rows[1:], d_rows[0]), lay)
+            d_weight = _weights_of_rows_back(lay, d_of_row)
+        return dx.astype(x.dtype), d_weight, (*d_first, d_down)
+
+    def every(x, weight, pre, g, *mats):
+        def one(e, sums):
+            dx, *rest = sums
+            dx_e, *rest_e = jax.vjp(
+                every_expert_over_every_token,
+                x, *_of_expert(e, weight, *mats))[1](g)
+            return (dx + dx_e.astype(jnp.float32), *(
+                jax.lax.dynamic_update_index_in_dim(whole, part, e, axis)
+                for whole, part, axis in zip(
+                    rest, rest_e, (1,) + (0,) * len(mats))))
+
+        dx, d_weight, *d_mats = jax.lax.fori_loop(
+            0, weight.shape[1], one,
+            (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(weight),
+             *(jnp.zeros_like(m) for m in mats)))
+        return dx.astype(x.dtype), d_weight, tuple(d_mats)
+
+    return jax.lax.cond(jnp.sum(counts) <= room, pairs, every,
+                        x, weight, pre, g, *mats)
+
+
+def _routed_over_pairs_bwd(room, kept, g):
+    x, weight, counts, mats, lay, of_row, pre = kept
+    dx, d_weight, d_mats = _backward(x, weight, counts, lay, of_row, pre, g,
+                                     *mats, room=room)
+    # what reads the matrices' gradients stays outside the ``cond``: moved
+    # into its branches (the compiler does that), the cast to the leaves'
+    # float32 and the gradient norm's sums made the optimizer's pass read
+    # a float32 gradient it had to be written first, 8 bytes a parameter
+    # more (`optimizer_ms_per_step` 30.9 -> 37.5, my chip run, PR 50)
+    return dx, d_weight, None, None, jax.lax.optimization_barrier(d_mats)
+
+
+routed_over_pairs.defvjp(_routed_over_pairs_fwd, _routed_over_pairs_bwd)

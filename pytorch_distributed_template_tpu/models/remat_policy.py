@@ -44,6 +44,10 @@ width stated beside that mixer (models/mixers.py, models/moe.py):
 - ``moe_router``: an expert layer's router logits, float32 and as wide as
   the experts published; they come from a float32 product of six passes,
   so a byte of them spares the most, and they go second;
+- ``moe_pairs``: where an expert layer's products run over the pairs of
+  token and held expert (models/moe.py), the pairs' layout, integers and
+  each row's weight, a hundred or two bytes a token: kept, the backward
+  does not sort and count again, and they stand with the router;
 - ``ssm_in_proj``, ``moe_latent``, ``moe_shared_up``: a state-space
   layer's input projection, an expert layer's latent projection and its
   shared expert's first product. Each contracts over ``d_model`` like
@@ -73,12 +77,18 @@ so keeping the result would spare next to nothing. So it is with the
 routed experts' where nothing reads their sum but a residual sum; where
 a projection does, the sum is ``moe_experts_out``, above. What lies
 inside the routed experts has two names (models/moe.py):
-``moe_experts_gate`` and ``moe_experts_up``, every held expert's first
-products over every token as the einsums make them (one,
-``moe_experts_up``, where an expert has two matrices). By recomputation
+``moe_experts_gate`` and ``moe_experts_up``, the experts' first products
+(one, ``moe_experts_up``, where an expert has two matrices): every held
+expert's over every token as the einsums make them, ``held * d_ff``
+features a token, where a token can take every expert held; where it can
+take fewer, the grouped products over the room for its pairs,
+``top_k * d_ff`` (``models/moe.expert_block_sizes`` states the one or
+the other by the layer's own rule: 24576 and 6144 features a token at 16
+held of 1536, 4 a token, so both names fit there, 101 MB a layer each,
+where one of 403 did). By recomputation
 spared for a byte they would stand with ``mlp_gate`` where they contract
 over ``d_model`` and at a quarter of that over a 1024-wide latent, but
-they are ``held`` times as wide as any other name, one order serves
+they are up to ``held`` times as wide as any other name, one order serves
 every model and the choice is a prefix, so a name that does not fit drops
 all behind it: they go last, each a group of its own, and no model loses
 a name to them; a budget with room for one keeps one. Kept or not they
@@ -115,6 +125,7 @@ logger = logging.getLogger(__name__)
 PREFERENCE: Tuple[Tuple[str, ...], ...] = (
     ("attn_out", "attn_lse"),
     ("moe_router",),
+    ("moe_pairs",),
     ("qkv_proj",),
     ("attn_gate",),
     ("attn_proj",),

@@ -185,59 +185,115 @@ def test_granite_step_lowers_and_fits_for_v5e(topo, monkeypatch,
     assert total < V5E_BYTES_LIMIT - (1 << 30)
 
 
-def test_lfm2_step_lowers_and_fits_for_v5e(topo, monkeypatch, fresh_records):
-    """`lfm2_24b_a2b_l5.seq8k`'s step (1 x 8192 on one chip): four gated
-    short convolutions (plain `jax.numpy`, no kernel) and one rotated,
-    q/k-normed attention at head 64 through the three flash kernels, the
-    leading layer's 11776-wide gated MLP, 16 held of 64 gated experts
-    over every token in the four layers behind it, and the tied head
-    over a quarter of the vocabulary through the fused loss compile for
-    the v5e; the checkpoint policy reckons three kinds of block and
-    keeps the names it was told; the step stays under the chip's
-    `bytes_limit`."""
+@pytest.fixture(scope="module")
+def lfm2_step(topo):
+    """`lfm2_24b_a2b_l5.seq8k`'s step, compiled once for the tests that
+    read it."""
     import json
     from pathlib import Path
 
     from pytorch_distributed_template_tpu.config.registry import MODELS
     import pytorch_distributed_template_tpu.models  # noqa: F401
-    from pytorch_distributed_template_tpu.parallel import build_mesh
 
     arch = json.loads((
         Path(__file__).resolve().parent.parent / "benchmarks" / "configs"
         / "lfm2_24b_a2b_l5.json").read_text())["experiment"]["arch"]
-    mesh = build_mesh({"data": 1}, devices=topo.devices[:1])
-    _, compiled = _compiled_train_step(
-        MODELS.get(arch["type"])(**arch["args"]), mesh, 1, 8192, monkeypatch)
-    text = compiled.as_text()
+    return _step_compiled_once(MODELS.get(arch["type"])(**arch["args"]),
+                               topo, 1, 8192)
+
+
+def _grouped_products_under(text, scope):
+    """The kernels the compiler made of `jax.lax.ragged_dot` and
+    `ragged_dot_general` anywhere in the compiled text under the scope,
+    by what each returns."""
+    return [m.group(1) for m in re.finditer(
+        r'%ragged-dot[-\w.]* = (\w+\[[\d,]*\])[^\n]*'
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        rf'op_name="[^"]*/{scope}/', text)]
+
+
+def test_lfm2_step_lowers_and_fits_for_v5e(lfm2_step):
+    """`lfm2_24b_a2b_l5.seq8k`'s step (1 x 8192 on one chip): four gated
+    short convolutions (plain `jax.numpy`, no kernel) and one rotated,
+    q/k-normed attention at head 64 through the three flash kernels, the
+    leading layer's 11776-wide gated MLP, 16 held of 64 gated experts in
+    the four layers behind it, and the tied head over a quarter of the
+    vocabulary through the fused loss compile for the v5e; the
+    checkpoint policy reckons three kinds of block and keeps the names it
+    was told; the step stays under the chip's `bytes_limit`.
+
+    A token takes 4 of the 16 experts held, so the routed experts'
+    products run over the pairs (ISSUE 50): grouped products with one
+    group a held expert in a room of 32768 rows, which the compiler makes
+    kernels of its own under `moe_experts`; no array a held expert by every token wide is made
+    outside the branch a step with ties takes; and both first products
+    are kept by name, a quarter as wide as they were."""
+    text, said = lfm2_step.text, lfm2_step.said
     for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
         assert len(re.findall(rf"%{kernel}(\.\d+)? = ", text)) == 1
-    assert "ssm_conv_bwd" not in text and "ragged-dot" not in text
-    (policy,) = _said("remat/policy")
-    print(f"lfm2 step: {_compiled_bytes(compiled)} bytes compiled, "
+    assert "ssm_conv_bwd" not in text
+    # a layer: three products forward and a rows' gradient each backward
+    # over the room's rows; a matrix's gradient each, as the leaf lies
+    products = _grouped_products_under(text, "moe_experts")
+    assert len(products) == 4 * 9
+    rows = [p for p in products if "[32768," in p]
+    assert sorted(set(rows)) == ["bf16[32768,1536]", "bf16[32768,2048]",
+                                 "f32[32768,2048]"] and len(rows) == 4 * 6
+    assert sorted(p for p in products if p not in rows) == sorted(
+        4 * ["bf16[16,1536,2048]"] + 8 * ["bf16[16,2048,1536]"])
+    assert not re.search(r"\[16,8192,1536\]|\[8192,16,1536\]"
+                         r"|\[16,1536,8192\]", text)
+    (policy,) = _said("remat/policy", said)
+    print(f"lfm2 step: {lfm2_step.total_bytes} bytes compiled, "
           f"policy {policy}")
     assert policy["blocks"] == 5
     # parameters and both moments; the gradient is the backward's own
     assert abs(policy["held_bytes"] - 788_052_352 * 12) < 64
-    (pattern,) = _said("model/pattern")
+    (pattern,) = _said("model/pattern", said)
     assert pattern["pattern"] == "cfccc" and pattern["n_dense_layers"] == 1
     assert (pattern["rows"], pattern["of_rows"]) == (16384, 65536)
     assert (pattern["held"], pattern["moe_n_routed"], pattern["moe_top_k"],
             pattern["conv_taps"]) == (16, 64, 4, 3)
-    (conv,) = [c for c in _said("conv/short") if c["positions"] == 8192]
+    (conv,) = [c for c in _said("conv/short", said)
+               if c["positions"] == 8192]
     assert conv == dict(taps=3, channels=2048, positions=8192,
                         read_bytes=3 * 8192 * 2048 * 2,
                         written_bytes=8192 * 2048 * 2)
-    (dispatch,) = [d for d in _said("moe/dispatch") if d["tokens"] == 8192]
+    (dispatch,) = [d for d in _said("moe/dispatch", said)
+                   if d["tokens"] == 8192]
     assert dispatch["rows"] == 32768 and dispatch["expected"] == 8192
     assert dispatch["experts"] == "gated"
-    (said,) = _said("head_loss/slice")
-    assert said["gradients"] == "forward"
-    # every name its three kinds make but the last: the 16 held experts'
-    # gate products fit (403 MB a layer, 1.61 GB over four), their up
-    # products beside them do not (2.895 GB kept of a budget of 3.688)
+    assert dispatch["dense_rows"] == 16 * 8192
+    (said_slice,) = _said("head_loss/slice", said)
+    assert said_slice["gradients"] == "forward"
+    # every name its three kinds make: the first products over the pairs'
+    # room are 101 MB a layer each (403 over every token, where `up`'s did
+    # not fit: 2.895 GB kept of a budget of 3.688 then), and beside the
+    # router the pairs' layout, 1.4 MB a layer
     assert policy["names"].split(",") == [
-        "attn_out", "attn_lse", "moe_router", "qkv_proj", "attn_proj",
-        "conv_in_proj", "conv_out_proj", "mlp_gate", "mlp_up", "attn_qkv",
-        "moe_experts_gate"]
-    assert policy["budget_bytes"] >= policy["kept_bytes"] == 2_895_118_336
-    assert _compiled_bytes(compiled) < V5E_BYTES_LIMIT - (1 << 30)
+        "attn_out", "attn_lse", "moe_router", "moe_pairs", "qkv_proj",
+        "attn_proj", "conv_in_proj", "conv_out_proj", "mlp_gate", "mlp_up",
+        "attn_qkv", "moe_experts_gate", "moe_experts_up"]
+    assert policy["budget_bytes"] >= policy["kept_bytes"] == 2_095_316_992
+    # under what the step over every token compiled to (PR 49), and with
+    # it 1 GiB under the chip's limit
+    assert lfm2_step.total_bytes < 13_889_780_224 < V5E_BYTES_LIMIT - (1 << 30)
+
+
+def test_the_optimizer_reads_lfm2s_expert_matrices_where_they_lie(lfm2_step):
+    """As for solar's three matrices an expert (PR 46), over the pairs:
+    the matrices' gradients leave the grouped products `[16][2048][1536]`
+    and `[16][1536][2048]` as the leaves are stored, so no leaf, moment
+    or result is copied from one order to another, and the pass over
+    each of the twelve matrices takes the jit's own three arguments."""
+    copies = _copies_of(lfm2_step.text, (16, 2048, 1536), (16, 1536, 2048))
+    # the backward's rows' gradients take each matrix transposed: its cast
+    # to bfloat16 writes it in that order, one pass a matrix as a cast is;
+    # no float32 array, a leaf, a moment or a gradient, is copied
+    assert len(copies) == 4 * 3 and all(c.startswith("bf16[") for c in copies)
+    reads = _optimizer_reads(lfm2_step.text, r"experts_(gate|up|down)__")
+    assert len(reads) == 4 * 3 * 3              # layers, matrices, holders
+    for parameter, results in reads.items():
+        wide = (16, 1536, 2048) if "experts_down" in parameter else (
+            16, 2048, 1536)
+        assert results.count(list(wide)) == 3, (parameter, results)

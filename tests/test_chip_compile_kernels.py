@@ -201,6 +201,55 @@ def test_convolutions_backward_kernel_compiles_for_v5e(
     assert text.count("tpu_custom_call") == 1
 
 
+# tokens, the experts' width and features, held, a token's: shapes other
+# than the one cell's that take the pairs' form (its 2048 x 1536 is in the
+# whole step's compile, test_chip_compile.py): a room that is no whole
+# number of any row tile, a second width, and a whole uncut SolarOpen2's
+# wide experts on one chip
+EXPERT_LAYERS = {
+    "an-odd-room": (60, 256, 128, 8, 2),
+    "a-second-width": (2048, 1024, 512, 8, 2),
+    "a-wide-expert": (2048, 4096, 1280, 16, 8),
+}
+
+
+@pytest.mark.parametrize("sizes", EXPERT_LAYERS.values(),
+                         ids=EXPERT_LAYERS.keys())
+def test_an_expert_layer_over_the_pairs_compiles_for_v5e(one_chip, sizes):
+    """models/moe.ExpertLayer where a token takes fewer experts than are
+    held, forward and every gradient on one v5e: the compiler takes the
+    grouped products (`jax.lax.ragged_dot`, ops/grouped.py) at whatever
+    room and width, as kernels of its own."""
+    from pytorch_distributed_template_tpu.models.moe import ExpertLayer
+    from pytorch_distributed_template_tpu.observability import trace
+    from pytorch_distributed_template_tpu.observability.trace import (
+        get_recorder,
+    )
+
+    trace._said.clear()
+    get_recorder().clear()
+    tokens, width, d_ff, held, top_k = sizes
+    layer = ExpertLayer(d_model=width, d_ff=d_ff, n_routed=4 * held,
+                        top_k=top_k, held=(0, held), gated=True,
+                        selection_bias=True, dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((1, tokens, width), jnp.bfloat16,
+                             sharding=one_chip)
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(layer.init, jax.random.key(0), x)["params"])
+
+    def loss(p, x):
+        return jnp.sum(layer.apply({"params": p}, x).astype(jnp.float32))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    (said,) = [e["args"] for e in get_recorder().snapshot()
+               if e["name"] == "moe/dispatch"]
+    assert said["rows"] == tokens * top_k < said["dense_rows"]
+    # three products forward, a rows' gradient and a matrix's each
+    assert len(re.findall(r"%ragged-dot[-\w.]* = ", text)) >= 9
+
+
 def test_a_convolution_without_a_bias_compiles_for_v5e(one_chip, monkeypatch):
     """The KDA mixer's three: 1024 channels read from their own
     projection, no bias leaf; the kernel is the same one."""

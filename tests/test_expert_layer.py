@@ -76,15 +76,20 @@ def init(module, x, seed=0, bias=0.3):
 @pytest.mark.parametrize("kw", [
     {}, {"held": (0, 0)}, {"latent": 0}, {"shared_d_ff": 0},
     {"selection_bias": False}, {"router": "softmax", "scale": 1.0},
-    {"top_k": 5, "held": (9, 7)},
+    {"top_k": 5, "held": (9, 7)}, {"held": (4, 8)},
+    {"held": (4, 8), "latent": 0}, {"top_k": 5, "held": (9, 5)},
 ], ids=["share", "all-held", "no-latent", "no-shared", "no-bias", "softmax",
-        "held-more-than-chosen"])
+        "held-more-than-chosen", "share-pairs", "no-latent-pairs",
+        "held-as-many-as-chosen"])
 def test_layer_is_the_plain_sum_over_the_experts_held(kw):
     module = layer(**kw)
     x = jax.random.normal(jax.random.key(2), (3, 20, D))
     params = init(module, x)
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(module.apply)({"params": params}, x)
+        got, sown = jax.jit(lambda p: module.apply(
+            {"params": p}, x, mutable=["counters"]))(params)
+    assert float(sown["counters"]["moe_rows_run"]) == rows_run(
+        module, 60, sown["counters"])[1]
     lo, n_held = module.held[0], module.held[1] or module.n_routed
     want = plain(params, np.asarray(x).reshape(-1, D), lo, n_held,
                  module.top_k, module.scale, module.router == "softmax")
@@ -296,10 +301,35 @@ def plain_gated(params, x, lo, n_held, top_k, scale):
     return out
 
 
-@pytest.mark.parametrize("kw", [
-    {}, {"held": (0, 0)}, {"shared_d_ff": 0}, {"top_k": 5, "held": (9, 7)},
-], ids=["share", "all-held", "no-shared", "held-more-than-chosen"])
-def test_gated_layer_is_the_plain_sum_over_the_experts_held(kw):
+def rows_run(module, tokens, counters=None):
+    """What `moe_rows_run` reads of a layer over `tokens` tokens whose
+    pairs fit their room, (the form it takes, the rows): every held
+    expert over every token where a token can take every expert held,
+    else the rows the grouped products' groups hold, which are the pairs
+    that step (`counters`)."""
+    n_held = module.held[1] or module.n_routed
+    if module.top_k >= n_held:
+        return "dense", tokens * n_held
+    pairs = None if counters is None else float(counters["moe_pairs_here"])
+    assert pairs is None or 0 < pairs <= tokens * module.top_k
+    return "pairs", pairs
+
+
+# each case as its shapes make it and at the other form: two experts held
+# of which a token takes two is every held expert over every token, eight
+# held the pairs; all sixteen held, or seven of which a token takes five,
+# the pairs, and as many held as a token takes (or fewer) the dense form
+@pytest.mark.parametrize("kw,form", [
+    ({}, "dense"), ({"held": (4, 8)}, "pairs"),
+    ({"held": (0, 0)}, "pairs"), ({"held": (0, 0), "top_k": 16}, "dense"),
+    ({"shared_d_ff": 0}, "dense"), ({"shared_d_ff": 0, "held": (4, 8)},
+                                    "pairs"),
+    ({"top_k": 5, "held": (9, 7)}, "pairs"),
+    ({"top_k": 5, "held": (9, 5)}, "dense"),
+], ids=["share", "share-pairs", "all-held", "all-held-dense", "no-shared",
+        "no-shared-pairs", "held-more-than-chosen",
+        "held-as-many-as-chosen"])
+def test_gated_layer_is_the_plain_sum_over_the_experts_held(kw, form):
     module = gated(**kw)
     x = jax.random.normal(jax.random.key(2), (3, 20, D))
     params = init(module, x)
@@ -307,7 +337,11 @@ def test_gated_layer_is_the_plain_sum_over_the_experts_held(kw):
     assert ("shared" in params) == bool(module.shared_d_ff)
     assert "shared_up" not in params and "latent_down" not in params
     with jax.default_matmul_precision("highest"):
-        got = jax.jit(module.apply)({"params": params}, x)
+        got, sown = jax.jit(lambda p: module.apply(
+            {"params": p}, x, mutable=["counters"]))(params)
+    assert rows_run(module, 60)[0] == form
+    assert float(sown["counters"]["moe_rows_run"]) == rows_run(
+        module, 60, sown["counters"])[1]
     lo, n_held = module.held[0], module.held[1] or module.n_routed
     want = plain_gated(params, np.asarray(x).reshape(-1, D), lo, n_held,
                        module.top_k, module.scale)
@@ -315,9 +349,12 @@ def test_gated_layer_is_the_plain_sum_over_the_experts_held(kw):
                                rtol=0, atol=2e-5 * np.abs(want).max())
 
 
+@pytest.mark.parametrize("held", [(4, 2), (4, 8)], ids=["dense", "pairs"])
 @pytest.mark.parametrize("expert", [4, 5])
-def test_gated_no_token_is_dropped_when_all_pick_one_expert(expert):
-    module = gated(shared_d_ff=0)
+def test_gated_no_token_is_dropped_when_all_pick_one_expert(expert, held):
+    """All 60 tokens on one held expert: that group is 60 rows of the
+    pairs' 120 and the other seven share what second choices fall here."""
+    module = gated(shared_d_ff=0, held=held)
     x = jax.random.normal(jax.random.key(3), (3, 20, D))
     params = skewed(module, x, expert)
     with jax.default_matmul_precision("highest"):
@@ -326,7 +363,9 @@ def test_gated_no_token_is_dropped_when_all_pick_one_expert(expert):
     counters = sown["counters"]
     assert float(counters["moe_pairs_here"]) >= 60
     assert float(counters["moe_tokens_unserved"]) == 0
-    want = plain_gated(params, np.asarray(x).reshape(-1, D), 4, 2, 2, 1.0)
+    assert float(counters["moe_rows_run"]) == rows_run(module, 60,
+                                                        counters)[1]
+    want = plain_gated(params, np.asarray(x).reshape(-1, D), *held, 2, 1.0)
     np.testing.assert_allclose(np.asarray(got).reshape(-1, D), want,
                                rtol=0, atol=2e-5 * np.abs(want).max())
     assert np.all(np.abs(want).sum(axis=1) > 0)

@@ -537,6 +537,9 @@ def test_new_names_leave_the_old_names_order_as_it_was():
     assert old == [("attn_out", "attn_lse"), ("qkv_proj",), ("attn_proj",),
                    ("mlp_gate",), ("mlp_up",), ("attn_qkv",)]
     new = [n for g in rp.PREFERENCE for n in g if n.startswith(later)]
+    # PR 50's one, the layout of an expert layer's pairs, beside the router
+    assert new.index("moe_pairs") == new.index("moe_router") + 1
+    new.remove("moe_pairs")
     # PR 33's four in their order, PR 42's three between them
     # PR 47's one among them: the layer's sum over the experts held, as
     # wide as `moe_latent` and a product over every held expert's features
@@ -558,7 +561,8 @@ def test_new_names_leave_the_old_names_order_as_it_was():
     assert rp.PREFERENCE[-2:] == (("moe_experts_gate",),
                                   ("moe_experts_up",))
     assert list(rp.PREFERENCE[:-2]) == [
-        ("attn_out", "attn_lse"), ("moe_router",), ("qkv_proj",),
+        ("attn_out", "attn_lse"), ("moe_router",), ("moe_pairs",),
+        ("qkv_proj",),
         ("attn_gate",), ("attn_proj",), ("kda_in_proj",), ("kda_out_proj",),
         ("conv_in_proj",), ("conv_out_proj",),
         ("ssm_in_proj",), ("moe_experts_out",), ("moe_latent",),
@@ -582,9 +586,10 @@ def test_the_hybrid_model_states_its_three_kinds():
     assert {n: TOK * w for n, w in experts.widths.items()} == EXPERTS
     assert {n: TOK * w for n, w in ssm.widths.items()} == SSM
     assert (attends.attn_heads, attends.head_dim) == (4, 128)
-    # none said held: all 512 are
+    # none said held: all 512 are, of which a token takes 22: the first
+    # product runs over the pairs, whose room is 22 rows a token
     whole = MODELS.get("NemotronH")(pattern="E")._block_kinds()[0]
-    assert whole.widths["moe_experts_up"] == 512 * 2688
+    assert whole.widths["moe_experts_up"] == 22 * 2688
     # the E kind, 243 -> 948 MB, is the largest with the product in it:
     # the margin grows by twice 705 MB, and by twice 33.5 MB with the sum
     args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
@@ -615,18 +620,36 @@ def test_the_hybrid_cell_keeps_its_ten_names_and_not_the_first_product():
     assert rp.choose_names(HYBRID, 4_588_793_516) == got
 
 
-def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
+# the routed experts' two forms under the stacks (models/moe.ExpertLayer
+# reads which from its shapes): the tiny stacks hold all 8 experts and a
+# token takes 2, so their products run over the pairs; a share of 2 held
+# takes every held expert over every token, as the hybrid's and solar's
+# cells do on the chip (8 held of which a token takes 22 or 8). The first
+# products are 2 x 48 features a token either way; the layout of the pairs
+# (`moe_pairs`, 86 bytes a token: 22 of float32's four) is the pairs' alone
+FORMS = pytest.mark.parametrize("held,pairs", [((0, 0), 22), ((0, 2), 0)],
+                                ids=["over-the-pairs", "every-held-expert"])
+
+
+def _names(text, pairs):
+    return text if pairs else text.replace("moe_pairs,", "")
+
+
+@FORMS
+def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog,
+                                                      held, pairs):
     """The pattern-built stack under a training step on a device of known
     capacity: one `remat/policy` record for its 5 blocks of three kinds,
     names from every kind, and the same loss and gradient as with nothing
-    kept."""
+    kept, in both forms of the routed experts' products."""
     from pytorch_distributed_template_tpu.engine.losses import (
         lm_cross_entropy,
     )
 
     trace._said.clear()
     get_recorder().clear()
-    model = MODELS.get("TinyNemotronH")(pattern="EMEM*", remat=True)
+    model = MODELS.get("TinyNemotronH")(pattern="EMEM*", remat=True,
+                                        moe_held=held)
     tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, 256)
     params = jax.jit(model.init)(jax.random.key(1), tokens)["params"]
 
@@ -644,14 +667,15 @@ def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
     (said,) = [e["args"] for e in get_recorder().snapshot()
                if e["name"] == "remat/policy"]
     assert said["blocks"] == 5
-    assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_proj,"
-                             "ssm_in_proj,moe_experts_out,moe_latent,"
-                             "moe_shared_up,moe_experts_up")
+    assert said["names"] == _names(
+        "attn_out,moe_router,moe_pairs,qkv_proj,attn_proj,ssm_in_proj,"
+        "moe_experts_out,moe_latent,moe_shared_up,moe_experts_up", pairs)
     tok = 2 * 32 * 4
     d_in, heads = 4 * 16, 4
     assert said["kept_bytes"] == tok * (
         heads * 16                                  # attn_out, one block
-        + 2 * (8 + 32 + 32 + 96 + 8 * 48)           # two expert layers
+        + 2 * (8 + pairs + 32 + 32 + 96 + 2 * 48)   # two expert layers: the
+        # pairs' layout, 2 experts a token of the 8 held or both of 2 held
         + (heads + 2 * 2) * 16 + 64                 # qkv_proj, attn_proj
         + 2 * (2 * d_in + heads + 2 * 2 * 16))      # two in_proj outputs
     assert "remat/policy: keeping [attn_out,moe_router" in caplog.text
@@ -725,10 +749,11 @@ def test_the_solar_model_states_its_two_kinds():
     # no latent, so no projection reads the routed experts' sum and
     # neither kind states `moe_experts_out`: to the byte what it was
     assert "moe_experts_out" not in {**kda.widths, **attn.widths}
-    # 8 held experts of 1280 features; none said held: all 320 are
+    # 8 held experts of 1280 features; none said held: all 320 are, of
+    # which a token takes 8: the pairs' room is 8 rows a token
     assert kda.widths["moe_experts_gate"] == 8 * 1280
     whole = MODELS.get("SolarOpen2")(pattern="K")._block_kinds()[0]
-    assert whole.widths["moe_experts_up"] == 320 * 1280
+    assert whole.widths["moe_experts_up"] == 8 * 1280
     assert (attn.attn_heads, attn.head_dim, attn.scratch) == (8, 128, 0)
     # the scan's pairwise decays: 8 heads x 16 positions x 128 channels of
     # float32 a token, 537 MB a layer at 8192 tokens
@@ -749,17 +774,21 @@ def test_the_solar_model_states_its_two_kinds():
     assert rp.choose_names(SOLAR, budget) == SOLAR_ORDER
 
 
-def test_solar_model_reckons_two_kinds_and_says_so(monkeypatch, caplog):
+@FORMS
+def test_solar_model_reckons_two_kinds_and_says_so(monkeypatch, caplog, held,
+                                                   pairs):
     """The stack under a training step on a device of known capacity: one
     `remat/policy` record for its 3 blocks of two kinds, names from both,
-    and the same loss and gradient as with nothing kept."""
+    and the same loss and gradient as with nothing kept, in both forms of
+    the routed experts' products."""
     from pytorch_distributed_template_tpu.engine.losses import (
         lm_cross_entropy,
     )
 
     trace._said.clear()
     get_recorder().clear()
-    model = MODELS.get("TinySolarOpen2")(pattern="*KK", remat=True)
+    model = MODELS.get("TinySolarOpen2")(pattern="*KK", remat=True,
+                                         moe_held=held)
     tokens = jax.random.randint(jax.random.key(0), (2, 32), 0, 256)
     params = jax.jit(model.init)(jax.random.key(1), tokens)["params"]
 
@@ -777,14 +806,17 @@ def test_solar_model_reckons_two_kinds_and_says_so(monkeypatch, caplog):
     (said,) = [e["args"] for e in get_recorder().snapshot()
                if e["name"] == "remat/policy"]
     assert said["blocks"] == 3
-    assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_gate,"
-                             "attn_proj,kda_in_proj,kda_out_proj,mlp_gate,"
-                             "mlp_up,moe_experts_gate,moe_experts_up")
+    assert said["names"] == _names(
+        "attn_out,moe_router,moe_pairs,qkv_proj,attn_gate,attn_proj,"
+        "kda_in_proj,kda_out_proj,mlp_gate,mlp_up,moe_experts_gate,"
+        "moe_experts_up", pairs)
     tok = 2 * 32 * 4
     assert said["kept_bytes"] == tok * (
         4 * 16                                      # attn_out, one block
-        + 3 * (8 + 48 + 48)                         # router, shared gate, up
-        + 3 * 2 * 8 * 48                            # 8 experts' two products
+        + 3 * (8 + pairs + 48 + 48)                 # router, the pairs' layout,
+        # shared gate, up
+        + 3 * 2 * 2 * 48                            # two products: 2 experts a
+        # token of the 8 held, or both of 2 held
         + (4 + 2 * 2) * 16 + 4 * 16 + 64            # qkv, gate, attn_proj
         + 2 * (3 * 4 * 16 + 64))                    # two KDA blocks
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
@@ -809,8 +841,10 @@ def test_the_lfm2_model_states_a_kind_a_mixer_and_second_sublayer():
     dense, conv, attn = model._block_kinds()
     assert (dense.count, conv.count, attn.count) == (1, 3, 1)
     mixer = {"conv_in_proj": 3 * 2048, "conv_out_proj": 2048}
-    experts = {"moe_router": 128, "moe_experts_gate": 16 * 1536,
-               "moe_experts_up": 16 * 1536}
+    # 16 held of which a token takes 4: the first products run over the
+    # pairs, whose room is 4 rows a token
+    experts = {"moe_router": 128, "moe_pairs": 84,
+               "moe_experts_gate": 4 * 1536, "moe_experts_up": 4 * 1536}
     assert dense.widths == {**mixer, "mlp_gate": 11776, "mlp_up": 11776}
     assert conv.widths == {**mixer, **experts}
     assert attn.widths == {"qkv_proj": (32 + 2 * 8) * 64, "attn_proj": 2048,
@@ -825,7 +859,7 @@ def test_the_lfm2_model_states_a_kind_a_mixer_and_second_sublayer():
     assert [k.count for k in two._block_kinds()] == [4, 1]
     whole = MODELS.get("Lfm2Moe")()._block_kinds()
     assert [k.count for k in whole] == [2, 28, 10]
-    assert whole[1].widths["moe_experts_up"] == 64 * 1536
+    assert whole[1].widths["moe_experts_up"] == 4 * 1536
     # the families that came before state a kind a symbol, as they did
     granite = MODELS.get("GraniteHybrid")()._block_kinds()
     assert [k.count for k in granite] == [9, 1]      # one period
@@ -857,7 +891,7 @@ def test_lfm2_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
     (said,) = [e["args"] for e in get_recorder().snapshot()
                if e["name"] == "remat/policy"]
     assert said["blocks"] == 3
-    assert said["names"] == ("attn_out,moe_router,qkv_proj,attn_proj,"
+    assert said["names"] == ("attn_out,moe_router,moe_pairs,qkv_proj,attn_proj,"
                              "conv_in_proj,conv_out_proj,mlp_gate,mlp_up,"
                              "moe_experts_gate,moe_experts_up")
     tok = 2 * 32 * 4
@@ -865,7 +899,8 @@ def test_lfm2_model_reckons_three_kinds_and_says_so(monkeypatch, caplog):
         4 * 16 + (4 + 2 * 2) * 16 + 64              # attn_out, qkv, attn_proj
         + 2 * (3 * 64 + 64)                         # two convolution mixers
         + 2 * 96                                    # the leading layer's MLP
-        + 2 * (8 + 2 * 8 * 48))                     # two expert layers
+        + 2 * (8 + 22 + 2 * 2 * 48))                # two expert layers: two
+    # products over the pairs of 2 experts a token of the 8 held
     np.testing.assert_allclose(got[0], want[0], rtol=1e-6)
     for g, w in zip(jax.tree.leaves(got[1]), jax.tree.leaves(want[1])):
         np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7)

@@ -451,17 +451,22 @@ def test_delta_rule_step_carries_its_scopes(caplog):
 
 
 def test_lfm2_counters_reach_the_flight_record(tmp_path):
-    """The expert layers' three counters through the Trainer, from the
-    three layers behind the leading dense one."""
+    """The expert layers' four counters through the Trainer, from the
+    three layers behind the leading dense one. A token takes 2 of the 4
+    experts held, so the products run over the pairs: `moe_rows_run`,
+    which this family alone carries, reads the rows the products' groups
+    hold, the pairs themselves, and not tokens x held."""
     trainer = _hybrid_trainer(tmp_path, config="lfm2_moe_debug.json")
     assert trainer.model.step_counters == (
-        "moe_pairs_here", "moe_load_max_over_mean", "moe_tokens_unserved")
+        "moe_pairs_here", "moe_load_max_over_mean", "moe_tokens_unserved",
+        "moe_rows_run")
     trainer._train_epoch(1)
     logged = [r for r in trainer.recorder.last() if "loss" in r]
     assert logged
     for r in logged:
         # 16 x 32 tokens, 2 of 8 experts a token, 4 held, 3 expert layers
         assert 0.5 * 1536 < r["moe_pairs_here"] < 1.5 * 1536
+        assert r["moe_rows_run"] == r["moe_pairs_here"] < 3 * 512 * 4
         assert r["moe_load_max_over_mean"] >= 1.0
         assert 0 <= r["moe_tokens_unserved"] <= 3 * 512
     params = trainer.state.params
