@@ -104,7 +104,7 @@ from .remat_policy import BlockKind, block_policy
 logger = logging.getLogger(__name__)
 
 MOE_COUNTERS = ("moe_pairs_here", "moe_load_max_over_mean",
-                "moe_tokens_unserved")
+                "moe_tokens_unserved", "moe_rows_run")
 
 
 class Family(NamedTuple):
@@ -143,7 +143,7 @@ SOLAR_OPEN2 = Family(
 LFM2_MOE = Family(
     "Lfm2Moe", ("conv", "full_attention"), "operator_norm", "experts",
     "ffn_norm", rope_base=1e6, qk_norm=True, tied_head=True,
-    gated_experts=True, step_counters=MOE_COUNTERS + ("moe_rows_run",))
+    gated_experts=True, step_counters=MOE_COUNTERS)
 
 
 def _experts(c) -> dict:

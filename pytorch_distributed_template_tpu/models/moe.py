@@ -370,6 +370,27 @@ def held_gated_experts(x, weight, gate, up, down):
                       preferred_element_type=jnp.float32)
 
 
+# the room for pairs of token and held expert over what uniform routing
+# fills of it. Four leaves the densest routing the cells' records show (1.09
+# and 1.19 times uniform a layer: 7 126 pairs a step over solar's four
+# layers, 33 540 over the hybrid's five) under a quarter of its room, and
+# gives a chip that holds a quarter of the experts or more its bound,
+# ``min(top_k, held)`` places a token
+ROOM_OVER_UNIFORM = 4
+
+
+def token_places(top_k: int, n_held: int, n_routed: int) -> int:
+    """The places a token has in the room for its pairs with the
+    ``n_held`` experts held here of ``n_routed``, of which it takes
+    ``top_k``: ``ROOM_OVER_UNIFORM`` times the ``top_k * n_held /
+    n_routed`` that uniform routing gives it, rounded up, at least one
+    and at most the bound ``min(top_k, n_held)``. The room is that a
+    token; at ``n_held`` places it is every held expert over every token
+    (``ExpertLayer`` says what follows from either)."""
+    uniform = -(-ROOM_OVER_UNIFORM * top_k * n_held // n_routed)
+    return max(1, min(top_k, n_held, uniform))
+
+
 class ExpertLayer(nn.Module):
     """Routed experts as a chip holds them, with what today's sparse
     models put around them. Each part is there or not by its argument.
@@ -389,43 +410,46 @@ class ExpertLayer(nn.Module):
     layer runs without its exchange, and the sum over all shares of
     ``held`` (the shared expert counted once) is the whole layer.
 
-    No token is dropped whatever the imbalance. The room for pairs of
-    token and held expert is sized from shapes alone, ``tokens x
-    min(top_k, held)`` rows, and which of two forms the routed experts'
-    products take is read from the same two numbers:
+    No token is dropped whatever the imbalance. How many places a token
+    has in the room for pairs of token and held expert is read from
+    shapes alone (``token_places``): what uniform routing gives a token
+    here, ``top_k * held / n_routed`` pairs, times ``ROOM_OVER_UNIFORM``,
+    rounded up; never under one place nor over the bound ``min(top_k,
+    held)``. The room is ``tokens x places`` rows, and which of two forms
+    the routed experts' products take is read from the same number:
 
-    - ``top_k >= held`` (a chip's share of a few experts of many: 8 held,
-      22 or 8 a token): the room is ``tokens x held``, which no routing
-      can pass; with that much room every held expert has a place for
-      every token, so nothing is sorted or gathered: each held expert's
-      products run over all the tokens, weighed 0 where a token did not
-      choose it (``held_experts``), and the step's time does not follow
-      the routing. That is ``held`` dense products where routing needs
-      ``top_k / n_routed`` of each. (On a v5e at 16384 tokens, 8 held of
-      512, 22 a token, forward and gradient: 27.1 ms a layer whatever
-      the routing; a buffer of that size sorted by expert under
-      ``jax.lax.ragged_dot`` took 24.8 ms at uniform routing and 42.2 ms
-      when three held experts take every token, and three times the
-      memory. PERF.md, PR 33: the reason this form stays here, beside
-      routing that drifts inside the benchmark's window.)
-    - ``top_k < held`` (many small experts held, or all of them: 16 held,
-      4 a token): every held expert over every token would be ``held /
-      top_k`` times the room, four times what the worst routing fills
-      and sixteen times what balanced routing does. The products run
-      over the pairs (``routed_over_pairs``): the tokens' rows gathered
-      expert by expert into the room (``hit.T`` read row-major is in
-      expert order, so a pair's row is a running count and nothing is
-      sorted by expert), grouped products with one group a held expert
-      (ops/grouped.py: ``jax.lax.ragged_dot``, which the TPU's compiler
-      makes kernels that visit only the row tiles a group fills, so the
-      time follows the rows filled and not the room), a token's rows
-      summed back in float32. The step's time
-      follows ``moe_pairs_here``. The room is a bound only while no
-      expert ties exactly at a token's bar (the choice is a mask, so a
-      tie gives the token more than ``top_k`` experts: a router of zeros
-      gives it all of them): in a step whose pairs pass the room, a
+    - ``places < held`` (8 held of 320, 8 a token: 1 place, a room of
+      8192 rows at 8192 tokens where uniform routing fills 1638; 8 held
+      of 512, 22 a token: 2 places; 16 held of 64, 4 a token: 4 places,
+      the bound): the products run over the pairs
+      (``routed_over_pairs``): the tokens' rows gathered expert by expert
+      into the room (``hit.T`` read row-major is in expert order, so a
+      pair's row is a running count and nothing is sorted by expert),
+      grouped products with one group a held expert (ops/grouped.py:
+      ``jax.lax.ragged_dot``, which the TPU's compiler makes kernels
+      that visit only the row tiles a group fills, so their time follows
+      the rows filled and not the room; the gathers, the casts and the
+      activation's passes follow the room), a token's rows summed back
+      in float32. The room is no bound: routing more than
+      ``ROOM_OVER_UNIFORM`` times as dense as uniform passes it, and so
+      do experts tied exactly at a token's bar (the choice is a mask, so
+      a tie gives the token more than ``top_k`` experts: a router of
+      zeros gives it all of them). In a step whose pairs pass the room a
       ``cond`` on their count takes every held expert over every token
-      instead, and ``moe_rows_run`` says so. Nothing is clipped.
+      instead, and ``moe_rows_run`` says so. Nothing is clipped. (Every
+      held expert over every token was 65 536 rows a layer where 8 of
+      320 are held, 163.9 ms of a 372 ms step for what fills 1 400-1 800
+      of them: PERF.md, PR 51. A buffer sized at the bound and sorted by
+      expert had cost as much as the dense products there, PR 33: the
+      room has to be the routing's, not the bound's.)
+    - ``places == held`` (as many experts a token by uniform routing as
+      are held, or nearly: a chip that holds 2 of 8 of which a token
+      takes 2, or all of 16 of which it takes 16): with that much room
+      every held expert has a place for every token, so nothing is
+      sorted or gathered: each held expert's products run over all the
+      tokens, weighed 0 where a token did not choose it
+      (``held_experts``), no routing passes the room, and the step's
+      time does not follow the routing.
 
     ``gated`` makes every expert three matrices,
     ``(silu(l @ gate_e) * (l @ up_e)) @ down_e`` (``held_gated_experts``),
@@ -451,7 +475,7 @@ class ExpertLayer(nn.Module):
     and ``moe_rows_run``, the rows the routed experts' products ran over
     (``tokens x held``; over the pairs the rows the groups hold, which
     are the pairs: the tiles ``ragged_dot`` rounds a group up to are the
-    compiler's; ``tokens x held`` again in a step whose ties pass the
+    compiler's; ``tokens x held`` again in a step whose pairs pass the
     room).
     """
     d_model: int
@@ -539,23 +563,25 @@ class ExpertLayer(nn.Module):
         down = self.param("experts_down", _init(0.02),
                           (n_held, self.d_ff, width), jnp.float32)
 
-        # the room for pairs at its bound; fewer rows than every held
-        # expert over every token only where a token can take fewer
-        # experts than are held
-        room = s * min(k, n_held)
-        over_pairs = min(k, n_held) < n_held
+        # the room for pairs from what uniform routing fills, under its
+        # bound; the pairs' form wherever that is fewer rows than every
+        # held expert over every token
+        places = token_places(k, n_held, self.n_routed)
+        room, over_pairs = s * places, places < n_held
         said = dict(tokens=s, held=n_held, routed=self.n_routed, top_k=k,
                     expected=s * k * n_held / self.n_routed, rows=room,
                     **({"experts": "gated"} if self.gated else {}))
         text = ("moe/dispatch: %(tokens)d tokens, %(held)d of %(routed)d "
                 "experts held, %(top_k)d a token: %(expected).0f pairs a "
-                "layer a step at uniform routing, room for %(rows)d, ")
+                "layer a step at uniform routing, "
+                f"{places} place(s) a token, room for %(rows)d, ")
         if over_pairs:
-            text += ("under every held expert over every token "
+            text += (f"{room / said['expected']:.1f} times that and "
+                     "under every held expert over every token "
                      "(%(dense_rows)d rows): the products run over the "
-                     "pairs, one group a held expert; every "
-                     "held expert over every token only when ties fill "
-                     "more than the room")
+                     "pairs, one group a held expert; every held expert "
+                     "over every token only in a step whose pairs pass "
+                     "the room")
             said["dense_rows"] = s * n_held
         else:
             text += ("which no routing passes: every held expert over every "
@@ -651,13 +677,15 @@ def expert_block_sizes(d_ff: int, n_routed: int, top_k: int = 0, held=(0, 0),
     makes with its width in features a token of ``dtype``. The float32
     router logits count twice a 16-bit model's item; the routed experts'
     first products are as wide as the rows they run over a token times
-    their ``d_ff``: the experts held or, where a token can take fewer
-    (``top_k``; 0: not said, every held expert), the room for its pairs,
-    whose layout is then ``moe_pairs``;
+    their ``d_ff``: the places a token has in the room for its pairs
+    (``token_places``; ``top_k`` 0: not said, every held expert), and
+    where those are fewer than the experts held the pairs' layout has a
+    name too, ``moe_pairs``;
     the layer's sum has a name only where ``latent_up`` reads it; a gated
     layer's shared expert is a ``SwiGLU`` and makes its two names."""
     n_held = held[1] or n_routed
-    places = min(top_k or n_held, n_held)
+    places = (token_places(top_k, n_held, n_routed) if top_k
+              else n_held)
     first, item = places * d_ff, jnp.dtype(dtype).itemsize
     widths = {"moe_router": n_routed * 4 // item}
     if places < n_held:
@@ -750,8 +778,17 @@ def rows_of_tokens(x, lay: PairLayout):
     of ``x``. A row no pair fills holds some token's row, which nothing
     may read: the grouped products do not, and ``tokens_of_rows``, its
     transpose over the rows that pairs fill, does not. Each is the
-    other's backward: rows are gathered both ways and never scattered."""
+    other's backward: the room's rows are gathered both ways, and only
+    the few pairs a token has past its places are scattered."""
     return x[lay.token]
+
+
+# the pairs past their tokens' places that one turn behind the places adds:
+# a row added costs some 0.7 us at 4096 float32 wide, a pair or padding
+# (0.72 ms a turn of 1024 inside solar_open2_l4.seq8k's step, where a layer
+# has 150-400 such pairs; PERF.md, PR 51), so a turn is what a layer
+# usually has
+PAST_PLACES = 256
 
 
 @jax.custom_vjp
@@ -759,28 +796,45 @@ def tokens_of_rows(y, lay: PairLayout):
     """``y [room, D]`` -> ``[S, D]`` in float32: each token's row is the
     float32 sum of its pairs' rows of ``y``, read where a pair is and
     nowhere else. A token's first ``room / S`` pairs are gathered at
-    once, every token's; a token with more (experts tied exactly at its
-    bar) makes a loop go on behind them, one place a turn, for as long
-    as the busiest token needs."""
+    once, every token's: the room's rows. A token can have more (the
+    room is what uniform routing fills several times over, not a
+    token's bound, and experts tied exactly at its bar are all taken):
+    those pairs, a few hundred a layer where the room is far under the
+    bound, are counted off in the tokens' order and added to their
+    tokens' rows ``PAST_PLACES`` a turn, so that what they cost follows
+    their number and not the tokens' (a pass over every token for each
+    place the busiest token has was a gather of ``S`` rows a turn, two
+    or three turns a layer at one place a token)."""
     s = lay.taken.shape[0]
     places = lay.live.shape[0] // s
 
-    def row(i):
-        return jnp.sum(jnp.where(lay.order == i, lay.pos, 0), axis=1)
+    def row(i, order, pos):
+        return jnp.sum(jnp.where(order == i, pos, 0), axis=1)
 
+    rows = jnp.concatenate([row(i, lay.order, lay.pos)
+                            for i in range(places)])
     # place by place, so that the rows gathered split into places on
     # their major dimension and are summed as they lie
-    def add(i, out, got):
-        return out + jnp.where((i < lay.taken)[:, None],
-                               got.astype(jnp.float32), 0.0)
-
-    rows = jnp.concatenate([row(i) for i in range(places)])
     got = y[rows].reshape(places, s, -1)
     out = jnp.zeros((s, y.shape[1]), jnp.float32)
     for i in range(places):         # one pass over what was gathered
-        out = add(i, out, got[i])
-    return jax.lax.fori_loop(places, jnp.max(lay.taken),
-                             lambda i, out: add(i, out, y[row(i)]), out)
+        out = out + jnp.where((i < lay.taken)[:, None],
+                              got[i].astype(jnp.float32), 0.0)
+    past = jnp.cumsum(jnp.maximum(lay.taken - places, 0))
+
+    def add_past(turn, out):
+        # the turn's pairs: whose each is (the first token whose running
+        # count passes it; ``s`` behind the last, which the add drops) and
+        # which of its token's pairs
+        nth = turn * PAST_PLACES + jnp.arange(PAST_PLACES, dtype=jnp.int32)
+        token = jnp.sum(past[None, :] <= nth[:, None], axis=1)
+        at = jnp.minimum(token, s - 1)
+        place = lay.taken[at] - (past[at] - nth)
+        got = y[row(place[:, None], lay.order[at], lay.pos[at])]
+        return out.at[token].add(got.astype(jnp.float32), mode="drop",
+                                 indices_are_sorted=True)
+
+    return jax.lax.fori_loop(0, -(-past[-1] // PAST_PLACES), add_past, out)
 
 
 rows_of_tokens.defvjp(
@@ -867,8 +921,10 @@ def _of_expert(e, weight, *mats):
 def routed_over_pairs(x, weight, hit, counts, mats, room: int):
     """``experts_over_pairs`` where the pairs fit the ``room``, and
     ``every_expert_over_every_token`` in the step where they do not
-    (exact ties at a token's bar give it more than ``top_k`` experts, so
-    ``tokens x top_k`` is no bound then): no token is dropped either way.
+    (the room is what uniform routing fills several times over, no
+    bound; and exact ties at a token's bar give it more than ``top_k``
+    experts, so ``tokens x top_k`` is none either): no token is dropped
+    either way.
     One ``cond`` on the pairs' count forward and one backward, under a
     backward rule of this function's own, so that neither way costs the
     other anything: differentiated, a ``cond`` hands out what EACH branch

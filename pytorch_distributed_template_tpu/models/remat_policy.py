@@ -80,12 +80,13 @@ inside the routed experts has two names (models/moe.py):
 ``moe_experts_gate`` and ``moe_experts_up``, the experts' first products
 (one, ``moe_experts_up``, where an expert has two matrices): every held
 expert's over every token as the einsums make them, ``held * d_ff``
-features a token, where a token can take every expert held; where it can
-take fewer, the grouped products over the room for its pairs,
-``top_k * d_ff`` (``models/moe.expert_block_sizes`` states the one or
-the other by the layer's own rule: 24576 and 6144 features a token at 16
-held of 1536, 4 a token, so both names fit there, 101 MB a layer each,
-where one of 403 did). By recomputation
+features a token, where a token has a place for every expert held;
+where it has fewer, the grouped products over the room for its pairs,
+``places * d_ff`` (``models/moe.expert_block_sizes`` states the one or
+the other by the layer's own rule, ``token_places``: 24576 and 6144
+features a token at 16 held of 1536, 4 a token, so both names fit
+there, 101 MB a layer each, where one of 403 did; 10240 and 1280 at 8
+held of 320 experts of 1280, 8 a token, one place). By recomputation
 spared for a byte they would stand with ``mlp_gate`` where they contract
 over ``d_model`` and at a quarter of that over a 1024-wide latent, but
 they are up to ``held`` times as wide as any other name, one order serves

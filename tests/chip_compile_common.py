@@ -286,6 +286,16 @@ _PREFETCH = ("copy-done", "copy-start", "slice-done", "slice-start",
              "ConcatBitcast")
 
 
+def _grouped_products_under(text, scope):
+    """The kernels the compiler made of `jax.lax.ragged_dot` and
+    `ragged_dot_general` anywhere in the compiled text under the scope,
+    by what each returns."""
+    return [m.group(1) for m in re.finditer(
+        r'%ragged-dot[-\w.]* = (\w+\[[\d,]*\])[^\n]*'
+        r'custom_call_target="tpu_custom_call"[^\n]*'
+        rf'op_name="[^"]*/{scope}/', text)]
+
+
 def _optimizer_reads(text, leaf):
     """How the optimizer's pass reads the leaves whose name `leaf` finds:
     {the jit's parameter: the result shapes of the instruction under the

@@ -11,8 +11,8 @@ import pytest
 
 from chip_compile_common import (  # noqa: F401  (fixtures by name)
     V5E_BYTES_LIMIT, _compiled_bytes, _compiled_train_step, _copies_of,
-    _entry_lines, _optimizer_reads, _said, _scope_instructions,
-    _step_compiled_once, fresh_records, topo,
+    _entry_lines, _grouped_products_under, _optimizer_reads, _said,
+    _scope_instructions, _step_compiled_once, fresh_records, topo,
 )
 
 
@@ -37,29 +37,46 @@ def hybrid_step(topo):
 
 def test_hybrid_step_lowers_and_fits_for_v5e(hybrid_step):
     """`nemotron3_super_l11.seq8k`'s step (2 x 8192 on one chip): the
-    pattern-built stack with its scan, its dropless expert layers (every
-    held expert over every token: no branch, no sorted buffer) and the
-    flash kernels compiles for the v5e, the checkpoint policy reckons
+    pattern-built stack with its scan, its dropless expert layers and
+    the flash kernels compiles for the v5e, the checkpoint policy reckons
     three kinds of block, and the step stays under the chip's
-    `bytes_limit`."""
+    `bytes_limit`.
+
+    8 of 512 experts are held and a token takes 22: uniform routing gives
+    a token 0.34 pairs here, so it has two places in a room of 32768 rows
+    (`moe.token_places`), a quarter of every held expert over every
+    token, and the routed experts' products run over the pairs (ISSUE
+    51): six grouped products a layer under `moe_experts`, no array a
+    held expert by every token wide, and the first product, a quarter as
+    wide as it was, kept by name with every other name the kinds make."""
     text, said = hybrid_step.text, hybrid_step.said
-    assert "ragged-dot" not in text
     for kernel in ("flash_fwd", "flash_dkv", "flash_dq"):
         assert re.search(rf"%{kernel}(\.\d+)? = ", text)
+    # a layer: two products forward, the second's rows float32; backward a
+    # rows' gradient each and a matrix's gradient each, as the leaf lies
+    products = _grouped_products_under(text, "moe_experts")
+    assert sorted(products) == sorted(
+        5 * (2 * ["bf16[32768,2688]"] + ["f32[32768,1024]"]
+             + ["bf16[32768,1024]", "bf16[8,1024,2688]",
+                "bf16[8,2688,1024]"]))
+    assert not re.search(r"\[8,16384,(2688|1024)\]|\[16384,8,(2688|1024)\]"
+                         r"|\[8,(2688|1024),16384\]", text)
     (policy,) = _said("remat/policy", said)
+    print(f"hybrid step: {hybrid_step.total_bytes} bytes compiled, "
+          f"policy {policy}")
     assert policy["blocks"] == 11
-    # every name its kinds make but the last: the layers' sum over the
-    # experts held beside the latent projection (168 MB over five layers)
-    assert policy["names"] == ("attn_out,attn_lse,moe_router,qkv_proj,"
-                               "attn_proj,ssm_in_proj,moe_experts_out,"
-                               "moe_latent,moe_shared_up,attn_qkv")
-    # the routed experts' first product (3.52 GB over five layers) is in
-    # the E kind's margin and outside what is kept
-    assert policy["budget_bytes"] >= policy["kept_bytes"] == 1_990_983_680
+    # every name its kinds make: the first product is 176 MB a layer in
+    # the room (705 over every held expert, 3.52 GB over five layers,
+    # which no budget held), the pairs' layout 1.4 MB
+    assert policy["names"] == ("attn_out,attn_lse,moe_router,moe_pairs,"
+                               "qkv_proj,attn_proj,ssm_in_proj,"
+                               "moe_experts_out,moe_latent,moe_shared_up,"
+                               "attn_qkv,moe_experts_up")
+    assert policy["budget_bytes"] >= policy["kept_bytes"] == 2_878_832_640
     # the state's init traces one sequence, the step two
     dispatch = [d for d in _said("moe/dispatch", said) if d["tokens"] == 16384]
-    assert dispatch and all(d["rows"] == 131072 and d["expected"] == 5632
-                            for d in dispatch)
+    assert dispatch == [dict(tokens=16384, held=8, routed=512, top_k=22,
+                             expected=5632, rows=32768, dense_rows=131072)]
     # five mixers' convolutions: a backward kernel each, over two rows
     conv = [c for c in _said("ssm/conv", said) if c["positions"] == 16384]
     assert conv == [dict(
@@ -74,13 +91,17 @@ def test_hybrid_step_lowers_and_fits_for_v5e(hybrid_step):
 def test_the_optimizer_reads_the_hybrids_expert_matrices_where_they_lie(
         hybrid_step):
     """As `test_chip_compile_delta_rule.py` has it for three matrices an
-    expert: `held_experts` has one first product, `experts_up`, whose
-    parameter, `mu` and `nu` were copied into the gradient's order and
-    the three results back in each of the five expert layers, 30 copies
-    of 88 MB a step. With `models/moe.gradient_as_stored` none is, and
-    the pass over each of the ten matrices takes the jit's own three
-    arguments (or the compiler's prefetch of one, in the same order)."""
-    assert not _copies_of(hybrid_step.text, (8, 1024, 2688), (8, 2688, 1024))
+    expert: the matrices' gradients leave the grouped products `[8][1024]
+    [2688]` and `[8][2688][1024]` as the leaves are stored, so no leaf,
+    moment or result is copied from one order to another (30 copies of
+    88 MB a step before `models/moe.gradient_as_stored`), and the pass
+    over each of the ten matrices takes the jit's own three arguments
+    (or the compiler's prefetch of one, in the same order)."""
+    copies = _copies_of(hybrid_step.text, (8, 1024, 2688), (8, 2688, 1024))
+    # the backward's rows' gradients take each matrix transposed: its cast
+    # to bfloat16 writes it in that order, one pass a matrix as a cast is;
+    # no float32 array, a leaf, a moment or a gradient, is copied
+    assert len(copies) == 5 * 2 and all(c.startswith("bf16[") for c in copies)
     reads = _optimizer_reads(hybrid_step.text, r"experts_(up|down)__")
     assert len(reads) == 5 * 2 * 3              # layers, matrices, holders
     for parameter, results in reads.items():
@@ -89,33 +110,32 @@ def test_the_optimizer_reads_the_hybrids_expert_matrices_where_they_lie(
         assert results.count(list(wide)) == 3, (parameter, results)
 
 
-def test_the_hybrids_second_product_runs_once_a_layer(hybrid_step):
-    """After the forward nothing reads the routed experts' second
-    product again. The token's weight lies on the activation, so the
-    `[8, 16384, 1024]` result a held expert wide (268 MB a layer) is made
-    nowhere in the step and the weight's gradient reads the activation;
-    the layer's `[16384, 1024]` sum, which `latent_up`'s weight gradient
-    reads, is kept by its name. Under `moe_experts` that leaves seven
-    fusions with a product a layer where there were eight: forward two,
-    the first recomputed, backward four."""
+def test_no_product_of_the_hybrids_experts_runs_a_second_time(hybrid_step):
+    """After the forward nothing runs a product of the routed experts
+    again. The token's weight lies on the activation, so no `[8, 16384,
+    1024]` result a held expert wide (268 MB a layer) is made anywhere in
+    the step; the layer's `[16384, 1024]` sum, which `latent_up`'s weight
+    gradient reads, and the first product over the room's rows are both
+    kept by name, so of the thirty grouped products none stands in the
+    recomputation (over every held expert the first did, 21.6 ms a
+    step), and the dense products left under `moe_experts` are the
+    branch's that a step whose pairs pass the room takes, an expert at a
+    time."""
     text = hybrid_step.text
     assert "[8,16384,1024]" not in text
     assert not re.search(r"\[16384,8,1024\]|\[8,1024,16384\]", text)
-    under = list(_scope_instructions(text, "moe_experts"))
+    grouped = [ln for ln in text.splitlines()
+               if re.match(r"\s*(?:ROOT )?%ragged-dot[-\w.]* = \w+\[", ln)
+               and "/moe_experts/" in ln]
+    assert len(grouped) == 5 * 6
+    assert not [ln for ln in grouped if "rematted_computation" in ln]
     lines = {m.group(1): m.string for m in (
         re.match(r"\s*(?:ROOT )?%(\S+) = ", ln) for ln in _entry_lines(text))
         if m}
-    again = [name for name, *_ in under
+    again = [name for name, *_ in _scope_instructions(text, "moe_experts")
              if "rematted_computation" in lines[name]
-             and "esf,efd" in lines[name]]
+             and (" convolution(" in lines[name] or "ragged" in lines[name])]
     assert not again, again
-    products = [name for name, _, _, _, body in under
-                if " convolution(" in body]
-    assert len(products) == 5 * 7, products
-    recomputed = [name for name in products
-                  if "rematted_computation" in lines[name]]
-    assert len(recomputed) == 5
-    assert all("sd,edf->esf" in lines[name] for name in recomputed)
     assert hybrid_step.total_bytes < V5E_BYTES_LIMIT - (1 << 30)
 
 
@@ -200,16 +220,6 @@ def lfm2_step(topo):
         / "lfm2_24b_a2b_l5.json").read_text())["experiment"]["arch"]
     return _step_compiled_once(MODELS.get(arch["type"])(**arch["args"]),
                                topo, 1, 8192)
-
-
-def _grouped_products_under(text, scope):
-    """The kernels the compiler made of `jax.lax.ragged_dot` and
-    `ragged_dot_general` anywhere in the compiled text under the scope,
-    by what each returns."""
-    return [m.group(1) for m in re.finditer(
-        r'%ragged-dot[-\w.]* = (\w+\[[\d,]*\])[^\n]*'
-        r'custom_call_target="tpu_custom_call"[^\n]*'
-        rf'op_name="[^"]*/{scope}/', text)]
 
 
 def test_lfm2_step_lowers_and_fits_for_v5e(lfm2_step):

@@ -201,26 +201,31 @@ def test_convolutions_backward_kernel_compiles_for_v5e(
     assert text.count("tpu_custom_call") == 1
 
 
-# tokens, the experts' width and features, held, a token's: shapes other
-# than the one cell's that take the pairs' form (its 2048 x 1536 is in the
-# whole step's compile, test_chip_compile.py): a room that is no whole
-# number of any row tile, a second width, and a whole uncut SolarOpen2's
-# wide experts on one chip
+# tokens, the experts' width and features, held, a token's, routed: shapes
+# other than the three cells' that take the pairs' form (theirs are in the
+# whole steps' compiles, test_chip_compile.py and
+# test_chip_compile_delta_rule.py): a room that is no whole number of any
+# row tile, a second width, a whole uncut SolarOpen2's wide experts on one
+# chip, and a room under its bound, one place a token of a bound of four,
+# with the sum's tail behind the places
 EXPERT_LAYERS = {
-    "an-odd-room": (60, 256, 128, 8, 2),
-    "a-second-width": (2048, 1024, 512, 8, 2),
-    "a-wide-expert": (2048, 4096, 1280, 16, 8),
+    "an-odd-room": (60, 256, 128, 8, 2, 32),
+    "a-second-width": (2048, 1024, 512, 8, 2, 32),
+    "a-wide-expert": (2048, 4096, 1280, 16, 8, 64),
+    "a-room-under-its-bound": (1000, 512, 256, 4, 4, 64),
 }
 
 
 @pytest.mark.parametrize("sizes", EXPERT_LAYERS.values(),
                          ids=EXPERT_LAYERS.keys())
 def test_an_expert_layer_over_the_pairs_compiles_for_v5e(one_chip, sizes):
-    """models/moe.ExpertLayer where a token takes fewer experts than are
-    held, forward and every gradient on one v5e: the compiler takes the
-    grouped products (`jax.lax.ragged_dot`, ops/grouped.py) at whatever
-    room and width, as kernels of its own."""
-    from pytorch_distributed_template_tpu.models.moe import ExpertLayer
+    """models/moe.ExpertLayer where a token has fewer places in the room
+    than experts are held, forward and every gradient on one v5e: the
+    compiler takes the grouped products (`jax.lax.ragged_dot`,
+    ops/grouped.py) at whatever room and width, as kernels of its own."""
+    from pytorch_distributed_template_tpu.models.moe import (
+        ExpertLayer, token_places,
+    )
     from pytorch_distributed_template_tpu.observability import trace
     from pytorch_distributed_template_tpu.observability.trace import (
         get_recorder,
@@ -228,8 +233,8 @@ def test_an_expert_layer_over_the_pairs_compiles_for_v5e(one_chip, sizes):
 
     trace._said.clear()
     get_recorder().clear()
-    tokens, width, d_ff, held, top_k = sizes
-    layer = ExpertLayer(d_model=width, d_ff=d_ff, n_routed=4 * held,
+    tokens, width, d_ff, held, top_k, routed = sizes
+    layer = ExpertLayer(d_model=width, d_ff=d_ff, n_routed=routed,
                         top_k=top_k, held=(0, held), gated=True,
                         selection_bias=True, dtype=jnp.bfloat16)
     x = jax.ShapeDtypeStruct((1, tokens, width), jnp.bfloat16,
@@ -245,7 +250,9 @@ def test_an_expert_layer_over_the_pairs_compiles_for_v5e(one_chip, sizes):
         params, x).compile().as_text()
     (said,) = [e["args"] for e in get_recorder().snapshot()
                if e["name"] == "moe/dispatch"]
-    assert said["rows"] == tokens * top_k < said["dense_rows"]
+    assert said["rows"] == tokens * token_places(top_k, held, routed) \
+        < said["dense_rows"]
+    assert said["rows"] <= tokens * min(top_k, held)
     # three products forward, a rows' gradient and a matrix's each
     assert len(re.findall(r"%ragged-dot[-\w.]* = ", text)) >= 9
 

@@ -20,7 +20,7 @@ from pytorch_distributed_template_tpu.engine.steps import (
     COUNTER_PREFIX, finalize_metrics, make_train_step, selection_bias_step,
 )
 from pytorch_distributed_template_tpu.models.moe import (
-    ExpertLayer, held_experts, held_gated_experts,
+    ExpertLayer, held_experts, held_gated_experts, token_places,
 )
 
 D, F, LATENT = 16, 24, 12
@@ -302,33 +302,101 @@ def plain_gated(params, x, lo, n_held, top_k, scale):
 
 
 def rows_run(module, tokens, counters=None):
-    """What `moe_rows_run` reads of a layer over `tokens` tokens whose
-    pairs fit their room, (the form it takes, the rows): every held
-    expert over every token where a token can take every expert held,
-    else the rows the grouped products' groups hold, which are the pairs
-    that step (`counters`)."""
+    """What `moe_rows_run` reads of a layer over `tokens` tokens, (the
+    form it takes, the rows): every held expert over every token where a
+    token has a place for every expert held (`token_places`), and in the
+    step whose pairs pass the room; else the rows the grouped products'
+    groups hold, which are the pairs that step (`counters`)."""
     n_held = module.held[1] or module.n_routed
-    if module.top_k >= n_held:
+    places = token_places(min(module.top_k, module.n_routed), n_held,
+                          module.n_routed)
+    if places == n_held:
         return "dense", tokens * n_held
     pairs = None if counters is None else float(counters["moe_pairs_here"])
-    assert pairs is None or 0 < pairs <= tokens * module.top_k
+    if pairs is not None and pairs > tokens * places:
+        return "pairs", tokens * n_held
     return "pairs", pairs
 
 
-# each case as its shapes make it and at the other form: two experts held
-# of which a token takes two is every held expert over every token, eight
-# held the pairs; all sixteen held, or seven of which a token takes five,
-# the pairs, and as many held as a token takes (or fewer) the dense form
+# the layer's three cells, then the tiny stacks of the tests, of
+# `configs/*_debug.json` and of the cells' rehearsal sizes (which keep the
+# forms they had), then this file's layers and a few more: a token's, held,
+# routed -> places, the bound
+@pytest.mark.parametrize("top_k,held,routed,places,bound", [
+    (8, 8, 320, 1, 8), (22, 8, 512, 2, 8), (4, 16, 64, 4, 4),
+    (2, 4, 4, 2, 2), (2, 8, 8, 2, 2), (2, 2, 8, 2, 2), (4, 5, 12, 4, 4),
+    (4, 4, 16, 4, 4), (4, 4, 12, 4, 4),
+    (2, 2, 16, 1, 2), (2, 8, 16, 2, 2), (2, 16, 16, 2, 2),
+    (16, 16, 16, 16, 16), (5, 7, 16, 5, 5), (5, 5, 16, 5, 5),
+    (4, 4, 32, 2, 4), (1, 1, 1024, 1, 1), (8, 8, 64, 4, 8),
+], ids=lambda v: str(v))
+def test_a_tokens_places_are_uniform_routings_under_the_bound(
+        top_k, held, routed, places, bound):
+    """`token_places`: `ROOM_OVER_UNIFORM` (4) times what uniform
+    routing gives a token of the experts held, rounded up, at least 1 and
+    at most `min(top_k, held)`; the room is that a token, and the pairs'
+    form where it is under `held`. solar_open2_l4.seq8k: 1 place, 8192
+    rows for 1638 pairs; nemotron3_super_l11.seq8k: 2, 32768 for 5632;
+    lfm2_24b_a2b_l5.seq8k: 4, its bound, 32768 for 8192."""
+    from pytorch_distributed_template_tpu.models import moe
+
+    assert moe.ROOM_OVER_UNIFORM == 4
+    assert bound == min(top_k, held)
+    got = token_places(top_k, held, routed)
+    assert got == places == max(1, min(bound, -(-4 * top_k * held // routed)))
+    # never under what uniform routing fills, and four times it or more
+    # wherever the bound does not stop it
+    assert 1 <= got <= bound
+    assert got >= min(bound, 4 * top_k * held / routed)
+
+
+@pytest.mark.parametrize("cell,tokens,top_k,held,routed,room,uniform", [
+    ("solar_open2_l4.seq8k", 8192, 8, 8, 320, 8192, 1638.4),
+    ("nemotron3_super_l11.seq8k", 16384, 22, 8, 512, 32768, 5632),
+    ("lfm2_24b_a2b_l5.seq8k", 8192, 4, 16, 64, 32768, 8192),
+])
+def test_the_three_cells_rooms(cell, tokens, top_k, held, routed, room,
+                               uniform, caplog):
+    """What `moe/dispatch` says of each cell's layer, from shapes alone:
+    nothing runs (`jax.eval_shape`)."""
+    import logging
+
+    from pytorch_distributed_template_tpu.observability import trace
+
+    trace._said.clear()
+    trace.get_recorder().clear()
+    module = ExpertLayer(d_model=16, d_ff=8, n_routed=routed, top_k=top_k,
+                         held=(0, held), gated=True)
+    with caplog.at_level(logging.INFO):
+        jax.eval_shape(lambda: module.init(jax.random.key(0),
+                                           jnp.zeros((1, tokens, 16))))
+    (said,) = [e["args"] for e in trace.get_recorder().snapshot()
+               if e["name"] == "moe/dispatch"]
+    assert said == dict(tokens=tokens, held=held, routed=routed, top_k=top_k,
+                        expected=uniform, rows=room, experts="gated",
+                        dense_rows=tokens * held), cell
+    assert room < tokens * held and room / uniform >= 4
+    assert (f"{room // tokens} place(s) a token, room for {room}, "
+            f"{room / uniform:.1f} times that") in caplog.text
+
+
+# each case as its shapes make it: two experts held of sixteen, of which a
+# token takes two, have one place a token (a room under its bound) and
+# eight held two, the pairs' form both; so have all sixteen held, or seven
+# of which a token takes five; two held of four, as many held as a token
+# takes of sixteen where uniform routing gives it more than a quarter of
+# them, or all sixteen taken, are every held expert over every token
 @pytest.mark.parametrize("kw,form", [
-    ({}, "dense"), ({"held": (4, 8)}, "pairs"),
+    ({}, "pairs"), ({"held": (4, 8)}, "pairs"),
     ({"held": (0, 0)}, "pairs"), ({"held": (0, 0), "top_k": 16}, "dense"),
-    ({"shared_d_ff": 0}, "dense"), ({"shared_d_ff": 0, "held": (4, 8)},
+    ({"shared_d_ff": 0}, "pairs"), ({"shared_d_ff": 0, "held": (4, 8)},
                                     "pairs"),
     ({"top_k": 5, "held": (9, 7)}, "pairs"),
     ({"top_k": 5, "held": (9, 5)}, "dense"),
-], ids=["share", "share-pairs", "all-held", "all-held-dense", "no-shared",
-        "no-shared-pairs", "held-more-than-chosen",
-        "held-as-many-as-chosen"])
+    ({"n_routed": 4, "held": (2, 2)}, "dense"),
+], ids=["share-one-place", "share-pairs", "all-held", "all-held-dense",
+        "no-shared-one-place", "no-shared-pairs", "held-more-than-chosen",
+        "held-as-many-as-chosen", "half-held-dense"])
 def test_gated_layer_is_the_plain_sum_over_the_experts_held(kw, form):
     module = gated(**kw)
     x = jax.random.normal(jax.random.key(2), (3, 20, D))
@@ -349,11 +417,16 @@ def test_gated_layer_is_the_plain_sum_over_the_experts_held(kw, form):
                                rtol=0, atol=2e-5 * np.abs(want).max())
 
 
-@pytest.mark.parametrize("held", [(4, 2), (4, 8)], ids=["dense", "pairs"])
+@pytest.mark.parametrize("held", [(4, 2), (4, 8)],
+                         ids=["one-place", "two-places"])
 @pytest.mark.parametrize("expert", [4, 5])
 def test_gated_no_token_is_dropped_when_all_pick_one_expert(expert, held):
-    """All 60 tokens on one held expert: that group is 60 rows of the
-    pairs' 120 and the other seven share what second choices fall here."""
+    """All 60 tokens on one held expert. Eight held, two places a token:
+    that group is 60 rows of the pairs' 120 and the other seven share
+    what second choices fall here. Two held, one place: the 60 pairs
+    fill the room, and with one second choice that falls on the other
+    held expert they pass it and the step takes both held experts over
+    every token (`rows_run` reads which from the counters)."""
     module = gated(shared_d_ff=0, held=held)
     x = jax.random.normal(jax.random.key(3), (3, 20, D))
     params = skewed(module, x, expert)
@@ -369,6 +442,52 @@ def test_gated_no_token_is_dropped_when_all_pick_one_expert(expert, held):
     np.testing.assert_allclose(np.asarray(got).reshape(-1, D), want,
                                rtol=0, atol=2e-5 * np.abs(want).max())
     assert np.all(np.abs(want).sum(axis=1) > 0)
+
+
+@pytest.mark.parametrize("make", ["gated", "relu-squared-latent"])
+@pytest.mark.parametrize("shares,top_k", [(4, 2), (4, 4), (8, 2), (8, 1)],
+                         ids=["four-shares-at-the-bound", "four-shares-dense",
+                              "eight-shares-one-place",
+                              "eight-shares-one-a-token"])
+def test_the_shares_of_held_add_up_to_the_uncut_layer(make, shares, top_k):
+    """Sixteen experts cut into four shares of four or eight of two, as
+    the chips of a host or a slice hold them: each chip's layer adds its
+    own experts' part, in whatever form its shapes give it (two places a
+    token under four held; every held expert over every token at four a
+    token; one place under two held, a room under its bound that the
+    seed's skewed routing passes in some shares and not in others), and
+    the parts add up to the layer with all sixteen held."""
+    make = (gated if make == "gated" else layer)
+    kw = dict(top_k=top_k, shared_d_ff=0)
+    x = jax.random.normal(jax.random.key(9), (3, 20, D))
+    whole = make(held=(0, 0), **kw)
+    params = init(whole, x, bias=0.1)
+    n = 16 // shares
+
+    def of(module, p):
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(lambda p: module.apply(
+                {"params": p}, x, mutable=["counters"]))(p)
+
+    want, sown = of(whole, params)
+    total, forms, pairs = 0, set(), 0
+    for lo in range(0, 16, n):
+        module = make(held=(lo, n), **kw)
+        share = {k: (v[lo:lo + n] if k.startswith("experts_") else v)
+                 for k, v in params.items()}
+        got, counters = of(module, share)
+        counters = counters["counters"]
+        forms.add((rows_run(module, 60)[0],
+                   float(counters["moe_rows_run"]) == 60 * n))
+        assert float(counters["moe_rows_run"]) == rows_run(
+            module, 60, counters)[1]
+        pairs += float(counters["moe_pairs_here"])
+        total = total + got
+    assert pairs == float(sown["counters"]["moe_pairs_here"]) == 60 * top_k
+    assert {f for f, _ in forms} == {
+        "dense" if token_places(top_k, n, 16) == n else "pairs"}
+    np.testing.assert_allclose(total, want, rtol=0,
+                               atol=3e-6 * float(jnp.abs(want).max()))
 
 
 def test_held_gated_experts_is_the_sum_expert_by_expert_with_its_gradient():
@@ -500,8 +619,10 @@ def test_the_layers_sum_has_a_name_where_a_projection_reads_it(latent):
     holds it twice; the output and every gradient are the same either
     way. A layer without `latent` adds the sum to the shared expert's
     output, no gradient reads it, it makes no such name, and its backward
-    holds the second product once under any policy."""
-    module = layer(latent=latent)
+    holds the second product once under any policy. (Two held of four,
+    every held expert over every token: the form that has the product
+    as one einsum.)"""
+    module = layer(latent=latent, n_routed=4, held=(2, 2))
     x = jax.random.normal(jax.random.key(8), (2, 20, D))
     params = init(module, x)
     named = str(jax.make_jaxpr(module.apply)({"params": params}, x)).count(

@@ -1,9 +1,11 @@
-"""models/moe.ExpertLayer where a token can take fewer experts than are
-held (16 routed, 8 held, 2 a token): the routed experts' products over
-the pairs of token and held expert (`routed_over_pairs`), their layout,
-the step whose ties pass the room, the checkpoint names through the
-`cond`. The cases both forms
-share are in test_expert_layer.py, whose helpers these use."""
+"""models/moe.ExpertLayer where a token has fewer places in the room
+for its pairs than experts are held (16 routed, 8 held, 2 a token: two
+places, the bound; 32 routed, 4 held, 4 a token: two places of a bound
+of four): the routed experts' products over the pairs of token and held
+expert (`routed_over_pairs`), their layout, a token with more pairs than
+places, the step whose pairs pass the room, the checkpoint names through
+the `cond`. The rule that sizes the room and the cases both forms share
+are in test_expert_layer.py, whose helpers these use."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -19,6 +21,7 @@ from test_expert_layer import (
     D, F, LATENT, gated, held_experts_weight_on_the_output, init, layer,
     plain_gated, silu,
 )
+
 
 
 
@@ -46,20 +49,43 @@ def the_dense_body(monkeypatch):
         every_expert_over_every_token(x, weight, *mats))
 
 
-def gated_one_by_one(params, x, lo, n_held, top_k):
-    """`plain_gated` in `jax.numpy`, float32, expert by expert, so that
-    it has gradients: the routing as the layer makes it, a mask."""
+def the_layers_own_dense_body(monkeypatch):
+    """The same layer with `held_experts` or `held_gated_experts` in the
+    pairs' place, as a layer with a place for every held expert runs
+    them: in float32 the same laying too."""
+    monkeypatch.setattr(
+        moe, "routed_over_pairs",
+        lambda x, weight, hit, counts, mats, room:
+        (moe.held_gated_experts if len(mats) == 3 else moe.held_experts)(
+            x, weight, *mats))
+
+
+def one_by_one(module, params, x):
+    """(The layer without its shared expert in `jax.numpy`, float32,
+    expert by expert, so that it has gradients; which pairs of token and
+    held expert its routing makes, a mask as the layer's is.)"""
     p, xf = params, x.reshape(-1, x.shape[-1]).astype(jnp.float32)
+    lo, n_held = module.held
     scores = jax.nn.sigmoid(jnp.matmul(xf, p["router"], precision="highest"))
     choice = jax.lax.stop_gradient(scores + p["selection_bias"])
-    took = choice >= jax.lax.top_k(choice, top_k)[0][:, -1:]
-    weight = jnp.where(took, scores, 0) / jnp.sum(
+    took = choice >= jax.lax.top_k(choice, module.top_k)[0][:, -1:]
+    weight = module.scale * jnp.where(took, scores, 0) / jnp.sum(
         jnp.where(took, scores, 0), axis=1, keepdims=True)
+    lat = xf @ p["latent_down"]["kernel"] if module.latent else xf
     out = 0
     for e in range(n_held):
-        h = jax.nn.silu(xf @ p["experts_gate"][e]) * (xf @ p["experts_up"][e])
+        h = (jax.nn.silu(lat @ p["experts_gate"][e]) * (lat @ p["experts_up"][e])
+             if module.gated else
+             jnp.square(jax.nn.relu(lat @ p["experts_up"][e])))
         out = out + weight[:, lo + e, None] * (h @ p["experts_down"][e])
-    return out.reshape(x.shape)
+    if module.latent:
+        out = out @ p["latent_up"]["kernel"]
+    return out.reshape(x.shape), took[:, lo:lo + n_held]
+
+
+def gap(got, want):
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    return np.linalg.norm(got - want) / np.linalg.norm(want)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -80,17 +106,13 @@ def test_over_the_pairs_the_layer_and_every_gradient_are_the_dense_ones(
         dense_out, dense_counters, dense_grads = value_and_gradients(
             module, params, x)
         want = jax.jit(jax.value_and_grad(
-            lambda p, x: jnp.sum(jnp.sin(gated_one_by_one(p, x, 4, 8, 2))),
+            lambda p, x: jnp.sum(jnp.sin(one_by_one(module, p, x)[0])),
             argnums=(0, 1)))(params, x)[1]
-        want_out = gated_one_by_one(params, x, 4, 8, 2)
+        want_out = one_by_one(module, params, x)[0]
     assert float(counters["moe_rows_run"]) == float(
         counters["moe_pairs_here"]) <= 60 * 2
     jax.tree.map(np.testing.assert_array_equal, counters, dense_counters)
     assert not np.any(np.asarray(grads[0]["selection_bias"]))
-
-    def gap(got, want):
-        got, want = (np.asarray(a, np.float32) for a in (got, want))
-        return np.linalg.norm(got - want) / np.linalg.norm(want)
 
     tol = 2e-6 if dtype == jnp.float32 else 2e-2
     assert gap(out, dense_out) < tol and gap(out, want_out) < tol
@@ -101,6 +123,74 @@ def test_over_the_pairs_the_layer_and_every_gradient_are_the_dense_ones(
         assert near < tol and near < 1.5 * dense_near + 1e-6, name
     assert gap(grads[1], dense_grads[1]) < tol
     assert gap(grads[1], want[1]) < tol
+
+
+def routed(kind, module, params, x):
+    """Parameters and input under which the layer's 60 tokens route as
+    `kind` says, four held of 32 and four a token, two places a token in
+    a room of 120: "as-seeded" some 30 pairs; "a-long-tail" the first six
+    tokens on three held experts each, one more than their places;
+    "past-the-room" every token on all four held, 240 pairs."""
+    if kind == "a-long-tail":
+        # a router that reads the first feature alone, for experts 4-6
+        x = x.at[..., 0].set(0.0).at[0, :6, 0].set(1.0)
+        params = dict(params, router=params["router"].at[0].set(0.0)
+                      .at[0, 4:7].set(50.0))
+    if kind == "past-the-room":
+        params = dict(params, selection_bias=jnp.zeros(32).at[4:8].set(10.0))
+    return params, x
+
+
+@pytest.mark.parametrize("kind", ["as-seeded", "a-long-tail",
+                                  "past-the-room"])
+@pytest.mark.parametrize("make,latent", [
+    (gated, 0), (gated, LATENT), (layer, 0), (layer, LATENT)],
+    ids=["gated", "gated-latent", "relu-squared", "relu-squared-latent"])
+def test_in_a_room_under_its_bound_the_layer_is_the_dense_bodys(
+        make, latent, kind, monkeypatch):
+    """A room sized from uniform routing, under `tokens x min(top_k,
+    held)`: output, the input's gradient, the router's and every
+    matrix's are those of `held_experts` / `held_gated_experts` on the
+    same routing and of the float32 sum expert by expert, for experts of
+    two and three matrices, with and without a latent: as the seed
+    routes; with tokens that hold more pairs than they have places (the
+    sum's tail behind the places); and in the step whose pairs pass the
+    room, which takes every held expert over every token (`moe_rows_run`
+    reads tokens x held) and drops no token."""
+    module = make(n_routed=32, held=(4, 4), top_k=4, latent=latent,
+                  shared_d_ff=0)
+    assert moe.token_places(4, 4, 32) == 2 < min(module.top_k, 4)
+    x = jax.random.normal(jax.random.key(12), (3, 20, D))
+    params, x = routed(kind, module, init(module, x, bias=0.01), x)
+    with jax.default_matmul_precision("highest"):
+        out, counters, grads = value_and_gradients(module, params, x)
+        the_layers_own_dense_body(monkeypatch)
+        dense_out, dense_counters, dense_grads = value_and_gradients(
+            module, params, x)
+        want_grads = jax.jit(jax.grad(
+            lambda p, x: jnp.sum(jnp.sin(one_by_one(module, p, x)[0])),
+            argnums=(0, 1)))(params, x)
+        want_out, hit = one_by_one(module, params, x)
+    pairs, busiest = int(hit.sum()), int(hit.sum(axis=1).max())
+    assert float(counters["moe_pairs_here"]) == pairs
+    if kind == "past-the-room":
+        assert pairs == 240 > 120
+        assert float(counters["moe_rows_run"]) == 60 * 4
+        assert float(counters["moe_tokens_unserved"]) == 0
+    else:
+        assert float(counters["moe_rows_run"]) == pairs <= 120
+        assert busiest > 2 or kind == "as-seeded"
+    jax.tree.map(np.testing.assert_array_equal, counters, dense_counters)
+    assert gap(out, dense_out) < 2e-6 and gap(out, want_out) < 2e-6
+    leaves = ["router", "experts_up", "experts_down"] + (
+        ["experts_gate"] if module.gated else []) + (
+        ["latent_down", "latent_up"] if latent else [])
+    for name in leaves:
+        got, dense, want = (jax.tree.leaves(g[0][name])[0] for g in (
+            grads, dense_grads, want_grads))
+        assert gap(got, dense) < 2e-6 and gap(got, want) < 2e-6, name
+    assert gap(grads[1], dense_grads[1]) < 2e-6
+    assert gap(grads[1], want_grads[1]) < 2e-6
 
 
 def test_a_token_with_no_held_expert_gets_nothing_and_costs_no_row():
@@ -165,21 +255,32 @@ def test_tied_experts_pass_the_room_and_no_token_is_dropped(make,
                                    rtol=0, atol=2e-5 * np.abs(want).max())
 
 
-def test_a_token_tied_past_its_places_is_summed_whole():
-    """Pairs that fit the room while one token holds more of them than
-    `top_k`: its experts past the places gathered at once are taken by
-    the loop behind them, forward and backward."""
-    k = jax.random.split(jax.random.key(5), 6)
-    s, e, top_k = 24, 6, 2
+def a_few_busy_tokens(s, e, every):
+    """`hit [s, e]`: every `every`-th token on one expert, token 5 on all
+    `e` and token 11 on four."""
     hit = np.zeros((s, e), bool)
-    hit[np.arange(s), np.arange(s) % e] = True
+    of = np.arange(0, s, every)
+    hit[of, of % e] = True
     hit[5] = True                                   # six pairs on one token
     hit[11, :4] = True
-    hit = jnp.asarray(hit)
+    return jnp.asarray(hit)
+
+
+@pytest.mark.parametrize("places,every,pairs", [(2, 1, 33), (1, 3, 18)],
+                         ids=["two-places", "one-place"])
+def test_a_token_with_more_pairs_than_places_is_summed_whole(places, every,
+                                                             pairs):
+    """Pairs that fit the room while one token holds six of them and one
+    four, in rooms of two places a token and of one: the pairs past the
+    places gathered at once are added behind them, forward and
+    backward."""
+    k = jax.random.split(jax.random.key(5), 6)
+    s, e = 24, 6
+    hit = a_few_busy_tokens(s, e, every)
     weight = jnp.where(hit, jax.random.uniform(k[0], (s, e), minval=0.2), 0.0)
     counts = jnp.sum(hit, axis=0).astype(jnp.int32)
-    assert int(counts.sum()) == 33 <= s * top_k
-    assert int(jnp.max(jnp.sum(hit, axis=1))) == 6 > top_k
+    assert int(counts.sum()) == pairs <= s * places
+    assert int(jnp.max(jnp.sum(hit, axis=1))) == 6 > places
     x = jax.random.normal(k[1], (s, LATENT))
     mats = (0.3 * jax.random.normal(k[2], (e, LATENT, F)),
             0.3 * jax.random.normal(k[3], (e, LATENT, F)),
@@ -191,7 +292,7 @@ def test_a_token_tied_past_its_places_is_summed_whole():
 
     with jax.default_matmul_precision("highest"):
         got = through(lambda x, w, *m: routed_over_pairs(
-            x, w, hit, counts, m, s * top_k))(x, weight, *mats)
+            x, w, hit, counts, m, s * places))(x, weight, *mats)
         want = through(every_expert_over_every_token)(x, weight, *mats)
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
     for name, g, w in zip(("x", "weight", "gate", "up", "down"), *(
@@ -200,6 +301,37 @@ def test_a_token_tied_past_its_places_is_summed_whole():
             w = jnp.where(hit, w, 0.0)
         np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()),
                                    err_msg=name)
+
+
+@pytest.mark.parametrize("a_turn", [2, 5, 1024],
+                         ids=["two-a-turn", "five-a-turn", "all-in-one-turn"])
+@pytest.mark.parametrize("places,every", [(2, 1), (1, 3), (1, 24)],
+                         ids=["two-places", "one-place", "one-place-sparse"])
+def test_the_sum_of_a_tokens_rows_by_hand(places, every, a_turn,
+                                          monkeypatch):
+    """`tokens_of_rows` against a loop over the pairs: whole numbers, so
+    any order of the float32 sums is exact. The pairs past the tokens'
+    places (8, 7 and 7 here) are added `PAST_PLACES` a turn: in one turn,
+    in turns that end inside a token's pairs, and in no turn at all
+    where no token has more pairs than places."""
+    monkeypatch.setattr(moe, "PAST_PLACES", a_turn)
+    s, e = 24, 6
+    hit = a_few_busy_tokens(s, e, every)
+    counts = jnp.sum(hit, axis=0).astype(jnp.int32)
+    lay, _ = moe.lay_pairs(jnp.where(hit, 1.0, 0.0), hit, counts, s * places)
+    y = (jnp.arange(s * places * 3.0).reshape(s * places, 3) % 17) + 1
+    want = np.zeros((s, 3), np.float32)
+    for t, ex in zip(*np.nonzero(np.asarray(hit))):
+        want[t] += np.asarray(y)[int(lay.pos[t, ex])]
+    np.testing.assert_array_equal(jax.jit(moe.tokens_of_rows)(y, lay), want)
+    # and with nobody past their places
+    few = hit.at[5].set(False).at[11].set(False).at[5, 0].set(True)
+    lay, _ = moe.lay_pairs(jnp.where(few, 1.0, 0.0), few, jnp.sum(
+        few, axis=0).astype(jnp.int32), s * places)
+    want = np.zeros((s, 3), np.float32)
+    for t, ex in zip(*np.nonzero(np.asarray(few))):
+        want[t] += np.asarray(y)[int(lay.pos[t, ex])]
+    np.testing.assert_array_equal(jax.jit(moe.tokens_of_rows)(y, lay), want)
 
 
 def test_the_pairs_layout_by_hand():

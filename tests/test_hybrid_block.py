@@ -272,17 +272,30 @@ def test_the_block_is_assembled_from_a_record(monkeypatch, caplog):
           latent=1024, shared_d_ff=5376, router="sigmoid",
           selection_bias=True, scale=5.0, gated=False, n_layers=5,
           dtype=jnp.bfloat16),
-     {"moe_router": 1024, "moe_latent": 1024, "moe_experts_out": 1024,
-      "moe_shared_up": 5376, "moe_experts_up": 8 * 2688}),
-    # solar_open2_l4's experts: 8 of 320 held, gated, no latent
+     # two places a token in the room for its pairs (`token_places`: four
+     # times the 22 x 8 / 512 that uniform routing gives it), and their
+     # layout: 9 bytes a place, 8 a held expert, 4 a token
+     {"moe_router": 1024, "moe_pairs": 43, "moe_latent": 1024,
+      "moe_experts_out": 1024, "moe_shared_up": 5376,
+      "moe_experts_up": 2 * 2688}),
+    # solar_open2_l4's experts: 8 of 320 held, gated, no latent: one place
     (dict(d_model=4096, d_ff=1280, n_routed=320, top_k=8, held=(0, 8),
           latent=0, shared_d_ff=1280, router="sigmoid", selection_bias=True,
           scale=1.0, gated=True, n_layers=4, dtype=jnp.bfloat16),
-     {"moe_router": 640, "mlp_gate": 1280, "mlp_up": 1280,
-      "moe_experts_gate": 10240, "moe_experts_up": 10240}),
+     {"moe_router": 640, "moe_pairs": 39, "mlp_gate": 1280, "mlp_up": 1280,
+      "moe_experts_gate": 1280, "moe_experts_up": 1280}),
+    # lfm2_24b_a2b_l5's: 16 of 64 held, 4 a token: four places, the bound
+    (dict(d_model=2048, d_ff=1536, n_routed=64, top_k=4, held=(0, 16),
+          selection_bias=True, gated=True, n_layers=4, dtype=jnp.bfloat16),
+     {"moe_router": 128, "moe_pairs": 84, "moe_experts_gate": 6144,
+      "moe_experts_up": 6144}),
+    # a share of 2 of 8, 2 a token: a place for every expert held, no layout
+    (dict(d_ff=48, n_routed=8, top_k=2, held=(0, 2), gated=True),
+     {"moe_router": 8, "moe_experts_gate": 96, "moe_experts_up": 96}),
     # none said held: all are; float32: the router's logits count once
     (dict(d_ff=48, n_routed=8), {"moe_router": 8, "moe_experts_up": 384}),
-], ids=["hybrid-E", "solar-experts", "bare"])
+], ids=["hybrid-E", "solar-experts", "lfm2-experts", "a-share-of-two",
+        "bare"])
 def test_an_expert_layers_names_and_widths(fields, want):
     assert expert_block_sizes(**fields) == want
 

@@ -409,23 +409,26 @@ def test_the_step_says_what_it_holds(capacity, family, setting, tx, extra):
 # -- blocks of unequal bytes (a stack whose layers are of several kinds) ----
 
 # nemotron3_super_l11.seq8k's three kinds at 2 x 8192 tokens in bfloat16:
-# 5 state-space layers, 5 expert layers (the router's logits float32), one
-# attention layer of 4 heads of 128
+# 5 state-space layers, 5 expert layers (the router's logits float32; 8 held
+# of 512 and 22 a token: two places a token in the room for its pairs, so
+# the first product is 2 x 2688 features a token where every held expert
+# over every token made 8 x 2688 = 21504, and the pairs' layout has a name),
+# one attention layer of 4 heads of 128
 TOK = 2 * 8192 * 2
 SSM = {"ssm_in_proj": TOK * 2320}
-EXPERTS = {"moe_router": TOK * 1024, "moe_latent": TOK * 1024,
-           "moe_experts_out": TOK * 1024, "moe_shared_up": TOK * 5376,
-           "moe_experts_up": TOK * 21504}
+EXPERTS = {"moe_router": TOK * 1024, "moe_pairs": TOK * 43,
+           "moe_latent": TOK * 1024, "moe_experts_out": TOK * 1024,
+           "moe_shared_up": TOK * 5376, "moe_experts_up": TOK * 5376}
 ATTENDS = {"attn_out": 16777216, "attn_lse": 262144, "qkv_proj": TOK * 768,
            "attn_proj": TOK * 4096, "attn_qkv": 3 * 16777216}
 HYBRID = [(SSM, 5), (EXPERTS, 5), (ATTENDS, 1)]
-HYBRID_ORDER = ("attn_out", "attn_lse", "moe_router", "qkv_proj",
+HYBRID_ORDER = ("attn_out", "attn_lse", "moe_router", "moe_pairs", "qkv_proj",
                 "attn_proj", "ssm_in_proj", "moe_experts_out", "moe_latent",
                 "moe_shared_up", "attn_qkv", "moe_experts_up")
-# what the policy reckons on the chip with the routed experts' first
-# product in the E kind's margin (4 588 793 516 without it) and, since
-# PR 47, the layer's sum over the experts held (3 247 664 812 without it)
-HYBRID_BUDGET = 3_180_555_948
+# what the policy reckons on the chip with the E kind's names in its margin
+# (4 588 793 516 when the scan's kind was the largest; 3 180 555 948 with a
+# first product over every held expert, 21504 features a token, PR 47-50)
+HYBRID_BUDGET = 4_234_702_508
 
 
 def kept_bytes(blocks, names):
@@ -435,11 +438,14 @@ def kept_bytes(blocks, names):
 
 @pytest.mark.parametrize("budget,n_kept", [
     (0, 0), (17039360 - 1, 0), (17039360, 2),
-    (17039360 + 5 * TOK * 1024, 3), (GIB // 2, 5), (892076032, 7), (GIB, 8),
-    (4 * GIB, 10), (HYBRID_BUDGET, 10), (6 * GIB, 11)],
-    ids=["empty", "one-byte-short", "attention", "router", "projections",
-         "the-layers-sum-and-no-latent", "latent", "all", "the-cells-3.18-GB",
-         "the-first-product-too"])
+    (17039360 + 5 * TOK * 1024, 3), (17039360 + 5 * TOK * (1024 + 43), 4),
+    (GIB // 2, 6), (899121152, 8), (GIB, 9), (2_000_000_000, 11),
+    (1_998_028_800 + 5 * TOK * 5376 - 1, 11), (HYBRID_BUDGET, 12),
+    (6 * GIB, 12)],
+    ids=["empty", "one-byte-short", "attention", "router", "the-pairs-layout",
+         "projections", "the-layers-sum-and-no-latent", "latent",
+         "all-but-the-first-product", "the-first-product-a-byte-short",
+         "the-cells-4.23-GB", "room-to-spare"])
 def test_unequal_blocks_are_summed_by_kind_and_count(budget, n_kept):
     got = rp.choose_names(HYBRID, budget)
     assert got == HYBRID_ORDER[:n_kept]
@@ -458,7 +464,8 @@ def test_a_name_costs_only_the_kinds_that_make_it():
     assert kept_bytes(HYBRID, ("qkv_proj",)) == TOK * 768
     assert kept_bytes(HYBRID, ("moe_experts_out",)) == 5 * TOK * 1024
     one_kind = rp.choose_names([(EXPERTS, 5)], GIB)
-    assert one_kind == ("moe_router", "moe_experts_out", "moe_latent")
+    assert one_kind == ("moe_router", "moe_pairs", "moe_experts_out",
+                        "moe_latent")
     assert rp.choose_names([(EXPERTS, 5), ({}, 6)], GIB) == one_kind
 
 
@@ -499,11 +506,11 @@ def test_a_kinds_scratch_is_part_of_its_backwards_room():
 
     args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
                 block_input_bytes=TOK * 4096, head_bytes=2 * TOK * 4096)
-    # the expert layers without their routed experts' first product and
-    # their sum, as they were reckoned before those had names: 7424
-    # features a token
-    blocks = [(SSM, 5), ({n: b for n, b in EXPERTS.items()
-                          if not n.startswith("moe_experts_")}, 5),
+    # the expert layers without their routed experts' first product, its
+    # pairs' layout and their sum, as they were reckoned before those had
+    # names: 7424 features a token
+    blocks = [(SSM, 5), ({n: b for n, b in EXPERTS.items() if not
+                          n.startswith(("moe_experts_", "moe_pairs"))}, 5),
               (ATTENDS, 1)]
     plain = rp.budget_bytes(16_909_336_064, blocks=blocks, **args)
     assert rp.budget_bytes(16_909_336_064, blocks=blocks,
@@ -518,8 +525,9 @@ def test_a_kinds_scratch_is_part_of_its_backwards_room():
     small = rp.budget_bytes(16_909_336_064, blocks=blocks,
                             scratch=[TOK * 1024, 0, 0], **args)
     assert small == plain
-    # with the first product (21504 features) the expert layers are the
-    # largest kind by far, and the scan's masks change nothing
+    # with the first product (5376 features in a room of two places a
+    # token) the expert layers are the largest kind still, 13867 features
+    # against 8464, and the scan's masks change nothing
     assert rp.budget_bytes(
         16_909_336_064, blocks=HYBRID, scratch=[TOK * scratch, 0, 0],
         **args) == rp.budget_bytes(16_909_336_064, blocks=HYBRID, **args)
@@ -578,11 +586,14 @@ def test_the_hybrid_model_states_its_three_kinds():
     model = MODELS.get("NemotronH")(**NEMOTRON_CELL)
     ssm, experts, attends = model._block_kinds()
     assert (ssm.count, experts.count, attends.count) == (5, 5, 1)
-    # the routed experts' first product: 8 held experts of 2688 features
-    # and their sum over the experts held, where `latent_up` reads it
-    assert experts.widths == {"moe_router": 1024, "moe_latent": 1024,
-                              "moe_experts_out": 1024, "moe_shared_up": 5376,
-                              "moe_experts_up": 8 * 2688}
+    # the routed experts' first product: two places a token in the room
+    # for its pairs (`moe.token_places(22, 8, 512)`) of 2688 features,
+    # their layout, and the sum over the experts held, where `latent_up`
+    # reads it
+    assert experts.widths == {"moe_router": 1024, "moe_pairs": 43,
+                              "moe_latent": 1024, "moe_experts_out": 1024,
+                              "moe_shared_up": 5376,
+                              "moe_experts_up": 2 * 2688}
     assert {n: TOK * w for n, w in experts.widths.items()} == EXPERTS
     assert {n: TOK * w for n, w in ssm.widths.items()} == SSM
     assert (attends.attn_heads, attends.head_dim) == (4, 128)
@@ -590,43 +601,52 @@ def test_the_hybrid_model_states_its_three_kinds():
     # product runs over the pairs, whose room is 22 rows a token
     whole = MODELS.get("NemotronH")(pattern="E")._block_kinds()[0]
     assert whole.widths["moe_experts_up"] == 22 * 2688
-    # the E kind, 243 -> 948 MB, is the largest with the product in it:
-    # the margin grows by twice 705 MB, and by twice 33.5 MB with the sum
+    # the E kind, 243 -> 454 MB, is the largest with the product in it
+    # (948 MB when the product ran over every held expert): the margin
+    # grows by twice 176 MB, twice 33.5 MB with the sum, twice 1.4 MB with
+    # the layout
     args = dict(held_bytes=8410386260, outside_param_bytes=536887296,
                 block_input_bytes=TOK * 4096, head_bytes=2 * TOK * 4096)
     budget = rp.budget_bytes(16_909_336_064, blocks=HYBRID,
                              scratch=[TOK * ssm.scratch, 0, 0], **args)
     assert budget == HYBRID_BUDGET == (
         16_909_336_064 - 8410386260 - 536887296 - 13 * TOK * 4096
-        - 2 * TOK * (1024 + 1024 + 1024 + 5376 + 21504)
+        - 2 * TOK * (1024 + 43 + 1024 + 1024 + 5376 + 5376)
         - rp.HEADROOM_BYTES)
-    # (before, the scan's kind with its masks was: 2320 + 6144 features)
-    assert budget == 4_588_793_516 - 2 * TOK * (29952 - 2320 - 6144)
-    assert budget == 3_247_664_812 - 2 * TOK * 1024
+    # (before, the scan's kind with its masks was: 2320 + 6144 features;
+    # with a first product over every held expert the E kind was 29952)
+    assert budget == 4_588_793_516 - 2 * TOK * (13867 - 2320 - 6144)
+    assert budget == 3_180_555_948 + 2 * TOK * (29952 - 13867)
 
 
-def test_the_hybrid_cell_keeps_its_ten_names_and_not_the_first_product():
-    """`nemotron3_super_l11.seq8k` at its chip's budget: the first product
-    is 705 MB a layer, 3.52 GB over five, more than the whole budget; the
-    nine names kept before it had a name (1.823 GB) still fit, and so
-    does the layers' sum beside them (33.5 MB a layer, 168 MB over five)."""
+def test_the_hybrid_cell_keeps_every_name_and_the_first_product_too():
+    """`nemotron3_super_l11.seq8k` at its chip's budget: in a room of two
+    places a token the first product is 176 MB a layer, 0.881 GB over
+    five, and fits beside the ten names kept before (1.991 GB) and the
+    pairs' layout (1.4 MB a layer): every name the kinds make. Over
+    every held expert it was 705 MB a layer, 3.52 GB over five, more than
+    the whole budget it left itself (PR 43-50)."""
     got = rp.choose_names(HYBRID, HYBRID_BUDGET)
-    assert got == HYBRID_ORDER[:10]
-    assert kept_bytes(HYBRID, got) == 1_823_211_520 + 5 * TOK * 1024 \
-        == 1_990_983_680 <= HYBRID_BUDGET
-    assert kept_bytes(HYBRID, ("moe_experts_up",)) == 5 * TOK * 21504 \
-        == 3_523_215_360 > HYBRID_BUDGET
-    # nor would the budget it had before the margin held the product
-    assert rp.choose_names(HYBRID, 4_588_793_516) == got
+    assert got == HYBRID_ORDER
+    assert kept_bytes(HYBRID, got) == 1_990_983_680 + 5 * TOK * (43 + 5376) \
+        == 2_878_832_640 <= HYBRID_BUDGET
+    over_every_token = [(SSM, 5), (dict(
+        EXPERTS, moe_experts_up=TOK * 21504), 5), (ATTENDS, 1)]
+    assert kept_bytes(over_every_token, ("moe_experts_up",)) \
+        == 3_523_215_360 > 3_180_555_948
+    assert rp.choose_names(over_every_token, 3_180_555_948) \
+        == HYBRID_ORDER[:11]
 
 
 # the routed experts' two forms under the stacks (models/moe.ExpertLayer
-# reads which from its shapes): the tiny stacks hold all 8 experts and a
-# token takes 2, so their products run over the pairs; a share of 2 held
-# takes every held expert over every token, as the hybrid's and solar's
-# cells do on the chip (8 held of which a token takes 22 or 8). The first
-# products are 2 x 48 features a token either way; the layout of the pairs
-# (`moe_pairs`, 86 bytes a token: 22 of float32's four) is the pairs' alone
+# reads which from its shapes, `token_places`): the tiny stacks hold all 8
+# experts and a token takes 2, two places a token, so their products run
+# over the pairs; a share of 2 held of the 8 has a place for every expert
+# held and takes every held expert over every token. (The cells' own
+# layers, 8 held of 512 or of 320, have two places and one: the tables
+# above.) The first products are 2 x 48 features a token either way; the
+# layout of the pairs (`moe_pairs`, 86 bytes a token: 22 of float32's four)
+# is the pairs' alone
 FORMS = pytest.mark.parametrize("held,pairs", [((0, 0), 22), ((0, 2), 0)],
                                 ids=["over-the-pairs", "every-held-expert"])
 
@@ -688,10 +708,13 @@ def test_hybrid_model_reckons_three_kinds_and_says_so(monkeypatch, caplog,
 # block, each with the gated experts, at 1 x 8192 tokens in bfloat16 --------
 
 SOLAR_TOK = 1 * 8192 * 2
-SOLAR_EXPERTS = {"moe_router": SOLAR_TOK * 640, "mlp_gate": SOLAR_TOK * 1280,
-                 "mlp_up": SOLAR_TOK * 1280,
-                 "moe_experts_gate": SOLAR_TOK * 10240,
-                 "moe_experts_up": SOLAR_TOK * 10240}
+# (8 held of 320 and 8 a token: one place a token in the room for its pairs,
+# so each first product is 1280 features a token where every held expert
+# over every token made 10240, and the pairs' layout has a name)
+SOLAR_EXPERTS = {"moe_router": SOLAR_TOK * 640, "moe_pairs": SOLAR_TOK * 39,
+                 "mlp_gate": SOLAR_TOK * 1280, "mlp_up": SOLAR_TOK * 1280,
+                 "moe_experts_gate": SOLAR_TOK * 1280,
+                 "moe_experts_up": SOLAR_TOK * 1280}
 SOLAR_KDA = {"kda_in_proj": SOLAR_TOK * 3072, "kda_out_proj": SOLAR_TOK * 4096,
              **SOLAR_EXPERTS}
 SOLAR_ATTN = {"attn_out": 16777216, "attn_lse": 262144,
@@ -699,23 +722,27 @@ SOLAR_ATTN = {"attn_out": 16777216, "attn_lse": 262144,
               "attn_proj": SOLAR_TOK * 4096, "attn_qkv": 3 * 16777216,
               **SOLAR_EXPERTS}
 SOLAR = [(SOLAR_KDA, 3), (SOLAR_ATTN, 1)]
-SOLAR_ORDER = ("attn_out", "attn_lse", "moe_router", "qkv_proj", "attn_gate",
-               "attn_proj", "kda_in_proj", "kda_out_proj", "mlp_gate",
-               "mlp_up", "attn_qkv", "moe_experts_gate", "moe_experts_up")
-# what the policy reckons on the chip with the experts' two products in
-# the KDA kind's margin (3 123 629 792 without them)
-SOLAR_BUDGET = 2_452_541_152
+SOLAR_ORDER = ("attn_out", "attn_lse", "moe_router", "moe_pairs", "qkv_proj",
+               "attn_gate", "attn_proj", "kda_in_proj", "kda_out_proj",
+               "mlp_gate", "mlp_up", "attn_qkv", "moe_experts_gate",
+               "moe_experts_up")
+# what the policy reckons on the chip with the experts' two products and
+# their pairs' layout in the KDA kind's margin (3 123 629 792 without them;
+# 2 452 541 152 with two products over every held expert, PR 43-50)
+SOLAR_BUDGET = 3_038_465_760
 
 
 @pytest.mark.parametrize("budget,n_kept", [
     (0, 0), (17039360, 2), (17039360 + 4 * SOLAR_TOK * 640, 3),
-    (GIB // 4, 6), (GIB // 2, 8), (734_265_344, 11), (1_400_000_000, 11),
-    (734_265_344 + 4 * SOLAR_TOK * 10240, 12), (2_000_000_000, 12),
-    (SOLAR_BUDGET, 13)],
-    ids=["empty", "attention", "router", "attention-block", "kda",
+    (17039360 + 4 * SOLAR_TOK * (640 + 39), 4),
+    (GIB // 4, 7), (GIB // 2, 9), (736_821_248, 12), (800_000_000, 12),
+    (736_821_248 + 4 * SOLAR_TOK * 1280, 13), (900_000_000, 13),
+    (SOLAR_BUDGET, 14)],
+    ids=["empty", "attention", "router", "the-pairs-layout",
+         "attention-block", "kda",
          "neither-product", "neither-product-with-room-to-spare",
-         "the-gate-product-alone", "the-gate-product-and-0.6-GB",
-         "the-cells-2.45-GB"])
+         "the-gate-product-alone", "the-gate-product-and-79-MB",
+         "the-cells-3.04-GB"])
 def test_the_two_new_kinds_keep_a_prefix_that_fits(budget, n_kept):
     got = rp.choose_names(SOLAR, budget)
     assert got == SOLAR_ORDER[:n_kept]
@@ -723,11 +750,13 @@ def test_the_two_new_kinds_keep_a_prefix_that_fits(budget, n_kept):
     if n_kept < len(SOLAR_ORDER):
         step = 2 if n_kept == 0 else 1
         assert kept_bytes(SOLAR, SOLAR_ORDER[:n_kept + step]) > budget
-    # every older name the two kinds make is 0.734 GB, each of the routed
-    # experts' products 0.671 GB over the four layers: 2.076 GB, inside
-    # the 2.45 GB the policy reckons on the chip
-    assert kept_bytes(SOLAR, SOLAR_ORDER[:11]) == 734_265_344
-    assert kept_bytes(SOLAR, SOLAR_ORDER) == 2_076_442_624 <= SOLAR_BUDGET
+    # every older name the two kinds make and the pairs' layout are 0.737
+    # GB, each of the routed experts' products 0.084 GB over the four
+    # layers in a room of one place a token (0.671 over every held
+    # expert: 2.076 GB in all then): 0.905 GB, inside the 3.04 GB the
+    # policy reckons on the chip
+    assert kept_bytes(SOLAR, SOLAR_ORDER[:12]) == 736_821_248
+    assert kept_bytes(SOLAR, SOLAR_ORDER) == 904_593_408 <= SOLAR_BUDGET
 
 
 def test_the_solar_model_states_its_two_kinds():
@@ -743,15 +772,16 @@ def test_the_solar_model_states_its_two_kinds():
     assert {n: SOLAR_TOK * w for n, w in kda.widths.items()} == SOLAR_KDA
     assert attn.widths == {"qkv_proj": 1280, "attn_gate": 1024,
                            "attn_proj": 4096, "moe_router": 640,
-                           "mlp_gate": 1280, "mlp_up": 1280,
-                           "moe_experts_gate": 10240,
-                           "moe_experts_up": 10240}
+                           "moe_pairs": 39, "mlp_gate": 1280, "mlp_up": 1280,
+                           "moe_experts_gate": 1280,
+                           "moe_experts_up": 1280}
     # no latent, so no projection reads the routed experts' sum and
     # neither kind states `moe_experts_out`: to the byte what it was
     assert "moe_experts_out" not in {**kda.widths, **attn.widths}
-    # 8 held experts of 1280 features; none said held: all 320 are, of
-    # which a token takes 8: the pairs' room is 8 rows a token
-    assert kda.widths["moe_experts_gate"] == 8 * 1280
+    # 8 held of 320 experts of 1280 features, 8 a token: one place a
+    # token (`moe.token_places(8, 8, 320)`); none said held: all 320 are,
+    # of which a token takes 8: the pairs' room is 8 rows a token
+    assert kda.widths["moe_experts_gate"] == 1 * 1280
     whole = MODELS.get("SolarOpen2")(pattern="K")._block_kinds()[0]
     assert whole.widths["moe_experts_up"] == 8 * 1280
     assert (attn.attn_heads, attn.head_dim, attn.scratch) == (8, 128, 0)
@@ -768,9 +798,11 @@ def test_the_solar_model_states_its_two_kinds():
                       - 6 * SOLAR_TOK * 4096
                       - 2 * (sum(SOLAR_KDA.values()) + 536870912)
                       - rp.HEADROOM_BYTES)
-    # the KDA kind's backward holds both products (335.5 MB) and their
-    # cotangents: 671 MB less than before they had names
-    assert budget == SOLAR_BUDGET == 3_123_629_792 - 4 * SOLAR_TOK * 10240
+    # the KDA kind's backward holds both products (42 MB) and their
+    # cotangents, and the pairs' layout: 85 MB less than before they had
+    # names (671 MB less when they ran over every held expert)
+    assert budget == SOLAR_BUDGET == 3_123_629_792 - 2 * SOLAR_TOK * (
+        2 * 1280 + 39)
     assert rp.choose_names(SOLAR, budget) == SOLAR_ORDER
 
 

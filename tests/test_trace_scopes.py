@@ -253,6 +253,9 @@ def test_model_counters_reach_the_flight_record(tmp_path):
         assert 0.5 * 1024 < r["moe_pairs_here"] < 1.5 * 1024
         assert r["moe_load_max_over_mean"] >= 1.0
         assert 0 <= r["moe_tokens_unserved"] <= 512
+        # every expert family carries the counter that says which way a
+        # step went: over the pairs here, two places a token
+        assert r["moe_rows_run"] == r["moe_pairs_here"]
     assert all("moe_pairs_here" not in r
                for r in trainer.recorder.last() if "loss" not in r)
     # the configuration states a selection_bias_rate: six steps have moved
